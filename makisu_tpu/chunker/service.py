@@ -23,7 +23,7 @@ from concurrent.futures import Future
 
 import numpy as np
 
-from makisu_tpu.ops import sha256
+from makisu_tpu.chunker import route as _route
 from makisu_tpu.chunker.cdc import _BUCKETS
 from makisu_tpu.utils import metrics
 
@@ -116,9 +116,9 @@ class HashService:
         t0 = time.monotonic()
         try:
             from makisu_tpu.ops import backend as _backend
-            from makisu_tpu.ops import sha256_pallas
+            route = _route.chunk_route(shared=True)
             words = _backend.sync_bounded(
-                sha256_pallas.sha256_lanes_auto(data, lengths),
+                _route.hash_lanes(route, data, lengths),
                 "shared-service digest readback")
         except BaseException as e:  # noqa: BLE001
             metrics.counter_add("makisu_hash_batch_failures_total",
@@ -144,8 +144,7 @@ class HashService:
         metrics.counter_add("makisu_hash_batches_total", bucket=cap)
         metrics.counter_add("makisu_bytes_hashed_total",
                             int(lengths.sum()),
-                            backend=sha256_pallas.last_route,
-                            path="service")
+                            backend=route.sha, path="service")
         metrics.observe("makisu_hash_batch_seconds",
                         time.monotonic() - t0, bucket=cap)
         metrics.observe("makisu_hash_batch_fill", len(batch),

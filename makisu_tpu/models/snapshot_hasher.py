@@ -30,13 +30,13 @@ class SnapshotHasher:
     lanes: int = 1024               # chunk lanes hashed per step
     lane_cap: int = 16 * 1024       # bytes per lane buffer
     # Gear route: None = auto (the fused Pallas kernel on TPU backends,
-    # matching the production chunker's default; XLA elsewhere). The
-    # driver's compile gate (__graft_entry__.entry) pins False so a
-    # Mosaic regression can never fail the single-chip compile check.
-    # SHA stays on the XLA SSA path inside this jitted model until the
-    # sha256_pallas kernel has device-validated digests (a jitted
-    # forward cannot run the per-process parity probe the production
-    # dispatch requires — chunk digests are cache identity).
+    # matching the production chunker's default; XLA elsewhere), which
+    # is what the driver's compile gate (__graft_entry__.entry)
+    # compiles. SHA stays on the XLA SSA path inside this jitted model:
+    # a TPU build hashes chunks with the sha256_pallas kernel
+    # (chunker/route.py), but behind a per-process parity probe against
+    # hashlib that a jitted forward cannot run — chunk digests are
+    # cache identity.
     use_pallas: bool | None = None
 
     def example_inputs(self) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -59,7 +59,7 @@ class SnapshotHasher:
 
         use_pallas = self.use_pallas
         if use_pallas is None:
-            use_pallas = (gear_pallas.pallas_enabled()
+            use_pallas = (gear_pallas.env_enabled()
                           and jax.default_backend() != "cpu"
                           and self.block_bytes
                           % (gear_pallas.ROW_TILE * gear_pallas.ROW)

@@ -354,11 +354,6 @@ def isa_route() -> str | None:
     return f"gear={_isa_route[0]},sha={_isa_route[1]}"
 
 
-def isa_label() -> str:
-    """``isa_route()`` for metric labels: never None."""
-    return isa_route() or "unavailable"
-
-
 def isa_route_if_resolved() -> str | None:
     """Like :func:`isa_route` but never forces the library load —
     for telemetry on commands that may not touch the hash path."""

@@ -130,25 +130,24 @@ def bytes_to_words(msg: jax.Array) -> jax.Array:
 # lax.scan (tunable via a MAKISU_TPU_SHA_UNROLL knob, now retired)
 # whose carry stacked the state ([8, L]) and shifted the 16-word message
 # schedule ([16, L]) with a concatenate EVERY round — ~256KB of pure
-# relayout copies per round per 4096 lanes, measured 1.5 GB/s on a real
-# v5e. The SSA formulation below keeps every word in its own loop-carried
-# variable (the schedule window rotates by variable renaming: zero
-# copies, no gather, static round indices) with HLO size bounded by
-# peeling rounds 0-15 and scanning 3 groups of 16 schedule rounds — a
-# 16-round group rotates the window exactly once, so the scan carry maps
-# positionally.
+# relayout copies per round per 4096 lanes. The SSA formulation below
+# keeps every word in its own loop-carried variable (the schedule
+# window rotates by variable renaming: zero copies, no gather, static
+# round indices) with HLO size bounded by peeling rounds 0-15 and
+# scanning 3 groups of 16 schedule rounds — a 16-round group rotates
+# the window exactly once, so the scan carry maps positionally.
 
-# Unroll factors for the two scans, swept on a real v5e (2026-07, this
-# repo's device session): the inner 16-round-group scan and the outer
-# block scan. Measured on 4096x16KiB lanes, device-side loop timing:
-#   inner=1 outer=1:  8.8 GB/s     inner=3 outer=1: 21.9 GB/s
-#   inner=1 outer=2:  8.3 GB/s     inner=3 outer=4: 24.0 GB/s
-# (the pre-SSA scan formulation measured 1.5 GB/s on the same shapes).
-# Defaults are chosen PER BACKEND at trace time: the swept optimum on
-# accelerators, 1/1 on CPU where the unrolled body (192 inlined rounds
-# per scan step) explodes XLA:CPU compile time and throughput is
-# emulation anyway. Env-tunable for other TPU generations. NOT cache
-# identity — digests are identical at any unroll.
+# Unroll factors for the two scans: the inner 16-round-group scan and
+# the outer block scan. 3 and 4 are one v5e's optimum as an earlier
+# round's builders swept it (no record of the sweep survives; the
+# figures are "not measured" until a ledger row carries them). v5e is
+# the one device there is, so every backend that is not the CPU gets
+# them; another TPU generation has to be swept, not assumed
+# (chip_smoke.py refuses a device_kind it does not know). On the CPU
+# the unrolled body (192 inlined rounds per scan step) explodes XLA:CPU
+# compile time, so it runs 1/1. Chosen at trace time from the
+# process's backend; env-tunable. NOT cache identity — digests are
+# identical at any unroll.
 def _unroll(env_key: str, tpu_default: int) -> int:
     val = _os.environ.get(env_key, "")
     if val:

@@ -20,10 +20,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from makisu_tpu.ops import gear, sha256
@@ -65,18 +62,6 @@ def gear_bitmap_sharded(mesh: Mesh, avg_bits: int = gear.DEFAULT_AVG_BITS):
     return jax.jit(_shard)
 
 
-def _mark_varying(x: jax.Array, axes: tuple[str, ...]) -> jax.Array:
-    """Mark a replicated constant as device-varying for shard_map's
-    per-axis typing. The API moved across jax releases — ``pcast``
-    (typing prototype) → ``pvary`` (0.6+) — and older releases have no
-    varying-ness typing at all, where the value is correct as-is."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axes)
-    return x
-
-
 def sha256_lanes_sharded(mesh: Mesh):
     """Jitted ragged-lane SHA-256 with lanes spread over every device."""
     lanes_spec = P((DATA_AXIS, SEQ_AXIS), None)
@@ -93,7 +78,8 @@ def sha256_lanes_sharded(mesh: Mesh):
         # accordingly.
         state0 = jnp.broadcast_to(jnp.asarray(sha256._H0)[:, None],
                                   (8, data.shape[0]))
-        state0 = _mark_varying(state0, (DATA_AXIS, SEQ_AXIS))
+        state0 = jax.lax.pcast(state0, (DATA_AXIS, SEQ_AXIS),
+                               to="varying")
         return sha256.sha256_lanes_impl(data, lengths, init_state=state0)
 
     return jax.jit(_shard)

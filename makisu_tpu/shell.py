@@ -9,7 +9,6 @@ from __future__ import annotations
 import os
 import pwd
 import subprocess
-import sys
 import threading
 
 from makisu_tpu.utils import logging as log
@@ -35,15 +34,7 @@ def exec_command(workdir: str, user: str, *argv: str,
     because cache pushes run on background threads during builds).
     """
     run_env = dict(os.environ if env is None else env)
-    if sys.version_info >= (3, 11):
-        popen_kwargs: dict = {"process_group": 0}
-    else:
-        # Popen(process_group=...) is 3.11+; older versions get
-        # start_new_session (setsid in the C child path — a new session
-        # IS a new process group, and it's async-signal-safe, unlike a
-        # preexec_fn, which matters because cache pushes run on
-        # background threads during builds).
-        popen_kwargs = {"start_new_session": True}
+    popen_kwargs: dict = {"process_group": 0}
     if user:
         uid, gid = sysutils.resolve_chown(user)
         popen_kwargs.update(user=uid, group=gid, extra_groups=[])

@@ -90,9 +90,13 @@ def _make_template(root: str, index: int, files: int,
     os.makedirs(src, exist_ok=True)
     for i in range(files):
         body = [f"# template {index} module {i}\n"]
-        line = f"payload_{index}_{i} = {i}\n"
-        while sum(len(s) for s in body) < file_kb * 1024:
-            body.append(line * 16)
+        block = f"payload_{index}_{i} = {i}\n" * 16
+        # (counted as it grows: re-summing the list per block made a
+        # 1 MiB file cost millions of len() calls)
+        size = len(body[0])
+        while size < file_kb * 1024:
+            body.append(block)
+            size += len(block)
         with open(os.path.join(src, f"mod{i}.py"), "w") as f:
             f.write("".join(body))
     # A stable base/ layer edits never touch: warm rebuilds HIT its

@@ -1,19 +1,17 @@
 """Device-session ledger: one durable record per backend-probe attempt.
 
-Every bench round r01–r05 died at device-backend init with nothing
-finer than ``"died in: backend"`` — each attempt's evidence (how far
-init got, where it parked, which attachment it was pointed at) lived
-and died with the process. This module is the cross-session record:
-every probe attempt — bench child, build-path ``backend_ready()``,
-worker warm probe — appends a ``makisu-tpu.deviceprobe.v1`` line to
-``benchmarks/device_sessions/device_probes.jsonl`` (the artifact
-bench.py has promised in comments since round 3; failed sessions are
-exactly the data the device-route fix needs).
+A probe attempt's evidence (how far init got, where it parked, which
+attachment it was pointed at) otherwise lives and dies with the
+process. This module is the cross-session record: every probe attempt
+— build-path ``backend_ready()``, worker warm probe — appends a
+``makisu-tpu.deviceprobe.v1`` line to
+``benchmarks/device_sessions/device_probes.jsonl`` (git-ignored;
+failed sessions are exactly the data a device-route fix needs).
 
 Record shape (written by ``ops/backend.py``'s watcher thread):
 
     {"schema": "makisu-tpu.deviceprobe.v1", "ts": ..., "pid": ...,
-     "source": "build|worker|bench",
+     "source": "build|worker",
      "platform": "<JAX_PLATFORMS or (default)>",
      "attachment": {"key": <hashed attachment-env fingerprint>,
                     "vars": [<attachment var NAMES present>]},
@@ -35,8 +33,8 @@ dominates the wedges, at which frame, per-attachment verdict history,
 and when the route was last healthy.
 
 Path resolution: ``$MAKISU_TPU_DEVICE_SESSIONS_DIR`` wins (empty value
-disables recording entirely); unset, the ledger lands next to the
-bench evidence files in ``<repo>/benchmarks/device_sessions``.
+disables recording entirely); unset, the ledger lands in
+``<repo>/benchmarks/device_sessions``.
 Recording is additionally gated by ``ops/backend.py`` on a device
 actually being configured, so CPU-only runs don't write unless the
 env var opts them in (CI's healthy-path smoke does exactly that).
@@ -79,8 +77,8 @@ def ledger_path() -> str | None:
 
 def append_record(record: dict) -> str | None:
     """Append one record as a single ``O_APPEND`` write (POSIX keeps
-    concurrent writers' lines whole — a worker's warm probe and a
-    bench child can share the file). Returns the path written, or
+    concurrent writers' lines whole — two processes' probes can share
+    the file). Returns the path written, or
     None when recording is disabled."""
     path = ledger_path()
     if path is None:
@@ -100,8 +98,8 @@ def append_record(record: dict) -> str | None:
 
 def read_records(path: str | None = None) -> list[dict]:
     """Load deviceprobe records from a ledger file, a sessions
-    directory (every ``*.jsonl`` inside — the bench evidence files
-    interleave, their non-matching schemas are skipped), or the
+    directory (every ``*.jsonl`` inside — lines of another schema
+    are skipped), or the
     default directory (``path=None``). Missing paths yield ``[]``;
     torn final lines of a killed process are salvaged like every
     other JSONL artifact."""

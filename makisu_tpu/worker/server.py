@@ -726,11 +726,16 @@ def _warm_probe_wanted() -> bool:
     if platforms:
         return platforms.lower() != "cpu"
     # JAX_PLATFORMS unset: default platform discovery may still find an
-    # accelerator. The attachment env vars (the same signal the probe's
-    # wedge-cache key uses) say whether one is configured.
-    from makisu_tpu.ops.backend import ATTACHMENT_ENV_PREFIXES
+    # accelerator. The attachment env vars (the same signal the
+    # device-session ledger fingerprints) say whether one is
+    # configured; per-process ones say nothing about a device.
+    from makisu_tpu.ops.backend import (
+        ATTACHMENT_ENV_EXCLUDE,
+        ATTACHMENT_ENV_PREFIXES,
+    )
     from makisu_tpu.utils import logging as log
-    if any(k.startswith(ATTACHMENT_ENV_PREFIXES) for k in os.environ):
+    if any(k.startswith(ATTACHMENT_ENV_PREFIXES)
+           and k not in ATTACHMENT_ENV_EXCLUDE for k in os.environ):
         return True
     log.info("warm probe gated off (no device platform configured); "
              "set MAKISU_TPU_WORKER_WARM_PROBE=1 if this host has an "

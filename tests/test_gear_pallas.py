@@ -85,36 +85,49 @@ def test_gear_bitmap_flat_matches_staged_rows(start, live):
     np.testing.assert_array_equal(got, want)
 
 
-def test_chunk_session_falls_back_to_xla_on_kernel_failure(monkeypatch):
-    """A Pallas failure must downgrade to the XLA gear path (identical
-    chunks), not degrade fingerprinting."""
+@pytest.mark.parametrize("v2", ["", "1"])
+def test_kernel_failure_fails_the_session(monkeypatch, v2):
+    """A kernel the compiler refuses propagates: the session raises
+    with the kernel's reason. No breaker hands the scan to another
+    route (v2 to v1, v1 to XLA) — that would change what a build
+    measures without a word — and only MAKISU_TPU_CHUNK_STRICT=0
+    degrades the layer."""
     # Kernel-route test: pin off the native CPU route (it never
     # touches Pallas, so the simulated failure would not fire).
     monkeypatch.setenv("MAKISU_TPU_CHUNK_NATIVE", "0")
+    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
+    monkeypatch.setenv("MAKISU_TPU_PALLAS_V2", v2)
     from makisu_tpu.chunker.cdc import ChunkSession
 
     payload = np.random.default_rng(11).integers(
         0, 256, size=400_000, dtype=np.uint8).tobytes()
-
-    def run():
-        s = ChunkSession(block=128 * 1024)
-        s.update(payload)
-        return [(c.offset, c.length, c.digest) for c in s.finish()]
-
-    baseline = run()
+    other_routes = []
 
     def boom(*a, **k):
         raise RuntimeError("synthetic Mosaic rejection")
 
-    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
-    monkeypatch.setattr(gear_pallas, "gear_bitmap_flat", boom)
-    monkeypatch.setattr(gear_pallas, "_broken", False)
-    try:
-        assert run() == baseline          # XLA fallback, same cuts
-        assert gear_pallas._broken        # and the route is disabled
-        assert not gear_pallas.pallas_enabled()
-    finally:
-        gear_pallas._broken = False
+    def other(*a, **k):
+        other_routes.append(1)
+        raise AssertionError("the scan moved to another route")
+
+    failing = "gear_bitmap_flat2" if v2 else "gear_bitmap_flat"
+    standby = "gear_bitmap_flat" if v2 else "gear_bitmap_flat2"
+    monkeypatch.setattr(gear_pallas, failing, boom)
+    monkeypatch.setattr(gear_pallas, standby, other)
+    monkeypatch.setattr(gear, "gear_bitmap", other)
+
+    monkeypatch.delenv("MAKISU_TPU_CHUNK_STRICT", raising=False)
+    s = ChunkSession(block=128 * 1024)
+    with pytest.raises(RuntimeError, match="synthetic Mosaic rejection"):
+        s.update(payload)
+    assert not other_routes
+
+    monkeypatch.setenv("MAKISU_TPU_CHUNK_STRICT", "0")
+    s = ChunkSession(block=128 * 1024)
+    s.update(payload)
+    assert s.finish() == []
+    assert "synthetic Mosaic rejection" in s._degraded
+    assert not other_routes
 
 
 @pytest.mark.parametrize("n_live", [1, 100, 33000, 200000])
@@ -154,50 +167,6 @@ def test_chunk_session_v2_path_matches(monkeypatch):
     monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
     monkeypatch.setenv("MAKISU_TPU_PALLAS_V2", "1")
     assert run() == baseline
-
-
-def test_v2_failure_falls_back_to_v1_not_xla(monkeypatch):
-    """A v2-kernel failure must trip ONLY v2's breaker (advisor r3):
-    the production-default v1 route — with its measured device win —
-    keeps running; chunks are identical either way."""
-    # Kernel-route test: pin off the native CPU route (it never
-    # touches Pallas, so the simulated failure would not fire).
-    monkeypatch.setenv("MAKISU_TPU_CHUNK_NATIVE", "0")
-    from makisu_tpu.chunker.cdc import ChunkSession
-
-    payload = np.random.default_rng(13).integers(
-        0, 256, size=400_000, dtype=np.uint8).tobytes()
-
-    def run():
-        s = ChunkSession(block=128 * 1024)
-        s.update(payload)
-        return [(c.offset, c.length, c.digest) for c in s.finish()]
-
-    baseline = run()
-
-    def boom(*a, **k):
-        raise RuntimeError("synthetic v2 Mosaic rejection")
-
-    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
-    monkeypatch.setenv("MAKISU_TPU_PALLAS_V2", "1")
-    monkeypatch.setattr(gear_pallas, "gear_bitmap_flat2", boom)
-    v1_calls = []
-    real_flat = gear_pallas.gear_bitmap_flat
-
-    def traced_v1(*a, **k):
-        v1_calls.append(1)
-        return real_flat(*a, **k)
-
-    monkeypatch.setattr(gear_pallas, "gear_bitmap_flat", traced_v1)
-    try:
-        assert run() == baseline
-        assert gear_pallas._v2_broken      # v2 disabled...
-        assert not gear_pallas._broken     # ...v1 breaker untouched
-        assert gear_pallas.pallas_enabled()
-        assert not gear_pallas.v2_enabled()
-        assert v1_calls                    # blocks rode the v1 kernel
-    finally:
-        gear_pallas._v2_broken = False
 
 
 def test_gear_bitmap_batch_matches_xla_above_window():

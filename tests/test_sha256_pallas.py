@@ -1,19 +1,20 @@
-"""SHA-256 Pallas kernel: dispatch gating, parity-probe breaker, and
+"""SHA-256 Pallas kernel: route selection, the parity probe, and
 (opt-in, slow on CPU) interpret-mode correctness.
 
 The kernel's round math is sha256._schedule_rounds16 / _round — the
 exact functions the heavily-tested XLA path runs — so CPU CI focuses on
-the dispatch/breaker logic; bit-level kernel validation runs on device
-(bench.py _sha_ab_gbps asserts digest parity before timing) and via the
-per-process parity probe in production."""
+the dispatch logic; bit-level kernel validation runs on the chip
+(benchmarks/kernel_check.py compares every digest with hashlib at both
+production shapes) and via the per-process parity probe in production.
+"""
 
 import os
 
-import jax
 import numpy as np
 import pytest
 
-from makisu_tpu.ops import gear_pallas, sha256_pallas
+from makisu_tpu.chunker import route
+from makisu_tpu.ops import sha256_pallas
 
 
 def _hashlib_digests(data, lengths):
@@ -23,126 +24,181 @@ def _hashlib_digests(data, lengths):
             for i in range(len(lengths))]
 
 
+def _hashlib_kernel(calls):
+    """A stand-in kernel that is digest-correct by construction
+    (hashlib, not the slow-on-CPU lane path — the probe runs the
+    production shape itself)."""
+    def kernel(data, lengths, interpret=False):
+        data, lengths = np.asarray(data), np.asarray(lengths)
+        calls.append(data.shape)
+        out = np.zeros((len(lengths), 8), np.uint32)
+        for i, digest in enumerate(_hashlib_digests(data, lengths)):
+            out[i] = np.frombuffer(digest, dtype=">u4")
+        return out
+    return kernel
+
+
 @pytest.fixture(autouse=True)
-def _reset_breaker(monkeypatch):
-    # Tests below monkeypatch jax.default_backend() to "tpu", which
-    # would flip sha256's per-backend scan unrolls to the TPU optimum —
-    # a many-minute compile on XLA:CPU. Pin the CPU-safe unrolls.
-    monkeypatch.setenv("MAKISU_TPU_SHA_INNER_UNROLL", "1")
-    monkeypatch.setenv("MAKISU_TPU_SHA_BLOCK_UNROLL", "1")
-    yield
-    gear_pallas._broken = False
-    sha256_pallas._broken = False
-    sha256_pallas._parity_ok = {}
+def _fresh_parity(monkeypatch):
+    monkeypatch.setattr(sha256_pallas, "_parity_ok", {})
 
 
-def test_auto_on_cpu_never_touches_kernel(monkeypatch):
-    """CPU backends ride the XLA path even when pallas is force-enabled
-    (the kernel's unrolled body explodes XLA:CPU compile time)."""
-    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
+def _tpu_route(sha="pallas"):
+    return route.ChunkRoute("pallas", sha, "tpu", "TPU v5 lite", 1)
 
+
+def test_select_is_a_pure_function_of_backend_and_options():
+    """The whole decision table: no breaker, no history, nothing read
+    but the arguments."""
+    sel = route.select
+    # A TPU backend rides both kernels; v2 is opt-in.
+    assert sel("tpu", False, False, {}) == ("pallas", "pallas")
+    assert sel("tpu", True, False, {}) == ("pallas", "pallas")
+    assert sel("tpu", False, False,
+               {"MAKISU_TPU_PALLAS_V2": "1"}) == ("pallas_v2", "pallas")
+    assert sel("tpu", False, False,
+               {"MAKISU_TPU_PALLAS": "0"}) == ("xla", "xla")
+    # v2 rides the shared gate: off with the kernels.
+    assert sel("tpu", False, False, {"MAKISU_TPU_PALLAS": "0",
+                                     "MAKISU_TPU_PALLAS_V2": "1"}) \
+        == ("xla", "xla")
+    # The native route never engages on an accelerator, even with the
+    # library present.
+    assert sel("tpu", False, True, {}) == ("pallas", "pallas")
+    # A CPU backend chunks natively, except through the shared service,
+    # without the library, or when told not to.
+    assert sel("cpu", False, True, {}) == ("native", "native")
+    assert sel("cpu", True, True, {}) == ("xla", "xla")
+    assert sel("cpu", False, False, {}) == ("xla", "xla")
+    assert sel("cpu", False, True,
+               {"MAKISU_TPU_CHUNK_NATIVE": "0"}) == ("xla", "xla")
+    # Forced kernels on the CPU: gear in interpret mode, SHA stays on
+    # XLA (the kernel's unrolled body explodes XLA:CPU compile time).
+    assert sel("cpu", True, True,
+               {"MAKISU_TPU_PALLAS": "1"}) == ("pallas", "xla")
+
+
+def test_route_is_logged_once_and_labels_the_counters(monkeypatch):
+    """One `chunk route:` line per process and decision, and the bytes
+    counters carry the decision's names — not whichever route ran
+    last."""
+    from makisu_tpu.chunker.cdc import ChunkSession
+    from makisu_tpu.utils import logging as log
+    from makisu_tpu.utils import metrics
+
+    lines = []
+    monkeypatch.setattr(
+        log, "info",
+        lambda msg, *a, **k: lines.append(msg % a if a else msg))
+    route._announce.cache_clear()
+    monkeypatch.setenv("MAKISU_TPU_CHUNK_NATIVE", "0")
+    payload = np.random.default_rng(5).integers(
+        0, 256, size=300_000, dtype=np.uint8).tobytes()
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
+    try:
+        for _ in range(2):
+            s = ChunkSession(block=128 * 1024)
+            s.update(payload)
+            assert s.finish()
+    finally:
+        metrics.reset_build_registry(token)
+    logged = [ln for ln in lines if ln.startswith("chunk route:")]
+    assert len(logged) == 1
+    assert logged[0].startswith("chunk route: device cpu ")
+    assert logged[0].endswith("gear=xla sha=xla")
+    assert registry.counter_total("makisu_gear_scan_bytes_total",
+                                  backend="xla") == 2 * len(payload)
+    assert registry.counter_total("makisu_bytes_hashed_total",
+                                  backend="xla", path="cdc") \
+        == 2 * len(payload)
+    assert registry.counter_total("makisu_bytes_hashed_total") \
+        == 2 * len(payload)
+
+    # The native route says so, once.
+    monkeypatch.delenv("MAKISU_TPU_CHUNK_NATIVE")
+    s = ChunkSession()
+    if s._native:
+        assert lines[-1] == "chunk route: native (backend cpu)"
+
+
+def test_xla_route_never_touches_kernel(monkeypatch):
     def boom(*a, **k):
-        raise AssertionError("kernel dispatched on cpu")
+        raise AssertionError("kernel dispatched on the xla route")
 
     monkeypatch.setattr(sha256_pallas, "sha256_lanes_pallas", boom)
     rng = np.random.default_rng(0)
     data = rng.integers(0, 256, size=(16, 256), dtype=np.uint8)
     lengths = rng.integers(0, 247, size=16).astype(np.int32)
-    got = np.asarray(sha256_pallas.sha256_lanes_auto(data, lengths))
-    want = _hashlib_digests(data, lengths)
-    assert [g.astype(">u4").tobytes() for g in got] == want
+    got = np.asarray(route.hash_lanes(_tpu_route("xla"), data, lengths))
+    assert [g.astype(">u4").tobytes() for g in got] == _hashlib_digests(
+        data, lengths)
 
 
-def test_parity_probe_mismatch_pins_xla(monkeypatch):
-    """A kernel that compiles but produces wrong digests must trip the
-    breaker before any production digest is computed."""
-    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
+def test_parity_probe_mismatch_raises(monkeypatch):
+    """A kernel that compiles but produces wrong digests raises before
+    any production digest is computed, and goes on raising: nothing
+    pins the process to the XLA path."""
     def wrong(data, lengths, interpret=False):
         return np.zeros((data.shape[0], 8), dtype=np.uint32)
 
+    def no_xla(*a, **k):
+        raise AssertionError("fell back to the XLA path")
+
     monkeypatch.setattr(sha256_pallas, "sha256_lanes_pallas", wrong)
+    monkeypatch.setattr(sha256_pallas.sha256, "sha256_lanes", no_xla)
     rng = np.random.default_rng(1)
     data = rng.integers(0, 256, size=(8, 256), dtype=np.uint8)
     lengths = rng.integers(0, 247, size=8).astype(np.int32)
-    got = np.asarray(sha256_pallas.sha256_lanes_auto(data, lengths))
-    assert [g.astype(">u4").tobytes() for g in got] == _hashlib_digests(
-        data, lengths)                       # correct XLA digests
-    assert sha256_pallas._broken             # SHA breaker tripped...
-    assert not gear_pallas._broken           # ...gear kernel unaffected
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="digest mismatch vs hashlib"):
+            route.hash_lanes(_tpu_route(), data, lengths)
     assert sha256_pallas._parity_ok[(8, 256)] is False
 
 
-def test_parity_probe_exception_pins_xla(monkeypatch):
-    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
+def test_parity_probe_exception_propagates(monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("synthetic Mosaic rejection")
 
     monkeypatch.setattr(sha256_pallas, "sha256_lanes_pallas", boom)
     data = np.zeros((4, 64), dtype=np.uint8)
     lengths = np.array([0, 1, 2, 3], dtype=np.int32)
-    got = np.asarray(sha256_pallas.sha256_lanes_auto(data, lengths))
-    assert [g.astype(">u4").tobytes() for g in got] == _hashlib_digests(
-        data, lengths)
-    assert sha256_pallas._broken
-    assert not gear_pallas._broken
+    with pytest.raises(RuntimeError, match="synthetic Mosaic rejection"):
+        route.hash_lanes(_tpu_route(), data, lengths)
+    # No verdict was reached, so none is cached: the next call probes
+    # (and fails) again instead of trusting or condemning the kernel.
+    assert (4, 64) not in sha256_pallas._parity_ok
 
 
 def test_parity_probe_pass_routes_to_kernel(monkeypatch):
     """When the probe passes, production dispatch uses the kernel."""
-    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     calls = []
-
-    def fake_kernel(data, lengths, interpret=False):
-        import hashlib
-
-        data, lengths = np.asarray(data), np.asarray(lengths)
-        calls.append(data.shape)
-        # Digest-correct by construction (hashlib, not the slow-on-CPU
-        # lane path — the probe runs the production shape itself).
-        out = np.zeros((len(lengths), 8), np.uint32)
-        for i, n in enumerate(lengths):
-            d = hashlib.sha256(data[i, :n].tobytes()).digest()
-            out[i] = np.frombuffer(d, dtype=">u4")
-        return out
-
     monkeypatch.setattr(sha256_pallas, "sha256_lanes_pallas",
-                        fake_kernel)
+                        _hashlib_kernel(calls))
     rng = np.random.default_rng(2)
     data = rng.integers(0, 256, size=(8, 256), dtype=np.uint8)
     lengths = rng.integers(0, 247, size=8).astype(np.int32)
-    got = np.asarray(sha256_pallas.sha256_lanes_auto(data, lengths))
-    assert [g.astype(">u4").tobytes() for g in got] == _hashlib_digests(
-        data, lengths)
+    for _ in range(2):
+        got = np.asarray(route.hash_lanes(_tpu_route(), data, lengths))
+        assert [g.astype(">u4").tobytes() for g in got] \
+            == _hashlib_digests(data, lengths)
     assert sha256_pallas._parity_ok[(8, 256)] is True
-    assert len(calls) == 2                   # probe + production call
+    assert len(calls) == 3           # one probe + two production calls
 
 
 def test_parity_probe_runs_per_bucket_shape(monkeypatch):
     """Each distinct (lanes, cap) compiles a different kernel program,
     so each must be parity-probed before its digests become cache
-    identity (advisor r3, medium): a kernel correct at the first bucket
-    shape but wrong at the second must be caught when the second shape
-    first flushes — never trusted on the strength of the first probe."""
-    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    identity: a kernel correct at the first bucket shape but wrong at
+    the second must be caught when the second shape first flushes —
+    never trusted on the strength of the first probe."""
     probed_shapes = []
+    good = _hashlib_kernel(probed_shapes)
 
     def shape_dependent_kernel(data, lengths, interpret=False):
-        import hashlib
-
-        data, lengths = np.asarray(data), np.asarray(lengths)
-        probed_shapes.append(data.shape)
+        out = good(data, lengths)
         if data.shape[1] >= 512:  # "miscompiles" at the bigger bucket
-            return np.zeros((len(lengths), 8), np.uint32)
-        out = np.zeros((len(lengths), 8), np.uint32)
-        for i, n in enumerate(lengths):
-            d = hashlib.sha256(data[i, :n].tobytes()).digest()
-            out[i] = np.frombuffer(d, dtype=">u4")
+            out[:] = 0
         return out
 
     monkeypatch.setattr(sha256_pallas, "sha256_lanes_pallas",
@@ -151,29 +207,25 @@ def test_parity_probe_runs_per_bucket_shape(monkeypatch):
 
     small = rng.integers(0, 256, size=(8, 256), dtype=np.uint8)
     small_len = rng.integers(0, 247, size=8).astype(np.int32)
-    got = np.asarray(sha256_pallas.sha256_lanes_auto(small, small_len))
+    got = np.asarray(route.hash_lanes(_tpu_route(), small, small_len))
     assert [g.astype(">u4").tobytes() for g in got] == _hashlib_digests(
         small, small_len)
     assert sha256_pallas._parity_ok[(8, 256)] is True
-    assert not sha256_pallas._broken
 
     big = rng.integers(0, 256, size=(4, 512), dtype=np.uint8)
     big_len = rng.integers(0, 503, size=4).astype(np.int32)
-    got = np.asarray(sha256_pallas.sha256_lanes_auto(big, big_len))
-    # The second shape's probe caught the miscompile; production digests
-    # came from the XLA path and are correct.
-    assert [g.astype(">u4").tobytes() for g in got] == _hashlib_digests(
-        big, big_len)
+    with pytest.raises(RuntimeError, match="4x512: digest mismatch"):
+        route.hash_lanes(_tpu_route(), big, big_len)
     assert sha256_pallas._parity_ok[(4, 512)] is False
-    assert (8, 256) in [s for s in probed_shapes]
-    assert (4, 512) in [s for s in probed_shapes]
+    assert (8, 256) in probed_shapes
+    assert (4, 512) in probed_shapes
 
 
 @pytest.mark.skipif(
     os.environ.get("MAKISU_TPU_SLOW_TESTS") != "1",
     reason="interpret-mode kernel compile takes minutes on XLA:CPU "
            "(set MAKISU_TPU_SLOW_TESTS=1; device validation runs in "
-           "bench.py's SHA A/B and the production parity probe)")
+           "benchmarks/kernel_check.py and the production parity probe)")
 def test_kernel_interpret_matches_hashlib():
     rng = np.random.default_rng(3)
     data = rng.integers(0, 256, size=(8, 128), dtype=np.uint8)
