@@ -104,6 +104,8 @@ class BuildNode:
                     memfs.replay_layer(ops, chain_key=hex_digest)
                 metrics.counter_add(
                     metrics.CACHED_LAYERS_APPLIED_TOTAL)
+                metrics.counter_add(metrics.LAYER_REPLAY_TOTAL,
+                                    result="memo")
                 return
         log.info("applying cached layer %s (unpack=%s)", hex_digest,
                  modify_fs)
@@ -112,7 +114,8 @@ class BuildNode:
         # through the cache manager when it can supply one — with chunk
         # dedup attached, a lazily-pulled layer streams straight from
         # local chunks (no blob transfer, no gzip inflate at all).
-        with metrics.span("apply_layer", digest=hex_digest[:12]):
+        with metrics.span("apply_layer", digest=hex_digest[:12]), \
+                metrics.span("apply_layer.inflate"):
             open_tar = getattr(cache_mgr, "open_layer_tar", None)
             if open_tar is not None:
                 with open_tar(pair) as gz:
@@ -131,6 +134,7 @@ class BuildNode:
             session.replay_store(memo_key, record)
         # After the span: a failed application must not count.
         metrics.counter_add(metrics.CACHED_LAYERS_APPLIED_TOTAL)
+        metrics.counter_add(metrics.LAYER_REPLAY_TOTAL, result="inflate")
 
     def pull_cache_layer(self, cache_mgr) -> bool:
         """Try to prefetch this node's layer. A miss or failure returns

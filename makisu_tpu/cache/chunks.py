@@ -1087,12 +1087,13 @@ class ChunkStore:
 
 def _record_index(layer_hex: str, cache_id: str,
                   triples: list[tuple[int, int, str]],
-                  added: list[str]) -> None:
+                  added: list[str]) -> int:
     """Per-layer dedup accounting after index_layer: how many of the
     layer's bytes were NOVEL (the re-chunked fraction an edit cost)
     vs already held — the `makisu-tpu explain` blame for commit-side
     work, plus aggregate counters and a per-layer dedup-ratio gauge so
-    chunking efficiency is visible without a ledger."""
+    chunking efficiency is visible without a ledger. Returns the
+    novel bytes."""
     bytes_total = sum(n for _, n, _ in triples)
     lengths: dict[str, int] = {}
     for _, n, h in triples:
@@ -1119,6 +1120,7 @@ def _record_index(layer_hex: str, cache_id: str,
                   cache_id=cache_id, chunks=len(triples),
                   added=len(added), bytes_total=bytes_total,
                   bytes_added=bytes_added, bytes_reused=bytes_reused)
+    return bytes_added
 
 
 def attach_chunk_dedup(manager, chunk_root: str) -> ChunkStore:
@@ -1143,10 +1145,13 @@ def attach_chunk_dedup(manager, chunk_root: str) -> ChunkStore:
                 path = manager.store.layers.path(layer_hex)
                 triples = [(c.offset, c.length, c.hex_digest)
                            for c in commit.chunks]
-                added = chunk_store.index_layer(path, triples)
-                metrics.counter_add("makisu_chunks_indexed_total",
-                                    len(added))
-                _record_index(layer_hex, cache_id, triples, added)
+                with metrics.span("chunk_index",
+                                  chunks=len(triples)) as sp:
+                    added = chunk_store.index_layer(path, triples)
+                    metrics.counter_add("makisu_chunks_indexed_total",
+                                        len(added))
+                    sp.set(added=len(added), bytes_added=_record_index(
+                        layer_hex, cache_id, triples, added))
                 log.info("indexed %d new chunks for %s", len(added),
                          cache_id)
                 _spawn_recipe_publish(pair, triples, commit, cache_id)

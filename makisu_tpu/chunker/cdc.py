@@ -38,6 +38,8 @@ SURVEY.md §5 "long-context" mapping.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import contextvars
 import time
 import typing
@@ -117,15 +119,63 @@ class Chunk(typing.NamedTuple):
         return self.digest.hex()
 
 
+class FeedClock:
+    """Where one stream's device feed spends the host's time, and what
+    it moves: monotonic seconds per stage (``gear_dispatch``,
+    ``gear_readback``, ``host_cut``, ``sha_dispatch``, ``sha_readback``,
+    ``service_wait``) and bytes per crossing, kept in plain numbers and
+    flushed once (``ChunkSession.finish``, or per batch by the shared
+    hash service) into ``makisu_commit_stage_busy_seconds{stage}`` and
+    ``makisu_device_transfer_bytes_total{direction,stage}``. A stage
+    entered inside another (a lane dispatch during the host cut) is
+    charged to itself alone. Each stage is also a bare annotation on the
+    profiler's host timeline. One thread at a time."""
+
+    def __init__(self) -> None:
+        self.seconds: dict[str, float] = collections.defaultdict(float)
+        self.bytes: dict[tuple[str, str], int] = \
+            collections.defaultdict(int)
+        self._nested = 0.0  # seconds of stages closed at this depth
+
+    @contextlib.contextmanager
+    def stage(self, name: str):
+        t0 = time.monotonic()
+        nested0 = self._nested
+        with metrics.annotation(name):
+            try:
+                yield
+            finally:
+                self.add(name, time.monotonic() - t0,
+                         self._nested - nested0)
+
+    def add(self, name: str, elapsed: float, nested: float = 0.0) -> None:
+        self.seconds[name] += elapsed - nested
+        self._nested += elapsed - nested
+
+    def moved(self, direction: str, stage: str, nbytes: int) -> None:
+        self.bytes[direction, stage] += nbytes
+
+    def flush(self) -> None:
+        for name, seconds in self.seconds.items():
+            metrics.stage_busy_add(name, seconds)
+        for (direction, stage), nbytes in self.bytes.items():
+            metrics.counter_add(metrics.DEVICE_TRANSFER_BYTES, nbytes,
+                                direction=direction, stage=stage)
+        self.seconds.clear()
+        self.bytes.clear()
+
+
 class _LaneBatcher:
     """Accumulates chunks into one bucket's fixed [L, CAP] buffer and
     dispatches sha256_lanes when full."""
 
     def __init__(self, cap: int, lanes: int,
-                 route: "_route.ChunkRoute | None") -> None:
+                 route: "_route.ChunkRoute | None",
+                 clock: FeedClock) -> None:
         self.cap = cap
         self.lanes = lanes
         self.route = route
+        self.clock = clock
         self.data = np.zeros((lanes, cap), dtype=np.uint8)
         self.lengths = np.zeros(lanes, dtype=np.int32)
         self.meta: list[tuple[int, int]] = []  # (offset, length)
@@ -144,8 +194,11 @@ class _LaneBatcher:
     def flush(self) -> None:
         if not self.meta:
             return
-        digests = _route.hash_lanes(
-            self.route, self.data, self.lengths)  # async dispatch
+        with self.clock.stage("sha_dispatch"):
+            digests = _route.hash_lanes(
+                self.route, self.data, self.lengths)  # async dispatch
+        self.clock.moved("h2d", "sha",
+                         self.data.nbytes + self.lengths.nbytes)
         metrics.counter_add("makisu_bytes_hashed_total",
                             sum(n for _, n in self.meta),
                             backend=self.route.sha, path="cdc")
@@ -161,8 +214,10 @@ class _LaneBatcher:
         out: list[Chunk] = []
         for digests, meta in self.pending:
             t0 = time.monotonic()
-            host = _backend.sync_bounded(
-                digests, "lane digest readback")  # bounded sync point
+            with self.clock.stage("sha_readback"):
+                host = _backend.sync_bounded(
+                    digests, "lane digest readback")  # bounded sync point
+            self.clock.moved("d2h", "sha", host.nbytes)
             # Readback-wait per program (timed around the sync only:
             # dispatch was async at flush and the host kept scanning in
             # between — flush-to-drain wall time would charge that host
@@ -218,6 +273,7 @@ class ChunkSession:
         self._sha_meta: list[tuple[int, int]] = []  # (offset, length)
         self._sha_pending: list = []  # ordered (meta, Future->digests)
         self._degraded: str | None = None  # failure summary once degraded
+        self._clock = FeedClock()  # device-feed seconds and bytes
         # The route (chunker/route.py), decided once per process from
         # what the backend probe found. The probe is the hang guard: a
         # backend init that blocks (chip held by another process) never
@@ -238,8 +294,9 @@ class ChunkSession:
         self._native = self._route is not None and self._route.native
         if (self._route is not None and not self._native
                 and service is None):
-            self._batchers = [_LaneBatcher(cap, lanes, self._route)
-                              for cap, lanes in _BUCKETS]
+            self._batchers = [
+                _LaneBatcher(cap, lanes, self._route, self._clock)
+                for cap, lanes in _BUCKETS]
         # The gear table is deterministic by contract; one copy per
         # session, not one 256-iteration rebuild per 4MiB block.
         self._table = gear.gear_table() if self._native else None
@@ -384,16 +441,21 @@ class ChunkSession:
                     self._sha_pending = []
                 for b in self._batchers:
                     self._chunks.extend(b.drain())
-                _t = _backend.sync_timeout()
-                svc_timeout = _t if _t > 0 else None
-                for offset, length, fut in self._service_pending:
-                    # Bounded like the direct readbacks: a dead service
-                    # dispatcher must degrade the layer, not block it.
-                    self._chunks.append(
-                        Chunk(offset, length,
-                              fut.result(timeout=svc_timeout)))
+                if self._service_pending:
+                    _t = _backend.sync_timeout()
+                    svc_timeout = _t if _t > 0 else None
+                    with self._clock.stage("service_wait"):
+                        for offset, length, fut in self._service_pending:
+                            # Bounded like the direct readbacks: a dead
+                            # service dispatcher must degrade the
+                            # layer, not block it.
+                            self._chunks.append(
+                                Chunk(offset, length,
+                                      fut.result(timeout=svc_timeout)))
             except Exception as e:  # noqa: BLE001 - device plane
                 self._degrade("lane hashing", e)
+        # Once per stream, degraded or not: what the feed did happen.
+        self._clock.flush()
         if self._native_hashed:
             # One flush for the whole stream (a per-chunk counter_add
             # measured ~13% of the native session); degraded sessions
@@ -461,16 +523,18 @@ class ChunkSession:
             # Opt-in natural-layout kernel (MAKISU_TPU_PALLAS_V2=1):
             # pure-reshape staging, full-buffer bitmap (XLA-contract
             # slicing) — see gear_pallas.py v2 block.
-            buf = np.frombuffer(hblk, dtype=np.uint8)
-            need = ((len(buf) + gear_pallas.V2_TILE - 1)
-                    // gear_pallas.V2_TILE) * gear_pallas.V2_TILE
-            if need != len(buf):
-                qbuf = np.zeros(need, dtype=np.uint8)
-                qbuf[:len(buf)] = buf
-            else:
-                qbuf = buf
-            words = gear_pallas.gear_bitmap_flat2(
-                qbuf, self.avg_bits, interpret=route.interpret)
+            with self._clock.stage("gear_dispatch"):
+                buf = np.frombuffer(hblk, dtype=np.uint8)
+                need = ((len(buf) + gear_pallas.V2_TILE - 1)
+                        // gear_pallas.V2_TILE) * gear_pallas.V2_TILE
+                if need != len(buf):
+                    qbuf = np.zeros(need, dtype=np.uint8)
+                    qbuf[:len(buf)] = buf
+                else:
+                    qbuf = buf
+                words = gear_pallas.gear_bitmap_flat2(
+                    qbuf, self.avg_bits, interpret=route.interpret)
+            self._clock.moved("h2d", "gear", qbuf.nbytes)
             # entry[0] is the READBACK layout tag (v2 words decode
             # like XLA's), not the executing backend.
             entry = ("xla", words, halo_len, live, hblk, self._scanned)
@@ -479,16 +543,21 @@ class ChunkSession:
             # device inside the same program. The live region is
             # zero-padded to the kernel's 64 KiB row-grid granularity
             # so distinct tail-block sizes share compiles.
-            buf = np.frombuffer(hblk, dtype=np.uint8)
-            words = gear_pallas.gear_bitmap_flat(
-                gear_pallas.quantize_flat(buf, halo_len, live),
-                halo_len, self.avg_bits, interpret=route.interpret)
+            with self._clock.stage("gear_dispatch"):
+                qbuf = gear_pallas.quantize_flat(
+                    np.frombuffer(hblk, dtype=np.uint8), halo_len, live)
+                words = gear_pallas.gear_bitmap_flat(
+                    qbuf, halo_len, self.avg_bits,
+                    interpret=route.interpret)
+            self._clock.moved("h2d", "gear", qbuf.nbytes)
             entry = ("pallas", words, gear_pallas.nrows_for(live),
                      live, hblk, self._scanned, halo_len)
         else:
-            words = gear.gear_bitmap(
-                np.frombuffer(hblk, dtype=np.uint8),
-                self.avg_bits)  # async dispatch
+            with self._clock.stage("gear_dispatch"):
+                words = gear.gear_bitmap(
+                    np.frombuffer(hblk, dtype=np.uint8),
+                    self.avg_bits)  # async dispatch
+            self._clock.moved("h2d", "gear", len(hblk))
             entry = ("xla", words, halo_len, live, hblk, self._scanned)
         metrics.counter_add("makisu_gear_scan_bytes_total", live,
                             backend=route.gear)
@@ -532,23 +601,33 @@ class ChunkSession:
                 # here and degrades the session like any scan failure).
                 words = words.result()
             candidates = words.astype(np.int64) + base  # host positions
-        elif kind == "pallas":
-            from makisu_tpu.ops import gear_pallas
+            self._cut_block(candidates, hblk, halo_len, live)
+            return
+        with self._clock.stage("gear_readback"):
             host_words = _backend.sync_bounded(
                 words, "gear bitmap readback")
-            nrows = meta
-            halo_len = entry[6]
-            bits = gear.unpack_bits_np(
-                host_words[:nrows], nrows * gear_pallas.ROW)
-            candidates = np.nonzero(
-                bits.reshape(-1)[:live])[0] + base
-        else:
-            host_words = _backend.sync_bounded(
-                words, "gear bitmap readback")
-            halo_len = meta
-            bits = gear.unpack_bits_np(
-                host_words, halo_len + live)[halo_len:halo_len + live]
-            candidates = np.nonzero(bits)[0] + base
+        self._clock.moved("d2h", "gear", host_words.nbytes)
+        # Bit unpack, the cut policy and lane packing; the lane
+        # dispatches it triggers are charged to themselves.
+        with self._clock.stage("host_cut"):
+            if kind == "pallas":
+                from makisu_tpu.ops import gear_pallas
+                nrows = meta
+                halo_len = entry[6]
+                bits = gear.unpack_bits_np(
+                    host_words[:nrows], nrows * gear_pallas.ROW)
+                candidates = np.nonzero(
+                    bits.reshape(-1)[:live])[0] + base
+            else:
+                halo_len = meta
+                bits = gear.unpack_bits_np(
+                    host_words, halo_len + live)[halo_len:halo_len + live]
+                candidates = np.nonzero(bits)[0] + base
+            self._cut_block(candidates, hblk, halo_len, live)
+
+    def _cut_block(self, candidates, hblk: bytes, halo_len: int,
+                   live: int) -> None:
+        """Apply the cut policy to one block's candidate positions."""
         with memoryview(hblk) as mv:
             self._tail.extend(mv[halo_len:halo_len + live])
         # tolist(): one C conversion instead of a numpy-scalar __int__
@@ -696,9 +775,11 @@ class ChunkSession:
             self._notify(digest.hex())
             return
         if self.service is not None:
-            self._service_pending.append(
-                (offset, len(data),
-                 self.service.submit(data, owner=id(self))))
+            # A full service queue blocks the build here (backpressure).
+            t0 = time.monotonic()
+            fut = self.service.submit(data, owner=id(self))
+            self._clock.add("service_wait", time.monotonic() - t0)
+            self._service_pending.append((offset, len(data), fut))
             return
         for b in self._batchers:
             if len(data) <= b.cap - 64:  # leave room for sha padding

@@ -219,6 +219,11 @@ class _NativeTarWriter:
         self._offset = 0
         self._closed = False
 
+    @property
+    def offset(self) -> int:
+        """Bytes written so far, as ``tarfile.TarFile.offset``."""
+        return self._offset
+
     def addfile(self, tarinfo, fileobj=None) -> None:
         buf = tarinfo.tobuf(*self._FMT)
         self._sink._handle.write(buf)

@@ -24,7 +24,7 @@ from concurrent.futures import Future
 import numpy as np
 
 from makisu_tpu.chunker import route as _route
-from makisu_tpu.chunker.cdc import _BUCKETS
+from makisu_tpu.chunker.cdc import _BUCKETS, FeedClock
 from makisu_tpu.utils import metrics
 
 # Batch-size histogram buckets: lane-fill powers of two up to the
@@ -114,18 +114,28 @@ class HashService:
             data[i, :len(chunk)] = np.frombuffer(chunk, dtype=np.uint8)
             lengths[i] = len(chunk)
         t0 = time.monotonic()
+        # The builds wait in ``service_wait`` (cdc.py) while this thread
+        # dispatches and reads back: the device-feed seconds and bytes
+        # of the farm route are recorded here, per batch.
+        clock = FeedClock()
         try:
             from makisu_tpu.ops import backend as _backend
             route = _route.chunk_route(shared=True)
-            words = _backend.sync_bounded(
-                _route.hash_lanes(route, data, lengths),
-                "shared-service digest readback")
+            with clock.stage("sha_dispatch"):
+                pending = _route.hash_lanes(route, data, lengths)
+            clock.moved("h2d", "sha", data.nbytes + lengths.nbytes)
+            with clock.stage("sha_readback"):
+                words = _backend.sync_bounded(
+                    pending, "shared-service digest readback")
+            clock.moved("d2h", "sha", words.nbytes)
         except BaseException as e:  # noqa: BLE001
+            clock.flush()
             metrics.counter_add("makisu_hash_batch_failures_total",
                                 bucket=cap)
             for _, fut, _ in batch:
                 fut.set_exception(e)
             return
+        clock.flush()
         self.batches += 1
         # Device execution telemetry: dispatch latency ring + compile
         # gauge + H2D/padding-waste bytes, per bucket (ops/backend.py

@@ -2021,6 +2021,9 @@ def main(argv: list[str] | None = None) -> int:
         from makisu_tpu import ops  # noqa: F401
         import jax
         jax.profiler.start_trace(args.jax_profile)
+        # Spans on the trace's host timeline even when no build of
+        # this invocation brings the device backend up (--hasher cpu).
+        metrics.set_annotation_factory(jax.profiler.TraceAnnotation)
         jax_trace = True
     # Every invocation gets its own telemetry registry, bound to this
     # context exactly like the worker's per-build log sink: concurrent

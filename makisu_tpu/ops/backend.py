@@ -181,9 +181,19 @@ def _read_identity(jax) -> dict:
             "device_count": len(devices)}
 
 
+def _annotate_spans(jax) -> None:
+    """Put ``metrics.span`` on the profiler's clock: from here on every
+    span also opens a ``TraceAnnotation`` on its thread, which costs a
+    no-op native call unless a profiler session is running (``build
+    --jax-profile``, the benchmark's traced run)."""
+    from makisu_tpu.utils import metrics
+    metrics.set_annotation_factory(jax.profiler.TraceAnnotation)
+
+
 def _phase_device_enumeration(ctx: dict) -> None:
     global _identity
     _identity = _read_identity(ctx["jax"])
+    _annotate_spans(ctx["jax"])
 
 
 def _phase_first_compile(ctx: dict) -> None:
@@ -503,6 +513,7 @@ def backend_ready(timeout: float | None = None,
         if _identity is None:
             import jax
             _identity = _read_identity(jax)
+            _annotate_spans(jax)
         return None
     warm_probe(source=source)
     if _done.is_set():

@@ -206,6 +206,7 @@ def _gear_bitmap_blocked(data: jax.Array, avg_bits: int, block: int,
 
 @functools.partial(jax.jit, static_argnums=(1,),
                    static_argnames=("avg_bits",))
+@jax.named_scope("gear_scan")
 def gear_bitmap(data: jax.Array, avg_bits: int = DEFAULT_AVG_BITS) -> jax.Array:
     """Fused: uint8 [..., N] -> packed candidate bitmap uint32 [..., N//32].
 

@@ -169,9 +169,13 @@ def _gear_kernel(avg_bits: int, rows_ref, out_ref) -> None:
 
 
 def _invoke_kernel(rows: jax.Array, avg_bits: int,
-                   interpret: bool) -> jax.Array:
+                   interpret: bool, name: str) -> jax.Array:
     """The one pallas_call site: uint8 rows [R, 32, COLS] (R a multiple
-    of ROW_TILE) → packed candidate bitmap [R, ROW//32]."""
+    of ROW_TILE) → packed candidate bitmap [R, ROW//32]. ``name`` is
+    the calling entry point's: XLA names the custom call after the
+    innermost scope, and the benchmark finds the kernel in a device
+    trace as ``%gear_bitmap_flat.N``, inside or outside a
+    ``named_scope``."""
     from jax.experimental import pallas as pl
 
     R = rows.shape[0]
@@ -186,19 +190,22 @@ def _invoke_kernel(rows: jax.Array, avg_bits: int,
         out_specs=pl.BlockSpec((ROW_TILE, _CCOLS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, _CCOLS), jnp.uint32),
         interpret=interpret,
+        name=name,
     )(rows)
 
 
 @functools.partial(jax.jit, static_argnames=("avg_bits", "interpret"))
+@jax.named_scope("gear_scan")
 def gear_bitmap_rows(rows: jax.Array,
                      avg_bits: int = gear.DEFAULT_AVG_BITS,
                      interpret: bool = False) -> jax.Array:
     """uint8 rows [R, 32, COLS] → packed candidate bitmap [R, ROW//32]."""
-    return _invoke_kernel(rows, avg_bits, interpret)
+    return _invoke_kernel(rows, avg_bits, interpret, "gear_bitmap_rows")
 
 
 @functools.partial(jax.jit,
                    static_argnames=("start", "avg_bits", "interpret"))
+@jax.named_scope("gear_scan")
 def gear_bitmap_flat(buf: jax.Array, start: int,
                      avg_bits: int = gear.DEFAULT_AVG_BITS,
                      interpret: bool = False) -> jax.Array:
@@ -234,7 +241,7 @@ def gear_bitmap_flat(buf: jax.Array, start: int,
         [seg[:HALO][None, :], live_m[:-1, ROW - HALO:]], axis=0)
     rows = (jnp.concatenate([halos, live_m], axis=1)
             .reshape(R, _HCOLS + _CCOLS, 32).transpose(0, 2, 1))
-    return _invoke_kernel(rows, avg_bits, interpret)
+    return _invoke_kernel(rows, avg_bits, interpret, "gear_bitmap_flat")
 
 
 # ---------------------------------------------------------------------------
@@ -308,6 +315,7 @@ def _gear_kernel2(avg_bits: int, rows_ref, out_ref, q_ref) -> None:
 
 
 @functools.partial(jax.jit, static_argnames=("avg_bits", "interpret"))
+@jax.named_scope("gear_scan")
 def gear_bitmap_flat2(buf: jax.Array,
                       avg_bits: int = gear.DEFAULT_AVG_BITS,
                       interpret: bool = False) -> jax.Array:
@@ -332,11 +340,13 @@ def gear_bitmap_flat2(buf: jax.Array,
         out_shape=jax.ShapeDtypeStruct((n // 128, 4), jnp.uint32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
         interpret=interpret,
+        name="gear_bitmap_flat2",
     )(rows)
     return words.reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("avg_bits", "interpret"))
+@jax.named_scope("gear_scan")
 def gear_bitmap_batch(blocks: jax.Array,
                       avg_bits: int = gear.DEFAULT_AVG_BITS,
                       interpret: bool = False) -> jax.Array:
@@ -358,7 +368,7 @@ def gear_bitmap_batch(blocks: jax.Array,
                     ((0, 0), (1, 0), (0, 0)))   # stream head: zero halo
     rows = (jnp.concatenate([halos, live_m], axis=2)
             .reshape(B * R, _HCOLS + _CCOLS, 32).transpose(0, 2, 1))
-    words = _invoke_kernel(rows, avg_bits, interpret)
+    words = _invoke_kernel(rows, avg_bits, interpret, "gear_bitmap_batch")
     return words.reshape(B, R * _CCOLS)
 
 

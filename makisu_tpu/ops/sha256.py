@@ -245,6 +245,7 @@ def sha256_words(words: jax.Array, n_blocks: jax.Array,
     return jnp.transpose(state, (1, 0))
 
 
+@jax.named_scope("chunk_sha")
 def sha256_lanes_impl(data: jax.Array, lengths: jax.Array,
                       init_state: jax.Array | None = None) -> jax.Array:
     """End-to-end: ragged uint8 lanes [L, CAP] + lengths [L] -> [L, 8] digests.

@@ -169,17 +169,22 @@ class MemFS:
         granularity so later modifications always look newer than this
         layer's scan (reference: mem_fs.go sync, :294-311)."""
         start = time.time()
-        try:
-            os.sync()
-        except (OSError, AttributeError):
-            pass
-        remaining = self.sync_wait - (time.time() - start)
-        if remaining > 0:
-            time.sleep(remaining)
+        with metrics.span("memfs_sync"):
+            with metrics.span("memfs_sync.os_sync"):
+                try:
+                    os.sync()
+                except (OSError, AttributeError):
+                    pass
+            remaining = self.sync_wait - (time.time() - start)
+            if remaining > 0:
+                with metrics.span("memfs_sync.mtime_wait"):
+                    time.sleep(remaining)
 
     def add_layer_by_scan(self, tw: tarfile.TarFile) -> Layer:
         self._sync()
-        layer = self._create_layer_by_scan()
+        with metrics.span("layer_scan") as sp:
+            layer = self._create_layer_by_scan()
+            sp.set(entries=len(layer))
         self._commit_layer(layer, tw)
         log.info("created layer by scan: %d entries", len(layer))
         return layer
@@ -187,9 +192,11 @@ class MemFS:
     def add_layer_by_copy_ops(self, ops: list[CopyOperation],
                               tw: tarfile.TarFile) -> Layer:
         self._sync()
-        layer = Layer()
-        for op in ops:
-            self._add_copy_to_layer(layer, op)
+        with metrics.span("layer_scan") as sp:
+            layer = Layer()
+            for op in ops:
+                self._add_copy_to_layer(layer, op)
+            sp.set(entries=len(layer))
         self._commit_layer(layer, tw)
         log.info("created copy layer: %d entries", len(layer))
         return layer
@@ -212,11 +219,13 @@ class MemFS:
             if route is not None:
                 self._isa_logged = True
                 log.info("layer-commit native ISA route: %s", route)
-        t0 = time.monotonic()  # same clock as every other stage
         try:
-            layer.commit(tw)
+            with metrics.span("tar_write", entries=len(layer)) as sp:
+                layer.commit(tw)
+                sp.set(bytes=tw.offset)
         finally:
-            metrics.stage_busy_add("tar_write", time.monotonic() - t0)
+            # The span's pair of clock reads is the stage counter's.
+            metrics.stage_busy_add("tar_write", sp.duration)
         # A commit folded entries into the tree without a chain key
         # (its digest exists only after the fact): any later cached
         # application on this tree must bypass the replay memo.

@@ -107,9 +107,11 @@ class AddCopyStep(BuildStep):
                  "bytes_rehashed": 0, "changed": []}
         if not self.from_stage:
             # Cross-stage copies rely on chained stage cache IDs instead.
-            for source in self._resolve_sources(ctx):
-                checksum = self._checksum_source(ctx, source, checksum,
-                                                 tally)
+            with metrics.span("copy_checksum") as sp:
+                for source in self._resolve_sources(ctx):
+                    checksum = self._checksum_source(ctx, source,
+                                                     checksum, tally)
+                sp.set(files=tally["files"])
         for name, content in self.inline_files:
             # Inline heredoc files are content too (their bodies carry
             # substituted build args, so identity must track them).

@@ -146,7 +146,10 @@ def commit_layer(ctx: BuildContext, step: BuildStep) -> list[DigestPair]:
                                              backend_id=ctx.gzip_backend_id)
                 with sink.open_tar() as tw:
                     write_diffs(tw)
-                layer_commit = sink.finish()
+                # Device drain and readbacks, chunk-SHA tail, gzip tail.
+                with metrics.span("sink_finish") as sp:
+                    layer_commit = sink.finish()
+                    sp.set(chunks=len(layer_commit.chunks))
             pair = layer_commit.digest_pair
             ctx.image_store.layers.link_file(
                 pair.gzip_descriptor.digest.hex(), tmp)

@@ -68,6 +68,11 @@ DEVICE_DISPATCH_SECONDS = "makisu_device_dispatch_seconds"
 DEVICE_COMPILE_SECONDS = "makisu_device_compile_seconds"
 DEVICE_H2D_BYTES = "makisu_device_h2d_bytes_total"
 DEVICE_PADDING_WASTE = "makisu_device_padding_waste_bytes_total"
+# Every byte that crosses between host and device on the chunk route,
+# counted where it crosses (chunker/cdc.py, chunker/service.py):
+# direction=h2d|d2h, stage=gear|sha. DEVICE_H2D_BYTES above is the SHA
+# lane buffers alone (h2d/sha here); it stays for /healthz.
+DEVICE_TRANSFER_BYTES = "makisu_device_transfer_bytes_total"
 
 # Fleet telemetry (makisu_tpu/fleet/): one name set shared by the
 # scheduler, the peer-exchange module, the worker's /chunks endpoint,
@@ -143,6 +148,11 @@ CACHED_LAYERS_APPLIED_TOTAL = "makisu_cached_layers_applied_total"
 # Resident build sessions (worker/session.py): reuse hits, dirty-set
 # invalidations by reason, and resident memo bytes per context.
 SESSION_HITS = "makisu_session_hits"
+# Size of the dirty set a session handed to each build (begin_build).
+SESSION_DIRTY_PATHS = "makisu_session_dirty_paths_total"
+# Cached layers applied to the MemFS tree, result=memo (replayed from
+# the session's recorded entries) | inflate (gunzip + tar parse).
+LAYER_REPLAY_TOTAL = "makisu_layer_replay_total"
 SESSION_INVALIDATIONS = "makisu_session_invalidations_total"
 SESSION_RESIDENT_BYTES = "makisu_session_resident_bytes"
 
@@ -350,7 +360,7 @@ class Span:
 
     __slots__ = ("name", "attrs", "start_unix", "duration", "error",
                  "children", "registry", "span_id", "parent_id", "_t0",
-                 "peak_rss", "cpu_seconds")
+                 "peak_rss", "cpu_seconds", "late_attrs")
 
     def __init__(self, name: str, attrs: dict[str, Any],
                  registry: "MetricsRegistry") -> None:
@@ -370,6 +380,13 @@ class Span:
         # sampled (sampler off, or span shorter than the interval).
         self.peak_rss: int | None = None
         self.cpu_seconds = 0.0
+        self.late_attrs: dict[str, str] = {}
+
+    def set(self, **attrs: Any) -> None:
+        """Attributes known only once the work is done (entries,
+        bytes, chunks): they ride on the ``span_end`` event."""
+        self.late_attrs.update((k, str(v)) for k, v in attrs.items())
+        self.attrs.update(self.late_attrs)
 
     def to_dict(self) -> dict[str, Any]:
         out: dict[str, Any] = {
@@ -709,12 +726,35 @@ def observe_batch(name: str, values,
         reg.observe_batch(name, values, buckets=buckets, **labels)
 
 
+# The profiler's clock: ``jax.profiler.TraceAnnotation`` once
+# ops/backend.py has a backend up (this module never imports jax), None
+# before that and on builds that never touch the device plane. Outside
+# a profiler session an annotation is a no-op in native code.
+_annotation_factory = None
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def set_annotation_factory(factory) -> None:
+    """``factory(name, **kwargs)`` returns a context manager that puts
+    the scope on the profiler's host timeline for the calling thread."""
+    global _annotation_factory
+    _annotation_factory = factory
+
+
+def annotation(name: str, **stats: str):
+    """A profiler-only scope: no span, no event. For sites too hot for
+    a span (per 4 MiB block, per lane batch)."""
+    factory = _annotation_factory
+    return _NO_ANNOTATION if factory is None else factory(name, **stats)
+
+
 @contextlib.contextmanager
 def span(name: str, **attrs: Any) -> Iterator[Span]:
     """Timed scope attached to the innermost bound registry's tree.
     Nested spans become children; exceptions mark the span and
     propagate (telemetry never swallows a build failure). Open/close
-    mirror onto the build event bus (no-op unless a sink is bound)."""
+    mirror onto the build event bus (no-op unless a sink is bound),
+    and onto the profiler's host timeline once a backend is up."""
     reg = active_registry()
     parent = _current_span.get()
     if parent is None or parent.registry is not reg:
@@ -731,19 +771,22 @@ def span(name: str, **attrs: Any) -> Iterator[Span]:
     events.emit("span_start", name=name, span_id=s.span_id,
                 parent_id=s.parent_id, trace_id=reg.trace_id,
                 **({"attrs": s.attrs} if s.attrs else {}))
-    try:
-        yield s
-    except BaseException as e:
-        s.error = f"{type(e).__name__}: {e}"
-        raise
-    finally:
-        s.duration = time.monotonic() - s._t0
-        _open_spans.pop(id(s), None)
-        _current_span.reset(token)
-        events.emit("span_end", name=name, span_id=s.span_id,
-                    duration=round(s.duration, 6),
-                    trace_id=reg.trace_id,
-                    **({"error": s.error} if s.error else {}))
+    with annotation(name, span_id=s.span_id, trace_id=reg.trace_id):
+        try:
+            yield s
+        except BaseException as e:
+            s.error = f"{type(e).__name__}: {e}"
+            raise
+        finally:
+            s.duration = time.monotonic() - s._t0
+            _open_spans.pop(id(s), None)
+            _current_span.reset(token)
+            events.emit("span_end", name=name, span_id=s.span_id,
+                        duration=round(s.duration, 6),
+                        trace_id=reg.trace_id,
+                        **({"attrs": s.late_attrs}
+                           if s.late_attrs else {}),
+                        **({"error": s.error} if s.error else {}))
 
 
 def has_trace_context() -> bool:

@@ -27,13 +27,19 @@ from typing import Any, Iterator
 # Span-name substrings -> build phase, first match wins. Order matters:
 # "pull_cache_layers" must classify as pull before "cache" could ever
 # grow a phase of its own, and commit/hash both land in hash (layer
-# commit IS the hashing path).
+# commit IS the hashing path; the spans under ``commit_layer`` keep its
+# phase, so a build's phase shares read as they did before it had
+# children).
 _PHASE_RULES: tuple[tuple[str, str], ...] = (
     ("pull", "pull"),
     ("from", "pull"),
     ("chunk", "chunk"),
     ("hash", "hash"),
     ("commit", "hash"),
+    ("memfs_sync", "hash"),
+    ("layer_scan", "hash"),
+    ("tar_write", "hash"),
+    ("sink_finish", "hash"),
     ("push", "push"),
 )
 
@@ -308,6 +314,7 @@ def assemble_fleet_trace(event_log: list[dict]) -> dict:
             span = spans.get(str(ev.get("span_id") or ""))
             if span is not None and span["duration"] is None:
                 span["duration"] = float(ev.get("duration") or 0.0)
+                span["attrs"].update(ev.get("attrs") or {})
                 if ev.get("error"):
                     span["error"] = str(ev["error"])
         elif etype == "queue_wait":

@@ -350,11 +350,13 @@ class InotifyWatcher:
         was itself a warm-floor term at 100k files."""
         if not self.healthy or not self._needs_resync:
             return
-        for wd in list(self._wd_paths):
-            self._libc.inotify_rm_watch(self._fd, wd)
-        self._wd_paths.clear()
-        self._needs_resync = False
-        self.healthy = self._add_watches()
+        with metrics.span("session_resync") as sp:
+            for wd in list(self._wd_paths):
+                self._libc.inotify_rm_watch(self._fd, wd)
+            self._wd_paths.clear()
+            self._needs_resync = False
+            self.healthy = self._add_watches()
+            sp.set(watches=len(self._wd_paths))
 
     def close(self) -> None:
         if self._fd >= 0:
@@ -562,6 +564,14 @@ class BuildSession:
         statcache persistence to a background thread — a one-shot CLI
         process must keep the synchronous save or it may exit before
         the write lands."""
+        with metrics.span("session_begin") as sp:
+            mode = self._begin_build(ctx, resident_process)
+            sp.set(mode=mode, dirty=len(ctx.dirty_paths))
+        metrics.counter_add(metrics.SESSION_DIRTY_PATHS,
+                            len(ctx.dirty_paths))
+        return mode
+
+    def _begin_build(self, ctx, resident_process: bool) -> str:
         self.builds += 1
         self.last_used_mono = time.monotonic()
         self._resident_hint = resident_process or self.builds >= 2
@@ -635,6 +645,11 @@ class BuildSession:
         return mode
 
     def finish_build(self, ctx, ok: bool) -> None:
+        # Watcher drain and resync, then the session's checkpoint.
+        with metrics.span("session_finish", ok=ok):
+            self._finish_build(ctx, ok)
+
+    def _finish_build(self, ctx, ok: bool) -> None:
         self.last_used_mono = time.monotonic()
         if ok:
             # Everything dirty was consumed by this build's scan.

@@ -1,0 +1,213 @@
+"""``perfbench/pbharness/hostspans.py`` on the small trace recorded
+beside this file, and each reader PR 24 adds on a constructed run.
+
+``hostspans_small.xplane.pb`` was recorded on the CPU with the
+program's own ``metrics.span`` on the profiler's clock: a mark, then
+lane A (``build`` > ``stage`` > ``step`` > ``commit_layer`` >
+``tar_write`` with a bare ``gear_readback`` inside, then ``step`` alone,
+then ``chunk_index``, then ``build`` alone), lane B (``build`` >
+``stage``), and the hash service's thread (a bare ``sha_readback``, no
+span). Seconds from the mark, read off the file when it was recorded:
+
+    lane A  build 0.050244-0.450231   stage/step 0.0804-0.4002
+            tar_write 0.100179-0.200184   gear_readback 0.140212-0.180185
+            chunk_index 0.300227-0.400183
+    lane B  build 0.250196-0.350427   stage 0.250209-0.350212
+    service sha_readback 0.120199-0.220176
+"""
+
+import os
+import shutil
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.join(os.path.dirname(HERE), "perfbench"))
+
+from pbharness import cells, driver, hostspans, xplane  # noqa: E402
+
+TRACE = os.path.join(HERE, "hostspans_small.xplane.pb")
+MARK = "perfbench_window_open"
+# Three device gaps: inside tar_write; across step-alone, lane B's
+# start and chunk_index; over lane A's last stretch and past its end.
+GAPS = [(0.11, 0.19), (0.22, 0.32), (0.42, 0.50)]
+READERS = os.path.join(os.path.dirname(HERE), "perfbench", "readers")
+
+
+def _reader(name):
+    return cells._load_module(os.path.join(READERS, name + ".py")).read
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    return hostspans.host_events(TRACE, MARK)
+
+
+def test_mark_found_and_events_relative_to_it(recorded):
+    assert hostspans.host_events(TRACE, "no_such_mark") is None
+    by_name = {}
+    for ev in recorded:
+        by_name.setdefault(ev.name, []).append(ev)
+    assert sorted(by_name) == [
+        "build", "chunk_index", "commit_layer", "gear_readback",
+        "sha_readback", "stage", "step", "tar_write"]
+    assert len({ev.thread for ev in recorded}) == 3
+    [tar] = by_name["tar_write"]
+    assert (tar.start_s, tar.end_s) == pytest.approx((0.100179, 0.200184),
+                                                    abs=1e-6)
+    assert tar.span_id and not by_name["gear_readback"][0].span_id
+    a, b = sorted(by_name["build"], key=lambda ev: ev.start_s)
+    assert a.thread == tar.thread != b.thread
+    assert by_name["sha_readback"][0].thread not in (a.thread, b.thread)
+
+
+def test_gap_seconds_go_to_the_innermost_span(recorded):
+    charged, total = hostspans.charge_gaps(recorded, GAPS)
+    # Gap 1: lane A alone (the service's thread has no span open).
+    readback = 0.180185 - 0.140212
+    assert charged["gear_readback"] == pytest.approx(readback, abs=1e-6)
+    assert charged["tar_write"] == pytest.approx(0.08 - readback, abs=1e-6)
+    assert "sha_readback" not in charged and "commit_layer" not in charged
+    # Gap 2: lane A under step alone, then shared with lane B.
+    alone = 0.250196 - 0.22
+    b_root = 0.250209 - 0.250196      # lane B's build before its stage
+    shared = 0.300227 - 0.250209      # A in step, B in stage
+    index = 0.32 - 0.300227           # A in chunk_index, B in stage
+    assert charged["step"] == pytest.approx(
+        alone + b_root / 2 + shared / 2, abs=1e-6)
+    assert charged["stage"] == pytest.approx(shared / 2 + index / 2,
+                                             abs=1e-6)
+    assert charged["chunk_index"] == pytest.approx(index / 2, abs=1e-6)
+    # Gap 3: under lane A's root span alone, then under no span at all.
+    under_root = 0.450231 - 0.42
+    assert charged["build"] == pytest.approx(b_root / 2 + under_root,
+                                             abs=1e-6)
+    assert total == pytest.approx(0.08 + 0.10 + under_root, abs=1e-6)
+    assert sum(charged.values()) == pytest.approx(total, abs=1e-9)
+
+
+def test_what_only_structural_spans_cover_is_unspanned(recorded):
+    charged, total = hostspans.charge_gaps(recorded, GAPS)
+    want = 100.0 * (charged["build"] + charged["stage"]
+                    + charged["step"]) / total
+    assert hostspans.unspanned_pct(charged, total) == pytest.approx(want)
+    assert want == pytest.approx(57.2439, abs=1e-3)
+    # A gap with no build executing is not idle time of a build.
+    assert hostspans.charge_gaps(recorded, [(0.46, 0.50)]) == ({}, 0.0)
+    assert hostspans.unspanned_pct({}, 0.0) is None
+    # Wholly inside chunk_index and lane B's stage.
+    charged, total = hostspans.charge_gaps(recorded, [(0.31, 0.33)])
+    assert hostspans.unspanned_pct(charged, total) == pytest.approx(50.0)
+
+
+# -- the readers, on a run made by hand -----------------------------------
+
+
+def _build(spans, ok=True):
+    b = driver.Build(lane=0, index=0, kind="rebuild", tag="", context="",
+                     storage="", context_bytes=1, exit_code=0 if ok else 1,
+                     terminal={"x": 1})
+    b.spans = spans
+    return b
+
+
+def _series(name, value, **labels):
+    return (name, tuple(sorted(labels.items()))), value
+
+
+BUSY = "makisu_commit_stage_busy_seconds"
+MOVED = "makisu_device_transfer_bytes_total"
+
+
+@pytest.fixture
+def run(tmp_path):
+    r = driver.Run(cell=None, seed=1, seconds=45.0, trace=True,
+                   work_dir=str(tmp_path))
+    r.counted = [
+        _build([("memfs_sync", 1.3), ("memfs_sync.os_sync", 0.25),
+                ("memfs_sync.mtime_wait", 0.75), ("tar_write", 0.5),
+                ("tar_write", 1.5), ("chunk_index", 6.0),
+                ("apply_layer", 0.25), ("apply_layer.inflate", 0.24)]),
+        _build([("memfs_sync.os_sync", 0.75),
+                ("memfs_sync.mtime_wait", 0.25), ("tar_write", 1.0),
+                ("chunk_index", 2.0), ("apply_layer", 0.75)]),
+        _build([("tar_write", 99.0)], ok=False),
+    ]
+    r.builds = list(r.counted)
+    r.counters_open = dict([
+        _series(BUSY, 10.0, stage="gear_readback"),
+        _series(BUSY, 5.0, stage="tar_write"),
+        _series(MOVED, 1e6, direction="h2d", stage="gear"),
+        _series("makisu_session_dirty_paths_total", 4.0)])
+    r.counters_close = dict([
+        _series(BUSY, 10.6, stage="gear_readback"),
+        _series(BUSY, 0.3, stage="sha_readback"),
+        _series(BUSY, 1.5, stage="service_wait"),
+        _series(BUSY, 50.0, stage="tar_write"),
+        _series(MOVED, 31e6, direction="h2d", stage="gear"),
+        _series(MOVED, 120e6, direction="h2d", stage="sha"),
+        _series(MOVED, 3e6, direction="d2h", stage="gear"),
+        _series("makisu_session_dirty_paths_total", 13.0)])
+    return r
+
+
+# Two of the three counted builds ended well: spans are summed over
+# those and divided by 2; counters grow over the window and are divided
+# by the 3 counted.
+@pytest.mark.parametrize("metric,want", [
+    ("sync_os_sync_s_per_build", (0.25 + 0.75) / 2),
+    ("sync_mtime_wait_s_per_build", (0.75 + 0.25) / 2),
+    ("tar_write_s_per_build", (0.5 + 1.5 + 1.0) / 2),
+    ("chunk_index_s_per_build", (6.0 + 2.0) / 2),
+    ("apply_layer_s_per_build", (0.25 + 0.75) / 2),
+    ("device_wait_s_per_build", (0.6 + 0.3 + 1.5) / 3),
+    ("device_transfer_mb_per_build", (30 + 120 + 3) / 3),
+    ("session_dirty_paths_per_build", 9.0 / 3),
+])
+def test_reader_gives_the_hand_computed_value(run, metric, want):
+    assert _reader(metric)(run) == pytest.approx(want)
+
+
+def test_idle_unspanned_reader_reads_the_runs_trace(run, capsys):
+    read = _reader("idle_unspanned_pct")
+    assert read(run) is None                      # no device trace
+    run.device_trace = xplane.DeviceTrace(
+        window_s=0.5, busy_s=0.0, devices=1, ops={}, gaps=GAPS)
+    assert read(run) is None                      # no trace file
+    where = os.path.join(run.work_dir, "trace", "plugins", "profile", "x")
+    os.makedirs(where)
+    shutil.copy(TRACE, os.path.join(where, "host.xplane.pb"))
+    assert read(run) == pytest.approx(57.2439, abs=1e-3)
+    assert "idle seconds by innermost program span" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("metric", [
+    "sync_os_sync_s_per_build", "sync_mtime_wait_s_per_build",
+    "tar_write_s_per_build", "chunk_index_s_per_build",
+    "device_wait_s_per_build", "device_transfer_mb_per_build",
+    "session_dirty_paths_per_build"])
+def test_reader_returns_nothing_for_a_program_without_the_span(run, metric):
+    """The parent commit has none of these spans or counters: the line
+    leaves the metric out, and nothing raises."""
+    for b in run.counted:
+        b.spans = [("commit_layer", 2.0), ("apply_layer", 0.5)]
+    old = {("makisu_device_h2d_bytes_total", (("bucket", "16384"),)): 8.0}
+    run.counters_open, run.counters_close = dict(old), dict(old)
+    assert _reader(metric)(run) is None
+
+
+def test_every_new_metric_has_its_reader_and_its_cells():
+    import json
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json"),
+              encoding="utf-8") as f:
+        benchmark = json.load(f)
+    cells_of = {m["name"]: m["workloads"] for m in benchmark["per_layer"]}
+    every = ["monorepo-cold", "farm-churn", "monorepo-edit",
+             "farm-unchanged"]
+    assert cells_of["idle_unspanned_pct"] == every
+    assert cells_of["sync_os_sync_s_per_build"] == every
+    assert cells_of["chunk_index_s_per_build"] == ["monorepo-cold",
+                                                   "monorepo-edit"]
+    for name in cells_of:
+        assert os.path.exists(os.path.join(READERS, name + ".py")), name

@@ -1,0 +1,454 @@
+"""The span-and-counter plane below ``commit_layer`` (PR 24): every
+span at its boundary with its parent, the device-feed stages and the
+bytes that cross, the two counters, the profiler's clock, and the names
+on the device."""
+
+import glob
+import json
+import re
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+from makisu_tpu import cli
+from makisu_tpu.chunker import cdc
+from makisu_tpu.chunker.service import HashService
+from makisu_tpu.ops import gear, gear_pallas, sha256, sha256_pallas
+from makisu_tpu.utils import events, metrics, traceexport
+
+# span -> its parent, for the spans one layer commit opens once.
+_PER_LAYER = {
+    "memfs_sync": "commit_layer",
+    "memfs_sync.os_sync": "memfs_sync",
+    "memfs_sync.mtime_wait": "memfs_sync",
+    "layer_scan": "commit_layer",
+    "tar_write": "commit_layer",
+    "sink_finish": "commit_layer",
+}
+
+
+def _build(tmp_path, hasher, n, tag="spans/plane:1"):
+    """Build ``tmp_path/ctx`` (two COPY layers) for the n-th time;
+    returns (event log, report)."""
+    report = tmp_path / f"report{n}.json"
+    log = tmp_path / f"events{n}.jsonl"
+    code = cli.main([
+        "--log-level", "error", "--metrics-out", str(report),
+        "--events-out", str(log),
+        "build", str(tmp_path / "ctx"), "-t", tag,
+        "--storage", str(tmp_path / "storage"),
+        "--root", str(tmp_path / "root"), "--hasher", hasher])
+    assert code == 0
+    with open(report, encoding="utf-8") as f:
+        return events.read_jsonl(str(log)), json.load(f)
+
+
+def _context(tmp_path):
+    ctx = tmp_path / "ctx"
+    for seed, name in enumerate(("a", "b")):
+        (ctx / name).mkdir(parents=True)
+        (ctx / name / "f.bin").write_bytes(
+            np.random.default_rng(seed).bytes(40_000))
+    (ctx / "Dockerfile").write_text(
+        "FROM scratch\nCOPY a /a/\nCOPY b /b/\n")
+    (tmp_path / "root").mkdir()
+
+
+def _spans(event_log):
+    """[(name, parent name, attrs at start and end, duration, span_id,
+    parent_id)] in opening order."""
+    names = {e["span_id"]: e["name"] for e in event_log
+             if e["type"] == "span_start"}
+    ends = {e["span_id"]: e for e in event_log if e["type"] == "span_end"}
+    return [(e["name"], names.get(e["parent_id"], ""),
+             {**(e.get("attrs") or {}),
+              **(ends[e["span_id"]].get("attrs") or {})},
+             ends[e["span_id"]]["duration"], e["span_id"], e["parent_id"])
+            for e in event_log if e["type"] == "span_start"]
+
+
+def _counter(report, name, **labels):
+    return sum(s["value"] for s in report["counters"].get(name, [])
+               if all(s["labels"].get(k) == v for k, v in labels.items()))
+
+
+@pytest.fixture
+def no_sleep(monkeypatch):
+    """Layer commits without the one-second mtime wait."""
+    from makisu_tpu.snapshot import memfs
+    original = memfs.MemFS.__init__
+
+    def init(self, *args, **kwargs):
+        original(self, *args, **kwargs)
+        self.sync_wait = 0.05
+    monkeypatch.setattr(memfs.MemFS, "__init__", init)
+
+
+@pytest.mark.parametrize("hasher", ["cpu", "tpu"])
+def test_each_boundary_span_once_per_layer_under_its_parent(
+        tmp_path, no_sleep, hasher):
+    """``--hasher cpu`` opens every span of the table but
+    ``chunk_index`` (the chunk store attaches to the tpu hasher; here
+    its native route)."""
+    _context(tmp_path)
+    event_log, report = _build(tmp_path, hasher, 1)
+    spans = _spans(event_log)
+    commits = [s for s in spans if s[0] == "commit_layer"]
+    assert len(commits) == 2
+    for commit in commits:
+        children = [s for s in spans if s[5] == commit[4]]
+        sync_id = next(s[4] for s in children if s[0] == "memfs_sync")
+        family = children + [s for s in spans if s[5] == sync_id]
+        assert sorted(s[0] for s in family) == sorted(_PER_LAYER)
+        for name, parent, *_ in family:
+            assert parent == _PER_LAYER[name]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s[0], []).append(s)
+    assert [s[1] for s in by_name["session_begin"]] == ["build"]
+    assert [s[1] for s in by_name["session_finish"]] == ["build"]
+    assert [s[1] for s in by_name["copy_checksum"]] == ["context_scan"] * 2
+    assert all(s[2]["files"] == "1" for s in by_name["copy_checksum"])
+    assert by_name["session_begin"][0][2]["mode"] == "rescan"
+    for name, attr in (("layer_scan", "entries"), ("tar_write", "bytes"),
+                       ("sink_finish", "chunks")):
+        assert all(int(s[2][attr]) >= 0 for s in by_name[name])
+    assert all(int(s[2]["bytes"]) > 40_000 for s in by_name["tar_write"])
+    # One pair of clock reads: the stage counter is the spans' sum.
+    assert _counter(report, metrics.COMMIT_STAGE_BUSY, stage="tar_write") \
+        == pytest.approx(sum(s[3] for s in by_name["tar_write"]), abs=1e-5)
+    if hasher == "cpu":
+        assert "chunk_index" not in by_name
+    else:
+        assert [s[1] for s in by_name["chunk_index"]] == ["step", "step"]
+        for s in by_name["chunk_index"]:
+            assert int(s[2]["chunks"]) >= int(s[2]["added"]) >= 1
+            assert int(s[2]["bytes_added"]) >= int(s[2]["added"])
+
+
+def test_memfs_sync_holds_its_two_children(tmp_path, no_sleep):
+    _context(tmp_path)
+    spans = _spans(_build(tmp_path, "cpu", 1)[0])
+    syncs = [s for s in spans if s[0] == "memfs_sync"]
+    assert len(syncs) == 2
+    for sync in syncs:
+        parts = {s[0]: s[3] for s in spans if s[5] == sync[4]}
+        assert set(parts) == {"memfs_sync.os_sync", "memfs_sync.mtime_wait"}
+        assert sum(parts.values()) <= sync[3]
+        assert sync[3] >= 0.05
+
+
+def test_layer_replay_counter_and_inflate_span(tmp_path, no_sleep):
+    """Rebuilds of an unchanged context in one process: the first
+    inflates both cached layers (``apply_layer.inflate`` under
+    ``apply_layer``), the second replays them from the session's memo;
+    an edit then shows in the dirty set's counter and span."""
+    _context(tmp_path)
+    _build(tmp_path, "cpu", 1)
+    event_log, report = _build(tmp_path, "cpu", 2)
+    spans = _spans(event_log)
+    assert [s[1] for s in spans if s[0] == "apply_layer.inflate"] \
+        == ["apply_layer"] * 2
+    assert _counter(report, metrics.LAYER_REPLAY_TOTAL,
+                    result="inflate") == 2
+    assert _counter(report, metrics.LAYER_REPLAY_TOTAL, result="memo") == 0
+    assert _counter(report, metrics.SESSION_DIRTY_PATHS) == 0
+
+    event_log, report = _build(tmp_path, "cpu", 3)
+    spans = _spans(event_log)
+    assert len([s for s in spans if s[0] == "apply_layer"]) == 2
+    assert not [s for s in spans if s[0] == "apply_layer.inflate"]
+    assert _counter(report, metrics.LAYER_REPLAY_TOTAL, result="memo") == 2
+    assert _counter(report, metrics.LAYER_REPLAY_TOTAL,
+                    result="inflate") == 0
+
+    (tmp_path / "ctx" / "b" / "f.bin").write_bytes(b"edited")
+    event_log, report = _build(tmp_path, "cpu", 4)
+    [begin] = [s for s in _spans(event_log) if s[0] == "session_begin"]
+    dirty = _counter(report, metrics.SESSION_DIRTY_PATHS)
+    assert dirty >= 1
+    assert begin[2] == {"mode": "resident", "dirty": str(int(dirty))}
+
+
+def test_session_resync_span_when_watches_are_rebuilt(tmp_path):
+    from makisu_tpu.worker import session
+    watcher = session.InotifyWatcher(str(tmp_path), [])
+    if not watcher.healthy:
+        pytest.skip("no inotify here")
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
+    try:
+        watcher.resync()             # steady path: nothing to do
+        assert not registry.root.children
+        (tmp_path / "new_dir").mkdir()
+        watcher.collect()
+        watcher.resync()
+    finally:
+        metrics.reset_build_registry(token)
+        watcher.close()
+    [span] = registry.root.children
+    assert span.name == "session_resync"
+    assert span.attrs == {"watches": "2"}
+
+
+def test_late_attrs_ride_span_end_into_the_trace():
+    seen = []
+    token = events.add_sink(seen.append)
+    try:
+        with metrics.span("outer", directive="COPY") as sp:
+            sp.set(entries=3, bytes=1024)
+        with metrics.span("plain"):
+            pass
+    finally:
+        events.reset_sink(token)
+    ends = {e["name"]: e for e in seen if e["type"] == "span_end"}
+    assert ends["outer"]["attrs"] == {"entries": "3", "bytes": "1024"}
+    assert "attrs" not in ends["plain"]
+    tree = traceexport.assemble_fleet_trace(seen)
+    [outer] = [s for s in _walk(tree) if s.get("name") == "outer"]
+    assert outer["attrs"] == {"directive": "COPY", "entries": "3",
+                              "bytes": "1024"}
+
+
+def _walk(node):
+    if isinstance(node, dict):
+        yield node
+        for value in node.values():
+            yield from _walk(value)
+    elif isinstance(node, list):
+        for item in node:
+            yield from _walk(item)
+
+
+@pytest.mark.parametrize("name,phase", [
+    ("memfs_sync.mtime_wait", "hash"), ("layer_scan", "hash"),
+    ("tar_write", "hash"), ("sink_finish", "hash"),
+    ("chunk_index", "chunk"), ("apply_layer.inflate", "other"),
+    ("copy_checksum", "other"), ("session_begin", "other")])
+def test_spans_under_commit_layer_keep_its_phase(name, phase):
+    """`report`, `history` and the sampler split a build by phase; the
+    spans that now sit inside ``commit_layer`` must not move its
+    seconds out of ``hash``."""
+    assert traceexport.phase_of("commit_layer") == "hash"
+    assert traceexport.phase_of(name) == phase
+
+
+# -- the device feed ------------------------------------------------------
+
+
+def test_feed_clock_charges_a_nested_stage_to_itself(monkeypatch):
+    now = [0.0]
+    monkeypatch.setattr(cdc.time, "monotonic", lambda: now[0])
+    clock = cdc.FeedClock()
+    with clock.stage("host_cut"):
+        now[0] += 1.0
+        with clock.stage("sha_dispatch"):
+            now[0] += 0.25
+        clock.add("service_wait", 0.5)
+        now[0] += 0.5 + 2.0
+    with clock.stage("host_cut"):
+        now[0] += 0.125
+    assert dict(clock.seconds) == {
+        "host_cut": 3.125, "sha_dispatch": 0.25, "service_wait": 0.5}
+    clock.moved("h2d", "gear", 10)
+    clock.moved("h2d", "gear", 5)
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
+    try:
+        clock.flush()
+    finally:
+        metrics.reset_build_registry(token)
+    assert registry.counter_total(metrics.COMMIT_STAGE_BUSY,
+                                  stage="host_cut") == 3.125
+    assert registry.counter_total(metrics.DEVICE_TRANSFER_BYTES,
+                                  direction="h2d", stage="gear") == 15
+    assert not clock.seconds and not clock.bytes
+
+
+@pytest.fixture
+def device_route(monkeypatch):
+    """The device formulation on the JAX CPU backend: the Pallas gear
+    kernel in interpret mode, SHA lanes through XLA."""
+    monkeypatch.setenv("MAKISU_TPU_CHUNK_NATIVE", "0")
+    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
+    monkeypatch.delenv("MAKISU_TPU_PALLAS_V2", raising=False)
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
+    yield registry
+    metrics.reset_build_registry(token)
+
+
+_BLOCK = 128 * 1024
+_STREAM = 300_000
+
+
+def _expected_gear_upload():
+    """Stream bytes plus each later block's halo plus the padding of
+    each block's live region to the kernel's row grid."""
+    total, left, first = 0, _STREAM, True
+    while left:
+        live = min(left, _BLOCK)
+        total += (0 if first else gear_pallas.HALO) \
+            + gear_pallas.padded_rows_for(live) * gear_pallas.ROW
+        left -= live
+        first = False
+    return total
+
+
+def test_chunk_session_records_the_feed_stages_and_crossings(device_route):
+    payload = np.random.default_rng(24).integers(
+        0, 256, size=_STREAM, dtype=np.uint8).tobytes()
+    session = cdc.ChunkSession(block=_BLOCK)
+    session.update(payload)
+    chunks = session.finish()
+    assert sum(c.length for c in chunks) == _STREAM
+    for stage in ("gear_dispatch", "gear_readback", "host_cut",
+                  "sha_dispatch", "sha_readback"):
+        assert device_route.counter_total(
+            metrics.COMMIT_STAGE_BUSY, stage=stage) > 0, stage
+    assert device_route.counter_total(
+        metrics.COMMIT_STAGE_BUSY, stage="service_wait") == 0
+
+    def moved(direction, stage):
+        return device_route.counter_total(
+            metrics.DEVICE_TRANSFER_BYTES, direction=direction, stage=stage)
+    assert _expected_gear_upload() == 327_936
+    assert moved("h2d", "gear") == _expected_gear_upload()
+    # One packed bit per position of every row the kernel wrote.
+    rows = sum(gear_pallas.padded_rows_for(n)
+               for n in (_BLOCK, _BLOCK, _STREAM - 2 * _BLOCK))
+    assert moved("d2h", "gear") == rows * gear_pallas.ROW // 8
+    # Each bucket flushed once: its lane buffer and its lengths up,
+    # eight words a lane down. The old counter is the buffers alone.
+    lanes = [(cap, n) for cap, n in cdc._BUCKETS]
+    assert moved("h2d", "sha") == sum(n * cap + 4 * n for cap, n in lanes)
+    assert moved("d2h", "sha") == sum(32 * n for _, n in lanes)
+    assert device_route.counter_total(metrics.DEVICE_H2D_BYTES) \
+        == sum(n * cap for cap, n in lanes)
+
+
+def test_service_route_records_the_wait_and_the_dispatchers_side(
+        device_route):
+    payload = np.random.default_rng(25).integers(
+        0, 256, size=_STREAM, dtype=np.uint8).tobytes()
+    g = metrics.global_registry()
+    before = {stage: g.counter_total(metrics.COMMIT_STAGE_BUSY, stage=stage)
+              for stage in ("sha_dispatch", "sha_readback")}
+    up = g.counter_total(metrics.DEVICE_TRANSFER_BYTES,
+                         direction="h2d", stage="sha")
+    service = HashService(linger_seconds=0.02)
+    try:
+        session = cdc.ChunkSession(block=_BLOCK, service=service)
+        session.update(payload)
+        assert sum(c.length for c in session.finish()) == _STREAM
+    finally:
+        service.close()
+    # The build's side: it waited for the service, and fed the gear
+    # scan itself.
+    assert device_route.counter_total(
+        metrics.COMMIT_STAGE_BUSY, stage="service_wait") > 0
+    assert device_route.counter_total(
+        metrics.DEVICE_TRANSFER_BYTES, direction="h2d",
+        stage="gear") == _expected_gear_upload()
+    assert device_route.counter_total(
+        metrics.COMMIT_STAGE_BUSY, stage="sha_readback") == 0
+    # The dispatcher's side runs outside any build: process totals.
+    for stage, was in before.items():
+        assert g.counter_total(metrics.COMMIT_STAGE_BUSY, stage=stage) > was
+    assert g.counter_total(metrics.DEVICE_TRANSFER_BYTES,
+                           direction="h2d", stage="sha") > up
+
+
+# -- the profiler's clock -------------------------------------------------
+
+
+def test_span_without_a_factory_imports_no_jax():
+    code = (
+        "import sys\n"
+        "from makisu_tpu.utils import metrics\n"
+        "with metrics.span('a', k=1) as sp:\n"
+        "    with metrics.annotation('gear_dispatch'):\n"
+        "        sp.set(n=2)\n"
+        "assert sp.duration is not None\n"
+        "assert not [m for m in sys.modules if m.split('.')[0] == 'jax']\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+@pytest.fixture
+def on_profiler_clock():
+    was = metrics._annotation_factory
+    metrics.set_annotation_factory(jax.profiler.TraceAnnotation)
+    yield
+    metrics.set_annotation_factory(was)
+
+
+def test_span_shows_on_the_profilers_host_plane(tmp_path, on_profiler_clock):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with metrics.span("plane_probe", directive="COPY") as sp:
+            with metrics.annotation("gear_dispatch"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in ("plane_probe", "gear_dispatch"):
+                        found[ev.name] = (ev, dict(ev.stats))
+    ev, stats = found["plane_probe"]
+    assert stats["span_id"] == sp.span_id
+    assert stats["trace_id"] == sp.registry.trace_id
+    assert ev.duration_ns / 1e9 == pytest.approx(sp.duration, abs=0.05)
+    inner, inner_stats = found["gear_dispatch"]
+    assert "span_id" not in inner_stats
+    assert ev.start_ns <= inner.start_ns \
+        and inner.start_ns + inner.duration_ns <= ev.start_ns + ev.duration_ns
+
+
+def test_backend_probe_installs_the_factory(monkeypatch):
+    from makisu_tpu.ops import backend
+    monkeypatch.setattr(metrics, "_annotation_factory", None)
+    backend._annotate_spans(jax)
+    assert metrics._annotation_factory is jax.profiler.TraceAnnotation
+
+
+# -- names on the device --------------------------------------------------
+
+_U8 = np.uint8
+_NAMED = [
+    ("gear_scan", gear.gear_bitmap,
+     (jax.ShapeDtypeStruct((2048,), _U8),), {}),
+    ("gear_scan", gear_pallas.gear_bitmap_flat,
+     (jax.ShapeDtypeStruct((gear_pallas.HALO + 65536,), _U8),
+      gear_pallas.HALO), {"interpret": True}),
+    ("chunk_sha", sha256.sha256_lanes,
+     (jax.ShapeDtypeStruct((8, 128), _U8),
+      jax.ShapeDtypeStruct((8,), np.int32)), {}),
+    ("chunk_sha", sha256_pallas.sha256_lanes_pallas,
+     (jax.ShapeDtypeStruct((8, 128), _U8),
+      jax.ShapeDtypeStruct((8,), np.int32)), {"interpret": True}),
+]
+
+
+@pytest.mark.parametrize("scope,fn,args,static", _NAMED,
+                         ids=["gear_xla", "gear_pallas", "sha_xla",
+                              "sha_pallas"])
+def test_every_operation_of_a_step_carries_its_scope(scope, fn, args,
+                                                     static):
+    text = fn.lower(*args, **static).as_text(debug_info=True)
+    # The step's own operations (a jitted helper such as jnp.where is
+    # lowered once, with paths relative to itself).
+    paths = re.findall(rf'loc\("(jit\({fn.__name__}\)[^"]*)"', text)
+    assert len(paths) > 10
+    assert all(f"/{scope}" in p for p in paths), \
+        [p for p in paths if f"/{scope}" not in p][:3]

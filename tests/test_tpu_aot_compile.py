@@ -15,6 +15,8 @@ scan unrolls (``ops/sha256.py _unroll``) and the Pallas gate. The
 fixture below makes tracing see a TPU process.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -83,6 +85,18 @@ def test_program_compiles_for_v5e(v5e, traced_as_tpu, program):
             for shape, dtype in program.shapes]
     compiled = program.fn.lower(*args, **program.static).compile()
     assert compiled is not None
+    # What the benchmark reads in a device trace: every operation's
+    # name path carries its step's scope, and each production kernel's
+    # custom call keeps the name the roofline readers key on.
+    text = compiled.as_text()
+    scope = "gear_scan" if program.name.startswith("gear") else "chunk_sha"
+    paths = re.findall(r'op_name="(jit\([^"]*)"', text)
+    assert paths and all(f"/{scope}" in p for p in paths)
+    if "pallas" in program.name:
+        kernel = {"gear_pallas_v2": "gear_bitmap_flat2"}.get(
+            program.name, "gear_bitmap_flat" if scope == "gear_scan"
+            else "sha256_lanes_pallas")
+        assert re.search(rf"%{kernel}\.\d+ = [^\n]*custom-call\(", text)
 
 
 def test_table_covers_the_production_shapes():
