@@ -16,6 +16,7 @@ rides the same registry blob plane the layer cache already uses.
 
 from __future__ import annotations
 
+import collections
 import gzip as gzip_mod
 import hashlib
 import os
@@ -254,7 +255,7 @@ class ChunkStore:
         # storm on a 4GB layer) runs on the commit pool DURING the
         # commit instead of serially inside index_layer afterwards.
         import threading
-        self._exists_memo: dict[str, bool] = {}
+        self._exists_memo: dict[str, bool | None] = {}
         self._probe_queue: list[str] = []
         self._memo_gen = 0  # bumped by reset; stale probes discard
         self._memo_lock = threading.Lock()
@@ -312,12 +313,12 @@ class ChunkStore:
         called from the commit pipeline as each chunk digest resolves.
         Existence stats batch onto the commit pool and memoize for
         index_layer; a tail shorter than PROBE_BATCH simply never
-        probes (advisory — _exists_cached falls back to the stat).
+        probes (advisory — index_layer's window makes the stat).
         Thread-safe, never raises."""
         with self._memo_lock:
             if hex_digest in self._exists_memo:
                 return
-            self._exists_memo[hex_digest] = False  # claimed; stat fills
+            self._exists_memo[hex_digest] = None  # claimed; stat fills
             self._probe_queue.append(hex_digest)
             if len(self._probe_queue) < self.PROBE_BATCH:
                 return
@@ -325,46 +326,36 @@ class ChunkStore:
             gen = self._memo_gen
 
         def probe(batch=batch, gen=gen) -> None:
-            hits = []
+            found = {}
             for h in batch:
                 try:
-                    if self.cas.exists(h):
-                        hits.append(h)
+                    found[h] = self.cas.exists(h)
                 except Exception:  # noqa: BLE001 - advisory stat
                     return
             with self._memo_lock:
                 if self._memo_gen != gen:
                     # reset_fingerprint_memo ran while this batch was
-                    # queued: its Trues belong to the PREVIOUS window
+                    # queued: its answers belong to the PREVIOUS window
                     # and must not repopulate the cleared memo.
                     return
-                for h in hits:
-                    self._exists_memo[h] = True
+                self._exists_memo.update(found)
         from makisu_tpu.utils import concurrency
         # Plain submit (no context copy): the probe touches no
         # telemetry, and a copy per batch on the hot path buys nothing.
         # check: allow(ctx-propagation)
         concurrency.hash_pool().submit(probe)
 
-    def _exists_cached(self, hex_digest: str,
-                       tally: list | None = None) -> bool:
-        """index_layer's dedup probe: the prefetched memo when the
-        observer saw this digest, else a plain stat. Only a memoized
-        True short-circuits the stat — a prefetch-time miss re-probes,
-        because the commit itself may have stored the chunk since (a
-        digest repeated within one layer). ``tally`` ([hits, probes])
-        accumulates for a caller-side flush: one labeled counter_add
-        per CHUNK is exactly the overhead the commit pipeline removed
-        from the hash path."""
+    def _probed(self, hex_digest: str) -> bool | None:
+        """What the streamed probe knows of a digest: True (stored),
+        False (it looked, and the chunk was not there) or None (it never
+        looked). index_layer takes both answers as they are: a True
+        cannot outlive its commit (reset_fingerprint_memo), and a False
+        that has gone stale, because another build stored the chunk
+        since, costs one write of identical bytes over it, never a
+        missing chunk. A digest the commit itself stores twice (repeated
+        within the layer) is index_layer's own to catch."""
         with self._memo_lock:
-            hit = self._exists_memo.get(hex_digest)
-        if tally is None:
-            tally = [0, 0]
-        if hit:
-            tally[0] += 1
-            return True
-        tally[1] += 1
-        return self.cas.exists(hex_digest)
+            return self._exists_memo.get(hex_digest)
 
     def reset_fingerprint_memo(self) -> None:
         """Drop the streamed memo. Called after every index_layer
@@ -472,49 +463,135 @@ class ChunkStore:
     def put(self, hex_digest: str, data: bytes) -> None:
         if hashlib.sha256(data).hexdigest() != hex_digest:
             raise ValueError(f"chunk content does not match {hex_digest}")
-        self.cas.write_bytes(hex_digest, data)
+        self.cas.write_many(((hex_digest, data),))
+        metrics.counter_add(metrics.CHUNK_INGEST, result="written")
+
+    # index_layer's ingest window. Chunks leave the gunzip pass in
+    # batches of up to INGEST_BATCH_BYTES (four of the largest chunk the
+    # chunker cuts, 64 KiB; ~32 average ones), at most INGEST_WRITERS of
+    # them on the commit pool at once, so the bytes staged outside the
+    # stream never pass (INGEST_WRITERS + 1) batches and one chunk,
+    # whatever the layer's size.
+    INGEST_WRITERS = 8
+    INGEST_BATCH_BYTES = 4 * 65536
+
+    def _ingest_batch(self, batch: list[tuple[str, bytes, bool]]
+                      ) -> list[str]:
+        """One writer of index_layer's window, on the commit pool:
+        probe the entries nobody has looked for, verify and store the
+        new ones. Returns the digests it stored, in batch order."""
+        new = [(h, data) for h, data, probe in batch
+               if not (probe and self.cas.exists(h))]
+        for hex_digest, data in new:
+            if hashlib.sha256(data).hexdigest() != hex_digest:
+                raise ValueError(
+                    f"chunk content does not match {hex_digest}")
+        self.cas.write_many(new)
+        return [h for h, _ in new]
 
     def index_layer(self, layer_blob_path: str,
-                    chunks: list[tuple[int, int, str]]) -> list[str]:
+                    chunks: list[tuple[int, int, str]],
+                    stats: dict | None = None) -> list[str]:
         """Slice a layer's uncompressed stream into its chunks and store
         any that are new locally (never fetching: the bytes are already
-        in hand). Returns the hex digests newly added.
+        in hand). Returns the hex digests newly added, each once, in
+        offset order; ``stats`` (if given) receives ``ingest_window``,
+        the peak number of writers in flight.
 
         Decompression is streamed — the chunk list is offset-sorted and
-        contiguous, so one forward pass over the gzip stream suffices and
-        memory stays bounded by the largest chunk (multi-GB layers never
-        materialize whole)."""
+        contiguous, so one forward pass over the gzip stream suffices —
+        and the stores run behind it through a bounded window of
+        writers (``_ingest_batch``), so memory stays bounded by the
+        window (multi-GB layers never materialize whole). The first
+        failure, of the stream or of any writer, is raised once the
+        window has drained; a writer that fails leaves nothing under a
+        final name."""
+        from makisu_tpu.utils import concurrency
         added: list[str] = []
-        tally = [0, 0]  # [prefetch hits, stat probes]; flushed below
-        with open(layer_blob_path, "rb") as raw:
-            stream = gzip_mod.GzipFile(fileobj=raw, mode="rb")
-            pos = 0
-            for offset, length, hex_digest in chunks:
-                if offset < pos:
-                    raise ValueError(
-                        f"chunk list not offset-sorted at {offset} < {pos}")
-                _skip(stream, offset - pos)
-                data = stream.read(length)
-                pos = offset + len(data)
-                if len(data) != length:
-                    raise ValueError(
-                        f"layer stream ended at {pos}, chunk needs "
-                        f"{offset + length}")
-                if self._exists_cached(hex_digest, tally):
-                    continue
-                self.put(hex_digest, data)
-                added.append(hex_digest)
-            # Drain to EOF so GzipFile validates the CRC32/ISIZE trailer
-            # (gzip.decompress did this implicitly before the rewrite);
-            # a corrupt blob must fail loudly here, not at reconstitute.
-            while stream.read(1 << 20):
-                pass
-        if tally[0]:
-            metrics.counter_add("makisu_chunk_exists_prefetch_total",
-                                tally[0], result="hit")
-        if tally[1]:
-            metrics.counter_add("makisu_chunk_exists_prefetch_total",
-                                tally[1], result="probe")
+        # result -> chunks; flushed into the counters below. "raced" is
+        # a digest this call already handed to a writer: judged here,
+        # where a stat would race the write in flight.
+        tally = collections.Counter()
+        handed: set[str] = set()
+        pool = concurrency.hash_pool()
+        window: collections.deque = collections.deque()
+        batch: list[tuple[str, bytes, bool]] = []
+        batch_bytes = 0
+        peak = 0
+        failure: list[BaseException] = []
+
+        def reap() -> None:
+            try:
+                added.extend(window.popleft().result())
+            except Exception as e:  # noqa: BLE001 - re-raised below
+                failure.append(e)
+
+        def flush() -> None:
+            nonlocal batch, batch_bytes, peak
+            while len(window) >= self.INGEST_WRITERS:
+                reap()
+            if not batch or failure:
+                return
+            window.append(concurrency.submit_ctx(
+                pool, self._ingest_batch, batch))
+            peak = max(peak, sum(not f.done() for f in window))
+            batch, batch_bytes = [], 0
+
+        try:
+            with open(layer_blob_path, "rb") as raw:
+                stream = gzip_mod.GzipFile(fileobj=raw, mode="rb")
+                pos = 0
+                for offset, length, hex_digest in chunks:
+                    if failure:
+                        break
+                    if offset < pos:
+                        raise ValueError(
+                            f"chunk list not offset-sorted at "
+                            f"{offset} < {pos}")
+                    _skip(stream, offset - pos)
+                    data = stream.read(length)
+                    pos = offset + len(data)
+                    if len(data) != length:
+                        raise ValueError(
+                            f"layer stream ended at {pos}, chunk needs "
+                            f"{offset + length}")
+                    known = self._probed(hex_digest)
+                    if known:
+                        tally["hit"] += 1
+                        continue
+                    if hex_digest in handed:
+                        tally["raced"] += 1
+                        continue
+                    handed.add(hex_digest)
+                    tally["probe" if known is None else "miss"] += 1
+                    batch.append((hex_digest, data, known is None))
+                    batch_bytes += length
+                    if batch_bytes >= self.INGEST_BATCH_BYTES:
+                        flush()
+                # Drain to EOF so GzipFile validates the CRC32/ISIZE
+                # trailer (gzip.decompress did this implicitly before
+                # the rewrite); a corrupt blob must fail loudly here,
+                # not at reconstitute.
+                while not failure and stream.read(1 << 20):
+                    pass
+            flush()
+        finally:
+            while window:
+                reap()
+        if failure:
+            raise failure[0]
+        if stats is not None:
+            stats["ingest_window"] = peak
+        for result in ("hit", "miss", "probe"):
+            if tally[result]:
+                metrics.counter_add("makisu_chunk_exists_prefetch_total",
+                                    tally[result], result=result)
+        for result, n in (
+                ("written", len(added)),
+                ("present", len(handed) - len(added) + tally["hit"]),
+                ("raced", tally["raced"])):
+            if n:
+                metrics.counter_add(metrics.CHUNK_INGEST, n, result=result)
         return added
 
     def build_packs(self, chunks: list[tuple[int, int, str]],
@@ -1147,11 +1224,13 @@ def attach_chunk_dedup(manager, chunk_root: str) -> ChunkStore:
                            for c in commit.chunks]
                 with metrics.span("chunk_index",
                                   chunks=len(triples)) as sp:
-                    added = chunk_store.index_layer(path, triples)
+                    stats: dict = {}
+                    added = chunk_store.index_layer(path, triples, stats)
                     metrics.counter_add("makisu_chunks_indexed_total",
                                         len(added))
                     sp.set(added=len(added), bytes_added=_record_index(
-                        layer_hex, cache_id, triples, added))
+                        layer_hex, cache_id, triples, added),
+                        ingest_window=stats["ingest_window"])
                 log.info("indexed %d new chunks for %s", len(added),
                          cache_id)
                 _spawn_recipe_publish(pair, triples, commit, cache_id)

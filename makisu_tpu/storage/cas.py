@@ -4,29 +4,67 @@ Reference capabilities covered: lib/storage/layer_tar_store.go (CAS by hex
 digest, download→cache state transition, hardlink in/out, LRU 256) and the
 generic machinery under lib/storage/base/ (atomic state transitions,
 last-access tracking, sharded dirs). Implementation is original: one class,
-per-key locks via a single mutex + atomic os.rename commits, eviction by
-persisted last-access time.
+atomic os.rename commits out of a staging directory, one mutex around the
+in-memory recency map, eviction by persisted last-access time.
 """
 
 from __future__ import annotations
 
+import itertools
 import os
 import shutil
-import tempfile
 import threading
 import time
-from typing import BinaryIO, Callable, Iterator
+from typing import BinaryIO, Callable, Iterable, Iterator
 
 _SHARD_CHARS = 2
+
+
+class _FdWriter:
+    """The ``write`` half of a file object over a raw descriptor: what
+    ``write_file``'s callback needs, without ``os.fdopen``'s ``fstat``
+    and buffer (every call here is a round trip on a network file
+    system, and a chunk is one ``write``)."""
+
+    def __init__(self, fd: int) -> None:
+        self._fd = fd
+
+    def write(self, data) -> int:
+        view = memoryview(data).cast("B")
+        total = len(view)
+        while view:
+            view = view[os.write(self._fd, view):]
+        return total
 
 
 class CASStore:
     """Content-addressed files under ``root/<aa>/<name>``.
 
     Names are arbitrary keys (layer hex digests in practice). Files land via
-    ``write_file``/``link_file``/a download handle, always committed with an
+    ``write_file``/``link_file``/``write_many``, always committed with an
     atomic rename so readers never observe partial content. ``max_entries``
     bounds the store; least-recently-used entries are evicted on overflow.
+
+    **Ingest sequence** (every path): create ``_tmp/<name>.<pid>.<n>``
+    with ``O_CREAT|O_EXCL``, write, close, rename onto ``<aa>/<name>``.
+    The staging name is unique per process and call, so nothing probes
+    for it and it is unlinked only when the sequence fails. A shard
+    directory is made the first time this store meets it (``_shards``);
+    a rename that reports ``ENOENT`` (someone removed the directory)
+    makes it again and retries once.
+
+    **Who wins a race.** ``write_file``/``write_bytes``/``link_file``
+    take arbitrary names and keep first-writer-wins: one ``stat`` of the
+    final path before the rename, an existing entry stays. ``write_many``
+    takes entries whose name is the caller-verified digest of their
+    bytes and issues no such ``stat``: two writers of one name hold
+    identical bytes, so a rename over a racing writer's file leaves
+    exactly what "first writer wins" would have left.
+
+    **The lock** guards the recency map (``_last_access``) and eviction,
+    nothing else. It is never held across a system call in ``exists``,
+    ``size``, ``path``, ``open`` or any ingest path; eviction alone
+    unlinks under it, so a victim is chosen and removed as one step.
     """
 
     # Stores below this cap seed their LRU map eagerly at construction
@@ -51,6 +89,10 @@ class CASStore:
         os.makedirs(root, exist_ok=True)
         self._tmp_dir = os.path.join(root, "_tmp")
         os.makedirs(self._tmp_dir, exist_ok=True)
+        # Shard directories this store has made or seen: one listdir
+        # here instead of a makedirs per commit.
+        self._shards: set[str] = set(os.listdir(root))
+        self._stage_seq = itertools.count()
         self._seeded = False
         self._seeding = False
         if max_entries < self._EAGER_SEED_BELOW:
@@ -108,22 +150,30 @@ class CASStore:
         return os.path.join(self.root, shard, name)
 
     def _touch(self, name: str) -> None:
-        self._last_access[name] = time.time()
+        with self._lock:
+            self._last_access[name] = time.time()
+
+    def _admit(self, names: Iterable[str]) -> None:
+        """Record committed entries and evict the overflow: one lock
+        round per call, however many names."""
+        now = time.time()
+        with self._lock:
+            for name in names:
+                self._last_access[name] = now
+            self._evict_locked()
 
     # -- queries ----------------------------------------------------------
 
     def exists(self, name: str) -> bool:
-        with self._lock:
-            if os.path.isfile(self._path(name)):
-                self._touch(name)
-                return True
-            return False
+        if os.path.isfile(self._path(name)):
+            self._touch(name)
+            return True
+        return False
 
     def size(self, name: str) -> int:
-        with self._lock:
-            size = os.path.getsize(self._path(name))  # raises if absent
-            self._touch(name)
-            return size
+        size = os.path.getsize(self._path(name))  # raises if absent
+        self._touch(name)
+        return size
 
     def keys(self) -> list[str]:
         out = []
@@ -136,58 +186,117 @@ class CASStore:
 
     # -- ingest -----------------------------------------------------------
 
+    def _stage_path(self, name: str) -> str:
+        """A staging path no other call can hold: the entry's name, this
+        process, a counter (``next`` on a count is atomic)."""
+        return os.path.join(
+            self._tmp_dir,
+            f"{name}.{os.getpid()}.{next(self._stage_seq)}")
+
+    def _stage(self, name: str, write: Callable[[BinaryIO], None]) -> str:
+        tmp = self._stage_path(name)
+        fd = os.open(tmp, os.O_CREAT | os.O_EXCL | os.O_WRONLY, 0o600)
+        try:
+            try:
+                write(_FdWriter(fd))
+            finally:
+                os.close(fd)
+        except BaseException:
+            self._remove(tmp)
+            raise
+        return tmp
+
+    @staticmethod
+    def _remove(path: str) -> None:
+        """Unlink; a file that is not there is fine."""
+        try:
+            os.unlink(path)
+        except FileNotFoundError:
+            pass
+
+    def _rename(self, tmp: str, dst: str) -> None:
+        """Move a staged file onto its final path; on failure the
+        staging file is removed and nothing shows under the name."""
+        sharddir = os.path.dirname(dst)
+        shard = os.path.basename(sharddir)
+        try:
+            if shard not in self._shards:
+                try:
+                    os.mkdir(sharddir)
+                except FileExistsError:
+                    pass
+                self._shards.add(shard)
+            try:
+                os.rename(tmp, dst)
+            except FileNotFoundError:
+                # The shard directory went away under the memo.
+                os.makedirs(sharddir, exist_ok=True)
+                os.rename(tmp, dst)
+        except BaseException:
+            self._remove(tmp)
+            raise
+
+    def _commit(self, name: str, tmp: str) -> str:
+        """First writer wins (names here are arbitrary keys): an entry
+        that is already there stays, and the staged file goes."""
+        dst = self._path(name)
+        if os.path.isfile(dst):
+            self._remove(tmp)
+            self._touch(name)
+            return dst
+        self._rename(tmp, dst)
+        self._admit((name,))
+        return dst
+
     def write_file(self, name: str, write: Callable[[BinaryIO], None]) -> str:
         """Stream content into the store via ``write(fileobj)``; atomic."""
-        fd, tmp = tempfile.mkstemp(dir=self._tmp_dir)
-        try:
-            with os.fdopen(fd, "wb") as f:
-                write(f)
-            return self._commit(name, tmp)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
+        return self._commit(name, self._stage(name, write))
 
     def write_bytes(self, name: str, data: bytes) -> str:
         return self.write_file(name, lambda f: f.write(data))
 
+    def write_many(self, items: Iterable[tuple[str, bytes]]) -> None:
+        """Bulk ingest of entries whose name is the digest of their
+        bytes (the caller has verified it, and has probed that they are
+        new). Four file-system calls an entry — create, write, close,
+        rename — and one lock round for the batch. No ``stat`` of the
+        final path: identical bytes under one name make a rename over a
+        racing writer's file the same outcome as losing to it. The
+        first failure is raised after the entries already renamed are
+        recorded; a failed entry leaves nothing behind."""
+        done: list[str] = []
+        try:
+            for name, data in items:
+                self._rename(self._stage(
+                    name, lambda f, data=data: f.write(data)),
+                    self._path(name))
+                done.append(name)
+        finally:
+            if done:
+                self._admit(done)
+
     def link_file(self, name: str, src: str) -> str:
         """Ingest an existing file by hardlink (falls back to copy across
         filesystems)."""
-        # A private subdir keeps the link target unique: os.link refuses to
-        # overwrite, so the name must not be reusable by a concurrent
-        # mkstemp the way an unlinked mkstemp path would be.
-        tmp_parent = tempfile.mkdtemp(dir=self._tmp_dir)
-        tmp = os.path.join(tmp_parent, "link")
+        tmp = self._stage_path(name)
         try:
             try:
                 os.link(src, tmp)
             except OSError:
                 shutil.copy2(src, tmp)
-            return self._commit(name, tmp)
-        finally:
-            shutil.rmtree(tmp_parent, ignore_errors=True)
-
-    def _commit(self, name: str, tmp: str) -> str:
-        dst = self._path(name)
-        os.makedirs(os.path.dirname(dst), exist_ok=True)
-        with self._lock:
-            if os.path.isfile(dst):
-                self._touch(name)  # first writer wins; content is identical
-                return dst
-            os.rename(tmp, dst)
-            self._touch(name)
-            self._evict_locked()
-        return dst
+        except BaseException:
+            self._remove(tmp)
+            raise
+        return self._commit(name, tmp)
 
     # -- egress -----------------------------------------------------------
 
     def path(self, name: str) -> str:
         """Path of a stored file (raises FileNotFoundError if absent)."""
         p = self._path(name)
-        with self._lock:
-            if not os.path.isfile(p):
-                raise FileNotFoundError(f"{name} not in store {self.root}")
-            self._touch(name)
+        if not os.path.isfile(p):
+            raise FileNotFoundError(f"{name} not in store {self.root}")
+        self._touch(name)
         return p
 
     def open(self, name: str) -> BinaryIO:
@@ -200,8 +309,7 @@ class CASStore:
         except FileNotFoundError:
             raise FileNotFoundError(
                 f"{name} not in store {self.root}") from None
-        with self._lock:
-            self._touch(name)
+        self._touch(name)
         return f
 
     def link_out(self, name: str, dst: str) -> None:
@@ -216,10 +324,8 @@ class CASStore:
             shutil.copy2(src, dst)
 
     def delete(self, name: str) -> None:
+        self._remove(self._path(name))
         with self._lock:
-            p = self._path(name)
-            if os.path.isfile(p):
-                os.unlink(p)
             self._last_access.pop(name, None)
 
     # -- eviction ---------------------------------------------------------

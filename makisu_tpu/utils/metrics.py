@@ -74,6 +74,12 @@ DEVICE_PADDING_WASTE = "makisu_device_padding_waste_bytes_total"
 # lane buffers alone (h2d/sha here); it stays for /healthz.
 DEVICE_TRANSFER_BYTES = "makisu_device_transfer_bytes_total"
 
+# Chunks through the chunk store's ingest (cache/chunks.py: index_layer
+# and put), by result: written (staged and renamed into the CAS),
+# present (a probe found it stored), raced (a digest index_layer had
+# already handed to a writer: repeated within the layer).
+CHUNK_INGEST = "makisu_chunk_ingest_total"
+
 # Fleet telemetry (makisu_tpu/fleet/): one name set shared by the
 # scheduler, the peer-exchange module, the worker's /chunks endpoint,
 # loadgen's fleet report, and the docs' metric table. Routing verdicts

@@ -50,3 +50,113 @@ def _no_mounts():
     mountinfo.set_mountpoints_for_testing(set())
     yield
     mountinfo.set_mountpoints_for_testing(None)
+
+
+class _FsCalls:
+    """Stand-in for the ``os`` module inside ``storage/cas.py``: counts
+    every file-system call the store issues (``os.path`` probes
+    included), notes any made by a thread that holds the store's lock,
+    and can make the k-th call of a name fail. The store's background
+    LRU seed (its own thread, once a store) is not on any caller's path
+    and is left out."""
+
+    _FS = ("open", "write", "close", "rename", "mkdir", "makedirs",
+           "unlink", "link", "listdir", "stat")
+    _FS_PATH = ("isfile", "isdir", "exists", "lexists", "getsize",
+                "getmtime")
+
+    class _OwnedLock:
+        def __init__(self):
+            import threading
+            self._lock = threading.Lock()
+            self.owner = None
+
+        def __enter__(self):
+            import threading
+            self._lock.acquire()
+            self.owner = threading.get_ident()
+
+        def __exit__(self, *exc):
+            self.owner = None
+            self._lock.release()
+
+    def __init__(self, store):
+        import collections
+        import threading
+        self._threading = threading
+        self.calls = collections.Counter()
+        self.under_lock = []          # names of calls made under the lock
+        self.fail_at = {}             # name -> (k, exception)
+        self._count_lock = threading.Lock()
+        self.lock = store._lock = self._OwnedLock()
+        self.path = self._Path(self)
+
+    def _wrap(self, name, fn):
+        def call(*args, **kwargs):
+            if self._threading.current_thread().name == "cas-lru-seed":
+                return fn(*args, **kwargs)
+            with self._count_lock:
+                self.calls[name] += 1
+                nth = self.calls[name]
+                if self.lock.owner == self._threading.get_ident():
+                    self.under_lock.append(name)
+            fail = self.fail_at.get(name)
+            if fail is not None and fail[0] == nth:
+                raise fail[1]
+            return fn(*args, **kwargs)
+        return call
+
+    class _Path:
+        def __init__(self, outer):
+            self._outer = outer
+
+        def __getattr__(self, name):
+            fn = getattr(os.path, name)
+            if name in _FsCalls._FS_PATH:
+                return self._outer._wrap(name, fn)
+            return fn
+
+    def __getattr__(self, name):
+        fn = getattr(os, name)
+        if name in self._FS:
+            return self._wrap(name, fn)
+        return fn
+
+    def total(self) -> int:
+        return sum(self.calls.values())
+
+
+@pytest.fixture
+def fs_calls(monkeypatch):
+    """``fs_calls(store)`` swaps the ``os`` that ``storage/cas.py`` sees
+    for a counting one (from this point on) and instruments the
+    store's lock; returns the recorder."""
+    from makisu_tpu.storage import cas as cas_mod
+
+    def install(store):
+        recorder = _FsCalls(store)
+        monkeypatch.setattr(cas_mod, "os", recorder)
+        return recorder
+    return install
+
+
+def _store_tree(root: str) -> dict[str, tuple[int, bytes]]:
+    """What a store directory holds: relative path -> (mode, bytes) per
+    file, ``"<dir>/" -> (0, b"")`` per empty directory."""
+    out = {}
+    for dirpath, dirs, files in os.walk(root):
+        for name in files:
+            path = os.path.join(dirpath, name)
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, root)] = (
+                    os.stat(path).st_mode & 0o777, f.read())
+        for name in dirs:
+            path = os.path.join(dirpath, name)
+            if not os.listdir(path):
+                out[os.path.relpath(path, root) + "/"] = (0, b"")
+    return out
+
+
+@pytest.fixture
+def store_tree():
+    return _store_tree

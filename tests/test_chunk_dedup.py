@@ -938,3 +938,163 @@ def test_degraded_session_ignores_further_updates(monkeypatch):
     assert len(calls) == 1
     assert not session._staging
     assert session.finish() == []
+
+
+# -- bulk ingest through index_layer (PR 25) ---------------------------------
+
+def _layer(tmp_path, pieces: list[bytes], name="layer.gz"):
+    """A gzip blob whose stream is ``pieces`` end to end, with its
+    (offset, length, sha256) chunk list."""
+    import gzip
+    import hashlib
+    path = tmp_path / name
+    path.write_bytes(gzip.compress(b"".join(pieces), mtime=0))
+    chunks, pos = [], 0
+    for piece in pieces:
+        chunks.append((pos, len(piece), hashlib.sha256(piece).hexdigest()))
+        pos += len(piece)
+    return str(path), chunks
+
+
+def _pieces(n: int, seed: int = 0) -> list[bytes]:
+    import random
+    rng = random.Random(seed)
+    return [rng.randbytes(rng.randrange(2_000, 66_000)) for _ in range(n)]
+
+
+@pytest.mark.parametrize("writers", [1, ChunkStore.INGEST_WRITERS])
+def test_index_layer_five_calls_a_new_chunk_none_under_lock(
+        tmp_path, fs_calls, monkeypatch, writers):
+    """A layer of N new chunks into a fresh store: a stat, create,
+    write, close and rename each; one mkdir a shard (two writers that
+    meet a new shard at once may each issue it); every call made with
+    the store's lock free."""
+    monkeypatch.setattr(ChunkStore, "INGEST_WRITERS", writers)
+    path, chunks = _layer(tmp_path, _pieces(90))
+    store = ChunkStore(str(tmp_path / "chunks"))
+    shards = {h[:2] for _, _, h in chunks}
+    rec = fs_calls(store.cas)
+    stats = {}
+    added = store.index_layer(path, chunks, stats)
+    assert added == [h for _, _, h in chunks]
+    assert 0 <= rec.calls.pop("mkdir") - len(shards) < writers
+    assert dict(rec.calls) == {
+        name: len(chunks)
+        for name in ("isfile", "open", "write", "close", "rename")}
+    assert rec.under_lock == []
+    assert 1 <= stats["ingest_window"] <= writers
+    # The same layer again: one stat a chunk, nothing written.
+    rec = fs_calls(store.cas)
+    assert store.index_layer(path, chunks) == []
+    assert dict(rec.calls) == {"isfile": len(chunks)}
+    assert rec.under_lock == []
+
+
+def test_index_layer_trusts_a_streamed_miss(tmp_path, fs_calls):
+    """Digests the commit's streamed probe already looked for cost no
+    second stat: four calls a new chunk, none for a stored one."""
+    import time
+    path, chunks = _layer(tmp_path, _pieces(12, seed=3))
+    store = ChunkStore(str(tmp_path / "chunks"))
+    store.PROBE_BATCH = 4
+    store.index_layer(*_layer(tmp_path, _pieces(12, seed=3)[:4], "old.gz"))
+    for _, _, h in chunks:
+        store.note_fingerprint(h)
+    deadline = time.monotonic() + 10  # the probes ride the commit pool
+    while (any(store._probed(h) is None for _, _, h in chunks)
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+    assert [store._probed(h) for _, _, h in chunks] == [True] * 4 + [False] * 8
+    rec = fs_calls(store.cas)
+    assert store.index_layer(path, chunks) == [h for _, _, h in chunks[4:]]
+    assert rec.calls["isfile"] == 0
+    assert rec.total() - rec.calls["mkdir"] == 4 * 8
+
+
+def test_index_layer_writes_a_repeated_digest_once(tmp_path, fs_calls):
+    from makisu_tpu.utils import metrics
+    a, b, c = _pieces(3, seed=1)
+    path, chunks = _layer(tmp_path, [a, b, a, c, b, a])
+    store = ChunkStore(str(tmp_path / "chunks"))
+    rec = fs_calls(store.cas)
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
+    try:
+        added = store.index_layer(path, chunks)
+    finally:
+        metrics.reset_build_registry(token)
+    assert added == [chunks[0][2], chunks[1][2], chunks[3][2]]
+    assert rec.calls["rename"] == rec.calls["isfile"] == 3
+    assert registry.counter_by_label(metrics.CHUNK_INGEST, "result") == {
+        "written": 3.0, "raced": 3.0}
+    assert store.get(chunks[0][2]) == a
+
+
+@pytest.mark.parametrize("failing", ["open", "write", "rename"])
+def test_index_layer_write_failure_raises_and_leaves_no_partial_chunk(
+        tmp_path, fs_calls, store_tree, failing):
+    import errno
+    import hashlib
+    import os
+    path, chunks = _layer(tmp_path, _pieces(60, seed=2))
+    store = ChunkStore(str(tmp_path / "chunks"))
+    rec = fs_calls(store.cas)
+    rec.fail_at[failing] = (17, OSError(errno.ENOSPC, "no space"))
+    with pytest.raises(OSError) as err:
+        store.index_layer(path, chunks)
+    assert err.value.errno == errno.ENOSPC
+    stored = store_tree(store.cas.root)
+    assert stored.pop("_tmp/") == (0, b"")        # staging drained
+    stored = {rel: data for rel, (_, data) in stored.items()
+              if not rel.endswith("/")}           # an empty shard is no chunk
+    assert 0 < len(stored) < len(chunks)
+    for rel, data in stored.items():
+        assert hashlib.sha256(data).hexdigest() == os.path.basename(rel)
+    # What the failed call left is put right by the next one.
+    rec.fail_at.clear()
+    store.index_layer(path, chunks)
+    assert len(store_tree(store.cas.root)) == len(chunks) + 1
+    assert store.coverage(chunks) == 1.0
+
+
+def test_index_layer_content_mismatch_stores_nothing_wrong(tmp_path):
+    pieces = _pieces(6, seed=4)
+    path, chunks = _layer(tmp_path, pieces)
+    chunks[3] = (chunks[3][0], chunks[3][1], "ab" * 32)
+    store = ChunkStore(str(tmp_path / "chunks"))
+    with pytest.raises(ValueError):
+        store.index_layer(path, chunks)
+    assert not store.cas.exists("ab" * 32)
+
+
+def test_index_layer_holds_the_entry_cap_and_pins(tmp_path):
+    store = ChunkStore(str(tmp_path / "chunks"), max_entries=8)
+    first = _layer(tmp_path, _pieces(4, seed=5), "first.gz")
+    pinned = first[1][0][2]
+    store.index_layer(*first)
+    for i, (_, _, h) in enumerate(first[1]):
+        store.cas._last_access[h] = float(i)     # the pinned one oldest
+    with store.pins.pinned("chunks", pinned):
+        added = store.index_layer(*_layer(tmp_path, _pieces(12, seed=6)))
+    assert len(added) == 12
+    keys = set(store.cas.keys())
+    assert len(keys) == 8 and pinned in keys
+    assert not keys & {h for _, _, h in first[1][1:]}
+
+
+def test_index_layer_store_equals_the_one_file_a_chunk_layout(
+        tmp_path, store_tree):
+    """The listing, bytes and modes the parent's put-by-put path left
+    for the same layer, built here with hashlib: ``<aa>/<hex>`` files
+    of mode 0600 holding the chunk's bytes, an empty ``_tmp/``."""
+    import hashlib
+    pieces = _pieces(70, seed=7)
+    pieces += pieces[:5]                           # repeats change nothing
+    path, chunks = _layer(tmp_path, pieces)
+    store = ChunkStore(str(tmp_path / "chunks"))
+    store.index_layer(path, chunks)
+    golden = {"_tmp/": (0, b"")}
+    for piece in pieces:
+        h = hashlib.sha256(piece).hexdigest()
+        golden[f"{h[:2]}/{h}"] = (0o600, piece)
+    assert store_tree(store.cas.root) == golden

@@ -454,11 +454,12 @@ def test_exists_prefetch_memo(tmp_path):
             if store._exists_memo.get(digest):
                 break
         time.sleep(0.01)
-    assert store._exists_cached(digest) is True
-    # A prefetch miss never short-circuits: the real stat decides.
-    assert store._exists_cached(missing) is False
+    assert store._probed(digest) is True
+    # The probe looked and found nothing: index_layer writes without a
+    # second stat (a stale miss costs a write of identical bytes).
+    assert store._probed(missing) is False
     store.reset_fingerprint_memo()
-    assert store._exists_cached(digest) is True  # falls back to stat
+    assert store._probed(digest) is None  # index_layer's window stats
 
 
 def test_observer_streams_fingerprints_from_session():

@@ -127,6 +127,7 @@ def test_each_boundary_span_once_per_layer_under_its_parent(
         for s in by_name["chunk_index"]:
             assert int(s[2]["chunks"]) >= int(s[2]["added"]) >= 1
             assert int(s[2]["bytes_added"]) >= int(s[2]["added"])
+            assert 1 <= int(s[2]["ingest_window"]) <= 8
 
 
 def test_memfs_sync_holds_its_two_children(tmp_path, no_sleep):
