@@ -183,6 +183,25 @@ class Layer:
         self.entries[deleted] = entry
         return entry
 
+    def kind_counts(self) -> dict[str, int]:
+        """Entries by kind, as the tar will hold them: ``file``,
+        ``dir``, ``symlink``, ``whiteout``, ``other`` (hard links,
+        devices, fifos)."""
+        counts: dict[str, int] = {}
+        for entry in self.entries.values():
+            if isinstance(entry, WhiteoutEntry):
+                kind = "whiteout"
+            elif entry.hdr.isreg():
+                kind = "file"
+            elif entry.hdr.isdir():
+                kind = "dir"
+            elif entry.hdr.issym():
+                kind = "symlink"
+            else:
+                kind = "other"
+            counts[kind] = counts.get(kind, 0) + 1
+        return counts
+
     def commit(self, tw: tarfile.TarFile,
                workers: int | None = None) -> None:
         """Write entries in sorted path order (cache-identity-bearing).

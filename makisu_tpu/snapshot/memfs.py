@@ -226,6 +226,10 @@ class MemFS:
         finally:
             # The span's pair of clock reads is the stage counter's.
             metrics.stage_busy_add("tar_write", sp.duration)
+        # Once a layer, one add a kind present (never an add an entry:
+        # a 50k-entry layer must not pay 50k counter locks).
+        for kind, n in layer.kind_counts().items():
+            metrics.counter_add(metrics.LAYER_ENTRIES_TOTAL, n, kind=kind)
         # A commit folded entries into the tree without a chain key
         # (its digest exists only after the fact): any later cached
         # application on this tree must bypass the replay memo.

@@ -159,6 +159,9 @@ SESSION_DIRTY_PATHS = "makisu_session_dirty_paths_total"
 # Cached layers applied to the MemFS tree, result=memo (replayed from
 # the session's recorded entries) | inflate (gunzip + tar parse).
 LAYER_REPLAY_TOTAL = "makisu_layer_replay_total"
+# Entries of each committed layer as its tar holds them, kind=file|dir|
+# symlink|other|whiteout (snapshot/memfs.py, added once a layer).
+LAYER_ENTRIES_TOTAL = "makisu_layer_entries_total"
 SESSION_INVALIDATIONS = "makisu_session_invalidations_total"
 SESSION_RESIDENT_BYTES = "makisu_session_resident_bytes"
 

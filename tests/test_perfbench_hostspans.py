@@ -204,10 +204,10 @@ def test_every_new_metric_has_its_reader_and_its_cells():
         benchmark = json.load(f)
     cells_of = {m["name"]: m["workloads"] for m in benchmark["per_layer"]}
     every = ["monorepo-cold", "farm-churn", "monorepo-edit",
-             "farm-unchanged"]
+             "farm-unchanged", "small-files-edit"]
     assert cells_of["idle_unspanned_pct"] == every
     assert cells_of["sync_os_sync_s_per_build"] == every
-    assert cells_of["chunk_index_s_per_build"] == ["monorepo-cold",
-                                                   "monorepo-edit"]
+    assert cells_of["chunk_index_s_per_build"] == [
+        "monorepo-cold", "monorepo-edit", "small-files-edit"]
     for name in cells_of:
         assert os.path.exists(os.path.join(READERS, name + ".py")), name

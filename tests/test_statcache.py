@@ -2,6 +2,7 @@
 context files without ever changing cache identity."""
 
 import os
+import threading
 import time
 import types
 import zlib
@@ -13,6 +14,7 @@ from makisu_tpu.cache import CacheManager, MemoryStore, NoopCacheManager
 from makisu_tpu.context import BuildContext
 from makisu_tpu.docker.image import ImageName
 from makisu_tpu.dockerfile import parse_file
+from makisu_tpu.steps.add_copy import AddCopyStep
 from makisu_tpu.storage import ImageStore
 from makisu_tpu.utils.statcache import ContentIDCache
 
@@ -53,22 +55,41 @@ def test_warm_build_skips_unchanged_file_reads(tmp_path, monkeypatch):
     assert (tmp_path / "store" / "content_id_cache.json").exists()
 
     # Second build: same store -> the cache is primed. Count file
-    # opens under the context dir during checksumming.
+    # opens under the context dir during the checksum pass alone
+    # (AddCopyStep._checksum_tree): the commit reads every file it
+    # tars, on the tar writer's thread and its read-ahead workers.
     opened = []
     real_open = open
+    in_checksum = threading.local()
 
     def counting_open(path, *a, **k):
-        if isinstance(path, str) and str(ctx_dir) in path:
+        if (getattr(in_checksum, "depth", 0) and isinstance(path, str)
+                and str(ctx_dir) in path):
             opened.append(path)
         return real_open(path, *a, **k)
 
+    real_checksum_tree = AddCopyStep._checksum_tree
+    walked = []
+
+    def scoped_checksum_tree(self, ctx, path, *a, **k):
+        in_checksum.depth = getattr(in_checksum, "depth", 0) + 1
+        walked.append(path)
+        try:
+            return real_checksum_tree(self, ctx, path, *a, **k)
+        finally:
+            in_checksum.depth -= 1
+
     import builtins
+    monkeypatch.setattr(AddCopyStep, "_checksum_tree",
+                        scoped_checksum_tree)
     monkeypatch.setattr(builtins, "open", counting_open)
     m2, ids2 = _build(tmp_path, "b")
     monkeypatch.undo()
     assert ids1 == ids2  # identity unchanged
     assert [str(l.digest) for l in m1.layers] == \
         [str(l.digest) for l in m2.layers]
+    # The pass ran, over every file, and opened none of them.
+    assert sum(p.endswith(".bin") for p in walked) == 20
     content_reads = [p for p in opened if p.endswith(".bin")]
     assert content_reads == []
 
