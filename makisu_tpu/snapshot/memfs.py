@@ -11,6 +11,14 @@ Committing a step diffs reality against the tree:
 - ``update_from_tar`` merges a pulled layer into the tree (optionally
   materializing it on disk), honoring whiteouts.
 
+The diff compares mtimes in whole seconds, so both commits hold one
+invariant: a write made after a commit returns is stamped in a later
+second than every mtime that commit's scan visited. The reference
+sleeps a second before each scan (mem_fs.go sync, :294-311); here the
+scan remembers the newest mtime it visits and the commit waits, after
+its tar is written, only while the clock is still inside that second
+(``MemFS._wait_out_mtime``).
+
 Reference capability: lib/snapshot/mem_fs.go (NewMemFS:69,
 UpdateFromTarReader:165, AddLayerByScan:260, AddLayerByCopyOps:276,
 Checkpoint:91, CompareFS:720); the implementation is a fresh design over
@@ -42,6 +50,13 @@ from makisu_tpu.utils import logging as log
 from makisu_tpu.utils.fileio import Owner
 
 _MAX_SYMLINK_DEPTH = 64
+
+# A file system stamps mtimes from its own clock, the wait's deadline is
+# read from time.time(): the seconds a stamp may trail that read. Linux
+# stamps from the coarse clock, up to a tick behind (10 ms at HZ=100);
+# 9p under gVisor trailed by at most 0.5 ms over 1,000 files (PERF.md
+# §6, PR 27). Twice the longest tick.
+_CLOCK_MARGIN = 0.02
 
 
 class Node:
@@ -165,27 +180,37 @@ class MemFS:
     # ------------------------------------------------------------------
 
     def _sync(self) -> None:
-        """Flush pending writes and wait out tar's 1-second mtime
-        granularity so later modifications always look newer than this
-        layer's scan (reference: mem_fs.go sync, :294-311)."""
-        start = time.time()
+        """Flush pending writes before a scan."""
         with metrics.span("memfs_sync"):
             with metrics.span("memfs_sync.os_sync"):
                 try:
                     os.sync()
                 except (OSError, AttributeError):
                     pass
-            remaining = self.sync_wait - (time.time() - start)
-            if remaining > 0:
-                with metrics.span("memfs_sync.mtime_wait"):
-                    time.sleep(remaining)
+
+    def _wait_out_mtime(self, newest: int) -> None:
+        """Return once a later write cannot share a whole-second mtime
+        with anything the scan visited: when the clock has passed the
+        second of ``newest``, the newest mtime visited, added to the
+        layer or not. Never sleeps longer than ``sync_wait``, the
+        reference's sleep before each scan (mem_fs.go sync, :294-311)
+        and all an mtime in the future gets. Called after the tar
+        write, so that an mtime of a moment ago is old by now."""
+        wait = max(0.0, min(newest + 1 + _CLOCK_MARGIN - time.time(),
+                            self.sync_wait))
+        with metrics.span("memfs_sync.mtime_wait", wait_s=f"{wait:.3f}"):
+            if wait > 0:
+                time.sleep(wait)
+        metrics.counter_add(metrics.MTIME_WAIT_TOTAL,
+                            result="slept" if wait > 0 else "clear")
 
     def add_layer_by_scan(self, tw: tarfile.TarFile) -> Layer:
         self._sync()
         with metrics.span("layer_scan") as sp:
-            layer = self._create_layer_by_scan()
+            layer, newest = self._create_layer_by_scan()
             sp.set(entries=len(layer))
         self._commit_layer(layer, tw)
+        self._wait_out_mtime(newest)
         log.info("created layer by scan: %d entries", len(layer))
         return layer
 
@@ -194,10 +219,11 @@ class MemFS:
         self._sync()
         with metrics.span("layer_scan") as sp:
             layer = Layer()
-            for op in ops:
-                self._add_copy_to_layer(layer, op)
+            newest = max((self._add_copy_to_layer(layer, op) for op in ops),
+                         default=0)
             sp.set(entries=len(layer))
         self._commit_layer(layer, tw)
+        self._wait_out_mtime(newest)
         log.info("created copy layer: %d entries", len(layer))
         return layer
 
@@ -236,16 +262,20 @@ class MemFS:
         self.chain_tainted = True
         self.layers.append(layer)
 
-    def _create_layer_by_scan(self) -> Layer:
+    def _create_layer_by_scan(self) -> tuple[Layer, int]:
+        """The layer, and the newest mtime of any entry visited."""
         layer = Layer()
+        newest = 0
 
         def visit(path: str, st: os.stat_result) -> None:
+            nonlocal newest
             dst = pathutils.trim_root(path, self.root)
             hdr = tarinfo_from_stat(path, pathutils.rel_path(dst), self.root)
+            newest = max(newest, hdr.mtime)
             self._maybe_add(layer, path, dst, hdr, create_whiteouts=True)
 
         walk(self.root, self.blacklist, visit)
-        return layer
+        return layer, newest
 
     def _maybe_add(self, layer: Layer, src: str, dst: str,
                    hdr: tarfile.TarInfo, create_whiteouts: bool) -> None:
@@ -324,7 +354,9 @@ class MemFS:
             self._apply_entry(layer.add_header("", cur, hdr))
         return dst
 
-    def _add_copy_to_layer(self, layer: Layer, op: CopyOperation) -> None:
+    def _add_copy_to_layer(self, layer: Layer, op: CopyOperation) -> int:
+        """Returns the newest mtime of any source entry visited."""
+        newest = 0
         create_dst = True
         if len(op.srcs) == 1:
             only = pathutils.join_root(op.src_root, op.srcs[0])
@@ -342,6 +374,7 @@ class MemFS:
 
             def visit(cur: str, st: os.stat_result,
                       src=src, dst=dst) -> None:
+                nonlocal newest
                 if cur == src:
                     if os.path.isdir(cur) and not os.path.islink(cur):
                         return  # dir contents copy into dst, not dir itself
@@ -358,6 +391,7 @@ class MemFS:
                 else:
                     hdr.uid = op.uid
                     hdr.gid = op.gid
+                newest = max(newest, hdr.mtime)
                 self._maybe_add(layer, cur, pathutils.abs_path(cur_dst), hdr,
                                 create_whiteouts=False)
 
@@ -366,6 +400,7 @@ class MemFS:
             # incl. .dockerignore exclusions — internal (--from) copies
             # see everything in their sandbox.
             walk(src, None if op.internal else op.blacklist, visit)
+        return newest
 
     # ------------------------------------------------------------------
     # Tar merging / untarring

@@ -162,6 +162,10 @@ LAYER_REPLAY_TOTAL = "makisu_layer_replay_total"
 # Entries of each committed layer as its tar holds them, kind=file|dir|
 # symlink|other|whiteout (snapshot/memfs.py, added once a layer).
 LAYER_ENTRIES_TOTAL = "makisu_layer_entries_total"
+# Committed layers by what the wait for tar's one-second mtimes did
+# (snapshot/memfs.py, one add a layer): result=slept (the newest mtime
+# scanned was still in the clock's current second) | clear.
+MTIME_WAIT_TOTAL = "makisu_mtime_wait_total"
 SESSION_INVALIDATIONS = "makisu_session_invalidations_total"
 SESSION_RESIDENT_BYTES = "makisu_session_resident_bytes"
 

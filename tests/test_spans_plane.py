@@ -5,9 +5,11 @@ on the device."""
 
 import glob
 import json
+import os
 import re
 import subprocess
 import sys
+import time
 
 import jax
 import numpy as np
@@ -23,7 +25,7 @@ from makisu_tpu.utils import events, metrics, traceexport
 _PER_LAYER = {
     "memfs_sync": "commit_layer",
     "memfs_sync.os_sync": "memfs_sync",
-    "memfs_sync.mtime_wait": "memfs_sync",
+    "memfs_sync.mtime_wait": "commit_layer",
     "layer_scan": "commit_layer",
     "tar_write": "commit_layer",
     "sink_finish": "commit_layer",
@@ -47,11 +49,14 @@ def _build(tmp_path, hasher, n, tag="spans/plane:1"):
 
 
 def _context(tmp_path):
+    """Two directories of one file, written ten seconds ago."""
     ctx = tmp_path / "ctx"
     for seed, name in enumerate(("a", "b")):
         (ctx / name).mkdir(parents=True)
         (ctx / name / "f.bin").write_bytes(
             np.random.default_rng(seed).bytes(40_000))
+        then = time.time() - 10
+        os.utime(ctx / name / "f.bin", (then, then))
     (ctx / "Dockerfile").write_text(
         "FROM scratch\nCOPY a /a/\nCOPY b /b/\n")
     (tmp_path / "root").mkdir()
@@ -89,10 +94,11 @@ def no_sleep(monkeypatch):
 
 @pytest.mark.parametrize("hasher", ["cpu", "tpu"])
 def test_each_boundary_span_once_per_layer_under_its_parent(
-        tmp_path, no_sleep, hasher):
+        tmp_path, hasher):
     """``--hasher cpu`` opens every span of the table but
     ``chunk_index`` (the chunk store attaches to the tpu hasher; here
-    its native route)."""
+    its native route). The build keeps its one second of ``sync_wait``
+    and has nothing to wait for: the context is ten seconds old."""
     _context(tmp_path)
     event_log, report = _build(tmp_path, hasher, 1)
     spans = _spans(event_log)
@@ -117,6 +123,10 @@ def test_each_boundary_span_once_per_layer_under_its_parent(
                        ("sink_finish", "chunks")):
         assert all(int(s[2][attr]) >= 0 for s in by_name[name])
     assert all(int(s[2]["bytes"]) > 40_000 for s in by_name["tar_write"])
+    assert [s[2]["wait_s"] for s in by_name["memfs_sync.mtime_wait"]] \
+        == ["0.000"] * 2
+    assert _counter(report, metrics.MTIME_WAIT_TOTAL, result="clear") == 2
+    assert _counter(report, metrics.MTIME_WAIT_TOTAL, result="slept") == 0
     # One pair of clock reads: the stage counter is the spans' sum.
     assert _counter(report, metrics.COMMIT_STAGE_BUSY, stage="tar_write") \
         == pytest.approx(sum(s[3] for s in by_name["tar_write"]), abs=1e-5)
@@ -130,16 +140,28 @@ def test_each_boundary_span_once_per_layer_under_its_parent(
             assert 1 <= int(s[2]["ingest_window"]) <= 8
 
 
-def test_memfs_sync_holds_its_two_children(tmp_path, no_sleep):
+def test_the_sync_precedes_the_scan_and_the_wait_follows_the_tar(
+        tmp_path, no_sleep):
+    """Context files dated ahead of the clock: each layer syncs
+    before its scan and waits, for as long as ``no_sleep`` lets it,
+    after its tar is written."""
     _context(tmp_path)
+    ahead = time.time() + 3600
+    for name in ("a", "b"):
+        os.utime(tmp_path / "ctx" / name / "f.bin", (ahead, ahead))
     spans = _spans(_build(tmp_path, "cpu", 1)[0])
-    syncs = [s for s in spans if s[0] == "memfs_sync"]
-    assert len(syncs) == 2
-    for sync in syncs:
-        parts = {s[0]: s[3] for s in spans if s[5] == sync[4]}
-        assert set(parts) == {"memfs_sync.os_sync", "memfs_sync.mtime_wait"}
-        assert sum(parts.values()) <= sync[3]
-        assert sync[3] >= 0.05
+    commits = [s for s in spans if s[0] == "commit_layer"]
+    assert len(commits) == 2
+    for commit in commits:
+        children = [s for s in spans if s[5] == commit[4]]
+        assert [s[0] for s in children] == [
+            "memfs_sync", "layer_scan", "tar_write",
+            "memfs_sync.mtime_wait", "sink_finish"]
+        sync, wait = children[0], children[3]
+        assert [s[0] for s in spans if s[5] == sync[4]] \
+            == ["memfs_sync.os_sync"]
+        assert wait[2]["wait_s"] == "0.050"
+        assert wait[3] >= 0.05
 
 
 def test_layer_replay_counter_and_inflate_span(tmp_path, no_sleep):
