@@ -57,6 +57,7 @@ import threading
 import time
 from typing import Any, Iterator
 
+from makisu_tpu.storage import cas, contentstore
 from makisu_tpu.utils import events, fileio
 from makisu_tpu.utils import logging as log
 from makisu_tpu.utils import metrics
@@ -210,12 +211,13 @@ class IOBudget:
 _READ_PIECE = 1 << 20
 
 
-def _hash_file(path: str, budget: IOBudget) -> tuple[str, int]:
-    """Stream-hash one file under the budget (resident buffer ≤ one
-    piece; bytes/sec accounted per piece). Returns (hexdigest, size)."""
+def _hash_file(fh, budget: IOBudget) -> tuple[str, int]:
+    """Stream-hash one open file under the budget (resident buffer ≤
+    one piece; bytes/sec accounted per piece); closes it. Returns
+    (hexdigest, size)."""
     digest = hashlib.sha256()
     total = 0
-    with open(path, "rb") as fh:
+    with fh:
         while True:
             with budget.reserve(_READ_PIECE):
                 piece = fh.read(_READ_PIECE)
@@ -389,6 +391,10 @@ class StorageCensus:
         self.budget = budget or IOBudget.from_env()
         self.layers_dir = os.path.join(self.storage_dir, "layers")
         self.chunks_dir = os.path.join(self.storage_dir, "chunks")
+        # Bare directories, whether or not a store is live on them: an
+        # auditor's reads are not accesses the LRU should hear of.
+        self._blobs = cas.CASDir(self.layers_dir)
+        self._chunks = cas.CASDir(self.chunks_dir)
         self.manifests_dir = os.path.join(self.storage_dir, "manifests")
         serve = os.path.join(self.storage_dir, "serve")
         self.packs_dir = os.path.join(serve, "packs")
@@ -401,35 +407,15 @@ class StorageCensus:
 
     # -- plane walks ------------------------------------------------------
 
-    def _walk_cas(self, root: str) -> list[tuple[str, int, float]]:
-        """CAS layout (``<root>/<aa>/<name>``): (name, size, mtime)
-        per object, skipping the ``_tmp`` staging dir and in-flight
-        ``*.tmp`` atomic-write staging files."""
+    def _cas_rows(self, store: cas.CASDir
+                  ) -> list[tuple[str, int, float]]:
+        """(name, size, mtime) per object of a CAS directory, as its
+        owner (storage/cas.py) walks it; a stat's worth charged to the
+        budget each."""
         out: list[tuple[str, int, float]] = []
-        try:
-            shards = os.scandir(root)
-        except OSError:
-            return out
-        with shards:
-            for shard in shards:
-                if shard.name == "_tmp" or not shard.is_dir():
-                    continue
-                try:
-                    entries = os.scandir(shard.path)
-                except OSError:
-                    continue
-                with entries:
-                    for entry in entries:
-                        if entry.name.endswith(".tmp"):
-                            continue
-                        try:
-                            st = entry.stat()
-                        except OSError:
-                            continue  # deleted under us
-                        if not entry.is_file():
-                            continue
-                        out.append((entry.name, st.st_size, st.st_mtime))
-                        self.budget.throttle(256)  # stat accounting
+        for row in store.walk():
+            out.append(row)
+            self.budget.throttle(256)  # stat accounting
         return out
 
     def _walk_flat(self, root: str,
@@ -665,8 +651,8 @@ class StorageCensus:
         stat results only — never file contents — so resident memory
         is bounded by the object COUNT, not the byte total."""
         now = time.time()
-        blobs = self._walk_cas(self.layers_dir)
-        chunks = self._walk_cas(self.chunks_dir)
+        blobs = self._cas_rows(self._blobs)
+        chunks = self._cas_rows(self._chunks)
         table_rows = self._walk_flat(self.packs_dir, ".json")
         zpack_rows = self._walk_flat(self.zpacks_dir, ".zst")
         recipe_rows = self._walk_flat(self.recipes_dir, ".json")
@@ -734,9 +720,9 @@ class StorageCensus:
         findings += recipe_findings + table_findings \
             + snapshot_findings
 
-        chunk_rows = self._walk_cas(self.chunks_dir)
+        chunk_rows = self._cas_rows(self._chunks)
         chunk_names = {n for n, _, _ in chunk_rows}
-        blob_rows = self._walk_cas(self.layers_dir)
+        blob_rows = self._cas_rows(self._blobs)
         blob_names = {n for n, _, _ in blob_rows}
         zpack_rows = self._walk_flat(self.zpacks_dir, ".zst")
 
@@ -745,7 +731,6 @@ class StorageCensus:
         # tier) is DEMOTED, not dangling — the bytes are one local
         # decompress away and ensure_available promotes them back.
         # Only a missing chunk with no recoverable pack is an error.
-        from makisu_tpu.storage import contentstore
         _cstore = contentstore.store_for(self.storage_dir)
         _recoverable: dict[str, bool] = {}
 
@@ -1026,7 +1011,6 @@ class StorageCensus:
                 "seed": dict(seed_state),
                 "budget_bytes": int(budget_bytes),
             }
-        from makisu_tpu.storage import contentstore
         rows = contentstore.collect_rows(self.storage_dir)
         policy = contentstore.policy_for(self.storage_dir)
         return policy.plan(rows, int(budget_bytes),
@@ -1047,14 +1031,14 @@ class StorageCensus:
         chunks_checked = 0
         bytes_read = 0
 
-        chunk_rows = self._walk_cas(self.chunks_dir)
+        chunk_rows = self._cas_rows(self._chunks)
         for name, _, _ in rng.sample(
                 chunk_rows, min(chunk_samples, len(chunk_rows))):
             if not is_hex_digest(name):
                 continue
-            path = os.path.join(self.chunks_dir, name[:2], name)
             try:
-                actual, n = _hash_file(path, self.budget)
+                actual, n = _hash_file(self._chunks.open(name),
+                                       self.budget)
             except OSError:
                 continue  # evicted mid-scrub: not corruption
             chunks_checked += 1
@@ -1063,7 +1047,8 @@ class StorageCensus:
                 findings.append(make_finding(
                     "corruption", "error", "chunks",
                     f"chunk {name[:12]} bytes do not hash to their "
-                    f"name", path=path, object=name,
+                    f"name", path=self._chunks.where(name),
+                    object=name,
                     expected=name, actual=actual))
 
         packs_checked = 0
@@ -1119,10 +1104,8 @@ class StorageCensus:
                 pos = end
                 if end <= raw_off or start >= raw_off + raw_len:
                     continue
-                cpath = os.path.join(self.chunks_dir, fp[:2], fp)
                 with self.budget.reserve(int(length)):
-                    with open(cpath, "rb") as fh:
-                        data = fh.read()
+                    data = self._chunks.read(fp)
                 self.budget.throttle(len(data))
                 bytes_read += len(data)
                 lo = max(raw_off, start) - start
@@ -1333,16 +1316,7 @@ def render_storage_doctor(entries: list[dict], target: str) -> str:
 
 def seed_states(storage_dir: str) -> dict | None:
     """LRU seed state of the LIVE chunk CAS serving this storage dir,
-    when one is registered in-process (worker mode); None offline —
+    when this process has one open (worker mode); None offline —
     an offline walk's mtimes are complete by definition."""
-    try:
-        from makisu_tpu.cache import chunks as chunks_mod
-    except ImportError:  # pragma: no cover - partial install
-        return None
-    want = os.path.realpath(os.path.join(storage_dir, "chunks"))
-    for store in chunks_mod.serving_stores():
-        if os.path.realpath(store.cas.root) == want:
-            state = getattr(store.cas, "seed_state", None)
-            if callable(state):
-                return state()
-    return None
+    return cas.store_for(
+        os.path.join(storage_dir, "chunks")).seed_state()

@@ -26,7 +26,8 @@ import json
 from makisu_tpu import tario
 from makisu_tpu.docker.image import Digest, DigestPair
 from makisu_tpu.registry import transfer
-from makisu_tpu.storage.cas import CASStore
+from makisu_tpu.storage import cas as cas_mod
+from makisu_tpu.storage import contentstore
 from makisu_tpu.utils import events
 from makisu_tpu.utils import ledger
 from makisu_tpu.utils import logging as log
@@ -72,29 +73,15 @@ def pack_target_bytes() -> int:
     return max(target, 1_000_000)
 
 
-# -- process-wide serving registry (the worker's GET /chunks/<fp>) ----------
-
-# Every ChunkStore attached in this process, keyed by its CAS root: the
-# worker's read-only peer-exchange endpoint serves chunk bytes out of
-# whichever store holds them. Bounded by the number of distinct storage
-# roots the process has built against (a worker typically has one);
-# re-attaching a root replaces the entry, so the registry never grows
-# with build count.
-import threading as _threading
-
-_serving_stores: dict[str, "ChunkStore"] = {}
-_serving_lock = _threading.Lock()
-
+# -- serving (the worker's GET /chunks/<fp>) ---------------------------------
 
 def register_serving_store(store: "ChunkStore") -> None:
-    key = os.path.realpath(store.cas.root)
-    with _serving_lock:
-        _serving_stores[key] = store
-
-
-def serving_stores() -> list["ChunkStore"]:
-    with _serving_lock:
-        return list(_serving_stores.values())
+    """Enter a store's CAS among the process's live stores
+    (storage/cas.py keeps them, by real path of the root): the worker's
+    read-only peer-exchange endpoint serves chunk bytes out of
+    whichever holds them, and the evictor, the census and tier refetch
+    reach the same object for that root."""
+    cas_mod.register_live(store.cas)
 
 
 def open_served_chunk(hex_digest: str, roots=None):
@@ -109,12 +96,9 @@ def open_served_chunk(hex_digest: str, roots=None):
     registry is shared by every worker, and serving a sibling's bytes
     would fake the cross-host exchange the endpoint models (the same
     per-machine honesty the per-server session managers give)."""
-    for store in serving_stores():
-        if roots is not None \
-                and os.path.realpath(store.cas.root) not in roots:
-            continue
+    for store in cas_mod.live_stores(roots):
         try:
-            return store.cas.open(hex_digest)
+            return store.open(hex_digest)
         except FileNotFoundError:
             continue
     return None
@@ -240,12 +224,11 @@ class ChunkStore:
                     "MAKISU_TPU_CHUNK_CAS_ENTRIES", str(1 << 20)))
             except ValueError:
                 max_entries = 1 << 20  # cache sizing never fails builds
-        self.cas = CASStore(root, max_entries)
+        self.cas = cas_mod.CASStore(root, max_entries)
         # Refcount plane: reads pin their chunk for their duration, the
         # budget evictor and the CAS's own count-LRU both honor pins
         # (storage/contentstore.py keys the board by storage dir, so
         # the worker's serve plane and this store share one board).
-        from makisu_tpu.storage import contentstore
         self.pins = contentstore.board_for_chunk_root(root)
         self.cas.pin_check = self.pins.chunk_pinned
         self.registry = None  # attach via set_remote()
@@ -293,9 +276,8 @@ class ChunkStore:
         # twin before the registry is asked (local decompress beats a
         # WAN round trip; also the only route when no registry is
         # attached — the worker's serve path after budget eviction).
-        from makisu_tpu.storage import contentstore
         if contentstore.refetch_for_chunk_root(
-                self.cas.root, [hex_digest], {}, put=self.put):
+                self.cas.root, [hex_digest], {}):
             return True
         if self.registry is not None:
             return self._fetch_remote(hex_digest)
@@ -457,13 +439,12 @@ class ChunkStore:
         # Pin across the open+read: a concurrent eviction pass may cut
         # its victim list any time, and this read must win.
         with self.pins.pinned("chunks", hex_digest):
-            with self.cas.open(hex_digest) as f:
-                return f.read()
+            return self.cas.read(hex_digest)
 
     def put(self, hex_digest: str, data: bytes) -> None:
         if hashlib.sha256(data).hexdigest() != hex_digest:
             raise ValueError(f"chunk content does not match {hex_digest}")
-        self.cas.write_many(((hex_digest, data),))
+        self.cas.put(hex_digest, data)
         metrics.counter_add(metrics.CHUNK_INGEST, result="written")
 
     # index_layer's ingest window. Chunks leave the gunzip pass in
@@ -724,9 +705,8 @@ class ChunkStore:
         # still on disk (or one object-tier read away) in its pack's
         # compressed twin — promoting it back is a local decompress,
         # cheaper than any wire route. No serve plane: free no-op.
-        from makisu_tpu.storage import contentstore
         restored = contentstore.refetch_for_chunk_root(
-            self.cas.root, missing, lengths, put=self.put)
+            self.cas.root, missing, lengths)
         if restored:
             missing = [h for h in missing if h not in restored]
             if not missing:
@@ -970,7 +950,6 @@ class ChunkStore:
         digests updated incrementally, so peak memory is bounded by the
         largest chunk — a 10GB layer (BASELINE config 4) never
         materializes in RAM."""
-        import tempfile
         if gz_backend is not None and not tario.backend_id_usable(
                 gz_backend):
             # Byte-identity is unachievable without the producing
@@ -988,8 +967,7 @@ class ChunkStore:
         # Temp file lives beside the chunk CAS (not $TMPDIR, commonly
         # tmpfs): a 10GB layer must hit disk once, and the destination
         # CAS's link_file can usually hardlink instead of copying.
-        fd, tmp = tempfile.mkstemp(prefix="reconstitute-",
-                                   dir=self.cas._tmp_dir)
+        fd, tmp = self.cas.mkstemp("reconstitute-")
         try:
             with os.fdopen(fd, "wb") as raw:
                 tee = tario.TeeDigest(raw)

@@ -6,6 +6,12 @@ generic machinery under lib/storage/base/ (atomic state transitions,
 last-access tracking, sharded dirs). Implementation is original: one class,
 atomic os.rename commits out of a staging directory, one mutex around the
 in-memory recency map, eviction by persisted last-access time.
+
+This module owns the on-disk layout (``<root>/<aa>/<name>``, staging
+under ``<root>/_tmp/``): nothing else in the program builds an entry's
+path or lists a CAS directory. ``CASDir`` is the layout alone, usable on
+a store no process has open; ``CASStore`` adds recency, the entry cap
+and pins; ``store_for(root)`` hands out whichever this process has.
 """
 
 from __future__ import annotations
@@ -13,6 +19,7 @@ from __future__ import annotations
 import itertools
 import os
 import shutil
+import tempfile
 import threading
 import time
 from typing import BinaryIO, Callable, Iterable, Iterator
@@ -37,152 +44,114 @@ class _FdWriter:
         return total
 
 
-class CASStore:
-    """Content-addressed files under ``root/<aa>/<name>``.
+class CASDir:
+    """The layout of one CAS directory: ``root/<aa>/<name>``, entries
+    staged under ``root/_tmp/`` and renamed into place. It keeps no
+    recency and enforces no cap, so it serves a directory no store in
+    this process has open (census, scrub, the evictor, tier refetch).
 
-    Names are arbitrary keys (layer hex digests in practice). Files land via
-    ``write_file``/``link_file``/``write_many``, always committed with an
-    atomic rename so readers never observe partial content. ``max_entries``
-    bounds the store; least-recently-used entries are evicted on overflow.
+    Constructing one touches nothing on disk, and reading through one
+    never creates or lists the root: ``walk`` yields nothing for a root
+    that is not there, skips a shard that is deleted under it and an
+    entry that vanishes before its ``stat``. ``put`` makes what it
+    needs the first time it is refused. No method hands out an entry's
+    path; ``where`` names the place for a finding a person will read.
 
     **Ingest sequence** (every path): create ``_tmp/<name>.<pid>.<n>``
     with ``O_CREAT|O_EXCL``, write, close, rename onto ``<aa>/<name>``.
     The staging name is unique per process and call, so nothing probes
     for it and it is unlinked only when the sequence fails. A shard
-    directory is made the first time this store meets it (``_shards``);
+    directory is made the first time this handle meets it (``_shards``);
     a rename that reports ``ENOENT`` (someone removed the directory)
     makes it again and retries once.
-
-    **Who wins a race.** ``write_file``/``write_bytes``/``link_file``
-    take arbitrary names and keep first-writer-wins: one ``stat`` of the
-    final path before the rename, an existing entry stays. ``write_many``
-    takes entries whose name is the caller-verified digest of their
-    bytes and issues no such ``stat``: two writers of one name hold
-    identical bytes, so a rename over a racing writer's file leaves
-    exactly what "first writer wins" would have left.
-
-    **The lock** guards the recency map (``_last_access``) and eviction,
-    nothing else. It is never held across a system call in ``exists``,
-    ``size``, ``path``, ``open`` or any ingest path; eviction alone
-    unlinks under it, so a victim is chosen and removed as one step.
     """
 
-    # Stores below this cap seed their LRU map eagerly at construction
-    # (a few hundred stats); at or above it — the ~1M-entry chunk CAS,
-    # where the seed scan is tens of thousands of stats and was a
-    # measurable warm-rebuild floor term — seeding runs on a background
-    # thread armed by the first write, and eviction simply defers until
-    # the scan lands (advisory LRU: a few deferred evictions cost disk
-    # headroom, never correctness).
-    _EAGER_SEED_BELOW = 4096
-
-    def __init__(self, root: str, max_entries: int = 256) -> None:
+    def __init__(self, root: str) -> None:
         self.root = root
-        self.max_entries = max_entries
-        self._lock = threading.Lock()
-        self._last_access: dict[str, float] = {}
-        # Optional pin predicate (name -> bool). A True answer keeps
-        # the entry out of count-LRU victim selection — the content
-        # store's refcount plane wires this so an in-flight read can
-        # never lose its chunk to the entry-count cap either.
-        self.pin_check = None
-        os.makedirs(root, exist_ok=True)
         self._tmp_dir = os.path.join(root, "_tmp")
-        os.makedirs(self._tmp_dir, exist_ok=True)
-        # Shard directories this store has made or seen: one listdir
-        # here instead of a makedirs per commit.
-        self._shards: set[str] = set(os.listdir(root))
+        # Shard directories this handle has made or seen.
+        self._shards: set[str] = set()
         self._stage_seq = itertools.count()
-        self._seeded = False
-        self._seeding = False
-        if max_entries < self._EAGER_SEED_BELOW:
-            for name in self.keys():
-                self._last_access[name] = \
-                    os.path.getmtime(self._path(name))
-            self._seeded = True
-
-    def _seed_async_locked(self) -> None:
-        """Arm the background LRU seed (large stores). Runs at most
-        once; merges on-disk mtimes under the lock, live accesses
-        recorded meanwhile win, then catches up deferred eviction."""
-        if self._seeded or self._seeding:
-            return
-        self._seeding = True
-
-        def run() -> None:
-            seed: dict[str, float] = {}
-            try:
-                for name in self.keys():
-                    try:
-                        seed[name] = os.path.getmtime(self._path(name))
-                    except OSError:
-                        pass  # racing delete
-            finally:
-                with self._lock:
-                    for name, mtime in seed.items():
-                        self._last_access.setdefault(name, mtime)
-                    self._seeded = True
-                    self._seeding = False
-                    self._evict_locked()
-
-        threading.Thread(target=run, daemon=True,
-                         name="cas-lru-seed").start()
-
-    def seed_state(self) -> dict:
-        """Observability for the background LRU seed (PR 10's thread
-        is otherwise invisible): ``state`` is ``seeded`` (recency map
-        complete), ``seeding`` (scan in flight), or ``unseeded``
-        (large store, seed not yet armed — it arms on first write).
-        Consumers that rank objects by recency (the storage plane's
-        eviction dry-run) refuse to run unless ``seeded``."""
-        with self._lock:
-            if self._seeded:
-                state = "seeded"
-            elif self._seeding:
-                state = "seeding"
-            else:
-                state = "unseeded"
-            return {"state": state,
-                    "seeded_entries": len(self._last_access)}
 
     def _path(self, name: str) -> str:
         shard = name[:_SHARD_CHARS] if len(name) > _SHARD_CHARS else "__"
         return os.path.join(self.root, shard, name)
 
+    def where(self, name: str) -> str:
+        """The entry's place, for a finding or a log line: to be read,
+        never opened."""
+        return self._path(name)
+
+    # What a store with a recency map does on access, on commit and on
+    # delete; a bare directory has none to keep.
+
     def _touch(self, name: str) -> None:
-        with self._lock:
-            self._last_access[name] = time.time()
+        pass
 
     def _admit(self, names: Iterable[str]) -> None:
-        """Record committed entries and evict the overflow: one lock
-        round per call, however many names."""
-        now = time.time()
-        with self._lock:
-            for name in names:
-                self._last_access[name] = now
-            self._evict_locked()
+        pass
+
+    def _forget(self, name: str) -> None:
+        pass
+
+    def recency(self) -> dict[str, float]:
+        """Last-access times this process has seen, by name: none for a
+        bare directory, whose file mtimes are all there is."""
+        return {}
+
+    def seed_state(self) -> dict | None:
+        """None: no recency map to seed (mtimes on disk are complete)."""
+        return None
 
     # -- queries ----------------------------------------------------------
 
-    def exists(self, name: str) -> bool:
-        if os.path.isfile(self._path(name)):
-            self._touch(name)
-            return True
-        return False
+    def _entries(self) -> Iterator[os.DirEntry]:
+        """Every directory entry under a shard, staging left out."""
+        try:
+            shards = os.scandir(self.root)
+        except OSError:
+            return
+        with shards:
+            for shard in shards:
+                if shard.name == "_tmp" or not shard.is_dir():
+                    continue
+                try:
+                    entries = os.scandir(shard.path)
+                except OSError:
+                    continue  # shard deleted under us
+                with entries:
+                    yield from entries
 
-    def size(self, name: str) -> int:
-        size = os.path.getsize(self._path(name))  # raises if absent
-        self._touch(name)
-        return size
+    def walk(self) -> Iterator[tuple[str, int, float]]:
+        """``(name, size, mtime)`` of every committed entry: one
+        ``stat`` each, never a staging file."""
+        for entry in self._entries():
+            try:
+                st = entry.stat()
+            except OSError:
+                continue  # deleted under us
+            if entry.is_file():
+                yield entry.name, st.st_size, st.st_mtime
 
     def keys(self) -> list[str]:
-        out = []
-        for shard in os.listdir(self.root):
-            sharddir = os.path.join(self.root, shard)
-            if shard == "_tmp" or not os.path.isdir(sharddir):
-                continue
-            out.extend(os.listdir(sharddir))
-        return out
+        return [entry.name for entry in self._entries()]
+
+    def open(self, name: str) -> BinaryIO:
+        """Open for reading: ONE syscall on the happy path (the open
+        itself is the existence check) — this runs once per ~8KiB chunk
+        when a layer applies straight from the chunk CAS, so a
+        stat-then-open here is a measurable tax at 100k chunks."""
+        try:
+            f = open(self._path(name), "rb")
+        except FileNotFoundError:
+            raise FileNotFoundError(
+                f"{name} not in store {self.root}") from None
+        self._touch(name)
+        return f
+
+    def read(self, name: str) -> bytes:
+        with self.open(name) as f:
+            return f.read()
 
     # -- ingest -----------------------------------------------------------
 
@@ -195,7 +164,13 @@ class CASStore:
 
     def _stage(self, name: str, write: Callable[[BinaryIO], None]) -> str:
         tmp = self._stage_path(name)
-        fd = os.open(tmp, os.O_CREAT | os.O_EXCL | os.O_WRONLY, 0o600)
+        flags = os.O_CREAT | os.O_EXCL | os.O_WRONLY
+        try:
+            fd = os.open(tmp, flags, 0o600)
+        except FileNotFoundError:
+            # A bare directory nobody has written through yet.
+            os.makedirs(self._tmp_dir, exist_ok=True)
+            fd = os.open(tmp, flags, 0o600)
         try:
             try:
                 write(_FdWriter(fd))
@@ -236,25 +211,6 @@ class CASStore:
             self._remove(tmp)
             raise
 
-    def _commit(self, name: str, tmp: str) -> str:
-        """First writer wins (names here are arbitrary keys): an entry
-        that is already there stays, and the staged file goes."""
-        dst = self._path(name)
-        if os.path.isfile(dst):
-            self._remove(tmp)
-            self._touch(name)
-            return dst
-        self._rename(tmp, dst)
-        self._admit((name,))
-        return dst
-
-    def write_file(self, name: str, write: Callable[[BinaryIO], None]) -> str:
-        """Stream content into the store via ``write(fileobj)``; atomic."""
-        return self._commit(name, self._stage(name, write))
-
-    def write_bytes(self, name: str, data: bytes) -> str:
-        return self.write_file(name, lambda f: f.write(data))
-
     def write_many(self, items: Iterable[tuple[str, bytes]]) -> None:
         """Bulk ingest of entries whose name is the digest of their
         bytes (the caller has verified it, and has probed that they are
@@ -275,6 +231,166 @@ class CASStore:
             if done:
                 self._admit(done)
 
+    def put(self, name: str, data: bytes) -> None:
+        """One entry through ``write_many``: ``name`` is the digest of
+        ``data``, and the caller has checked it."""
+        self.write_many(((name, data),))
+
+    def delete(self, name: str) -> None:
+        self._remove(self._path(name))
+        self._forget(name)
+
+
+class CASStore(CASDir):
+    """A ``CASDir`` a process has open: recency, an entry cap, pins.
+
+    Names are arbitrary keys (layer hex digests in practice). Files land via
+    ``write_file``/``link_file``/``write_many``, always committed with an
+    atomic rename so readers never observe partial content. ``max_entries``
+    bounds the store; least-recently-used entries are evicted on overflow.
+    The root and its staging directory are made, and the root listed
+    once for the shards already there, when the store is opened.
+
+    **Who wins a race.** ``write_file``/``write_bytes``/``link_file``
+    take arbitrary names and keep first-writer-wins: one ``stat`` of the
+    final path before the rename, an existing entry stays. ``write_many``
+    takes entries whose name is the caller-verified digest of their
+    bytes and issues no such ``stat``: two writers of one name hold
+    identical bytes, so a rename over a racing writer's file leaves
+    exactly what "first writer wins" would have left.
+
+    **The lock** guards the recency map (``_last_access``) and eviction,
+    nothing else. It is never held across a system call in ``exists``,
+    ``size``, ``path``, ``open`` or any ingest path; eviction alone
+    unlinks under it, so a victim is chosen and removed as one step.
+    """
+
+    # Stores below this cap seed their LRU map eagerly at construction
+    # (a few hundred stats); at or above it — the ~1M-entry chunk CAS,
+    # where the seed scan is tens of thousands of stats and was a
+    # measurable warm-rebuild floor term — seeding runs on a background
+    # thread armed by the first write, and eviction simply defers until
+    # the scan lands (advisory LRU: a few deferred evictions cost disk
+    # headroom, never correctness).
+    _EAGER_SEED_BELOW = 4096
+
+    def __init__(self, root: str, max_entries: int = 256) -> None:
+        super().__init__(root)
+        self.max_entries = max_entries
+        self._lock = threading.Lock()
+        self._last_access: dict[str, float] = {}
+        # Optional pin predicate (name -> bool). A True answer keeps
+        # the entry out of count-LRU victim selection — the content
+        # store's refcount plane wires this so an in-flight read can
+        # never lose its chunk to the entry-count cap either.
+        self.pin_check = None
+        os.makedirs(root, exist_ok=True)
+        os.makedirs(self._tmp_dir, exist_ok=True)
+        # One listdir here instead of a makedirs per commit.
+        self._shards = set(os.listdir(root))
+        self._seeded = False
+        self._seeding = False
+        if max_entries < self._EAGER_SEED_BELOW:
+            for name, _, mtime in self.walk():
+                self._last_access[name] = mtime
+            self._seeded = True
+
+    def _seed_async_locked(self) -> None:
+        """Arm the background LRU seed (large stores). Runs at most
+        once; merges on-disk mtimes under the lock, live accesses
+        recorded meanwhile win, then catches up deferred eviction."""
+        if self._seeded or self._seeding:
+            return
+        self._seeding = True
+
+        def run() -> None:
+            seed: dict[str, float] = {}
+            try:
+                for name, _, mtime in self.walk():
+                    seed[name] = mtime
+            finally:
+                with self._lock:
+                    for name, mtime in seed.items():
+                        self._last_access.setdefault(name, mtime)
+                    self._seeded = True
+                    self._seeding = False
+                    self._evict_locked()
+
+        threading.Thread(target=run, daemon=True,
+                         name="cas-lru-seed").start()
+
+    def seed_state(self) -> dict:
+        """Observability for the background LRU seed (PR 10's thread
+        is otherwise invisible): ``state`` is ``seeded`` (recency map
+        complete), ``seeding`` (scan in flight), or ``unseeded``
+        (large store, seed not yet armed — it arms on first write).
+        Consumers that rank objects by recency (the storage plane's
+        eviction dry-run) refuse to run unless ``seeded``."""
+        with self._lock:
+            if self._seeded:
+                state = "seeded"
+            elif self._seeding:
+                state = "seeding"
+            else:
+                state = "unseeded"
+            return {"state": state,
+                    "seeded_entries": len(self._last_access)}
+
+    def _touch(self, name: str) -> None:
+        with self._lock:
+            self._last_access[name] = time.time()
+
+    def _admit(self, names: Iterable[str]) -> None:
+        """Record committed entries and evict the overflow: one lock
+        round per call, however many names."""
+        now = time.time()
+        with self._lock:
+            for name in names:
+                self._last_access[name] = now
+            self._evict_locked()
+
+    def _forget(self, name: str) -> None:
+        with self._lock:
+            self._last_access.pop(name, None)
+
+    def recency(self) -> dict[str, float]:
+        with self._lock:
+            return dict(self._last_access)
+
+    # -- queries ----------------------------------------------------------
+
+    def exists(self, name: str) -> bool:
+        if os.path.isfile(self._path(name)):
+            self._touch(name)
+            return True
+        return False
+
+    def size(self, name: str) -> int:
+        size = os.path.getsize(self._path(name))  # raises if absent
+        self._touch(name)
+        return size
+
+    # -- ingest -----------------------------------------------------------
+
+    def _commit(self, name: str, tmp: str) -> str:
+        """First writer wins (names here are arbitrary keys): an entry
+        that is already there stays, and the staged file goes."""
+        dst = self._path(name)
+        if os.path.isfile(dst):
+            self._remove(tmp)
+            self._touch(name)
+            return dst
+        self._rename(tmp, dst)
+        self._admit((name,))
+        return dst
+
+    def write_file(self, name: str, write: Callable[[BinaryIO], None]) -> str:
+        """Stream content into the store via ``write(fileobj)``; atomic."""
+        return self._commit(name, self._stage(name, write))
+
+    def write_bytes(self, name: str, data: bytes) -> str:
+        return self.write_file(name, lambda f: f.write(data))
+
     def link_file(self, name: str, src: str) -> str:
         """Ingest an existing file by hardlink (falls back to copy across
         filesystems)."""
@@ -289,6 +405,12 @@ class CASStore:
             raise
         return self._commit(name, tmp)
 
+    def mkstemp(self, prefix: str) -> tuple[int, str]:
+        """``tempfile.mkstemp`` in the staging directory: beside the
+        entries (one file system, so a ``link_file`` of the result can
+        hardlink) and out of every walk. The caller owns the file."""
+        return tempfile.mkstemp(prefix=prefix, dir=self._tmp_dir)
+
     # -- egress -----------------------------------------------------------
 
     def path(self, name: str) -> str:
@@ -298,19 +420,6 @@ class CASStore:
             raise FileNotFoundError(f"{name} not in store {self.root}")
         self._touch(name)
         return p
-
-    def open(self, name: str) -> BinaryIO:
-        """Open for reading: ONE syscall on the happy path (the open
-        itself is the existence check) — this runs once per ~8KiB chunk
-        when a layer applies straight from the chunk CAS, so a
-        stat-then-open here is a measurable tax at 100k chunks."""
-        try:
-            f = open(self._path(name), "rb")
-        except FileNotFoundError:
-            raise FileNotFoundError(
-                f"{name} not in store {self.root}") from None
-        self._touch(name)
-        return f
 
     def link_out(self, name: str, dst: str) -> None:
         """Hardlink a stored file out to ``dst`` (copy across filesystems)."""
@@ -322,11 +431,6 @@ class CASStore:
             os.link(src, dst)
         except OSError:
             shutil.copy2(src, dst)
-
-    def delete(self, name: str) -> None:
-        self._remove(self._path(name))
-        with self._lock:
-            self._last_access.pop(name, None)
 
     # -- eviction ---------------------------------------------------------
 
@@ -368,5 +472,33 @@ class CASStore:
                 os.unlink(p)
             del self._last_access[victim]
 
-    def __iter__(self) -> Iterator[str]:
-        return iter(self.keys())
+
+# -- the stores this process has open ----------------------------------------
+
+# Live stores by real path of their root. A store is entered by whoever
+# opens it to serve (a build's chunk dedup, the worker's serve plane);
+# a root entered again replaces its entry, so the map is bounded by the
+# storage roots the process has built against, not by its builds.
+_live: dict[str, CASStore] = {}
+_live_lock = threading.Lock()
+
+
+def register_live(store: CASStore) -> None:
+    with _live_lock:
+        _live[os.path.realpath(store.root)] = store
+
+
+def live_stores(roots=None) -> list[CASStore]:
+    """The live stores, or those among ``roots`` (real paths)."""
+    with _live_lock:
+        return [store for root, store in _live.items()
+                if roots is None or root in roots]
+
+
+def store_for(root: str) -> CASDir:
+    """The store for this root: the live ``CASStore`` where this
+    process has one open (its recency then hears of what is read, put
+    and deleted), else the bare directory."""
+    with _live_lock:
+        live = _live.get(os.path.realpath(root))
+    return live if live is not None else CASDir(root)

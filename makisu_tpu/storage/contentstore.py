@@ -61,7 +61,8 @@ import threading
 import time
 from contextlib import contextmanager
 
-from makisu_tpu.utils import events, fileio
+from makisu_tpu.storage import cas
+from makisu_tpu.utils import events
 from makisu_tpu.utils import logging as log
 from makisu_tpu.utils import metrics
 
@@ -284,39 +285,20 @@ def protected_set(storage_dir: str) -> tuple[set[tuple[str, str]], dict]:
 
 # -- decision input ----------------------------------------------------------
 
-def _live_chunk_store(storage_dir: str):
-    """The registered in-process ChunkStore serving this storage's
-    CAS, or None (offline walk)."""
-    from makisu_tpu.cache import chunks as chunks_mod
-    want = os.path.realpath(os.path.join(storage_dir, "chunks"))
-    for store in chunks_mod.serving_stores():
-        if os.path.realpath(store.cas.root) == want:
-            return store
-    return None
-
-
 def collect_rows(storage_dir: str
                  ) -> list[tuple[float, int, str, str]]:
     """The eviction decision input: ``(recency, size, plane, name)``
     per hot-tier object (chunks + blobs; packs and recipes follow
     their referents' lifecycle). Recency is file mtime — overlaid
-    with the live chunk store's in-memory access times when one is
-    registered, so the dry-run and the evictor judge reads the LRU
+    with a live store's in-memory access times where this process has
+    the root open, so the dry-run and the evictor judge reads the LRU
     actually saw, not just writes."""
-    from makisu_tpu.cache import census as census_mod
-    engine = census_mod.StorageCensus(storage_dir)
-    live = _live_chunk_store(storage_dir)
-    recency: dict[str, float] = {}
-    if live is not None:
-        try:
-            recency = dict(live.cas._last_access)
-        except RuntimeError:  # resized mid-copy; mtimes still serve
-            recency = {}
     rows: list[tuple[float, int, str, str]] = []
-    for name, size, mtime in engine._walk_cas(engine.chunks_dir):
-        rows.append((recency.get(name, mtime), size, "chunks", name))
-    for name, size, mtime in engine._walk_cas(engine.layers_dir):
-        rows.append((mtime, size, "blobs", name))
+    for plane, sub in (("chunks", "chunks"), ("blobs", "layers")):
+        store = cas.store_for(os.path.join(storage_dir, sub))
+        recency = store.recency()
+        rows.extend((recency.get(name, mtime), size, plane, name)
+                    for name, size, mtime in store.walk())
     return rows
 
 
@@ -692,17 +674,17 @@ class ContentStore:
         dst = os.path.join(rdir, "packs", f"{pack_hex}.pack")
         tmp = dst + ".tmp"
         h = hashlib.sha256()
+        # The bare directory: demotion works with no store open, and
+        # its reads are not accesses an LRU should hear of.
+        chunk_dir = cas.CASDir(self.chunks_dir)
         try:
             os.makedirs(os.path.dirname(dst), exist_ok=True)
             with open(tmp, "wb") as out:
                 for fp, length in members:
-                    # Straight off the chunk files (not the serving
-                    # registry — demotion must work offline too); a
-                    # pack hex is the sha256 of exactly these bytes
-                    # concatenated, verified below before commit.
-                    path = os.path.join(self.chunks_dir, fp[:2], fp)
-                    with open(path, "rb") as f:
-                        data = f.read()
+                    # A pack hex is the sha256 of exactly these
+                    # bytes concatenated, verified below before
+                    # commit.
+                    data = chunk_dir.read(fp)
                     if len(data) != int(length):
                         raise ValueError(
                             f"member {fp} is {len(data)} bytes, "
@@ -731,16 +713,15 @@ class ContentStore:
 
     # -- refetch (promotion) -----------------------------------------
 
-    def refetch_chunks(self, missing, lengths: dict[str, int],
-                       put=None) -> set[str]:
+    def refetch_chunks(self, missing,
+                       lengths: dict[str, int]) -> set[str]:
         """Promote evicted chunks back into the hot tier from the
         pack/remote tiers: spans map onto seekable-zstd frames (or
         raw-pack runs) through the same planners the ranged wire
         uses, each run's bytes are charged to the transfer engine's
         memory budget, and every carved chunk is digest-verified
         before the CAS stores it. Returns the fps restored."""
-        from makisu_tpu.cache.chunks import (ChunkStore,
-                                             plan_frame_runs)
+        from makisu_tpu.cache.chunks import plan_frame_runs
         from makisu_tpu.registry import transfer
         from makisu_tpu.utils import zstdio
         index = self.pack_index()
@@ -754,9 +735,7 @@ class ContentStore:
                 (off, int(lengths.get(fp, length) or length), fp))
         if not by_pack:
             return set()
-        if put is None:
-            live = _live_chunk_store(self.storage_dir)
-            put = live.put if live is not None else self._put_chunk
+        chunk_store = cas.store_for(self.chunks_dir)
         budget = transfer.engine().budget
         rs = self._recipe_store()
         restored: set[str] = set()
@@ -766,7 +745,8 @@ class ContentStore:
             if hashlib.sha256(data).hexdigest() != fp:
                 raise ValueError(f"tier refetch for {fp} carved "
                                  f"bytes that do not hash to it")
-            put(fp, data)
+            chunk_store.put(fp, data)
+            metrics.counter_add(metrics.CHUNK_INGEST, result="written")
             restored.add(fp)
 
         for pack_hex, spans in sorted(by_pack.items()):
@@ -834,13 +814,6 @@ class ContentStore:
                      "the pack/remote tier", len(restored), moved)
         return restored
 
-    def _put_chunk(self, fp: str, data: bytes) -> None:
-        """Offline CAS write (no live store registered): same shard
-        layout, atomic tmp+rename, digest already verified."""
-        shard = os.path.join(self.chunks_dir, fp[:2])
-        os.makedirs(shard, exist_ok=True)
-        fileio.write_bytes_atomic(os.path.join(shard, fp), data)
-
     # -- eviction ----------------------------------------------------
 
     def plan(self, budget_bytes: int | None = None,
@@ -865,7 +838,8 @@ class ContentStore:
         if budget <= 0:
             return {"skipped": "unbudgeted"}
         plan = self.plan(budget_bytes=budget, include_candidates=True)
-        live = _live_chunk_store(self.storage_dir)
+        stores = {"chunks": cas.store_for(self.chunks_dir),
+                  "blobs": cas.store_for(self.layers_dir)}
         index = self.pack_index()
         # Demote packs BEFORE deleting member chunks: a raw-pack
         # materialization needs the members present.
@@ -894,12 +868,7 @@ class ContentStore:
                     # plain eviction, honestly labeled.
                     reason = "lru" if reason == "demote" else reason
             try:
-                if plane == "chunks" and live is not None:
-                    live.cas.delete(name)
-                else:
-                    root = (self.chunks_dir if plane == "chunks"
-                            else self.layers_dir)
-                    os.unlink(os.path.join(root, name[:2], name))
+                stores[plane].delete(name)
             except OSError:
                 continue
             freed += size
@@ -1033,8 +1002,7 @@ def store_for(storage_dir: str) -> ContentStore:
 
 
 def refetch_for_chunk_root(chunk_root: str, missing,
-                           lengths: dict[str, int],
-                           put=None) -> set[str]:
+                           lengths: dict[str, int]) -> set[str]:
     """``ChunkStore.ensure_available``'s tier hook: promote what the
     local pack/remote tiers can recover before peers or the registry
     are consulted. Free no-op when the storage has no serve plane."""
@@ -1042,8 +1010,7 @@ def refetch_for_chunk_root(chunk_root: str, missing,
     if not os.path.isdir(os.path.join(storage_dir, "serve")):
         return set()
     try:
-        return store_for(storage_dir).refetch_chunks(
-            missing, lengths, put=put)
+        return store_for(storage_dir).refetch_chunks(missing, lengths)
     except Exception as e:  # noqa: BLE001 - a tier miss never fails
         log.debug("tier refetch unavailable for %s: %s",
                   storage_dir, e)

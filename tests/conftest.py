@@ -52,6 +52,15 @@ def _no_mounts():
     mountinfo.set_mountpoints_for_testing(None)
 
 
+def cas_entry_path(root, name: str) -> str:
+    """Where the CAS directory ``root`` keeps ``name``, for a test that
+    ages, corrupts or removes an entry behind the store's back. The
+    one place in ``tests/`` (outside ``test_storage.py``, which tests
+    the layout itself) that knows: it asks the owner."""
+    from makisu_tpu.storage import cas
+    return cas.CASDir(str(root))._path(name)
+
+
 class _FsCalls:
     """Stand-in for the ``os`` module inside ``storage/cas.py``: counts
     every file-system call the store issues (``os.path`` probes

@@ -13,6 +13,8 @@ import shutil
 
 import pytest
 
+from conftest import cas_entry_path
+
 from makisu_tpu.cache import census as census_mod
 from makisu_tpu.cache.census import IOBudget, StorageCensus
 from makisu_tpu.cache.chunks import ChunkStore
@@ -49,9 +51,10 @@ def _populate(tmp_path, tenant=""):
 
     blob_hex, config_hex = "cd" * 32, "ee" * 32
     for hx, size in ((blob_hex, 500), (config_hex, 80)):
-        blob_dir = storage / "layers" / hx[:2]
-        blob_dir.mkdir(parents=True, exist_ok=True)
-        (blob_dir / hx).write_bytes(b"z" * size)
+        blob = cas_entry_path(storage / "layers", hx)
+        os.makedirs(os.path.dirname(blob), exist_ok=True)
+        with open(blob, "wb") as f:
+            f.write(b"z" * size)
     man_dir = storage / "manifests" / "team" / "app"
     man_dir.mkdir(parents=True)
     (man_dir / "latest.json").write_text(json.dumps({
@@ -101,7 +104,7 @@ def test_census_totals_match_disk(tmp_path):
 
 def test_census_age_histogram_buckets(tmp_path):
     storage, _, fps = _populate(tmp_path)
-    old = os.path.join(storage, "chunks", fps[0][:2], fps[0])
+    old = cas_entry_path(os.path.join(storage, "chunks"), fps[0])
     past = os.path.getmtime(old) - 40 * 86400
     os.utime(old, (past, past))
     out = StorageCensus(storage).census()
@@ -160,7 +163,7 @@ def test_iobudget_reserve_is_balanced(tmp_path):
     budget = IOBudget(max_resident_bytes=1 << 20)
     big = tmp_path / "big"
     big.write_bytes(b"q" * (3 << 20))  # 3 pieces through a 1MiB budget
-    digest, size = census_mod._hash_file(str(big), budget)
+    digest, size = census_mod._hash_file(open(big, "rb"), budget)
     assert size == 3 << 20
     assert digest == hashlib.sha256(b"q" * (3 << 20)).hexdigest()
     assert budget.resident == 0
@@ -191,7 +194,7 @@ def test_audit_clean_store_has_no_findings(tmp_path):
 
 def test_audit_names_dangling_chunk(tmp_path):
     storage, _, fps = _populate(tmp_path)
-    os.unlink(os.path.join(storage, "chunks", fps[0][:2], fps[0]))
+    os.unlink(cas_entry_path(os.path.join(storage, "chunks"), fps[0]))
     # A missing chunk whose pack survives as a compressed twin is
     # DEMOTED (recoverable), not dangling — remove the twin so the
     # loss is genuinely unrecoverable.
@@ -215,7 +218,7 @@ def test_audit_missing_chunk_with_twin_is_demoted(tmp_path):
     the budget evictor's expected footprint: classified demoted, zero
     findings — a post-eviction `doctor --storage` must exit clean."""
     storage, _, fps = _populate(tmp_path)
-    os.unlink(os.path.join(storage, "chunks", fps[0][:2], fps[0]))
+    os.unlink(cas_entry_path(os.path.join(storage, "chunks"), fps[0]))
     out = StorageCensus(storage).audit()
     assert out["findings"] == []
     assert out["classification"]["chunks"]["demoted"] == 1
@@ -226,7 +229,7 @@ def test_audit_missing_chunk_with_twin_is_demoted(tmp_path):
 def test_audit_names_dangling_blob(tmp_path):
     storage, _, _ = _populate(tmp_path)
     blob_hex = "cd" * 32
-    os.unlink(os.path.join(storage, "layers", blob_hex[:2], blob_hex))
+    os.unlink(cas_entry_path(os.path.join(storage, "layers"), blob_hex))
     out = StorageCensus(storage).audit()
     dangling = [f for f in out["findings"]
                 if f["kind"] == "dangling_blob"]
@@ -324,7 +327,7 @@ def test_audit_truncated_zpack(tmp_path):
 
 def test_eviction_dry_run_lru_order_and_sum(tmp_path):
     storage, _, fps = _populate(tmp_path)
-    oldest = os.path.join(storage, "chunks", fps[1][:2], fps[1])
+    oldest = cas_entry_path(os.path.join(storage, "chunks"), fps[1])
     past = os.path.getmtime(oldest) - 3600
     os.utime(oldest, (past, past))
     out = StorageCensus(storage).eviction_dry_run(3000)
@@ -368,7 +371,7 @@ def test_scrub_clean_store(tmp_path):
 
 def test_scrub_names_corrupt_chunk(tmp_path):
     storage, _, fps = _populate(tmp_path)
-    victim = os.path.join(storage, "chunks", fps[0][:2], fps[0])
+    victim = cas_entry_path(os.path.join(storage, "chunks"), fps[0])
     with open(victim, "rb+") as f:
         f.write(b"!")  # flip the first byte
     captured = []
@@ -429,8 +432,8 @@ def test_worker_healthz_and_storage_endpoint(tmp_path):
         assert section["findings"]["total"] == 0
         # Break a reference (twin removed too — a recoverable miss
         # is demoted, not a finding); /storage re-walks and names it.
-        os.unlink(os.path.join(storage, "chunks",
-                               fps[0][:2], fps[0]))
+        os.unlink(cas_entry_path(os.path.join(storage, "chunks"),
+                                 fps[0]))
         shutil.rmtree(os.path.join(storage, "serve", "zpacks"),
                       ignore_errors=True)
         report = client.storage(eviction_budget=0)
@@ -485,7 +488,7 @@ def test_cli_doctor_storage_exit_codes(tmp_path, capsys):
     assert cli.main(["doctor", "--storage", storage]) == 0
     out = capsys.readouterr().out
     assert "no findings" in out
-    os.unlink(os.path.join(storage, "chunks", fps[0][:2], fps[0]))
+    os.unlink(cas_entry_path(os.path.join(storage, "chunks"), fps[0]))
     shutil.rmtree(os.path.join(storage, "serve", "zpacks"),
                   ignore_errors=True)
     assert cli.main(["doctor", "--storage", storage]) == 1

@@ -2,8 +2,11 @@
 reference lacks (whole-layer cache only)."""
 
 import json
+import os
 
 import pytest
+
+from conftest import cas_entry_path
 
 from makisu_tpu.builder import BuildPlan
 from makisu_tpu.cache import CacheManager, MemoryStore
@@ -1096,5 +1099,6 @@ def test_index_layer_store_equals_the_one_file_a_chunk_layout(
     golden = {"_tmp/": (0, b"")}
     for piece in pieces:
         h = hashlib.sha256(piece).hexdigest()
-        golden[f"{h[:2]}/{h}"] = (0o600, piece)
+        golden[os.path.relpath(cas_entry_path(store.cas.root, h),
+                               store.cas.root)] = (0o600, piece)
     assert store_tree(store.cas.root) == golden

@@ -8,9 +8,12 @@ import os
 
 import pytest
 
+from conftest import cas_entry_path
+
 from makisu_tpu.cache import census as census_mod
 from makisu_tpu.cache.chunks import ChunkStore
 from makisu_tpu.serve import recipe as recipe_mod
+from makisu_tpu.storage import cas as cas_mod
 from makisu_tpu.storage import contentstore
 from makisu_tpu.utils import zstdio
 
@@ -49,7 +52,7 @@ def _publish(tmp_path, payloads=None):
 
 
 def _chunk_path(storage, fp):
-    return os.path.join(storage, "chunks", fp[:2], fp)
+    return cas_entry_path(os.path.join(storage, "chunks"), fp)
 
 
 # -- parity: the dry-run IS the evictor's plan --------------------------------
@@ -156,9 +159,8 @@ def test_peer_serve_read_pins_member(tmp_path):
         assert hashlib.sha256(raw).hexdigest() == pack_hex
         assert board.count() == 0
     finally:
-        with chunks_mod._serving_lock:
-            chunks_mod._serving_stores.pop(
-                os.path.realpath(store.cas.root), None)
+        with cas_mod._live_lock:
+            cas_mod._live.pop(os.path.realpath(store.cas.root), None)
 
 
 def test_cas_count_lru_skips_pinned(tmp_path):

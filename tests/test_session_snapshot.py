@@ -11,6 +11,8 @@ import time
 
 import pytest
 
+from conftest import cas_entry_path
+
 from makisu_tpu import cli
 from makisu_tpu.cache.census import StorageCensus
 from makisu_tpu.docker.image import ImageName
@@ -270,7 +272,7 @@ def test_census_accounts_snapshots_and_flags_orphans(tmp_path):
     # Delete one shard chunk: the recipe classifies as orphaned with a
     # warning finding — never a crash.
     victim = recipe["shards"]["scan"]["chunk"]
-    os.unlink(os.path.join(storage, "chunks", victim[:2], victim))
+    os.unlink(cas_entry_path(os.path.join(storage, "chunks"), victim))
     audit = StorageCensus(storage).audit()
     snaps = audit["classification"]["snapshots"]
     assert snaps["orphaned"] == 1 and snaps["live"] == 0

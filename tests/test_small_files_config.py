@@ -13,6 +13,8 @@ import sys
 import numpy as np
 import pytest
 
+from conftest import cas_entry_path
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 CHECKOUT = os.path.dirname(HERE)
 PERFBENCH = os.path.join(CHECKOUT, "perfbench")
@@ -228,7 +230,7 @@ def built(tmp_path_factory):
     out["cold_check"] = _held_to_reference(reference, context, cold)
     hexd = _digests(cold)[0][0].split(":", 1)[1]
     out["cold_tar"] = reference.inflate(
-        os.path.join(storage, "layers", hexd[:2], hexd))
+        cas_entry_path(os.path.join(storage, "layers"), hexd))
     out["touched"] = gen.apply_edit(EDIT["edit"], context, ctx,
                                     np.random.default_rng([1, 0, 7]), "000001")
     edited, _ = _build(work, ctx, "edited", "tpu", storage)

@@ -13,6 +13,7 @@ from makisu_tpu.docker.image import (
     ImageName,
 )
 from makisu_tpu.storage import CASStore, ImageStore, ManifestStore
+from makisu_tpu.storage import cas as cas_mod
 
 
 def test_cas_roundtrip(tmp_path):
@@ -258,3 +259,162 @@ def test_cas_write_many_holds_the_entry_cap_and_pins(tmp_path):
     assert len(keys) == 8 and pinned in keys
     assert set(store._last_access) == keys
     assert not keys & {name for name, _ in entries[1:4]}
+
+
+# -- one owner of the layout (PR 28) ------------------------------------------
+
+
+def _handle(kind: str, root: str):
+    """A live store (root made, recency kept) or the bare directory
+    (nothing touched until the first put)."""
+    return CASStore(root) if kind == "live" else cas_mod.CASDir(root)
+
+
+@pytest.mark.parametrize("op", ["put", "read", "delete", "walk"])
+@pytest.mark.parametrize("kind", ["live", "offline"])
+def test_cas_handle_live_and_offline_agree(tmp_path, store_tree, kind, op):
+    """Either handle leaves the tree ``write_many`` leaves and reads it
+    back the same way: one layout, whoever holds it."""
+    root = str(tmp_path / "cas")
+    store = _handle(kind, root)
+    entries = _entries(12)
+    for name, data in entries:
+        store.put(name, data)
+    if op == "put":
+        assert store_tree(root) == _stored(entries)
+        # The other kind of handle over the same root adds to it.
+        other = _handle("offline" if kind == "live" else "live", root)
+        more = _entries(15)[12:]
+        for name, data in more:
+            other.put(name, data)
+        assert store_tree(root) == _stored(entries + more)
+    elif op == "read":
+        for name, data in entries:
+            assert store.read(name) == data
+            with store.open(name) as f:
+                assert f.read() == data
+        with pytest.raises(FileNotFoundError):
+            store.open("0" * 64)
+    elif op == "delete":
+        gone, kept = entries[:5], entries[5:]
+        for name, _ in gone:
+            store.delete(name)
+        store.delete(gone[0][0])                 # absent: not an error
+        tree = store_tree(root)
+        assert {k: v for k, v in tree.items() if not k.endswith("/")} \
+            == {k: v for k, v in _stored(kept).items()
+                if not k.endswith("/")}
+        assert sorted(store.keys()) == sorted(n for n, _ in kept)
+    else:
+        rows = sorted(store.walk())
+        assert [n for n, _, _ in rows] == sorted(store.keys())
+        assert [(n, s) for n, s, _ in rows] == sorted(
+            (n, len(d)) for n, d in entries)
+        assert all(mtime > 0 for _, _, mtime in rows)
+
+
+@pytest.mark.parametrize("hazard", ["missing_root", "stray_staging",
+                                    "shard_removed", "entry_vanishes"])
+@pytest.mark.parametrize("kind", ["live", "offline"])
+def test_cas_walk_yields_through_what_a_census_meets(tmp_path, kind,
+                                                     hazard):
+    import shutil
+    root = str(tmp_path / "cas")
+    store = _handle(kind, root)
+    if hazard == "missing_root":
+        shutil.rmtree(root, ignore_errors=True)
+        assert list(store.walk()) == [] and store.keys() == []
+        assert not os.path.exists(root)          # reading made nothing
+        return
+    names = ["aa01", "aa02", "bb01", "cc01"]
+    for name in names:
+        store.put(name, name.encode())
+    if hazard == "stray_staging":
+        with open(os.path.join(root, "_tmp", "aa03.1.0"), "wb") as f:
+            f.write(b"half written")
+        assert sorted(n for n, _, _ in store.walk()) == names
+        assert sorted(store.keys()) == names
+        return
+    walk = store.walk()
+    first = next(walk)[0]
+    if hazard == "shard_removed":
+        for shard in os.listdir(root):
+            if shard not in ("_tmp", first[:2]):
+                shutil.rmtree(os.path.join(root, shard))
+    else:
+        for name in names:
+            if name != first:
+                store.delete(name)
+    rest = [n for n, _, _ in walk]               # does not raise
+    assert set(rest) <= set(names) - {first}
+
+
+def test_cas_offline_handle_touches_nothing_until_it_writes(tmp_path):
+    root = str(tmp_path / "nowhere" / "cas")
+    store = cas_mod.CASDir(root)
+    assert list(store.walk()) == [] and store.recency() == {}
+    assert store.seed_state() is None
+    with pytest.raises(FileNotFoundError):
+        store.read("abcd")
+    store.delete("abcd")
+    assert not os.path.exists(os.path.dirname(root))
+
+
+def test_cas_store_for_root_is_live_while_one_is_open(tmp_path):
+    """"The store for this root" is the registered live store, found by
+    real path; otherwise the bare directory. A delete through it drops
+    the live store's recency entry."""
+    root = str(tmp_path / "cas")
+    live = CASStore(root)
+    (name, data), (name2, data2) = _entries(2)
+    assert type(cas_mod.store_for(root)) is cas_mod.CASDir
+    cas_mod.register_live(live)
+    try:
+        link = str(tmp_path / "link")
+        os.symlink(root, link)
+        assert cas_mod.store_for(root) is live
+        assert cas_mod.store_for(link) is live
+        assert cas_mod.live_stores().count(live) == 1
+        assert cas_mod.live_stores({os.path.realpath(root)}) == [live]
+        assert cas_mod.live_stores({str(tmp_path)}) == []
+        cas_mod.store_for(root).put(name, data)
+        cas_mod.CASDir(root).put(name2, data2)   # behind its back
+        assert set(live.recency()) == {name}
+        assert live.recency() is not live._last_access
+        cas_mod.store_for(root).delete(name)
+        assert name not in live._last_access and not live.exists(name)
+        assert live.seed_state()["state"] == "seeded"
+    finally:
+        with cas_mod._live_lock:
+            cas_mod._live.pop(os.path.realpath(root), None)
+    assert type(cas_mod.store_for(root)) is cas_mod.CASDir
+    assert sorted(cas_mod.store_for(root).keys()) == [name2]
+
+
+def test_cas_layout_is_spelled_in_one_file():
+    """The seam: no module but ``storage/cas.py`` joins a shard into a
+    path, and none reaches the private state the old call sites used."""
+    import re
+    pkg = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "makisu_tpu")
+    owner = os.path.join(pkg, "storage", "cas.py")
+    banned = re.compile(
+        r"_SHARD_CHARS|_walk_cas|_put_chunk|_live_chunk_store"
+        r"|cas\._last_access|cas\._tmp_dir|cas\._path")
+    found = []
+    for dirpath, _, files in os.walk(pkg):
+        for fn in files:
+            path = os.path.join(dirpath, fn)
+            if not fn.endswith(".py") or path == owner:
+                continue
+            with open(path, encoding="utf-8") as f:
+                lines = f.read().splitlines()
+            for i, line in enumerate(lines):
+                near = " ".join(lines[max(0, i - 1):i + 2])
+                if banned.search(line) or (
+                        "[:2]" in line and "join(" in near):
+                    found.append(f"{os.path.relpath(path, pkg)}:"
+                                 f"{i + 1}: {line.strip()}")
+    assert found == []
+    with open(owner, encoding="utf-8") as f:
+        assert "_SHARD_CHARS" in f.read()        # the scan sees the owner
