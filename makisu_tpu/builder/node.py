@@ -7,6 +7,7 @@ applyLayer:133, push/pullCacheLayer:151-181).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import tarfile
 
 from makisu_tpu import tario
@@ -51,12 +52,20 @@ class BuildNode:
         self.step.apply_ctx_and_config(self.ctx, prev_config)
         cached = self.digest_pairs is not None
         if cached:
+            # The tree has readers only if a later step commits; the
+            # MemFS applies these, in order, when one does. A RUN or a
+            # COPY --from reads the disk instead, at any moment.
+            memfs = self.ctx.memfs
             for pair in self.digest_pairs:
-                self._apply_layer(pair, opts.modify_fs, cache_mgr)
+                memfs.defer(pair.gzip_descriptor.digest.hex(),
+                            functools.partial(self._apply_layer, pair,
+                                              opts.modify_fs, cache_mgr))
+            if opts.modify_fs:
+                memfs.flush()
         if opts.skip_build:
             log.info("skipping execution; a later step was cached")
         elif cached:
-            log.info("skipping execution; cache was applied")
+            log.info("skipping execution; step was cached")
         else:
             self.step.execute(self.ctx, opts.modify_fs)
             if self.step.has_commit() or opts.force_commit:
@@ -78,6 +87,11 @@ class BuildNode:
 
     def _apply_layer(self, pair: DigestPair, modify_fs: bool,
                      cache_mgr=None) -> None:
+        """Fold one cached layer into the MemFS tree, and unpack it
+        under the root with ``modify_fs``. Runs from ``MemFS.flush``
+        alone: at once with ``modify_fs``, else when a later step of
+        the stage reads the tree, and never in a stage where none does
+        (``BuildStage.build`` drops it and counts it ``unread``)."""
         hex_digest = pair.gzip_descriptor.digest.hex()
         # Resident-session fast path: a layer this session has already
         # folded into a MemFS tree at this exact chain position replays
@@ -88,7 +102,8 @@ class BuildNode:
         # a different position (Dockerfile reorder) records fresh
         # instead of replaying stale state. Only for in-memory
         # application (modify_fs must hit the disk), and only on an
-        # untainted chain (every prior layer named itself).
+        # untainted chain (every prior layer named itself). It serves
+        # partially cached builds: a fully cached one applies nothing.
         memfs = self.ctx.memfs
         session = getattr(self.ctx, "session", None)
         memo_ok = (session is not None and not modify_fs

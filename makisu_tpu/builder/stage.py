@@ -168,6 +168,13 @@ class BuildStage:
                                f"{node.step.args}",
                     author="makisu-tpu",
                     empty_layer=True))
+        # Every reader of the tree is a step of this stage (a commit
+        # diffs against it); what is still pending was read by none.
+        for hex_digest in self.ctx.memfs.drop_pending():
+            log.info("cached layer %s not applied: no later step read "
+                     "the tree, its blob was not opened", hex_digest)
+            metrics.counter_add(metrics.LAYER_REPLAY_TOTAL,
+                                result="unread")
         assert config is not None
         config.created = _now_iso()
         config.history = histories

@@ -11,6 +11,16 @@ Committing a step diffs reality against the tree:
 - ``update_from_tar`` merges a pulled layer into the tree (optionally
   materializing it on disk), honoring whiteouts.
 
+A cached layer reaches the tree only when the tree is read. The build
+node hands its application over (``defer``); every method that reads or
+writes ``tree`` first applies what is pending, in order (``flush``). A
+stage whose later steps are all cache hits never reads the tree, drops
+its pending applications when it ends (``drop_pending``), and so never
+opens those layers' blobs: the image's manifest and config come from
+the digests the cache returned. Such a build reads no byte of those
+blobs, so their integrity at rest is the storage plane's work (the
+scrub, ``doctor --storage``), as for every blob a build does not touch.
+
 The diff compares mtimes in whole seconds, so both commits hold one
 invariant: a write made after a commit returns is stamped in a later
 second than every mtime that commit's scan visited. The reference
@@ -32,6 +42,7 @@ import os
 import shutil
 import tarfile
 import time
+from collections.abc import Callable
 from glob import glob
 
 from makisu_tpu import tario
@@ -112,11 +123,44 @@ class MemFS:
         # turns the memo off for this tree.
         self.applied_chain = ""
         self.chain_tainted = False
+        # Cached layers not folded into the tree yet: (digest, apply),
+        # oldest first. See ``defer``.
+        self._pending: list[tuple[str, Callable[[], None]]] = []
 
     def extend_chain(self, digest_hex: str) -> None:
         import hashlib
         self.applied_chain = hashlib.sha256(
             (self.applied_chain + digest_hex).encode()).hexdigest()
+
+    # ------------------------------------------------------------------
+    # Deferred application of cached layers
+    # ------------------------------------------------------------------
+
+    def defer(self, digest_hex: str, apply: Callable[[], None]) -> None:
+        """Queue one cached layer's application. ``apply`` folds the
+        layer into this tree (through ``update_from_tar`` or
+        ``replay_layer``) and runs at the next ``flush``, after every
+        application queued before it: the tree, ``applied_chain`` and
+        the replay memo's keys come out as if each had run at once."""
+        self._pending.append((digest_hex, apply))
+
+    def flush(self) -> None:
+        """Apply what is pending, in order. Called at the top of every
+        method that reads or writes ``tree``; with nothing pending it
+        is one truth test."""
+        if not self._pending:
+            return
+        # Taken first: each application re-enters through a method
+        # that flushes.
+        pending, self._pending = self._pending, []
+        for _, apply in pending:
+            apply()
+
+    def drop_pending(self) -> list[str]:
+        """Forget the pending applications: the stage has ended and
+        nothing read the tree. Returns the digests dropped, in order."""
+        pending, self._pending = self._pending, []
+        return [digest_hex for digest_hex, _ in pending]
 
     # ------------------------------------------------------------------
     # Tree bookkeeping
@@ -205,6 +249,7 @@ class MemFS:
                             result="slept" if wait > 0 else "clear")
 
     def add_layer_by_scan(self, tw: tarfile.TarFile) -> Layer:
+        self.flush()
         self._sync()
         with metrics.span("layer_scan") as sp:
             layer, newest = self._create_layer_by_scan()
@@ -216,6 +261,7 @@ class MemFS:
 
     def add_layer_by_copy_ops(self, ops: list[CopyOperation],
                               tw: tarfile.TarFile) -> Layer:
+        self.flush()
         self._sync()
         with metrics.span("layer_scan") as sp:
             layer = Layer()
@@ -427,6 +473,7 @@ class MemFS:
         ``chain_key`` names the layer (its blob digest) for the
         applied-chain identity; merges that can't name one taint the
         chain (diff/extract flows, which never consult the memo)."""
+        self.flush()
         layer = Layer()
         hardlinks: list[tuple[str, tarfile.TarInfo]] = []
         parent_mtimes: dict[str, float] = {}
@@ -478,7 +525,11 @@ class MemFS:
         layer-chain position over the same prior tree state — the
         session's digest-keyed lookup guarantees it). Per-entry cost
         drops to one tree fold, which is what makes a 100k-entry
-        cached chain replay in about a second instead of several."""
+        cached chain replay in about a second instead of several.
+
+        Only a build that reads its tree gets here (an edit above a
+        cached prefix): a fully cached build applies nothing at all."""
+        self.flush()
         layer = Layer()
         for entry in ops:
             self._apply_entry(entry)
@@ -553,6 +604,7 @@ class MemFS:
         """Copy ``sources`` (globs, stage-root-relative) into ``new_root``
         preserving their paths — the sandbox the next stage's COPY --from
         reads (reference: mem_fs.go Checkpoint:91)."""
+        self.flush()
         if not sources:
             return
         resolved: list[str] = []
@@ -575,6 +627,8 @@ class MemFS:
                 copier.copy_file(src, dst)
 
     def compare(self, other: "MemFS", ignore_mtime: bool = True) -> FSDiff:
+        self.flush()
+        other.flush()
         diff = FSDiff([], [], [])
 
         def rec(a: Node | None, b: Node | None, path: str) -> None:

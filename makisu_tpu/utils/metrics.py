@@ -156,8 +156,11 @@ CACHED_LAYERS_APPLIED_TOTAL = "makisu_cached_layers_applied_total"
 SESSION_HITS = "makisu_session_hits"
 # Size of the dirty set a session handed to each build (begin_build).
 SESSION_DIRTY_PATHS = "makisu_session_dirty_paths_total"
-# Cached layers applied to the MemFS tree, result=memo (replayed from
-# the session's recorded entries) | inflate (gunzip + tar parse).
+# Cached layers by how they reached the MemFS tree, result=memo
+# (replayed from the session's recorded entries) | inflate (gunzip +
+# tar parse) | unread (never applied: the stage ended with no step
+# having read the tree, builder/stage.py). The first two sum to
+# makisu_cached_layers_applied_total.
 LAYER_REPLAY_TOTAL = "makisu_layer_replay_total"
 # Entries of each committed layer as its tar holds them, kind=file|dir|
 # symlink|other|whiteout (snapshot/memfs.py, added once a layer).

@@ -7,10 +7,12 @@ build_stage_test.go: full plans on fixture contexts with fake caches).
 import gzip
 import io
 import json
+import os
 import tarfile
 
 import pytest
 
+from makisu_tpu import cli
 from makisu_tpu.builder import BuildPlan
 from makisu_tpu.cache import CacheManager, MemoryStore, NoopCacheManager
 from makisu_tpu.context import BuildContext
@@ -341,3 +343,215 @@ def test_synthesized_ancestor_dirs_are_timeless(tmp_path):
     # The real file keeps its source mtime (mtime-preserving copies).
     assert by_name["app/deep/app.py"].mtime == int(
         (ctx_dir / "app.py").stat().st_mtime)
+
+
+# ---------------------------------------------------------------------------
+# Deferred application of cached layers: a cached layer reaches the
+# MemFS tree when a later step reads the tree, and never when none does.
+# ---------------------------------------------------------------------------
+
+_BASE_THEN_SRC = "FROM scratch\nCOPY base /app/\nCOPY src /app/\n"
+
+
+class _Drive:
+    """Builds ``tmp_path/ctx`` through the CLI, one storage and one
+    root a name, and counts what the builds open."""
+
+    def __init__(self, tmp_path, monkeypatch):
+        from makisu_tpu.builder import stage
+        from makisu_tpu.snapshot import MemFS
+        from makisu_tpu.storage.cas import CASDir
+        self.tmp_path = tmp_path
+        self.ctx = tmp_path / "ctx"
+        self.blob_opens: list[str] = []
+        self.tar_merges = 0
+        self.builds = 0
+        # One clock reading for `created` and the history: two builds
+        # of one image then write one config, byte for byte.
+        monkeypatch.setattr(stage, "_now_iso",
+                            lambda: "2026-01-01T00:00:00.000000Z")
+        cas_open, merge = CASDir.open, MemFS.update_from_tar
+        drive = self
+
+        def counted_open(self, name):
+            drive.blob_opens.append(name)
+            return cas_open(self, name)
+
+        def counted_merge(self, *args, **kwargs):
+            drive.tar_merges += 1
+            return merge(self, *args, **kwargs)
+        monkeypatch.setattr(CASDir, "open", counted_open)
+        monkeypatch.setattr(MemFS, "update_from_tar", counted_merge)
+
+    def context(self, dockerfile=_BASE_THEN_SRC):
+        """``base`` makes /app/sub and /app/same.txt; ``src`` writes
+        over same.txt and adds under sub."""
+        files = {"base/same.txt": "from base\n", "base/sub/kept.txt": "k\n",
+                 "src/same.txt": "from src, longer\n",
+                 "src/sub/added.txt": "a\n"}
+        for name, text in files.items():
+            path = self.ctx / name
+            path.parent.mkdir(parents=True, exist_ok=True)
+            path.write_text(text)
+            os.utime(path, (1_700_000_000, 1_700_000_000))
+        (self.ctx / "Dockerfile").write_text(dockerfile)
+
+    def edit(self, name, text):
+        (self.ctx / name).write_text(text)
+        os.utime(self.ctx / name, (1_700_000_100, 1_700_000_100))
+
+    def build(self, name, *flags, hasher="cpu"):
+        """Returns (image, report): the image is every digest that
+        names it (manifest's config and layers, the config's diff_ids);
+        the counts are of this build alone."""
+        self.builds += 1
+        self.blob_opens.clear()
+        self.tar_merges = 0
+        report = self.tmp_path / f"report{self.builds}.json"
+        root = self.tmp_path / f"root-{name}"
+        root.mkdir(exist_ok=True)
+        tag = f"deferred/{name}:{self.builds}"
+        assert cli.main([
+            "--log-level", "error", "--metrics-out", str(report),
+            "build", str(self.ctx), "-t", tag, "--hasher", hasher,
+            "--storage", str(self.tmp_path / f"storage-{name}"),
+            "--root", str(root), *flags]) == 0
+        opened = list(self.blob_opens)
+        with ImageStore(str(self.tmp_path / f"storage-{name}")) as store:
+            manifest = store.manifests.load(ImageName.parse(tag))
+            config = load_config(store, manifest)
+            self.layer_files = [read_layer(store, d)
+                                for d in manifest.layers]
+        layers = [d.digest.hex() for d in manifest.layers]
+        self.layer_opens = [h for h in opened if h in layers]
+        with open(report, encoding="utf-8") as f:
+            report = json.load(f)
+        return ({"config": manifest.config.digest.hex(), "layers": layers,
+                 "sizes": [d.size for d in manifest.layers],
+                 "diff_ids": list(config.rootfs.diff_ids)}, report)
+
+
+def _replays(report):
+    return {s["labels"]["result"]: s["value"] for s in
+            report["counters"].get("makisu_layer_replay_total", [])}
+
+
+def _span_parents(report, name):
+    """The name of the parent of each span called ``name``."""
+    found = []
+
+    def walk(span, parent):
+        if span["name"] == name:
+            found.append(parent)
+        for child in span.get("children", []):
+            walk(child, span["name"])
+    for top in report["spans"]:
+        walk(top, "")
+    return found
+
+
+def _applied(report):
+    return sum(s["value"] for s in report["counters"].get(
+        "makisu_cached_layers_applied_total", []))
+
+
+@pytest.fixture
+def drive(tmp_path, monkeypatch):
+    return _Drive(tmp_path, monkeypatch)
+
+
+@pytest.fixture
+def eager():
+    """Switches deferral off: what ``defer`` is handed runs at once.
+    The oracle that deferred builds are held to, digest for digest."""
+    from makisu_tpu.snapshot import MemFS
+    patch = pytest.MonkeyPatch()
+
+    def switch(on):
+        if on:
+            patch.setattr(MemFS, "defer",
+                          lambda self, digest_hex, apply: apply())
+        else:
+            patch.undo()
+    yield switch
+    patch.undo()
+
+
+@pytest.mark.parametrize("hasher", ["cpu", "tpu"])
+def test_fully_cached_rebuild_opens_no_layer_blob(drive, hasher):
+    """Every step a cache hit: nothing reads the tree, so neither
+    layer's blob is opened, inflated or parsed, each is counted
+    ``unread`` once, and the image is the image of the build before."""
+    drive.context()
+    first, report = drive.build("a", hasher=hasher)
+    assert _replays(report) == {} and len(first["layers"]) == 2
+    for _ in range(2):  # without, then with, a resident session
+        again, report = drive.build("a", hasher=hasher)
+        assert again == first
+        assert drive.layer_opens == [] and drive.tar_merges == 0
+        assert _replays(report) == {"unread": 2}
+        assert _applied(report) == 0
+
+
+def test_rebuilt_last_layer_equals_eager_application(drive, eager):
+    """Cached ``base``, rebuilt ``src`` that overwrites a file of
+    base's and adds under a directory base made: the commit flushes
+    the cached layer first, so the new layer is, digest for digest,
+    the one an application at once gives. First by inflating the blob,
+    then (third build of the chain) from the session's memo."""
+
+    def chain(name):
+        """A cold build, then two rebuilds with ``src`` edited."""
+        drive.context()
+        images = [drive.build(name)[0]]
+        for n, result in enumerate(["inflate", "memo"]):
+            drive.edit("src/same.txt", f"from src, edit {n}\n")
+            drive.edit("src/sub/added.txt", "a" * (n + 2))
+            image, report = drive.build(name)
+            images.append(image)
+            assert _replays(report) == {result: 1}
+            assert _applied(report) == 1
+            assert len(drive.layer_opens) == (result == "inflate")
+        return images, report
+
+    images, report = chain("deferred")
+    assert _span_parents(report, "apply_layer") == ["commit_layer"]
+    cold, *edited = images
+    for image in edited:
+        assert image["layers"][0] == cold["layers"][0]
+        assert image["layers"][1] != cold["layers"][1]
+    # Ancestors base made ride in the new layer as base left them.
+    assert {"app", "app/same.txt", "app/sub", "app/sub/added.txt"} \
+        <= set(drive.layer_files[1])
+    eager(True)
+    images_eager, report = chain("eager")
+    assert _span_parents(report, "apply_layer") == ["step"]
+    assert images_eager == images
+
+
+@pytest.mark.parametrize("case", ["run_after_cached_copy",
+                                  "copied_from_stage"])
+def test_layers_needed_on_disk_are_applied_at_once(drive, case):
+    """``--modifyfs`` stages whose disk is read (a ``RUN``, a later
+    ``COPY --from``) defer nothing: the cached layer is unpacked at its
+    own step, and the step after it finds the files."""
+    if case == "run_after_cached_copy":
+        dockerfiles = [
+            "FROM scratch\nCOPY base /app/\n"
+            f"RUN cat app/same.txt > seen{n}.txt\n" for n in (1, 2)]
+        found = "seen2.txt"
+    else:
+        dockerfiles = [
+            "FROM scratch AS builder\nCOPY base /out/\nFROM scratch\n"
+            f"COPY --from=builder /out/same.txt /deploy/{n}.txt\n"
+            for n in (1, 2)]
+        found = "deploy/2.txt"
+    drive.context(dockerfiles[0])
+    drive.build("a", "--modifyfs")
+    drive.context(dockerfiles[1])
+    image, report = drive.build("a", "--modifyfs")
+    assert _replays(report) == {"inflate": 1} and _applied(report) == 1
+    assert drive.tar_merges == 1
+    assert _span_parents(report, "apply_layer") == ["step"]
+    member = drive.layer_files[-1][found]
+    assert member.size == len("from base\n")

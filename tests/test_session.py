@@ -349,10 +349,15 @@ def test_cli_builds_reuse_session_and_digests_match(tmp_path):
     assert session is not None
     assert session.builds == 2
     assert session.hits >= 1
-    assert session.layer_replay  # applied layers memoized
+    # A fully cached rebuild applies no layer, so memoizes none; one
+    # with its last layer edited applies (and memoizes) the first.
+    assert not session.layer_replay
+    (ctx / "top.txt").write_text("top, edited")
     d3 = _build(tmp_path, ctx, "s/t:3")
-    assert d3 == d1
-    assert session.hits >= 2
+    assert d3[0] == d1[0] and d3[1] != d1[1]
+    assert len(session.layer_replay) == 1
+    assert _build(tmp_path, ctx, "s/t:4") == d3
+    assert session.hits >= 3
 
 
 def test_cli_session_disabled_env(tmp_path, monkeypatch):
@@ -399,3 +404,30 @@ def test_worker_sessions_endpoint_and_invalidate(tmp_path, worker):
     assert client.sessions()["count"] == 0
     health = client.healthz()
     assert health.sessions["invalidations"].get("explicit") == 1
+
+
+def test_worker_metrics_count_unread_cached_layers(tmp_path, worker):
+    """``/metrics`` serves ``makisu_layer_replay_total{result=
+    "unread"}``: two more a build of an unchanged two-layer context,
+    none for a build whose last layer was edited."""
+    import re
+
+    ctx = _make_ctx(tmp_path)
+    client = WorkerClient(worker.socket_path)
+
+    def unread_after_build(n):
+        assert client.build([
+            "build", str(ctx), "-t", f"w/unread:{n}",
+            "--storage", str(tmp_path / "storage"),
+            "--root", str(tmp_path / "root")]) == 0
+        found = re.search(
+            r'^makisu_layer_replay_total\{result="unread"\} (\S+)$',
+            client.metrics(), re.M)
+        return float(found.group(1)) if found else 0.0
+
+    # The registry is the process's: count from where it stands.
+    base = unread_after_build(1)
+    assert unread_after_build(2) == base + 2
+    assert unread_after_build(3) == base + 4
+    (ctx / "top.txt").write_text("top, edited")
+    assert unread_after_build(4) == base + 4

@@ -435,3 +435,169 @@ def test_walk_survives_very_deep_trees(tmp_path):
 
     walk_mod.remove_all_children(str(tmp_path), [])
     assert os.listdir(tmp_path) == []
+
+
+# ---------------------------------------------------------------------------
+# Deferred application: cached layers wait until the tree is read
+# ---------------------------------------------------------------------------
+
+def _defer_two_layers(fs: MemFS, order: list) -> None:
+    """Queue a layer that makes a/one, then one that deletes it and
+    adds a/two: only in that order does the tree end with a/two alone."""
+    first = [("a/", tarfile.DIRTYPE, None, {}),
+             ("a/one", tarfile.REGTYPE, "1", {})]
+    second = [("a/", tarfile.DIRTYPE, None, {}),
+              ("a/.wh.one", tarfile.REGTYPE, "", {}),
+              ("a/two", tarfile.REGTYPE, "2", {})]
+    for name, entries in (("first", first), ("second", second)):
+        def apply(name=name, entries=entries):
+            order.append(name)
+            fs.update_from_tar(make_tar(entries), untar=False,
+                               chain_key=name)
+        fs.defer(name, apply)
+
+
+def _read_by_scan(fs, tmp_path):
+    # The disk has no a/: the scan saw it in the tree and whites it out.
+    assert scan_layer(fs)[0] == [".wh.a"]
+    return "scanned away"
+
+
+def _read_by_copy_ops(fs, tmp_path):
+    op = CopyOperation(["f1"], str(_ctx(tmp_path)), "/", "/dest.txt")
+    copyop_layer(fs, [op])
+
+
+def _read_by_tar(fs, tmp_path):
+    fs.update_from_tar(make_tar([("b/", tarfile.DIRTYPE, None, {})]),
+                       untar=False)
+
+
+def _read_by_tar_path(fs, tmp_path):
+    import gzip
+    buf = io.BytesIO()
+    with tarfile.open(fileobj=buf, mode="w|") as tw:
+        ti = tarfile.TarInfo("b/")
+        ti.type = tarfile.DIRTYPE
+        tw.addfile(ti)
+    path = tmp_path / "layer.tar.gz"
+    path.write_bytes(gzip.compress(buf.getvalue()))
+    fs.update_from_tar_path(str(path), untar=False)
+
+
+def _read_by_replay(fs, tmp_path):
+    fs.replay_layer([], chain_key="third")
+
+
+def _read_by_checkpoint(fs, tmp_path):
+    (tmp_path / "sandbox").mkdir()
+    fs.checkpoint(str(tmp_path / "sandbox"), [])
+
+
+def _read_by_compare(fs, tmp_path):
+    (tmp_path / "other").mkdir()
+    assert fs.compare(new_fs(tmp_path / "other")).missing_in_second \
+        == ["/a"]
+
+
+def _read_by_being_compared(fs, tmp_path):
+    (tmp_path / "other").mkdir()
+    assert new_fs(tmp_path / "other").compare(fs).missing_in_first \
+        == ["/a"]
+
+
+@pytest.mark.parametrize("read", [
+    _read_by_scan, _read_by_copy_ops, _read_by_tar, _read_by_tar_path,
+    _read_by_replay, _read_by_checkpoint, _read_by_compare,
+    _read_by_being_compared], ids=lambda f: f.__name__[len("_read_by_"):])
+def test_every_reader_of_the_tree_applies_pending_layers_first(
+        tmp_path, read):
+    """Each method that reads or writes ``tree`` flushes the queue at
+    its top: once each, oldest first, though the applications come
+    back in through ``update_from_tar``, which flushes too."""
+    root = tmp_path / "root"
+    root.mkdir()
+    fs = new_fs(root)
+    order = []
+    _defer_two_layers(fs, order)
+    assert order == [] and fs.tree.children == {}
+    chain_before = fs.applied_chain
+    scanned_away = read(fs, tmp_path)
+    assert order == ["first", "second"]
+    if not scanned_away:
+        assert sorted(fs.tree.children["a"].children) == ["two"]
+    assert fs.applied_chain != chain_before
+    assert fs.drop_pending() == []
+
+
+def test_dropped_applications_never_run(tmp_path):
+    """A stage that ends unread: the queue empties without one
+    application having run, and names what it dropped, in order."""
+    fs = new_fs(tmp_path)
+    order = []
+    _defer_two_layers(fs, order)
+    assert fs.drop_pending() == ["first", "second"]
+    fs.flush()
+    scan_layer(fs)
+    assert order == [] and fs.applied_chain == ""
+    assert "a" not in fs.tree.children
+
+
+def test_deferred_chain_identity_equals_eager(tmp_path):
+    """Flushes keep the order, so ``applied_chain``, the replay memo's
+    key, comes out as it does when each layer is applied at once."""
+    eager_fs, deferred_fs = new_fs(tmp_path), new_fs(tmp_path)
+    _defer_two_layers(eager_fs, [])
+    eager_fs.flush()
+    after_first = []
+
+    def probe():
+        after_first.append(deferred_fs.applied_chain)
+    _defer_two_layers(deferred_fs, [])
+    deferred_fs._pending.insert(1, ("probe", probe))
+    deferred_fs.flush()
+    assert deferred_fs.applied_chain == eager_fs.applied_chain
+    assert after_first and after_first[0] not in ("",
+                                                  eager_fs.applied_chain)
+    assert not deferred_fs.chain_tainted
+
+
+def test_scan_over_a_deferred_layer_equals_scan_over_an_applied_one(
+        tmp_path):
+    """A cached layer, then a disk that has since overwritten one of
+    its files, deleted one (a whiteout) and added one under a directory
+    the layer made: the scan's tar is byte for byte the same whether
+    the layer was folded in at once or waited for the scan."""
+    root = tmp_path / "root"
+    (root / "d").mkdir(parents=True)
+    for name in ("keep", "gone", "over"):
+        (root / "d" / name).write_text(name)
+    cached = io.BytesIO()
+    with tarfile.open(fileobj=cached, mode="w|") as tw:
+        new_fs(root).add_layer_by_scan(tw)
+    (root / "d" / "gone").unlink()
+    (root / "d" / "over").write_text("written over")
+    (root / "d" / "new").write_text("new")
+    for path in (root / "d" / "over", root / "d" / "new", root / "d"):
+        os.utime(path, (2_000_000_000, 2_000_000_000))
+
+    def scan(fs):
+        buf = io.BytesIO()
+        with tarfile.open(fileobj=buf, mode="w|") as tw:
+            fs.add_layer_by_scan(tw)
+        return buf.getvalue()
+
+    def apply(fs):
+        cached.seek(0)
+        with tarfile.open(fileobj=cached, mode="r|") as tf:
+            fs.update_from_tar(tf, untar=False, chain_key="cached")
+
+    at_once, deferred = new_fs(root), new_fs(root)
+    apply(at_once)
+    deferred.defer("cached", lambda: apply(deferred))
+    assert deferred.tree.children == {}
+    tar_at_once, tar_deferred = scan(at_once), scan(deferred)
+    assert tar_deferred == tar_at_once
+    with tarfile.open(fileobj=io.BytesIO(tar_deferred), mode="r|") as tr:
+        names = [m.name for m in tr]
+    assert names == ["d", "d/.wh.gone", "d/new", "d/over"]

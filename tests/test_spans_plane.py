@@ -164,36 +164,60 @@ def test_the_sync_precedes_the_scan_and_the_wait_follows_the_tar(
         assert wait[3] >= 0.05
 
 
+def _replay_counts(report):
+    return {result: _counter(report, metrics.LAYER_REPLAY_TOTAL,
+                             result=result)
+            for result in ("inflate", "memo", "unread")}
+
+
 def test_layer_replay_counter_and_inflate_span(tmp_path, no_sleep):
-    """Rebuilds of an unchanged context in one process: the first
-    inflates both cached layers (``apply_layer.inflate`` under
-    ``apply_layer``), the second replays them from the session's memo;
-    an edit then shows in the dirty set's counter and span."""
+    """Rebuilds in one process with the last layer edited before each:
+    its commit reads the tree, so the cached first layer is applied
+    there, under ``commit_layer``. The first rebuild inflates it
+    (``apply_layer.inflate`` under ``apply_layer``), the second replays
+    it from the session's memo; each edit shows in the dirty set's
+    counter and span."""
     _context(tmp_path)
     _build(tmp_path, "cpu", 1)
+    (tmp_path / "ctx" / "b" / "f.bin").write_bytes(b"edited")
     event_log, report = _build(tmp_path, "cpu", 2)
     spans = _spans(event_log)
+    assert [s[1] for s in spans if s[0] == "apply_layer"] \
+        == ["commit_layer"]
     assert [s[1] for s in spans if s[0] == "apply_layer.inflate"] \
-        == ["apply_layer"] * 2
-    assert _counter(report, metrics.LAYER_REPLAY_TOTAL,
-                    result="inflate") == 2
-    assert _counter(report, metrics.LAYER_REPLAY_TOTAL, result="memo") == 0
-    assert _counter(report, metrics.SESSION_DIRTY_PATHS) == 0
+        == ["apply_layer"]
+    assert _replay_counts(report) == {"inflate": 1, "memo": 0, "unread": 0}
+    assert _counter(report, metrics.CACHED_LAYERS_APPLIED_TOTAL) == 1
 
+    (tmp_path / "ctx" / "b" / "f.bin").write_bytes(b"edited again")
     event_log, report = _build(tmp_path, "cpu", 3)
     spans = _spans(event_log)
-    assert len([s for s in spans if s[0] == "apply_layer"]) == 2
+    [apply] = [s for s in spans if s[0] == "apply_layer"]
+    assert apply[1] == "commit_layer" and apply[2]["replay"] == "True"
     assert not [s for s in spans if s[0] == "apply_layer.inflate"]
-    assert _counter(report, metrics.LAYER_REPLAY_TOTAL, result="memo") == 2
-    assert _counter(report, metrics.LAYER_REPLAY_TOTAL,
-                    result="inflate") == 0
-
-    (tmp_path / "ctx" / "b" / "f.bin").write_bytes(b"edited")
-    event_log, report = _build(tmp_path, "cpu", 4)
-    [begin] = [s for s in _spans(event_log) if s[0] == "session_begin"]
+    assert _replay_counts(report) == {"inflate": 0, "memo": 1, "unread": 0}
+    assert _counter(report, metrics.CACHED_LAYERS_APPLIED_TOTAL) == 1
+    [begin] = [s for s in spans if s[0] == "session_begin"]
     dirty = _counter(report, metrics.SESSION_DIRTY_PATHS)
     assert dirty >= 1
     assert begin[2] == {"mode": "resident", "dirty": str(int(dirty))}
+
+
+def test_an_unchanged_rebuild_applies_no_layer(tmp_path, no_sleep):
+    """Rebuilds of an unchanged context in one process: every step is
+    a cache hit, nothing reads the tree, and both cached layers are
+    dropped unread: no ``apply_layer`` span, with or without the
+    session's memo."""
+    _context(tmp_path)
+    _build(tmp_path, "cpu", 1)
+    for n in (2, 3):
+        event_log, report = _build(tmp_path, "cpu", n)
+        assert not [s for s in _spans(event_log)
+                    if s[0].startswith("apply_layer")]
+        assert _replay_counts(report) \
+            == {"inflate": 0, "memo": 0, "unread": 2}
+        assert _counter(report, metrics.CACHED_LAYERS_APPLIED_TOTAL) == 0
+        assert _counter(report, metrics.SESSION_DIRTY_PATHS) == 0
 
 
 def test_session_resync_span_when_watches_are_rebuilt(tmp_path):
