@@ -312,7 +312,13 @@ class NativeLayerSink:
 
     def finish(self) -> LayerCommit:
         tar_hex, gz_hex, gz_size, _ = self._handle.finish()
+        # The stage the Python sink's compressor thread reports: here
+        # deflate runs inside the producer's write (zlib) or on the
+        # C++ block pool (pgzip), and the library keeps the seconds.
+        busy = self._handle.compress_seconds()
         self._handle.close()
+        if busy is not None:
+            metrics.stage_busy_add(metrics.COMPRESS_STAGE, busy)
         metrics.counter_add("makisu_bytes_hashed_total", self._nbytes,
                             backend="native", path="layer_sink")
         backend = self.backend_id.split("-", 1)[0]

@@ -199,6 +199,15 @@ def _load_lsk() -> ctypes.CDLL | None:
             _lsk_lib = lib
         except (OSError, AttributeError):
             _lsk_failed = True
+            return _lsk_lib
+        try:
+            # Newer symbol, bound separately: a prebuilt library from
+            # before it still commits layers, and reports no compress
+            # seconds.
+            lib.lsk_compress_seconds.restype = ctypes.c_double
+            lib.lsk_compress_seconds.argtypes = [ctypes.c_void_p]
+        except AttributeError:
+            pass
         return _lsk_lib
 
 
@@ -581,6 +590,12 @@ class LayerSinkHandle:
         self._check_tap()
         return (bytes(tar_sha).hex(), bytes(gz_sha).hex(),
                 gz_size.value, tar_size.value)
+
+    def compress_seconds(self) -> float | None:
+        """Seconds the gzip stream kept a thread busy (summed over the
+        pgzip lanes); ``None`` from a library that predates the count."""
+        fn = getattr(self._lib, "lsk_compress_seconds", None)
+        return float(fn(self._live())) if fn is not None else None
 
     def close(self) -> None:
         if self._handle:

@@ -141,6 +141,7 @@ HTTP_CONNECTIONS_TOTAL = "makisu_http_connections_total"
 # Process resource gauges (utils/resources.py sampler): what the
 # worker's /metrics scrape sees between builds.
 PROCESS_RSS_BYTES = "makisu_process_rss_bytes"
+PROCESS_PEAK_RSS_BYTES = "makisu_process_peak_rss_bytes"
 PROCESS_CPU_SECONDS = "makisu_process_cpu_seconds"
 PROCESS_THREADS = "makisu_process_threads"
 PROCESS_OPEN_FDS = "makisu_process_open_fds"

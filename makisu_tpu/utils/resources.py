@@ -7,7 +7,8 @@ went, and — through the flight recorder — what the process looked like
 right before it died. One daemon sampler thread per process:
 
 - publishes process gauges into the global registry
-  (``makisu_process_rss_bytes``, ``makisu_process_cpu_seconds``,
+  (``makisu_process_rss_bytes``, ``makisu_process_peak_rss_bytes``,
+  ``makisu_process_cpu_seconds``,
   ``makisu_process_open_fds``, ``makisu_process_threads``,
   ``makisu_process_io_read_bytes`` / ``_write_bytes``) — what the
   worker's ``/metrics`` scrape sees;
@@ -36,6 +37,11 @@ from typing import Any
 
 from makisu_tpu.utils import metrics
 
+try:
+    import resource as _resource
+except ImportError:  # pragma: no cover - non-POSIX
+    _resource = None
+
 DEFAULT_INTERVAL = 0.5          # seconds between samples
 TRAJECTORY_KEEP = 240           # recent samples kept for bundles (~2min)
 
@@ -54,12 +60,16 @@ def _rss_bytes() -> int:
         with open("/proc/self/statm", "rb") as f:
             return int(f.read().split()[1]) * _PAGE_SIZE
     except (OSError, ValueError, IndexError):
-        try:  # pragma: no cover - non-procfs fallback
-            import resource as _resource
-            return _resource.getrusage(
-                _resource.RUSAGE_SELF).ru_maxrss * 1024
-        except Exception:  # noqa: BLE001
-            return 0
+        return _peak_rss_bytes()  # pragma: no cover - non-procfs
+
+
+def _peak_rss_bytes() -> int:
+    """The kernel's own resident high-water mark (``ru_maxrss``, KiB on
+    Linux): a peak between two samples is in it. 0 where the platform
+    has no ``resource`` module."""
+    if _resource is None:
+        return 0
+    return _resource.getrusage(_resource.RUSAGE_SELF).ru_maxrss * 1024
 
 
 def _open_fds() -> int | None:
@@ -93,6 +103,7 @@ def read_sample() -> dict[str, Any]:
     sample: dict[str, Any] = {
         "ts": round(time.time(), 6),
         "rss_bytes": _rss_bytes(),
+        "peak_rss_bytes": _peak_rss_bytes(),
         "cpu_seconds": round(times.user + times.system, 6),
         "threads": threading.active_count(),
     }
@@ -118,6 +129,7 @@ class ResourceSampler:
         self._trajectory: "collections.deque[dict]" = \
             collections.deque(maxlen=TRAJECTORY_KEEP)
         self._last_cpu: float | None = None
+        self._peak_rss = 0
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
 
@@ -129,6 +141,11 @@ class ResourceSampler:
         self._trajectory.append(sample)
         g = metrics.global_registry()
         g.gauge_set(metrics.PROCESS_RSS_BYTES, sample["rss_bytes"])
+        # Never falls: the kernel's mark, or the largest sample where
+        # the kernel keeps none.
+        self._peak_rss = max(self._peak_rss, sample["peak_rss_bytes"],
+                             sample["rss_bytes"])
+        g.gauge_set(metrics.PROCESS_PEAK_RSS_BYTES, self._peak_rss)
         g.gauge_set(metrics.PROCESS_CPU_SECONDS, sample["cpu_seconds"])
         g.gauge_set(metrics.PROCESS_THREADS, sample["threads"])
         if "open_fds" in sample:

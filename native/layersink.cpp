@@ -23,6 +23,7 @@
 //   lsk_write(h, data, n)            raw tar bytes (headers, inline data)
 //   lsk_write_file(h, path, size)    file content + 512-byte padding
 //   lsk_finish(h, tar_sha32, gz_sha32, &gz_size, &tar_size)
+//   lsk_compress_seconds(h)          seconds the gzip stream kept a thread busy
 //   lsk_free(h)
 // All int-returning calls: 0 = ok, negative = error.
 
@@ -32,6 +33,7 @@
 #include <zlib.h>
 
 #include <cerrno>
+#include <chrono>
 #include <condition_variable>
 #include <cstdint>
 #include <cstring>
@@ -76,6 +78,16 @@ struct Sink {
   uLong crc = 0;          // crc32 of the uncompressed stream (trailer)
   bool failed = false;
   bool zinit = false;
+  // Seconds the gzip stream kept a thread busy: the zlib backend's
+  // deflate with the blob's digest and write (as the Python sink's
+  // compressor thread counts them), the pgzip backend's block deflates
+  // summed over its lanes. Guarded by mu where workers run.
+  double compress_s = 0;
+
+  static double since(std::chrono::steady_clock::time_point t0) {
+    return std::chrono::duration<double>(
+               std::chrono::steady_clock::now() - t0).count();
+  }
 
   // zlib backend: one continuous deflate stream.
   z_stream zs;
@@ -141,6 +153,13 @@ struct Sink {
   }
 
   bool zlib_consume(const uint8_t* data, size_t n, bool finish) {
+    auto t0 = std::chrono::steady_clock::now();
+    bool ok = zlib_deflate(data, n, finish);
+    compress_s += since(t0);
+    return ok;
+  }
+
+  bool zlib_deflate(const uint8_t* data, size_t n, bool finish) {
     zs.next_in = const_cast<Bytef*>(data);
     zs.avail_in = static_cast<uInt>(n);
     for (;;) {
@@ -169,10 +188,13 @@ struct Sink {
         job = claim_queue.front();
         claim_queue.pop_front();
       }
+      auto t0 = std::chrono::steady_clock::now();
       bool ok = DeflateSlice(job->in.data(), job->in.size(), level,
                               job->last, job->out);
+      double busy = since(t0);
       {
         std::lock_guard<std::mutex> lock(mu);
+        compress_s += busy;
         job->done = true;
         job->failed = !ok;
       }
@@ -185,8 +207,10 @@ struct Sink {
     job->in = std::move(data);
     job->last = last;
     if (workers.empty()) {
+      auto t0 = std::chrono::steady_clock::now();
       job->failed = !DeflateSlice(job->in.data(), job->in.size(), level,
                                    job->last, job->out);
+      compress_s += since(t0);
       job->done = true;
       jobs.push_back(job);
     } else {
@@ -354,6 +378,13 @@ int lsk_finish(void* handle, uint8_t tar_sha[32], uint8_t gz_sha[32],
   *gz_size = s->gz_size;
   *tar_size = s->tar_size;
   return 0;
+}
+
+// After lsk_finish (every lane is idle then).
+double lsk_compress_seconds(void* handle) {
+  auto* s = static_cast<Sink*>(handle);
+  std::lock_guard<std::mutex> lock(s->mu);
+  return s->compress_s;
 }
 
 void lsk_free(void* handle) { delete static_cast<Sink*>(handle); }

@@ -95,6 +95,28 @@ def test_native_matches_python_bytes_and_digests(tmp_path, backend_id):
         == nat.digest_pair.gzip_descriptor.digest.hex()
 
 
+@pytest.mark.parametrize("sink_cls", [LayerSink, NativeLayerSink],
+                         ids=["python", "native"])
+@pytest.mark.parametrize("backend_id", ["zlib-6", "pgzip-6-131072"])
+def test_either_sink_reports_its_compress_seconds(tmp_path, sink_cls,
+                                                  backend_id):
+    """``makisu_commit_stage_busy_seconds{stage="compress"}`` grows by
+    what the layer's gzip stream spent, whichever sink committed it
+    (the native one keeps the seconds in C++)."""
+    if backend_id.startswith("pgzip") and not native.pgzip_available():
+        pytest.skip("pgzip not built")
+    from makisu_tpu.utils import metrics
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
+    try:
+        _commit(sink_cls, _tree(tmp_path), str(tmp_path / "out.tar.gz"),
+                backend_id)
+    finally:
+        metrics.reset_build_registry(token)
+    busy = registry.counter_by_label(metrics.COMMIT_STAGE_BUSY, "stage")
+    assert 0 < busy["compress"] < 5
+
+
 def test_native_archive_is_valid_tar(tmp_path):
     root = _tree(tmp_path)
     out = str(tmp_path / "check.tar.gz")

@@ -204,10 +204,29 @@ def test_every_new_metric_has_its_reader_and_its_cells():
         benchmark = json.load(f)
     cells_of = {m["name"]: m["workloads"] for m in benchmark["per_layer"]}
     every = ["monorepo-cold", "farm-churn", "monorepo-edit",
-             "farm-unchanged", "small-files-edit"]
+             "farm-unchanged", "small-files-edit", "huge-layer-edit"]
     assert cells_of["idle_unspanned_pct"] == every
     assert cells_of["sync_os_sync_s_per_build"] == every
     assert cells_of["chunk_index_s_per_build"] == [
-        "monorepo-cold", "monorepo-edit", "small-files-edit"]
+        "monorepo-cold", "monorepo-edit", "small-files-edit",
+        "huge-layer-edit"]
     for name in cells_of:
         assert os.path.exists(os.path.join(READERS, name + ".py")), name
+
+
+@pytest.mark.parametrize("cell", [
+    "monorepo-cold", "farm-churn", "monorepo-edit", "farm-unchanged",
+    "small-files-edit", "huge-layer-edit"])
+def test_every_cell_finds_its_files_and_a_reader_for_each_metric(cell):
+    """What ``run.py`` looks up by name for a cell: configuration, mix,
+    reference, and a reader for every metric either kind of run
+    reports."""
+    found = cells.Cell(os.path.join(os.path.dirname(HERE), "BENCHMARK.json"),
+                       cell)
+    assert callable(found.reference.cut_points)
+    assert found.traffic["count"] in ("started", "completed")
+    names = [m["name"] for m in found.end_to_end() + found.per_layer()]
+    assert "setup_s" in names and "build_p50_s" in names
+    assert len(names) == len(set(names)) > 10
+    for name in names:
+        assert callable(found.reader(name)), name
