@@ -17,9 +17,9 @@ rides the same registry blob plane the layer cache already uses.
 from __future__ import annotations
 
 import collections
-import gzip as gzip_mod
 import hashlib
 import os
+import zlib
 
 import json
 
@@ -104,13 +104,67 @@ def open_served_chunk(hex_digest: str, roots=None):
     return None
 
 
-def _skip(stream, nbytes: int) -> None:
-    """Advance a non-seekable decompression stream by nbytes."""
-    while nbytes > 0:
-        step = stream.read(min(nbytes, 1 << 20))
-        if not step:
-            raise ValueError("layer stream truncated while seeking")
-        nbytes -= len(step)
+class _Inflated:
+    """A gzip blob's inflated stream, read forward a block at a time
+    (index_layer's one pass). zlib inflates each block with the GIL
+    free and verifies a member's trailer (CRC32, ISIZE) as it reads the
+    member's last byte; ``take`` copies out only the spans it is asked
+    for, and ``finish`` inflates whatever is left, so every byte is
+    inflated once whoever wanted it. What follows a member's trailer is
+    read as ``GzipFile`` reads it: zero padding is passed over, anything
+    else is a further member of the same stream (or fails as one)."""
+
+    READ = 1 << 20   # compressed bytes a read
+    BLOCK = 4 << 20  # inflated bytes a block, at most
+
+    def __init__(self, raw) -> None:
+        self._raw = raw
+        self._z = zlib.decompressobj(31)
+        self._block = b""
+        self._start = 0  # stream offset of _block[0]
+
+    def _next(self) -> bool:
+        """Step to the next block; False at the stream's end."""
+        self._start += len(self._block)
+        self._block = b""
+        while not self._block:
+            if self._z.eof:
+                pending = self._z.unused_data.lstrip(b"\0")
+                while not pending:
+                    pending = self._raw.read(self.READ)
+                    if not pending:
+                        return False
+                    pending = pending.lstrip(b"\0")
+                self._z = zlib.decompressobj(31)
+            else:
+                pending = (self._z.unconsumed_tail
+                           or self._raw.read(self.READ))
+                if not pending:
+                    raise EOFError("layer blob ended before its gzip "
+                                   "stream's end-of-stream marker")
+            self._block = self._z.decompress(pending, self.BLOCK)
+        return True
+
+    def take(self, offset: int, length: int) -> bytes:
+        """The stream's bytes [offset, offset + length). Offsets never
+        go back; blocks wholly before ``offset`` are dropped unsliced."""
+        end = offset + length
+        parts: list[bytes] = []
+        while offset < end:
+            while self._start + len(self._block) <= offset:
+                if not self._next():
+                    raise ValueError(f"layer stream ended at "
+                                     f"{self._start}, chunk needs {end}")
+            lo = offset - self._start
+            parts.append(self._block[lo:lo + end - offset])
+            offset += len(parts[-1])
+        return parts[0] if len(parts) == 1 else b"".join(parts)
+
+    def finish(self) -> int:
+        """Inflate to the blob's end; returns the stream's length."""
+        while self._next():
+            pass
+        return self._start
 
 
 def plan_pack_runs(rows, missing, gap=None, whole_fraction=None,
@@ -339,6 +393,17 @@ class ChunkStore:
         with self._memo_lock:
             return self._exists_memo.get(hex_digest)
 
+    def _probed_each(self, chunks: list[tuple[int, int, str]]):
+        """``(chunk, _probed(its digest))`` for each chunk in order,
+        looked up PROBE_BATCH chunks a lock round: the answers of
+        probes still on the pool when index_layer starts are seen as
+        they land."""
+        for i in range(0, len(chunks), self.PROBE_BATCH):
+            group = chunks[i:i + self.PROBE_BATCH]
+            with self._memo_lock:
+                known = [self._exists_memo.get(h) for _, _, h in group]
+            yield from zip(group, known)
+
     def reset_fingerprint_memo(self) -> None:
         """Drop the streamed memo. Called after every index_layer
         (push_cache): a memoized True must not outlive the commit that
@@ -479,10 +544,14 @@ class ChunkStore:
         offset order; ``stats`` (if given) receives ``ingest_window``,
         the peak number of writers in flight.
 
-        Decompression is streamed — the chunk list is offset-sorted and
-        contiguous, so one forward pass over the gzip stream suffices —
-        and the stores run behind it through a bounded window of
-        writers (``_ingest_batch``), so memory stays bounded by the
+        Decompression is streamed — the chunk list is offset-sorted,
+        so one forward pass over the gzip stream suffices — a block at
+        a time (``_Inflated``): the whole blob is inflated and its
+        trailer verified, but only a chunk nobody has found stored is
+        sliced out of its block; a run of chunks the streamed probe
+        found costs neither bytes nor a file-system call. The stores
+        run behind the pass through a bounded window of writers
+        (``_ingest_batch``), so memory stays bounded by a block and the
         window (multi-GB layers never materialize whole). The first
         failure, of the stream or of any writer, is raised once the
         window has drained; a writer that fails leaves nothing under a
@@ -520,24 +589,20 @@ class ChunkStore:
 
         try:
             with open(layer_blob_path, "rb") as raw:
-                stream = gzip_mod.GzipFile(fileobj=raw, mode="rb")
+                stream = _Inflated(raw)
                 pos = 0
-                for offset, length, hex_digest in chunks:
+                for (offset, length, hex_digest), known in \
+                        self._probed_each(chunks):
                     if failure:
                         break
                     if offset < pos:
                         raise ValueError(
                             f"chunk list not offset-sorted at "
                             f"{offset} < {pos}")
-                    _skip(stream, offset - pos)
-                    data = stream.read(length)
-                    pos = offset + len(data)
-                    if len(data) != length:
-                        raise ValueError(
-                            f"layer stream ended at {pos}, chunk needs "
-                            f"{offset + length}")
-                    known = self._probed(hex_digest)
+                    pos = offset + length
                     if known:
+                        # Stored already: its bytes stay in the block
+                        # they were inflated into, unsliced.
                         tally["hit"] += 1
                         continue
                     if hex_digest in handed:
@@ -545,17 +610,21 @@ class ChunkStore:
                         continue
                     handed.add(hex_digest)
                     tally["probe" if known is None else "miss"] += 1
-                    batch.append((hex_digest, data, known is None))
+                    batch.append((hex_digest, stream.take(offset, length),
+                                  known is None))
                     batch_bytes += length
                     if batch_bytes >= self.INGEST_BATCH_BYTES:
                         flush()
-                # Drain to EOF so GzipFile validates the CRC32/ISIZE
-                # trailer (gzip.decompress did this implicitly before
-                # the rewrite); a corrupt blob must fail loudly here,
-                # not at reconstitute.
-                while not failure and stream.read(1 << 20):
-                    pass
-            flush()
+                flush()
+                # To the blob's end, whoever wanted the bytes: zlib
+                # validates the CRC32/ISIZE trailer there, so a corrupt
+                # or short blob fails loudly here, not at reconstitute
+                # (and a run of stored chunks past the stream's end
+                # fails as a sliced one does).
+                if not failure and stream.finish() < pos:
+                    raise ValueError(
+                        f"layer stream ended before {pos}, where its "
+                        f"chunk list ends")
         finally:
             while window:
                 reap()

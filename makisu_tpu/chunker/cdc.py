@@ -171,11 +171,12 @@ class _LaneBatcher:
 
     def __init__(self, cap: int, lanes: int,
                  route: "_route.ChunkRoute | None",
-                 clock: FeedClock) -> None:
+                 clock: FeedClock, notify=None) -> None:
         self.cap = cap
         self.lanes = lanes
         self.route = route
         self.clock = clock
+        self.notify = notify  # called with each digest's hex, if given
         self.data = np.zeros((lanes, cap), dtype=np.uint8)
         self.lengths = np.zeros(lanes, dtype=np.int32)
         self.meta: list[tuple[int, int]] = []  # (offset, length)
@@ -227,7 +228,10 @@ class _LaneBatcher:
                 self.cap, self.lanes, len(meta),
                 sum(n for _, n in meta), time.monotonic() - t0)
             for i, (off, n) in enumerate(meta):
-                out.append(Chunk(off, n, host[i].astype(">u4").tobytes()))
+                digest = host[i].astype(">u4").tobytes()
+                out.append(Chunk(off, n, digest))
+                if self.notify is not None:
+                    self.notify(digest.hex())
         self.pending = []
         return out
 
@@ -287,6 +291,7 @@ class ChunkSession:
         # vector form. The service path (cross-build device batching)
         # and non-cpu backends keep the device route.
         self._route = None
+        self._observer = _chunk_observer.get()
         try:
             self._route = _route.chunk_route(shared=service is not None)
         except RuntimeError as e:
@@ -294,13 +299,15 @@ class ChunkSession:
         self._native = self._route is not None and self._route.native
         if (self._route is not None and not self._native
                 and service is None):
+            # Each batch's digests stream to the observer as its
+            # readback lands, while the batches behind it are read back.
             self._batchers = [
-                _LaneBatcher(cap, lanes, self._route, self._clock)
+                _LaneBatcher(cap, lanes, self._route, self._clock,
+                             self._observer and self._notify)
                 for cap, lanes in _BUCKETS]
         # The gear table is deterministic by contract; one copy per
         # session, not one 256-iteration rebuild per 4MiB block.
         self._table = gear.gear_table() if self._native else None
-        self._observer = _chunk_observer.get()
         # Bytes hashed on the native route, accumulated locally and
         # flushed once at finish(): a per-chunk counter_add (lock +
         # label sort, ×2 registries) measured ~13% of the whole native
@@ -694,6 +701,12 @@ class ChunkSession:
         except Exception:  # noqa: BLE001 - observer plane
             self._observer = None  # one failure disables, not N
 
+    def _notify_resolved(self, fut) -> None:
+        """Done-callback of a shared-service future: a digest goes to
+        the observer, a failure is finish()'s to raise."""
+        if not fut.cancelled() and fut.exception() is None:
+            self._notify(fut.result().hex())
+
     def _flush_sha_batch(self) -> None:
         if not self._sha_meta:
             return
@@ -778,6 +791,11 @@ class ChunkSession:
             # A full service queue blocks the build here (backpressure).
             t0 = time.monotonic()
             fut = self.service.submit(data, owner=id(self))
+            if self._observer is not None:
+                # On the service's thread, as the batch's readback
+                # lands: the digest streams out while this build is
+                # still writing its tar, not when finish() collects it.
+                fut.add_done_callback(self._notify_resolved)
             self._clock.add("service_wait", time.monotonic() - t0)
             self._service_pending.append((offset, len(data), fut))
             return

@@ -945,18 +945,23 @@ def test_degraded_session_ignores_further_updates(monkeypatch):
 
 # -- bulk ingest through index_layer (PR 25) ---------------------------------
 
-def _layer(tmp_path, pieces: list[bytes], name="layer.gz"):
-    """A gzip blob whose stream is ``pieces`` end to end, with its
-    (offset, length, sha256) chunk list."""
-    import gzip
+def _chunk_list(pieces: list[bytes]) -> list[tuple[int, int, str]]:
+    """The (offset, length, sha256) list of ``pieces`` end to end."""
     import hashlib
-    path = tmp_path / name
-    path.write_bytes(gzip.compress(b"".join(pieces), mtime=0))
     chunks, pos = [], 0
     for piece in pieces:
         chunks.append((pos, len(piece), hashlib.sha256(piece).hexdigest()))
         pos += len(piece)
-    return str(path), chunks
+    return chunks
+
+
+def _layer(tmp_path, pieces: list[bytes], name="layer.gz"):
+    """A gzip blob whose stream is ``pieces`` end to end, with its
+    chunk list."""
+    import gzip
+    path = tmp_path / name
+    path.write_bytes(gzip.compress(b"".join(pieces), mtime=0))
+    return str(path), _chunk_list(pieces)
 
 
 def _pieces(n: int, seed: int = 0) -> list[bytes]:
@@ -1102,3 +1107,232 @@ def test_index_layer_store_equals_the_one_file_a_chunk_layout(
         golden[os.path.relpath(cas_entry_path(store.cas.root, h),
                                store.cas.root)] = (0o600, piece)
     assert store_tree(store.cas.root) == golden
+
+
+# -- index_layer's one block pass (PR 31) ------------------------------------
+
+def _blob(tmp_path, backend: str, payload: bytes, name="layer.gz") -> str:
+    """``payload`` as the commit path's gzip writers write it."""
+    from makisu_tpu import tario
+    path = tmp_path / name
+    with open(path, "wb") as f:
+        w = tario.gzip_writer(f, backend_id=tario.make_backend_id(
+            backend, "default"))
+        w.write(payload)
+        w.close()
+    return str(path)
+
+
+def _parents_loop(chunks, memo, stored):
+    """What the parent's chunk-at-a-time loop gives for a chunk list, a
+    memo and the digests the store holds: (added, prefetch tally,
+    ingest tally, stats made)."""
+    import collections
+    prefetch, handed, added, stats = collections.Counter(), [], [], 0
+    for _, _, h in chunks:
+        known = memo.get(h)
+        if known:
+            prefetch["hit"] += 1
+        elif h in handed:
+            prefetch["raced"] += 1
+        else:
+            handed.append(h)
+            prefetch["probe" if known is None else "miss"] += 1
+            stats += known is None
+            if not (known is None and h in stored):
+                added.append(h)
+    ingest = {"written": len(added),
+              "present": len(handed) - len(added) + prefetch["hit"],
+              "raced": prefetch.pop("raced", 0)}
+    return (added, {k: float(v) for k, v in prefetch.items() if v},
+            {k: float(v) for k, v in ingest.items() if v}, stats)
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Blocks smaller than the largest chunk, so spans straddle two and
+    three of them and whole blocks are stepped over."""
+    from makisu_tpu.cache import chunks as chunks_mod
+    monkeypatch.setattr(chunks_mod._Inflated, "READ", 9_000)
+    monkeypatch.setattr(chunks_mod._Inflated, "BLOCK", 30_000)
+
+
+_MEMOS = {
+    # name -> (share of the layer's chunks the store holds beforehand,
+    #          what the memo says of chunk i of n, given it is stored)
+    "all_true": (1.0, lambda i, held: True),
+    "all_false": (0.0, lambda i, held: False),
+    "empty": (0.5, lambda i, held: None),
+    "mixed": (0.5, lambda i, held: (held, None, held)[i % 3]),
+    "repeated": (0.3, lambda i, held: (None, held)[i % 2]),
+}
+
+
+@pytest.mark.parametrize("backend", ["zlib", "pgzip"])
+@pytest.mark.parametrize("memo_kind", sorted(_MEMOS))
+def test_index_layer_one_pass_equals_the_parents_loop(
+        tmp_path, fs_calls, store_tree, small_blocks, memo_kind, backend):
+    import hashlib
+    from makisu_tpu.utils import metrics
+    pieces = _pieces(48, seed=11)
+    if memo_kind == "repeated":
+        pieces = pieces[:30] + pieces[5:12] + pieces[30:] + pieces[:3]
+    held_share, says = _MEMOS[memo_kind]
+    chunks = _chunk_list(pieces)
+    path = _blob(tmp_path, backend, b"".join(pieces))
+    store = ChunkStore(str(tmp_path / "chunks"))
+    distinct = list(dict.fromkeys(h for _, _, h in chunks))
+    stored = set(distinct[:int(len(distinct) * held_share)])
+    by_digest = {hashlib.sha256(p).hexdigest(): p for p in pieces}
+    for h in stored:
+        store.cas.put(h, by_digest[h])
+    memo = {h: says(i, h in stored) for i, h in enumerate(distinct)}
+    memo = {h: said for h, said in memo.items() if said is not None}
+    with store._memo_lock:
+        store._exists_memo.update(memo)
+    want_added, want_prefetch, want_ingest, want_stats = _parents_loop(
+        chunks, memo, stored)
+
+    rec = fs_calls(store.cas)
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
+    stats = {}
+    try:
+        added = store.index_layer(path, chunks, stats)
+    finally:
+        metrics.reset_build_registry(token)
+    assert added == want_added
+    assert registry.counter_by_label(
+        "makisu_chunk_exists_prefetch_total", "result") == want_prefetch
+    assert registry.counter_by_label(
+        metrics.CHUNK_INGEST, "result") == want_ingest
+    assert registry.counter_by_label(
+        "makisu_chunks_indexed_total", "result") == {}
+    assert rec.calls["isfile"] == want_stats
+    assert rec.calls["rename"] == len(want_added)
+    if memo_kind == "all_true":
+        assert stats["ingest_window"] == 0 and rec.total() == 0
+    else:
+        assert stats["ingest_window"] >= 1
+    golden = {os.path.relpath(cas_entry_path(store.cas.root, h),
+                              store.cas.root): (0o600, by_digest[h])
+              for h in stored | set(want_added)}
+    tree = store_tree(store.cas.root)
+    tree.pop("_tmp/", None)                    # empty, once a chunk is put
+    assert tree == golden
+
+
+def _spoil(path: str, how: str) -> None:
+    with open(path, "rb") as f:
+        blob = bytearray(f.read())
+    if how == "truncated":
+        del blob[-20:]              # the trailer and the stream's end
+    elif how == "crc":
+        blob[-8] ^= 0xFF            # CRC32's first byte
+    elif how == "isize":
+        blob[-1] ^= 0x01            # ISIZE's last byte
+    elif how == "midstream":
+        blob[len(blob) // 2] ^= 0x55
+    elif how == "garbage_after":
+        blob += b"not a gzip member"
+    with open(path, "wb") as f:
+        f.write(blob)
+
+
+@pytest.mark.parametrize("backend", ["zlib", "pgzip"])
+@pytest.mark.parametrize("memo_all_true", [True, False])
+@pytest.mark.parametrize("how", ["truncated", "crc", "isize", "midstream",
+                                 "garbage_after", "list_past_end"])
+def test_index_layer_raises_on_a_blob_that_is_not_whole(
+        tmp_path, small_blocks, how, memo_all_true, backend):
+    """Every byte is inflated and the trailer verified whether or not
+    anybody wanted the bytes: with every chunk found stored (no slice
+    taken) as with none."""
+    import zlib
+    pieces = _pieces(24, seed=12)
+    chunks = _chunk_list(pieces)
+    path = _blob(tmp_path, backend, b"".join(pieces))
+    store = ChunkStore(str(tmp_path / "chunks"))
+    store.index_layer(path, chunks)            # the whole blob is fine
+    if how == "list_past_end":
+        end = chunks[-1][0] + chunks[-1][1]
+        chunks.append((end, 4_000, chunks[0][2]))
+    else:
+        _spoil(path, how)
+    if memo_all_true:
+        with store._memo_lock:
+            store._exists_memo.update({h: True for _, _, h in chunks})
+    with pytest.raises((ValueError, EOFError, zlib.error, OSError)):
+        store.index_layer(path, chunks)
+
+
+@pytest.mark.parametrize("tail", ["zeros", "member"])
+def test_inflated_reads_what_follows_a_member_as_gzipfile_does(
+        tmp_path, small_blocks, tail):
+    import gzip
+    import io
+    from makisu_tpu.cache.chunks import _Inflated
+    first, second = (b"".join(_pieces(20, seed=s)) for s in (13, 14))
+    blob = gzip.compress(first, mtime=0) + {
+        "zeros": b"\0" * 20_000,
+        "member": b"\0" * 512 + gzip.compress(second, mtime=0)}[tail]
+    want = gzip.GzipFile(fileobj=io.BytesIO(blob)).read()
+    assert want == first + (second if tail == "member" else b"")
+    stream = _Inflated(io.BytesIO(blob))
+    assert stream.take(1_000, 35_000) == want[1_000:36_000]
+    assert stream.take(len(want) - 10, 10) == want[-10:]
+    assert stream.finish() == len(want)
+    with pytest.raises(ValueError):
+        _Inflated(io.BytesIO(blob)).take(len(want) - 5, 10)
+
+
+def test_note_fingerprint_from_many_threads_claims_each_digest_once(
+        tmp_path):
+    """The device routes notify from the hash service's threads as well
+    as from pool workers: 32 notifiers, every digest noted by two of
+    them, a switch interval that interleaves them. Each digest is
+    claimed once and answered rightly; only a tail under PROBE_BATCH
+    stays unanswered."""
+    import hashlib
+    import sys
+    import threading
+    import time
+    from makisu_tpu.utils import concurrency
+    store = ChunkStore(str(tmp_path / "chunks"))
+    store.PROBE_BATCH = 16
+    digests = [hashlib.sha256(b"%d" % i).hexdigest() for i in range(3000)]
+    for i, h in enumerate(digests[::3]):
+        store.cas.put(h, b"%d" % (3 * i))
+    submitted = []
+    pool = concurrency.hash_pool()
+    real_submit = pool.submit
+
+    def counting_submit(fn, *a, **k):
+        submitted.append(fn)
+        return real_submit(fn, *a, **k)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    pool.submit = counting_submit
+    try:
+        threads = [threading.Thread(
+            target=lambda k=k: [store.note_fingerprint(h)
+                                for h in digests[k % 16::16]])
+            for k in range(32)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        del pool.submit
+        sys.setswitchinterval(interval)
+    assert len(submitted) == len(digests) // 16      # each claimed once
+    deadline = time.monotonic() + 30
+    while (sum(store._probed(h) is None for h in digests) >= 16
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+    answers = [store._probed(h) for h in digests]
+    assert sum(a is None for a in answers) == len(digests) % 16
+    assert all(a is None or a == (i % 3 == 0)
+               for i, a in enumerate(answers))

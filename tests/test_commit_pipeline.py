@@ -488,3 +488,160 @@ def test_pooled_route_respects_serial_floor(monkeypatch):
     assert concurrency.default_hash_workers() == 1
     monkeypatch.setattr(os, "cpu_count", lambda: 16)
     assert concurrency.default_hash_workers() == 8
+
+
+# -- the streamed probe on the device routes (PR 31) -------------------------
+
+@pytest.fixture
+def device_formulation(monkeypatch):
+    """The device routes on the JAX CPU backend (the shapes of
+    test_spans_plane.py, so the compiled programs are shared)."""
+    monkeypatch.setenv("MAKISU_TPU_CHUNK_NATIVE", "0")
+    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
+    monkeypatch.delenv("MAKISU_TPU_PALLAS_V2", raising=False)
+
+
+def _observed_session(seed, service=None):
+    """A session over 300,000 random bytes whose observer was bound in
+    the context only while it was constructed: (session, what the
+    observer saw)."""
+    from makisu_tpu.chunker import cdc
+    seen = []
+    token = cdc.set_chunk_observer(seen.append)
+    try:
+        session = ChunkSession(block=128 * 1024, service=service)
+    finally:
+        cdc.reset_chunk_observer(token)
+    session.update(np.random.default_rng(seed).integers(
+        0, 256, size=300_000, dtype=np.uint8).tobytes())
+    return session, seen
+
+
+def _wait_for(n, seen):
+    """A shared-service future wakes its waiter before it runs its
+    callbacks: the last digests can land just after finish()."""
+    import time
+    deadline = time.monotonic() + 10
+    while len(seen) < n and time.monotonic() < deadline:
+        time.sleep(0.01)
+
+
+def test_lane_batcher_route_streams_every_digest_once(device_formulation):
+    session, seen = _observed_session(31)
+    assert session._batchers and not session._native
+    chunks = session.finish()
+    assert len(chunks) > 20
+    assert sorted(seen) == sorted(c.hex for c in chunks)
+    assert len(set(seen)) == len(seen)
+
+
+def test_lane_batcher_route_without_an_observer_notifies_nobody(
+        device_formulation):
+    from makisu_tpu.chunker import cdc
+    token = cdc.set_chunk_observer(None)  # whatever an earlier test left
+    try:
+        session = ChunkSession(block=128 * 1024)
+    finally:
+        cdc.reset_chunk_observer(token)
+    assert session._batchers
+    assert all(b.notify is None for b in session._batchers)
+
+
+def test_service_route_streams_each_sessions_digests_to_its_own_observer(
+        device_formulation):
+    import threading
+    from makisu_tpu.chunker.service import HashService
+    service = HashService(linger_seconds=0.02)
+    try:
+        sessions = [_observed_session(seed, service) for seed in (32, 33)]
+        results = [None, None]
+
+        def finish(i):
+            results[i] = sessions[i][0].finish()
+
+        threads = [threading.Thread(target=finish, args=(i,))
+                   for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        for (session, seen), chunks in zip(sessions, results):
+            assert session.service is service and not session._batchers
+            assert len(chunks) > 20
+            _wait_for(len(chunks), seen)
+            assert sorted(seen) == sorted(c.hex for c in chunks)
+        assert not set(sessions[0][1]) & set(sessions[1][1])
+    finally:
+        service.close()
+
+
+def test_service_route_failure_reaches_finish_not_the_observer(
+        device_formulation, monkeypatch):
+    from makisu_tpu.chunker import route as route_mod
+    from makisu_tpu.chunker.service import HashService
+
+    def boom(*a, **k):
+        raise RuntimeError("lane program refused (simulated)")
+
+    monkeypatch.setattr(route_mod, "hash_lanes", boom)
+    monkeypatch.delenv("MAKISU_TPU_CHUNK_STRICT", raising=False)
+    service = HashService(linger_seconds=0.02)
+    try:
+        session, seen = _observed_session(34, service)
+        with pytest.raises(RuntimeError, match="refused"):
+            session.finish()
+        assert seen == []
+    finally:
+        service.close()
+
+
+def test_streamed_digests_fill_the_stores_memo_on_a_device_route(
+        tmp_path, device_formulation):
+    """End to end for the store: a second session over the same bytes
+    on the shared-service route leaves the memo saying True for every
+    chunk the first stored, and index_layer then stats nothing."""
+    import gzip
+    from makisu_tpu.cache.chunks import ChunkStore
+    from makisu_tpu.chunker import cdc
+    from makisu_tpu.chunker.service import HashService
+    payload = np.random.default_rng(35).integers(
+        0, 256, size=300_000, dtype=np.uint8).tobytes()
+    blob = tmp_path / "layer.gz"
+    blob.write_bytes(gzip.compress(payload, mtime=0))
+    store = ChunkStore(str(tmp_path / "chunks"))
+    store.PROBE_BATCH = 8
+    service = HashService(linger_seconds=0.02)
+    try:
+        for expect_added in (True, False):
+            token = cdc.set_chunk_observer(store.note_fingerprint)
+            try:
+                session = ChunkSession(block=128 * 1024, service=service)
+            finally:
+                cdc.reset_chunk_observer(token)
+            session.update(payload)
+            triples = [(c.offset, c.length, c.hex) for c in session.finish()]
+            concurrency.hash_pool().submit(lambda: None).result()
+            import time
+            deadline = time.monotonic() + 10
+            full = len(triples) - len(triples) % store.PROBE_BATCH
+            while (sum(store._probed(h) is not None for _, _, h in triples)
+                   < full and time.monotonic() < deadline):
+                time.sleep(0.01)
+            registry = metrics.MetricsRegistry()
+            token = metrics.set_build_registry(registry)
+            try:
+                added = store.index_layer(str(blob), triples)
+            finally:
+                metrics.reset_build_registry(token)
+                store.reset_fingerprint_memo()
+            by = registry.counter_by_label(
+                "makisu_chunk_exists_prefetch_total", "result")
+            if expect_added:
+                assert added == [h for _, _, h in triples]
+                assert by.get("miss", 0) >= full and "hit" not in by
+            else:
+                assert added == []
+                assert by.get("hit", 0) >= full and "miss" not in by
+            assert by.get("probe", 0) <= len(triples) - full
+    finally:
+        service.close()

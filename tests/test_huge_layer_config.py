@@ -169,8 +169,13 @@ def _new_metrics_list_their_cells():
     assert cells_of["feed_host_s_per_build"] == four
     assert cells_of["process_rss_peak_mb"] == [
         CELL, "monorepo-cold", "small-files-edit"]
-    assert [m["name"] for m in BENCHMARK["per_layer"][-4:]] \
-        == list(NEW_READERS)
+    # Put at the end of the list at their PR, together and in order;
+    # later PRs append after them.
+    names = [m["name"] for m in BENCHMARK["per_layer"]]
+    first = names.index(NEW_READERS[0])
+    assert names[first:first + 4] == list(NEW_READERS)
+    assert cells_of["chunk_probe_hit_pct"] == [
+        CELL, "monorepo-edit", "monorepo-cold", "small-files-edit"]
 
 
 @pytest.mark.parametrize("statement", [
@@ -357,13 +362,19 @@ def _record(tmp_path, with_program_side):
                  counted([("commit_layer", 99.0)], ok=False)]
     r.builds = list(r.counted)
     hashed, busy = "makisu_bytes_hashed_total", metrics.COMMIT_STAGE_BUSY
+    probed = "makisu_chunk_exists_prefetch_total"
     r.counters_open = dict([
+        _series(probed, 100.0, result="hit"),
+        _series(probed, 50.0, result="probe"),
         _series(hashed, 100e6, backend="native", path="layer_sink"),
         _series(hashed, 100e6, backend="pallas", path="service"),
         _series(busy, 1.0, stage="compress"),
         _series(busy, 2.0, stage="host_cut"),
         _series(PEAK, 900e6)])
     r.counters_close = dict([
+        _series(probed, 1000.0, result="hit"),
+        _series(probed, 90.0, result="miss"),
+        _series(probed, 60.0, result="probe"),
         _series(hashed, 900e6, backend="native", path="layer_sink"),
         _series(hashed, 16e6, backend="python", path="layer_sink"),
         _series(hashed, 700e6, backend="pallas", path="service"),
@@ -391,6 +402,10 @@ def _record(tmp_path, with_program_side):
     ("compress_s_per_build", 12.0 / 3),
     ("feed_host_s_per_build", (3.0 + 1.5 + 0.75) / 3),
     ("process_rss_peak_mb", 1234.0),
+    # PR 31: of the chunks index_layer looked up in the window (900 found
+    # by the streamed probe, 90 looked for and absent, 10 never looked
+    # for), the share found.
+    ("chunk_probe_hit_pct", 90.0),
 ])
 def test_new_reader_reads_a_run_and_nothing_from_an_older_program(
         tmp_path, metric, want):
