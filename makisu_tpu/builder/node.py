@@ -129,7 +129,8 @@ class BuildNode:
         # through the cache manager when it can supply one — with chunk
         # dedup attached, a lazily-pulled layer streams straight from
         # local chunks (no blob transfer, no gzip inflate at all).
-        with metrics.span("apply_layer", digest=hex_digest[:12]), \
+        with metrics.span("apply_layer", digest=hex_digest[:12],
+                          untar=modify_fs), \
                 metrics.span("apply_layer.inflate"):
             open_tar = getattr(cache_mgr, "open_layer_tar", None)
             if open_tar is not None:

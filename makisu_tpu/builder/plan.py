@@ -120,9 +120,12 @@ class BuildPlan:
                 stage.last_image_config = None
                 stage.build(self.cache_mgr, last_stage, copied_from)
                 if self.allow_modify_fs:
-                    stage.checkpoint(
-                        self.copy_from_dirs.get(stage.alias, []))
-                    stage.cleanup()
+                    sources = self.copy_from_dirs.get(stage.alias, [])
+                    with metrics.span("stage_checkpoint", alias=stage.alias,
+                                      sources=len(sources)):
+                        stage.checkpoint(sources)
+                    with metrics.span("stage_cleanup", alias=stage.alias):
+                        stage.cleanup()
             # ARG/ENV exports live in each stage context's exec_env
             # (reset per stage), so no process-env restore is needed
             # (reference restores os.environ, :197-204 — we never touch

@@ -74,15 +74,17 @@ class CopyOperation:
                 dir_owner=Owner(src_stat.st_uid, src_stat.st_gid, False))
         return fileio.Copier(blacklist)
 
-    def execute(self, eval_symlinks, root: str = "/") -> None:
+    def execute(self, eval_symlinks, root: str = "/") -> tuple[int, int]:
         """Perform the copy on disk (modifyfs builds). ``dst`` is logical;
         ``root`` maps it to the physical build root (identity in
         production where root is "/"). ``eval_symlinks`` is
-        snapshot.walk.eval_symlinks."""
+        snapshot.walk.eval_symlinks. Returns the regular files written
+        and their bytes."""
         dst = pathutils.join_root(root, self.dst)
         if is_dir_format(self.dst):
             dst += "/"
         synthesized: list[str] = []
+        files = nbytes = 0
         for src in self.srcs:
             src = eval_symlinks(src, self.src_root)
             src = pathutils.join_root(self.src_root, src)
@@ -96,6 +98,8 @@ class CopyOperation:
             else:
                 copier.copy_file(src, dst)
             synthesized.extend(copier.created_dirs)
+            files += copier.files_copied
+            nbytes += copier.bytes_copied
         # Synthesized ancestors (e.g. /app for COPY . /app/) get epoch
         # mtime AFTER all writes (each child creation bumped the dir),
         # matching the epoch-mtime headers MemFS synthesizes for the
@@ -107,3 +111,4 @@ class CopyOperation:
                 os.utime(d, (0, 0))
             except OSError:
                 pass
+        return files, nbytes

@@ -477,6 +477,7 @@ class MemFS:
         layer = Layer()
         hardlinks: list[tuple[str, tarfile.TarInfo]] = []
         parent_mtimes: dict[str, float] = {}
+        unpacked = 0
         if record is not None:
             self._record_ops = record
         try:
@@ -495,7 +496,7 @@ class MemFS:
                     hardlinks.append((disk_path, hdr))
                     continue
                 if untar:
-                    self._untar_one(disk_path, hdr, tf)
+                    unpacked += self._untar_one(disk_path, hdr, tf)
                 self._maybe_add(layer, disk_path,
                                 pathutils.abs_path(hdr.name),
                                 hdr, create_whiteouts=False)
@@ -509,6 +510,9 @@ class MemFS:
             self._record_ops = None
         for parent, mtime in parent_mtimes.items():
             os.utime(parent, (mtime, mtime))
+        if untar:
+            metrics.counter_add(metrics.ON_DISK_BYTES_TOTAL, unpacked,
+                                op="untar")
         if chain_key is not None:
             self.extend_chain(chain_key)
         else:
@@ -549,7 +553,9 @@ class MemFS:
         return mountinfo.is_mounted(disk_path)
 
     def _untar_one(self, path: str, hdr: tarfile.TarInfo,
-                   tf: tarfile.TarFile | None) -> None:
+                   tf: tarfile.TarFile | None) -> int:
+        """Materialize one member under the root; returns the bytes of
+        file content written."""
         base = os.path.basename(path)
         if base.startswith(WHITEOUT_PREFIX):
             victim = os.path.join(
@@ -559,16 +565,16 @@ class MemFS:
                     shutil.rmtree(victim, ignore_errors=True)
                 else:
                     os.remove(victim)
-            return
+            return 0
         if os.path.lexists(path):
             local = tarinfo_from_stat(path, hdr.name, self.root)
             if tario.is_similar_header(local, hdr):
-                return
+                return 0
             if hdr.isdir() and local.isdir():
                 # Never delete an existing dir (it may shelter mounts);
                 # just update its metadata.
                 tario.apply_header(path, hdr)
-                return
+                return 0
             if os.path.isdir(path) and not os.path.islink(path):
                 shutil.rmtree(path)
             else:
@@ -595,6 +601,8 @@ class MemFS:
                     if reader is not None:
                         shutil.copyfileobj(reader, out)
             tario.apply_header(path, hdr)
+            return hdr.size
+        return 0
 
     # ------------------------------------------------------------------
     # Cross-stage checkpoint / diff
@@ -607,6 +615,7 @@ class MemFS:
         self.flush()
         if not sources:
             return
+        copied = 0
         resolved: list[str] = []
         for src in sources:
             # Sources are logical stage paths; map them under the build
@@ -625,6 +634,9 @@ class MemFS:
                 copier.copy_dir(src, dst)
             else:
                 copier.copy_file(src, dst)
+            copied += copier.bytes_copied
+        metrics.counter_add(metrics.ON_DISK_BYTES_TOTAL, copied,
+                            op="checkpoint")
 
     def compare(self, other: "MemFS", ignore_mtime: bool = True) -> FSDiff:
         self.flush()

@@ -292,7 +292,11 @@ class AddCopyStep(BuildStep):
                     internal=True, preserve_owner=self.preserve_owner)
             ctx.copy_ops.append(op)
             if modify_fs:
-                op.execute(eval_symlinks, ctx.root_dir)
+                with metrics.span("copy_on_disk") as sp:
+                    files, nbytes = op.execute(eval_symlinks, ctx.root_dir)
+                    sp.set(files=files, bytes=nbytes)
+                metrics.counter_add(metrics.ON_DISK_BYTES_TOTAL, nbytes,
+                                    op="copy")
 
 
 class AddStep(AddCopyStep):

@@ -105,6 +105,10 @@ class Copier:
         # synthesizes for the same paths — otherwise the next scan diff
         # re-emits every such dir with the wall clock in it.
         self.created_dirs: list[str] = []
+        # Regular files this copier wrote and their bytes: what the
+        # callers' spans and makisu_on_disk_bytes_total report.
+        self.files_copied = 0
+        self.bytes_copied = 0
 
     def _blacklisted(self, p: str) -> bool:
         return pathutils.is_descendant_of_any(p, self.blacklist)
@@ -180,6 +184,8 @@ class Copier:
             os.chmod(dst, 0o777)
         with open(src, "rb") as r, open(dst, "wb") as w:
             shutil.copyfileobj(r, w)
+        self.files_copied += 1
+        self.bytes_copied += st.st_size
         uid, gid = st.st_uid, st.st_gid
         if self.file_owner and self.file_owner.overwrite:
             uid, gid = self.file_owner.uid, self.file_owner.gid

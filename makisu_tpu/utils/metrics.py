@@ -151,6 +151,12 @@ PROCESS_IO_WRITE_BYTES = "makisu_process_io_write_bytes"
 # Build-plan execution (builder/plan.py, builder/node.py).
 STAGES_TOTAL = "makisu_stages_total"
 CACHED_LAYERS_APPLIED_TOTAL = "makisu_cached_layers_applied_total"
+# Bytes of regular files a --modifyfs build wrote for its stages' trees,
+# op=copy (a COPY executed under the root, steps/add_copy.py) | untar (a
+# cached layer unpacked under the root) | checkpoint (what later stages
+# COPY --from, copied into the sandbox; both snapshot/memfs.py). One add
+# an operation, never one a file.
+ON_DISK_BYTES_TOTAL = "makisu_on_disk_bytes_total"
 
 # Resident build sessions (worker/session.py): reuse hits, dirty-set
 # invalidations by reason, and resident memo bytes per context.

@@ -163,7 +163,9 @@ def _cell_reports_its_metrics():
 
 def _new_metrics_list_their_cells():
     cells_of = {m["name"]: m["workloads"] for m in BENCHMARK["per_layer"]}
-    four = [CELL, "monorepo-cold", "monorepo-edit", "small-files-edit"]
+    # PR 32 appended its cell to the three it reports.
+    four = [CELL, "monorepo-cold", "monorepo-edit", "small-files-edit",
+            "multi-stage-small-edit"]
     assert cells_of["commit_mb_per_s"] == four
     assert cells_of["compress_s_per_build"] == four
     assert cells_of["feed_host_s_per_build"] == four
@@ -175,7 +177,8 @@ def _new_metrics_list_their_cells():
     first = names.index(NEW_READERS[0])
     assert names[first:first + 4] == list(NEW_READERS)
     assert cells_of["chunk_probe_hit_pct"] == [
-        CELL, "monorepo-edit", "monorepo-cold", "small-files-edit"]
+        CELL, "monorepo-edit", "monorepo-cold", "small-files-edit",
+        "multi-stage-small-edit"]
 
 
 @pytest.mark.parametrize("statement", [

@@ -204,19 +204,20 @@ def test_every_new_metric_has_its_reader_and_its_cells():
         benchmark = json.load(f)
     cells_of = {m["name"]: m["workloads"] for m in benchmark["per_layer"]}
     every = ["monorepo-cold", "farm-churn", "monorepo-edit",
-             "farm-unchanged", "small-files-edit", "huge-layer-edit"]
+             "farm-unchanged", "small-files-edit", "huge-layer-edit",
+             "multi-stage-small-edit"]
     assert cells_of["idle_unspanned_pct"] == every
     assert cells_of["sync_os_sync_s_per_build"] == every
     assert cells_of["chunk_index_s_per_build"] == [
         "monorepo-cold", "monorepo-edit", "small-files-edit",
-        "huge-layer-edit"]
+        "huge-layer-edit", "multi-stage-small-edit"]
     for name in cells_of:
         assert os.path.exists(os.path.join(READERS, name + ".py")), name
 
 
 @pytest.mark.parametrize("cell", [
     "monorepo-cold", "farm-churn", "monorepo-edit", "farm-unchanged",
-    "small-files-edit", "huge-layer-edit"])
+    "small-files-edit", "huge-layer-edit", "multi-stage-small-edit"])
 def test_every_cell_finds_its_files_and_a_reader_for_each_metric(cell):
     """What ``run.py`` looks up by name for a cell: configuration, mix,
     reference, and a reader for every metric either kind of run
