@@ -270,8 +270,16 @@ class _NativeTarWriter:
         return self
 
     def __exit__(self, exc_type, *exc) -> None:
-        if exc_type is None:
-            self.close()
+        # A commit that dies in here never reaches finish(): the sink's
+        # compressor thread stops before ``out`` closes under it.
+        done = False
+        try:
+            if exc_type is None:
+                self.close()
+                done = True
+        finally:
+            if not done:
+                self._sink.abort()
 
 
 class NativeLayerSink:
@@ -310,15 +318,29 @@ class NativeLayerSink:
         events.note_progress()  # hashing is progress (see LayerSink)
         return len(data)
 
-    def finish(self) -> LayerCommit:
-        tar_hex, gz_hex, gz_size, _ = self._handle.finish()
-        # The stage the Python sink's compressor thread reports: here
-        # deflate runs inside the producer's write (zlib) or on the
-        # C++ block pool (pgzip), and the library keeps the seconds.
-        busy = self._handle.compress_seconds()
+    def abort(self) -> None:
+        """A commit that died between two entries: stop and join the
+        library's compressor thread now, while ``out`` is still open
+        (``__del__`` would, but only once the traceback lets go)."""
         self._handle.close()
+
+    def finish(self) -> LayerCommit:
+        try:
+            tar_hex, gz_hex, gz_size, _ = self._handle.finish()
+            # The stage the Python sink's compressor thread reports:
+            # here deflate runs on the library's own thread (zlib) or
+            # block pool (pgzip), and the library keeps the seconds;
+            # compress_wait is what this thread spent blocked on that
+            # stream (a full ring, the drain just now): near 0 where
+            # the producer is the brake.
+            busy = self._handle.compress_seconds()
+            waited = self._handle.wait_seconds()
+        finally:
+            self._handle.close()
         if busy is not None:
             metrics.stage_busy_add(metrics.COMPRESS_STAGE, busy)
+        if waited is not None:
+            metrics.stage_busy_add("compress_wait", waited)
         metrics.counter_add("makisu_bytes_hashed_total", self._nbytes,
                             backend="native", path="layer_sink")
         backend = self.backend_id.split("-", 1)[0]

@@ -200,14 +200,15 @@ def _load_lsk() -> ctypes.CDLL | None:
         except (OSError, AttributeError):
             _lsk_failed = True
             return _lsk_lib
-        try:
-            # Newer symbol, bound separately: a prebuilt library from
-            # before it still commits layers, and reports no compress
-            # seconds.
-            lib.lsk_compress_seconds.restype = ctypes.c_double
-            lib.lsk_compress_seconds.argtypes = [ctypes.c_void_p]
-        except AttributeError:
-            pass
+        # Newer symbols, each bound separately: a prebuilt library from
+        # before one still commits layers, and reports no such seconds.
+        for name in ("lsk_compress_seconds", "lsk_wait_seconds"):
+            try:
+                fn = getattr(lib, name)
+            except AttributeError:
+                continue
+            fn.restype = ctypes.c_double
+            fn.argtypes = [ctypes.c_void_p]
         return _lsk_lib
 
 
@@ -594,10 +595,21 @@ class LayerSinkHandle:
     def compress_seconds(self) -> float | None:
         """Seconds the gzip stream kept a thread busy (summed over the
         pgzip lanes); ``None`` from a library that predates the count."""
-        fn = getattr(self._lib, "lsk_compress_seconds", None)
+        return self._seconds("lsk_compress_seconds")
+
+    def wait_seconds(self) -> float | None:
+        """Seconds the writer was blocked on the zlib backend's
+        compressor thread (its ring full, or draining in ``finish``);
+        ``None`` from a library that predates the thread."""
+        return self._seconds("lsk_wait_seconds")
+
+    def _seconds(self, symbol: str) -> float | None:
+        fn = getattr(self._lib, symbol, None)
         return float(fn(self._live())) if fn is not None else None
 
     def close(self) -> None:
+        """Frees the sink; one that was never finished (its build died
+        between two entries) first stops and joins its compressor."""
         if self._handle:
             self._lib.lsk_free(self._handle)
             self._handle = None

@@ -179,6 +179,12 @@ def _new_metrics_list_their_cells():
     assert cells_of["chunk_probe_hit_pct"] == [
         CELL, "monorepo-edit", "monorepo-cold", "small-files-edit",
         "multi-stage-small-edit"]
+    # PR 33: what the builder waits for the sink's compressor thread,
+    # read where ``compress_s_per_build`` is, appended last.
+    assert cells_of["compress_wait_s_per_build"] == four
+    wait = BENCHMARK["per_layer"][names.index("compress_wait_s_per_build")]
+    beside = BENCHMARK["per_layer"][names.index("compress_s_per_build")]
+    assert {**wait, "name": beside["name"]} == beside
 
 
 @pytest.mark.parametrize("statement", [
@@ -337,11 +343,15 @@ def test_cpu_hasher_gives_the_same_layer_and_blob_digests(built):
 
 def test_native_sink_reports_its_compress_seconds(built):
     """The sink a worker's ``--hasher tpu`` build commits through
-    deflates inside the producer's write; the ``compress`` stage is
-    what it spent there."""
+    deflates on a thread of its own (PR 33): the ``compress`` stage is
+    what that thread spent, beside ``tar_write`` and no longer a part
+    of it, and ``compress_wait`` what the builder was blocked on it
+    (the drain in ``finish`` at least)."""
     busy = {s["labels"]["stage"]: s["value"] for s in
             built["cold_report"]["counters"][metrics.COMMIT_STAGE_BUSY]}
-    assert 0 < busy["compress"] <= busy["tar_write"]
+    assert 0 < busy["compress"]
+    assert 0 < busy["compress_wait"]
+    assert 0 < busy["tar_write"]
 
 
 # -- (d) the readers, on a run record made by hand -------------------------
@@ -372,6 +382,7 @@ def _record(tmp_path, with_program_side):
         _series(hashed, 100e6, backend="native", path="layer_sink"),
         _series(hashed, 100e6, backend="pallas", path="service"),
         _series(busy, 1.0, stage="compress"),
+        _series(busy, 0.5, stage="compress_wait"),
         _series(busy, 2.0, stage="host_cut"),
         _series(PEAK, 900e6)])
     r.counters_close = dict([
@@ -382,6 +393,7 @@ def _record(tmp_path, with_program_side):
         _series(hashed, 16e6, backend="python", path="layer_sink"),
         _series(hashed, 700e6, backend="pallas", path="service"),
         _series(busy, 13.0, stage="compress"),
+        _series(busy, 6.5, stage="compress_wait"),
         _series(busy, 5.0, stage="host_cut"),
         _series(busy, 1.5, stage="gear_dispatch"),
         _series(busy, 0.75, stage="sha_dispatch"),
@@ -403,6 +415,10 @@ def _record(tmp_path, with_program_side):
 @pytest.mark.parametrize("metric,want", [
     ("commit_mb_per_s", (800 + 16) / (8.0 + 8.0)),
     ("compress_s_per_build", 12.0 / 3),
+    # PR 33: the builder's wait for the sink's compressor thread, a
+    # series of its own beside ``compress`` (no part of it, nor of the
+    # feed's or the device's stages).
+    ("compress_wait_s_per_build", 6.0 / 3),
     ("feed_host_s_per_build", (3.0 + 1.5 + 0.75) / 3),
     ("process_rss_peak_mb", 1234.0),
     # PR 31: of the chunks index_layer looked up in the window (900 found

@@ -2,10 +2,16 @@
 Python pipeline is cache-identity-bearing — layer digests must not
 depend on which sink produced them."""
 
+import functools
+import gc
 import hashlib
 import io
+import json
 import os
+import random
 import tarfile
+import threading
+import zlib
 
 import pytest
 
@@ -70,16 +76,134 @@ def _commit(sink_cls, root, path, backend_id):
         return sink.finish()
 
 
-@pytest.mark.parametrize("backend_id", ["zlib-6", "zlib-1", "zlib-9",
-                                        "pgzip-6-131072"])
-def test_native_matches_python_bytes_and_digests(tmp_path, backend_id):
+# The zlib backend's ring (native/layersink.cpp: kSlotBytes, kSlots).
+_SLOT = 256 * 1024
+_RING = 64 * _SLOT
+
+
+@functools.cache
+def _text_block():
+    """256 KiB of seeded words: longer than deflate's window, so a
+    repeat of it compresses like text again, each level its own way."""
+    rnd = random.Random(2)
+    vocab = [rnd.randbytes(rnd.randint(2, 9)).hex().encode()
+             for _ in range(500)]
+    return b" ".join(rnd.choices(vocab, k=40_000))[:256 * 1024]
+
+
+def _stream_bytes(n, seed=11):
+    """``n`` seeded bytes: a quarter incompressible, a quarter text,
+    the rest a repeated 8 KiB block (deflate runs through 50 MB of it
+    fast)."""
+    rnd = random.Random(seed)
+    head = rnd.randbytes(n // 4)
+    text = _text_block()
+    text = (text * (n // 4 // len(text) + 1))[:n // 4]
+    block = rnd.randbytes(8192)
+    tail = n - len(head) - len(text)
+    return head + text + (block * (tail // len(block) + 1))[:tail]
+
+
+def _feed(handle, tmp_path, plan):
+    """Write a plan of ``("write", bytes)`` and ``("file", bytes)``
+    steps into a native handle, a file through ``lsk_write_file``
+    (content, then padding to 512); yields the stream bytes sent after
+    each step."""
+    sent = 0
+    for i, (kind, data) in enumerate(plan):
+        if kind == "file":
+            src = tmp_path / f"src{i}"
+            src.write_bytes(data)
+            handle.write_file(str(src), len(data))
+        else:
+            handle.write(data)
+        sent += len(data) + (-len(data) % 512 if kind == "file" else 0)
+        yield sent
+
+
+def _tar_of(plan):
+    """The stream a plan stands for."""
+    return b"".join(
+        d + (b"\0" * (-len(d) % 512) if kind == "file" else b"")
+        for kind, d in plan)
+
+
+def _commit_raw(sink_cls, tmp_path, path, backend_id, plan):
+    """Commit a plan's stream: the native sink is fed step by step,
+    the Python one is written the same bytes."""
+    with open(path, "wb") as f:
+        sink = sink_cls(f, backend_id=backend_id)
+        if sink_cls is NativeLayerSink:
+            for _ in _feed(sink._handle, tmp_path, plan):
+                pass
+        else:
+            data = _tar_of(plan)
+            for off in range(0, len(data), 1 << 20):
+                sink.write(data[off:off + (1 << 20)])
+        return sink.finish()
+
+
+def _interleaved_plan():
+    """Headers of 512-1,536 bytes between files whose sizes put the
+    slot edges inside a header, inside a file and on a boundary; more
+    than the ring holds in all."""
+    rnd = random.Random(5)
+    plan = []
+    for size in (0, 1, 511, _SLOT - 512, _SLOT, 3 * _SLOT + 7,
+                 _RING + 12_345, 700_001):
+        plan.append(("write", rnd.randbytes(512 * rnd.randint(1, 3))))
+        plan.append(("file", _stream_bytes(size, seed=size)))
+    plan.append(("write", b"\0" * 1024))
+    return plan
+
+
+_STREAMS = {
+    "tree": None,
+    "0": [],
+    "1": [("write", b"x")],
+    "slot-1": [("write", _SLOT - 1)],
+    "slot": [("write", _SLOT)],
+    "slot+1": [("write", _SLOT + 1)],
+    "ring+slot": [("write", _RING + _SLOT)],
+    "3-rings": [("write", 3 * _RING + 12_345)],
+    "one-file-3-rings": [("file", 3 * _RING + 12_345)],
+    "headers-and-files": _interleaved_plan,
+}
+
+
+def _plan(stream):
+    plan = _STREAMS[stream]
+    if callable(plan):
+        return plan()
+    return [(kind, _stream_bytes(d) if isinstance(d, int) else d)
+            for kind, d in plan]
+
+
+@pytest.mark.parametrize(
+    "backend_id,stream",
+    [(f"zlib-{level}", "tree") for level in range(1, 10)]
+    + [("pgzip-6-131072", "tree")]
+    + [("zlib-6", stream) for stream in _STREAMS if stream != "tree"]
+    + [("zlib-1", "headers-and-files"), ("zlib-9", "ring+slot"),
+       ("pgzip-6-131072", "headers-and-files")])
+def test_native_matches_python_bytes_and_digests(tmp_path, backend_id,
+                                                 stream):
+    """Whatever the sink, the same blob: for every zlib level, and for
+    streams that end before, on and after the edges of the native
+    sink's ring (its compressor thread takes the stream slot by slot)."""
     if backend_id.startswith("pgzip") and not native.pgzip_available():
         pytest.skip("pgzip not built")
-    root = _tree(tmp_path)
     py_path = str(tmp_path / "py.tar.gz")
     nat_path = str(tmp_path / "native.tar.gz")
-    py = _commit(LayerSink, root, py_path, backend_id)
-    nat = _commit(NativeLayerSink, root, nat_path, backend_id)
+    if stream == "tree":
+        root = _tree(tmp_path)
+        py = _commit(LayerSink, root, py_path, backend_id)
+        nat = _commit(NativeLayerSink, root, nat_path, backend_id)
+    else:
+        plan = _plan(stream)
+        py = _commit_raw(LayerSink, tmp_path, py_path, backend_id, plan)
+        nat = _commit_raw(NativeLayerSink, tmp_path, nat_path, backend_id,
+                          plan)
     with open(py_path, "rb") as f:
         py_bytes = f.read()
     with open(nat_path, "rb") as f:
@@ -93,6 +217,11 @@ def test_native_matches_python_bytes_and_digests(tmp_path, backend_id):
     # Self-consistency: the reported digests describe the actual bytes.
     assert hashlib.sha256(nat_bytes).hexdigest() \
         == nat.digest_pair.gzip_descriptor.digest.hex()
+    if stream != "tree":
+        tar = _tar_of(plan)
+        assert zlib.decompress(nat_bytes, 31) == tar
+        assert hashlib.sha256(tar).hexdigest() \
+            == nat.digest_pair.tar_digest.hex()
 
 
 @pytest.mark.parametrize("sink_cls", [LayerSink, NativeLayerSink],
@@ -115,6 +244,15 @@ def test_either_sink_reports_its_compress_seconds(tmp_path, sink_cls,
         metrics.reset_build_registry(token)
     busy = registry.counter_by_label(metrics.COMMIT_STAGE_BUSY, "stage")
     assert 0 < busy["compress"] < 5
+    # What the writer spent blocked on the native sink's stream (the
+    # drain at least, under zlib; pgzip reports 0): beside ``compress``,
+    # never a part of it. The Python sink has no such series.
+    if sink_cls is NativeLayerSink:
+        assert 0 <= busy["compress_wait"] < 5
+        if backend_id.startswith("zlib"):
+            assert busy["compress_wait"] > 0
+    else:
+        assert "compress_wait" not in busy
 
 
 def test_native_archive_is_valid_tar(tmp_path):
@@ -219,6 +357,305 @@ def test_native_tap_errors_fail_the_build(tmp_path):
         sink._handle.set_tap(sink._session.update)
         with pytest.raises(RuntimeError, match="chunk tap failed"):
             sink.write(b"x" * 100)
+
+
+def _within(seconds, fn):
+    """``fn()`` on a thread of its own: what it returned or raised, or
+    a failure if it has not come back in ``seconds`` (a hang, which a
+    call on this thread would turn into a stuck test run)."""
+    box = []
+
+    def run():
+        try:
+            box.append((fn(), None))
+        except Exception as e:  # noqa: BLE001 - handed to the caller
+            box.append((None, e))
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), f"still blocked after {seconds} s"
+    return box[0]
+
+
+@pytest.mark.parametrize("backend", ["zlib", "pgzip"])
+def test_tap_sees_every_byte_once_in_order_on_the_callers_thread(
+        tmp_path, backend):
+    """The tap is the chunker's intake (a Python callback, clocked on
+    the building thread): it runs synchronously in the writer's calls,
+    never on the sink's compressor thread, also for bytes that
+    ``write_file`` read straight into the ring."""
+    if backend == "pgzip" and not native.pgzip_available():
+        pytest.skip("pgzip not built")
+    seen, idents = [], set()
+
+    def tap(data):
+        idents.add(threading.get_ident())
+        seen.append(data)
+
+    plan = _interleaved_plan()
+    with open(tmp_path / "out.gz", "wb") as f:
+        handle = native.LayerSinkHandle(f.fileno(), backend, 6)
+        handle.set_tap(tap)
+        for sent in _feed(handle, tmp_path, plan):
+            # Synchronous: a call's bytes have been seen when it returns.
+            assert sum(map(len, seen)) == sent
+        tar_hex, _, _, tar_size = handle.finish()
+        handle.close()
+    assert idents == {threading.get_ident()}
+    tar = b"".join(seen)
+    assert len(tar) == tar_size == sent > _RING
+    assert hashlib.sha256(tar).hexdigest() == tar_hex
+    assert tar == _tar_of(plan)
+
+
+def _broken_pipe_handle():
+    """A zlib sink whose output took the gzip header and fails every
+    write after it (EPIPE: CPython ignores SIGPIPE): the error happens
+    on the sink's compressor thread."""
+    r, w = os.pipe()
+    handle = native.LayerSinkHandle(w, "zlib", 6)
+    os.close(r)
+    return handle, w
+
+
+@pytest.mark.parametrize("where", ["header", "write", "write_file",
+                                   "finish"])
+def test_failing_output_fd_fails_the_sink_and_does_not_hang(tmp_path,
+                                                            where):
+    """An output that stops taking bytes fails the commit: ``lsk_new``
+    where not even the header goes out, else the first ``write`` /
+    ``write_file`` after the compressor thread met the error (not
+    blocked on a ring nobody empties any more), ``finish`` at the
+    latest; and the failed sink can be closed."""
+    if where == "header":
+        with open("/dev/full", "wb", buffering=0) as f:
+            with pytest.raises(RuntimeError, match="lsk_new failed"):
+                native.LayerSinkHandle(f.fileno(), "zlib", 6)
+        return
+    handle, w = _broken_pipe_handle()
+    try:
+        if where == "finish":
+            handle.write(b"x" * 1000)  # stays in its slot: no error yet
+            _, err = _within(30, handle.finish)
+            assert isinstance(err, RuntimeError)
+            assert "finish failed" in str(err)
+        else:
+            # Incompressible, three times what the ring holds: a writer
+            # that only waited for a free slot would wait for ever.
+            data = random.Random(3).randbytes(1 << 20)
+            src = tmp_path / "src"
+            src.write_bytes(data)
+
+            def feed():
+                for _ in range(3 * _RING // len(data)):
+                    if where == "write":
+                        handle.write(data)
+                    else:
+                        handle.write_file(str(src), len(data))
+            _, err = _within(60, feed)
+            assert isinstance(err, RuntimeError)
+            assert "write failed" in str(err)
+            # It stays failed: the next call of either kind, then finish.
+            with pytest.raises(RuntimeError, match="write failed"):
+                handle.write(b"y")
+            with pytest.raises(RuntimeError, match="write failed"):
+                handle.write_file(str(src), len(data))
+            _, err = _within(30, handle.finish)
+            assert isinstance(err, RuntimeError)
+        _within(30, handle.close)
+    finally:
+        os.close(w)
+
+
+@pytest.mark.parametrize("fed", [0, 1000, 3 * _SLOT + 5, _RING + _SLOT])
+def test_close_on_an_unfinished_sink_returns(tmp_path, fed):
+    """A build that dies in ``write_diffs`` never calls ``finish``:
+    ``close`` (``__del__`` calls it) stops and joins the compressor
+    thread whatever the ring still holds, and the fd stays the
+    caller's."""
+    with open(tmp_path / "out.gz", "wb") as f:
+        handle = native.LayerSinkHandle(f.fileno(), "zlib", 6)
+        data = _stream_bytes(fed)
+        for off in range(0, fed, 1 << 20):
+            handle.write(data[off:off + (1 << 20)])
+        _within(30, handle.close)
+        with pytest.raises(RuntimeError, match="already closed"):
+            handle.write(b"x")
+        handle.close()  # idempotent
+        f.write(b"still open")
+
+
+def _compressor_threads():
+    """Live compressor threads of zlib sinks, by the name they take."""
+    gc.collect()  # a sink some earlier test dropped in a cycle
+    live = 0
+    for task in os.listdir("/proc/self/task"):
+        try:
+            with open(f"/proc/self/task/{task}/comm") as f:
+                live += f.read().strip() == "lsk-zlib"
+        except OSError:  # a thread that ended meanwhile
+            pass
+    return live
+
+
+def _more_than_the_ring_of_text():
+    """Slots that deflate far slower than a writer fills them."""
+    return _text_block() * (_RING // _SLOT + 8)
+
+
+def _stays_empty(path, number):
+    """The next file opened takes the fd number ``out`` had; nothing a
+    sink still had queued for that number may land in it."""
+    with open(path, "wb") as other:
+        assert other.fileno() == number
+        threading.Event().wait(0.3)
+        assert os.fstat(other.fileno()).st_size == 0
+
+
+class _Died(Exception):
+    pass
+
+
+@pytest.mark.parametrize("dies_in", ["write_diffs", "tar_close", "finish"])
+def test_a_commit_that_dies_stops_the_sink_before_out_closes(tmp_path,
+                                                             dies_in):
+    """``commit_layer``'s order: ``with out:`` around ``with
+    sink.open_tar()`` and ``sink.finish()``. Whichever of them raises
+    (a shrunk file, the chunker's tap, the device drain), the sink's
+    compressor thread is joined and its handle freed before ``out``
+    closes, though the ring is still full of work."""
+
+    class Session:
+        dies_in = None
+
+        def update(self, data):
+            if self.dies_in == "tar_close":
+                raise _Died
+
+        def finish(self):
+            if self.dies_in == "finish":
+                raise _Died
+            return []
+
+    text = _more_than_the_ring_of_text()
+    threads = _compressor_threads()
+    session = Session()
+    with pytest.raises((_Died, RuntimeError)):
+        with open(tmp_path / "out.gz", "wb") as out:
+            number = out.fileno()
+            sink = NativeLayerSink(out, backend_id="zlib-9",
+                                   session=session)
+            with sink.open_tar() as tw:
+                assert _compressor_threads() == threads + 1
+                hdr = tarfile.TarInfo("text")
+                hdr.size = len(text)
+                tw.addfile(hdr, io.BytesIO(text))  # slots deflate slowly
+                if dies_in == "write_diffs":
+                    raise _Died
+                session.dies_in = dies_in
+            sink.finish()
+    assert sink._handle._handle is None
+    assert _compressor_threads() == threads
+    _stays_empty(tmp_path / "other", number)
+
+
+def test_the_sink_writes_to_a_fd_of_its_own(tmp_path):
+    """``lsk_new`` dups the caller's fd: a caller that closes its own
+    under a live sink (no Python caller does) loses nothing, and the
+    file that takes the number next gets nothing."""
+    text = _more_than_the_ring_of_text()
+    fd = os.open(tmp_path / "out.gz", os.O_WRONLY | os.O_CREAT, 0o644)
+    handle = native.LayerSinkHandle(fd, "zlib", 9)
+    handle.write(text)
+    os.close(fd)
+    _stays_empty(tmp_path / "other", fd)
+    handle.write(b"tail")
+    tar_hex, gz_hex, gz_size, tar_size = handle.finish()
+    handle.close()
+    blob = (tmp_path / "out.gz").read_bytes()
+    assert len(blob) == gz_size
+    assert hashlib.sha256(blob).hexdigest() == gz_hex
+    assert zlib.decompress(blob, 31) == text + b"tail"
+
+
+# What the parent commit (86bb072, zlib in line with the writer) gave
+# for the stream of ``_interleaved_plan`` (recorded by running
+# ``_golden_row`` on a checkout of it, PR 33): level -> (tar digest,
+# blob digest under zlib _GOLDEN_ZLIB, blob size), then the chunk list
+# the TPU hasher's tap cut from it (count, sha-256 of its JSON). The
+# compressor thread may change none of them.
+_GOLDEN_ZLIB = "1.2.13"
+_GOLDEN = {
+    1: ("d6cd5810637b32bfa9e7df35fec2b062"
+        "ad6c7ca280aa3898e6d1c835e84ea4b8",
+        "e6794471c2c25e1644b4e9ebbc696853"
+        "55bb53806867b8f9832c6cdd12ca253a", 5_960_146),
+    2: ("d6cd5810637b32bfa9e7df35fec2b062"
+        "ad6c7ca280aa3898e6d1c835e84ea4b8",
+        "88744b587b645742c63f65fb7e25a8b9"
+        "7d62637f07be227e76b24c8ff36c6bec", 5_984_673),
+    3: ("d6cd5810637b32bfa9e7df35fec2b062"
+        "ad6c7ca280aa3898e6d1c835e84ea4b8",
+        "bc0275e3611b658c95ca5c80e57e1905"
+        "fb4cfd72e6bbcab0f5eb6a23f1eac490", 5_981_434),
+    4: ("d6cd5810637b32bfa9e7df35fec2b062"
+        "ad6c7ca280aa3898e6d1c835e84ea4b8",
+        "eddf27f2f92cb2e224b2e3698a1db6dd"
+        "eebc6f3dfba06d7696c6f2c55ea0038e", 5_755_627),
+    5: ("d6cd5810637b32bfa9e7df35fec2b062"
+        "ad6c7ca280aa3898e6d1c835e84ea4b8",
+        "54f384db98200ee5f81b6e3acb24c28d"
+        "b1f5afcd313a509575e1871080597f50", 5_759_262),
+    6: ("d6cd5810637b32bfa9e7df35fec2b062"
+        "ad6c7ca280aa3898e6d1c835e84ea4b8",
+        "f0fabc4bff9ad49d14e84fcfc7b29986"
+        "85029e34ff6cfcf65010ba6f89a5579d", 5_759_303),
+    7: ("d6cd5810637b32bfa9e7df35fec2b062"
+        "ad6c7ca280aa3898e6d1c835e84ea4b8",
+        "f0fabc4bff9ad49d14e84fcfc7b29986"
+        "85029e34ff6cfcf65010ba6f89a5579d", 5_759_303),
+    8: ("d6cd5810637b32bfa9e7df35fec2b062"
+        "ad6c7ca280aa3898e6d1c835e84ea4b8",
+        "f0fabc4bff9ad49d14e84fcfc7b29986"
+        "85029e34ff6cfcf65010ba6f89a5579d", 5_759_303),
+    9: ("d6cd5810637b32bfa9e7df35fec2b062"
+        "ad6c7ca280aa3898e6d1c835e84ea4b8",
+        "ab6d67aa9d904ce07b8419a75940ab3c"
+        "577bb0b78dfa2e0f63c90dbb7fac0aa1", 5_759_303),
+}
+_GOLDEN_CHUNKS = (
+    1_865, "7d6c64c11e868693a33856701e5d16b6"
+    "2e030dc435a9e5e6cd7faddbc29c21b6")
+
+
+def _golden_row(tmp_path, level, hasher=None):
+    from makisu_tpu.chunker import CPUHasher
+    with open(tmp_path / f"golden{level}.gz", "wb") as f:
+        sink = (hasher or CPUHasher()).open_layer(
+            f, backend_id=f"zlib-{level}")
+        assert isinstance(sink, NativeLayerSink)
+        for _ in _feed(sink._handle, tmp_path, _interleaved_plan()):
+            pass
+        commit = sink.finish()
+    pair = commit.digest_pair
+    chunks = [[c.offset, c.length, c.hex_digest] for c in commit.chunks]
+    return ((pair.tar_digest.hex(), pair.gzip_descriptor.digest.hex(),
+             pair.gzip_descriptor.size),
+            (len(chunks),
+             hashlib.sha256(json.dumps(chunks).encode()).hexdigest()))
+
+
+@pytest.mark.parametrize("level", range(1, 10))
+def test_digests_sizes_and_chunks_are_the_parents(tmp_path, level):
+    from makisu_tpu.chunker import TPUHasher
+    (tar, blob, size), chunks = _golden_row(
+        tmp_path, level, TPUHasher() if level == 6 else None)
+    assert tar == _GOLDEN[level][0]
+    if level == 6:
+        assert chunks == _GOLDEN_CHUNKS
+    # The blob is zlib's; another zlib may deflate otherwise.
+    if zlib.ZLIB_RUNTIME_VERSION == _GOLDEN_ZLIB:
+        assert (blob, size) == _GOLDEN[level][1:]
 
 
 def test_zlib0_never_chooses_native(tmp_path):
