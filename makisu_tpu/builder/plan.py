@@ -111,7 +111,8 @@ class BuildPlan:
         for k, stage in enumerate(self.stages):
             curr = stage
             log.info("stage %d/%d: %s", k + 1, len(self.stages), stage)
-            with metrics.span("stage", alias=stage.alias, index=k):
+            with metrics.span("stage", structural=True,
+                              alias=stage.alias, index=k):
                 metrics.counter_add(metrics.STAGES_TOTAL)
                 with metrics.span("pull_cache_layers"):
                     stage.pull_cache_layers(self.cache_mgr)
@@ -136,9 +137,10 @@ class BuildPlan:
         with metrics.span("wait_for_push"):
             self.cache_mgr.wait_for_push()
         assert curr is not None
-        manifest = curr.save_manifest(self.target)
-        for replica in self.replicas:
-            curr.save_manifest(replica)
+        with metrics.span("save_manifest", replicas=len(self.replicas)):
+            manifest = curr.save_manifest(self.target)
+            for replica in self.replicas:
+                curr.save_manifest(replica)
         total = sum(l.size for l in manifest.layers)
         log.info("computed total image size %d", total,
                  total_image_size=total)

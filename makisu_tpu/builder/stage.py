@@ -138,8 +138,9 @@ class BuildStage:
                         directive=node.step.directive,
                         cached=node.digest_pairs is not None,
                         skip=bool(opts.skip_build))
-            with metrics.span("step", directive=node.step.directive,
-                              index=i, cached=node.digest_pairs is not None,
+            with metrics.span("step", structural=True,
+                              directive=node.step.directive, index=i,
+                              cached=node.digest_pairs is not None,
                               skip=opts.skip_build), \
                     ledger.node_scope(stage=self.alias, step=i,
                                       directive=node.step.directive):

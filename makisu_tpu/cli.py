@@ -857,97 +857,109 @@ def _watch_loop(args) -> int:
 
 
 def _build_once(args) -> int:
-    from makisu_tpu.builder import BuildPlan
-    from makisu_tpu.cache import NoopCacheManager
-    from makisu_tpu.chunker import get_hasher
-    from makisu_tpu.context import BuildContext
-    from makisu_tpu.docker.image import ImageName
-    from makisu_tpu.dockerfile import parse_file
-    from makisu_tpu.registry import load_config_map, new_client
-    from makisu_tpu.storage import ImageStore
-
-    # Per-build registry config (never the process-global map: builds in
-    # one worker may carry different --registry-config flags).
-    registry_config_map = (load_config_map(args.registry_config)
-                          if args.registry_config else None)
-    # Validated per-build compression identity: threaded through the
-    # BuildContext rather than tario's process globals, so concurrent
-    # builds in one worker can use different flags. `auto` resolves to
-    # a concrete backend HERE (logged once per build) — only concrete
-    # backends enter cache identity.
-    gzip_backend = tario.resolve_backend(args.gzip_backend)
-    if args.gzip_backend == "auto":
-        log.info("gzip backend auto-selected: %s", gzip_backend)
-    gzip_backend_id = tario.make_backend_id(gzip_backend,
-                                            args.compression)
-    blacklist = list(pathutils.DEFAULT_BLACKLIST)
-    for extra in args.blacklist:
-        if extra not in blacklist:
-            blacklist.append(extra)
-
-    dockerfile_path = args.file or os.path.join(args.context, "Dockerfile")
-    with open(dockerfile_path) as f:
-        stages = parse_file(f.read(), _parse_build_args(args.build_arg))
-
-    target = ImageName.parse(args.tag)
-    replicas = [ImageName.parse(r) for r in args.replica]
-
-    with ImageStore(_storage_dir(args.storage)) as store:
-        ctx = BuildContext(args.root, os.path.abspath(args.context), store,
-                           hasher=get_hasher(args.hasher),
-                           blacklist=blacklist,
-                           gzip_backend_id=gzip_backend_id)
-        # The first push registry doubles as the cache's blob/chunk
-        # transfer plane (the reference's registryCacheManager pulls
-        # cached layers through the push registry the same way,
-        # lib/cache/cache_manager.go:116-182): a KV hit from another
-        # builder is materializable from there — lazily, and at chunk
-        # granularity when the TPU hasher indexed the layer.
-        cache_registry = None
-        if args.push:
-            cache_registry = new_client(
-                store, target.with_registry(args.push[0]),
-                config_map=registry_config_map)
-        cache_mgr = (_new_cache_manager(args, store, cache_registry)
-                     or NoopCacheManager())
-        if args.hasher == "tpu" and not isinstance(cache_mgr,
-                                                   NoopCacheManager):
-            from makisu_tpu.cache.chunks import attach_chunk_dedup
-            attach_chunk_dedup(cache_mgr, os.path.join(store.root, "chunks"))
-        preserver = None
-        if args.preserve_root and args.modifyfs:
-            from makisu_tpu.storage.root_preserver import RootPreserver
-            preserver = RootPreserver(args.root, store.sandbox_dir,
-                                      ctx.blacklist)
-        # Resident build session: lease (or mint) the warm state for
-        # this context + resolved-flag identity. A reused session arms
-        # the context with the dirty set, the scan memo, and the
-        # resident statcache/layer state; every outcome lands on the
-        # decision ledger (source=session) and the warm_mode history
-        # label. Leased IMMEDIATELY before the try whose finally
-        # releases it — any fallible setup between acquire and release
-        # would leak the session busy forever.
-        from makisu_tpu.utils import ledger as ledger_mod
-        from makisu_tpu.worker import session as session_mod
-        build_session = None
-        abs_context = os.path.abspath(args.context)
-        if session_mod.enabled():
-            # The restore spec (storage dir + PORTABLE flag identity)
-            # lets a cold acquire consult the chunk-addressed snapshot
-            # plane: same logical build, any worker — the fleet front
-            # door rewrites --storage per worker, which is exactly why
-            # the portable identity excludes it.
-            build_session, verdict = session_mod.manager().acquire(
-                abs_context, session_mod.identity_from_build_args(
-                    args, _storage_dir(args.storage), gzip_backend_id),
-                restore_spec=(
-                    _storage_dir(args.storage),
-                    session_mod.portable_identity_from_build_args(
-                        args, gzip_backend_id)))
-        else:
-            verdict = "disabled"
-        build_ok = False
+    # Named before the try whose finally releases them, so that the
+    # release covers the set-up span's own close as well.
+    store = ctx = preserver = build_session = None
+    build_ok = False
+    try:
         try:
+            # What a build does before its plan exists: the builder's
+            # modules imported (a process's first build), flags, the
+            # Dockerfile, the image store opened, the context, the cache
+            # manager (and the chunk store), the session's lease.
+            with metrics.span("build_setup"):
+                from makisu_tpu.builder import BuildPlan
+                from makisu_tpu.cache import NoopCacheManager
+                from makisu_tpu.chunker import get_hasher
+                from makisu_tpu.context import BuildContext
+                from makisu_tpu.docker.image import ImageName
+                from makisu_tpu.dockerfile import parse_file
+                from makisu_tpu.registry import load_config_map, new_client
+                from makisu_tpu.storage import ImageStore
+
+                # Per-build registry config (never the process-global map:
+                # builds in one worker may carry different
+                # --registry-config flags).
+                registry_config_map = (load_config_map(args.registry_config)
+                                      if args.registry_config else None)
+                # Validated per-build compression identity: threaded through
+                # the BuildContext rather than tario's process globals, so
+                # concurrent builds in one worker can use different flags.
+                # `auto` resolves to a concrete backend HERE (logged once
+                # per build) — only concrete backends enter cache identity.
+                gzip_backend = tario.resolve_backend(args.gzip_backend)
+                if args.gzip_backend == "auto":
+                    log.info("gzip backend auto-selected: %s", gzip_backend)
+                gzip_backend_id = tario.make_backend_id(gzip_backend,
+                                                        args.compression)
+                blacklist = list(pathutils.DEFAULT_BLACKLIST)
+                for extra in args.blacklist:
+                    if extra not in blacklist:
+                        blacklist.append(extra)
+
+                dockerfile_path = args.file or os.path.join(args.context,
+                                                            "Dockerfile")
+                with open(dockerfile_path) as f:
+                    stages = parse_file(f.read(),
+                                        _parse_build_args(args.build_arg))
+
+                target = ImageName.parse(args.tag)
+                replicas = [ImageName.parse(r) for r in args.replica]
+
+                store = ImageStore(_storage_dir(args.storage))
+                ctx = BuildContext(args.root, os.path.abspath(args.context),
+                                   store, hasher=get_hasher(args.hasher),
+                                   blacklist=blacklist,
+                                   gzip_backend_id=gzip_backend_id)
+                # The first push registry doubles as the cache's blob/chunk
+                # transfer plane (the reference's registryCacheManager pulls
+                # cached layers through the push registry the same way,
+                # lib/cache/cache_manager.go:116-182): a KV hit from another
+                # builder is materializable from there — lazily, and at
+                # chunk granularity when the TPU hasher indexed the layer.
+                cache_registry = None
+                if args.push:
+                    cache_registry = new_client(
+                        store, target.with_registry(args.push[0]),
+                        config_map=registry_config_map)
+                cache_mgr = (_new_cache_manager(args, store, cache_registry)
+                             or NoopCacheManager())
+                if args.hasher == "tpu" and not isinstance(cache_mgr,
+                                                           NoopCacheManager):
+                    from makisu_tpu.cache.chunks import attach_chunk_dedup
+                    attach_chunk_dedup(cache_mgr,
+                                       os.path.join(store.root, "chunks"))
+                if args.preserve_root and args.modifyfs:
+                    from makisu_tpu.storage.root_preserver import RootPreserver
+                    preserver = RootPreserver(args.root, store.sandbox_dir,
+                                              ctx.blacklist)
+                # Resident build session: lease (or mint) the warm state for
+                # this context + resolved-flag identity. A reused session
+                # arms the context with the dirty set, the scan memo, and
+                # the resident statcache/layer state; every outcome lands on
+                # the decision ledger (source=session) and the warm_mode
+                # history label. Leased LAST in the set-up, inside the
+                # try whose finally releases it — a lease the finally
+                # did not cover would leak the session busy forever.
+                from makisu_tpu.utils import ledger as ledger_mod
+                from makisu_tpu.worker import session as session_mod
+                abs_context = os.path.abspath(args.context)
+                if session_mod.enabled():
+                    # The restore spec (storage dir + PORTABLE flag
+                    # identity) lets a cold acquire consult the
+                    # chunk-addressed snapshot plane: same logical build,
+                    # any worker — the fleet front door rewrites --storage
+                    # per worker, which is exactly why the portable identity
+                    # excludes it.
+                    build_session, verdict = session_mod.manager().acquire(
+                        abs_context, session_mod.identity_from_build_args(
+                            args, _storage_dir(args.storage), gzip_backend_id),
+                        restore_spec=(
+                            _storage_dir(args.storage),
+                            session_mod.portable_identity_from_build_args(
+                                args, gzip_backend_id)))
+                else:
+                    verdict = "disabled"
             if build_session is not None:
                 mode = build_session.begin_build(
                     ctx,
@@ -1035,6 +1047,10 @@ def _build_once(args) -> int:
             DockerClient(args.docker_host,
                          args.docker_version).image_tar_load(tar_path)
             log.info("loaded image into docker daemon")
+    finally:
+        if store is not None:
+            with metrics.span("build_teardown"):
+                store.cleanup_sandbox()
     log.info("finished building %s", target)
     return 0
 
@@ -2131,7 +2147,7 @@ def main(argv: list[str] | None = None) -> int:
                 version=makisu_tpu.__version__)
     code = 1
     try:
-        with metrics.span(args.command or "cli"):
+        with metrics.span(args.command or "cli", structural=True):
             code = handler(args)
         return code
     except SystemExit as e:

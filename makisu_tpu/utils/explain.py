@@ -306,7 +306,7 @@ def _floor_phase(span_name: str) -> str:
     if span_name == "context_scan":
         return "context_scan"
     phase = traceexport.phase_of(span_name)
-    return "startup" if phase == "other" else phase
+    return phase if phase in FLOOR_PHASES else "startup"
 
 # Phases a fully-warm cache removes entirely: layer commit (chunk +
 # hash), pushes, and cache-driven transfers. Startup and the context

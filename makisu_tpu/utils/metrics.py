@@ -248,6 +248,13 @@ PROFILER_DROPPED = "makisu_profiler_dropped_total"
 PROFILER_STACKS = "makisu_profiler_distinct_stacks"
 PROFILER_OVERHEAD = "makisu_profiler_overhead_ratio"
 
+# Self time of the structural spans: a span its opener marks
+# ``structural=True`` only says where in a command the thread is (the
+# command's root span, the build's `stage` and `step` loops), so what
+# it does itself is what no named operation accounts for. One add a
+# close, label span=<name>.
+SPAN_SELF_SECONDS = "makisu_span_self_seconds_total"
+
 
 def stage_busy_add(stage: str, seconds: float) -> None:
     """Charge ``seconds`` of busy time to one commit-pipeline stage.
@@ -383,7 +390,8 @@ class Span:
 
     __slots__ = ("name", "attrs", "start_unix", "duration", "error",
                  "children", "registry", "span_id", "parent_id", "_t0",
-                 "peak_rss", "cpu_seconds", "late_attrs")
+                 "peak_rss", "cpu_seconds", "late_attrs", "thread",
+                 "child_seconds")
 
     def __init__(self, name: str, attrs: dict[str, Any],
                  registry: "MetricsRegistry") -> None:
@@ -397,6 +405,12 @@ class Span:
         self.registry = registry
         self.span_id = new_id(8)
         self.parent_id = ""
+        # Self time: the children that closed on the thread that opened
+        # this span ran inside its interval one after the other, so
+        # their durations sum to the part of it they cover. A child on
+        # another thread (a copied context) overlaps and is left out.
+        self.thread = threading.get_ident()
+        self.child_seconds = 0.0
         # Filled by the resource sampler (utils/resources.py) while the
         # span is open: peak process RSS observed, and the CPU seconds
         # charged to this span while it was an open LEAF. None = never
@@ -411,6 +425,12 @@ class Span:
         self.late_attrs.update((k, str(v)) for k, v in attrs.items())
         self.attrs.update(self.late_attrs)
 
+    @property
+    def self_seconds(self) -> float:
+        """Of a closed span: its duration less what the children on its
+        thread covered (a leaf's is its duration)."""
+        return max(self.duration - self.child_seconds, 0.0)
+
     def to_dict(self) -> dict[str, Any]:
         out: dict[str, Any] = {
             "name": self.name,
@@ -421,6 +441,8 @@ class Span:
         }
         if self.parent_id:
             out["parent_id"] = self.parent_id
+        if self.children and self.duration is not None:
+            out["self_seconds"] = round(self.self_seconds, 6)
         if self.attrs:
             out["attrs"] = dict(self.attrs)
         if self.error:
@@ -772,12 +794,15 @@ def annotation(name: str, **stats: str):
 
 
 @contextlib.contextmanager
-def span(name: str, **attrs: Any) -> Iterator[Span]:
+def span(name: str, *, structural: bool = False,
+         **attrs: Any) -> Iterator[Span]:
     """Timed scope attached to the innermost bound registry's tree.
     Nested spans become children; exceptions mark the span and
     propagate (telemetry never swallows a build failure). Open/close
     mirror onto the build event bus (no-op unless a sink is bound),
-    and onto the profiler's host timeline once a backend is up."""
+    and onto the profiler's host timeline once a backend is up.
+    ``structural`` is the opener's word that the span names a place
+    and no operation: its self time goes to ``SPAN_SELF_SECONDS``."""
     reg = active_registry()
     parent = _current_span.get()
     if parent is None or parent.registry is not reg:
@@ -804,9 +829,16 @@ def span(name: str, **attrs: Any) -> Iterator[Span]:
             s.duration = time.monotonic() - s._t0
             _open_spans.pop(id(s), None)
             _current_span.reset(token)
+            if parent.thread == s.thread:
+                parent.child_seconds += s.duration
+            if structural:
+                counter_add(SPAN_SELF_SECONDS, s.self_seconds, span=name)
             events.emit("span_end", name=name, span_id=s.span_id,
                         duration=round(s.duration, 6),
                         trace_id=reg.trace_id,
+                        # A leaf's self time is its duration.
+                        **({"self_seconds": round(s.self_seconds, 6)}
+                           if s.children else {}),
                         **({"attrs": s.late_attrs}
                            if s.late_attrs else {}),
                         **({"error": s.error} if s.error else {}))

@@ -746,7 +746,8 @@ def render_profile(doc: dict, top: int = 10) -> str:
 
 _PHASE_COLORS = {
     "pull": "#4e79a7", "chunk": "#f28e2b", "hash": "#e15759",
-    "push": "#76b7b2", "other": "#9c9c9c",
+    "push": "#76b7b2", "setup": "#b07aa1", "teardown": "#9d7660",
+    "other": "#9c9c9c",
 }
 
 

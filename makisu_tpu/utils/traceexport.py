@@ -41,9 +41,13 @@ _PHASE_RULES: tuple[tuple[str, str], ...] = (
     ("tar_write", "hash"),
     ("sink_finish", "hash"),
     ("push", "push"),
+    # What a build does before its plan exists and after its exports:
+    # `/builds` names a phase from a build's first span on.
+    ("build_setup", "setup"),
+    ("build_teardown", "teardown"),
 )
 
-PHASES = ("pull", "chunk", "hash", "push", "other")
+PHASES = ("setup", "pull", "chunk", "hash", "push", "teardown", "other")
 
 
 def phase_of(span_name: str) -> str:
@@ -64,6 +68,20 @@ def _duration(span: dict) -> float:
     # Open spans (process died mid-span) carry null; treat as zero so
     # analysis of a torn report still works.
     return float(span.get("duration") or 0.0)
+
+
+def _self_seconds(span: dict) -> float:
+    """A span's self time as the program recorded it where the span
+    closed (``metrics.Span.to_dict``: duration less the children that
+    ran on its own thread; a leaf says nothing, its self time is its
+    duration). Only a tree that no one program closed (stitched from
+    several processes' events, or written before spans carried it) has
+    parents without the field: there, duration less every child."""
+    recorded = span.get("self_seconds")
+    if recorded is not None:
+        return float(recorded)
+    covered = sum(_duration(c) for c in span.get("children", []))
+    return max(_duration(span) - covered, 0.0)
 
 
 def root_span(report: dict) -> dict | None:
@@ -139,11 +157,10 @@ def critical_path(report: dict) -> list[dict]:
     span, depth = top, 0
     while span is not None:
         children = span.get("children", [])
-        child_sum = sum(_duration(c) for c in children)
         path.append({
             "name": span.get("name", "?"),
             "duration": _duration(span),
-            "self": max(_duration(span) - child_sum, 0.0),
+            "self": _self_seconds(span),
             "depth": depth,
             "attrs": span.get("attrs", {}),
         })
@@ -157,16 +174,13 @@ def self_time_by_name(report: dict) -> dict[str, float]:
     out: dict[str, float] = {}
     for top in report.get("spans") or []:
         for span, _depth in _walk(top):
-            child_sum = sum(_duration(c)
-                            for c in span.get("children", []))
-            self_t = max(_duration(span) - child_sum, 0.0)
             name = span.get("name", "?")
-            out[name] = out.get(name, 0.0) + self_t
+            out[name] = out.get(name, 0.0) + _self_seconds(span)
     return out
 
 
 def phase_totals(report: dict) -> dict[str, float]:
-    """Self-time per build phase (pull/chunk/hash/push/other)."""
+    """Self-time per build phase (``PHASES``)."""
     totals = {phase: 0.0 for phase in PHASES}
     for name, self_t in self_time_by_name(report).items():
         totals[phase_of(name)] += self_t

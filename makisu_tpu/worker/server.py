@@ -187,6 +187,16 @@ class _BuildRecord:
         self.enqueued_mono = time.monotonic()
         self.started_mono: float | None = None
         self.finished_mono: float | None = None
+        # The request's own seconds (note_service): service is
+        # admission to the end of run_build's finally; set-up is the
+        # part of it before the command's root span opened, tear-down
+        # the part after it closed. Tear-down runs after the admission
+        # slot is handed on (_admission.release() precedes
+        # _retire_build, the census and maybe_evict()): the client
+        # waits for it, the slot does not.
+        self._root_open_mono: float | None = None
+        self._root_close_mono: float | None = None
+        self.service: dict[str, float] = {}
         self._last_event_mono = self.enqueued_mono
         self._mu = threading.Lock()
         self._ledger = ledger.LedgerSummary()
@@ -213,7 +223,12 @@ class _BuildRecord:
         with self._mu:
             self._last_event_mono = time.monotonic()
             if etype == "build_start":
+                # cli.main emits it as the root span opens, and
+                # build_end as it has closed.
                 self.trace_id = event.get("trace_id", "")
+                self._root_open_mono = self._last_event_mono
+            elif etype == "build_end":
+                self._root_close_mono = self._last_event_mono
             elif etype == "span_start":
                 phase = traceexport.phase_of(event.get("name", ""))
                 if phase != "other":
@@ -242,6 +257,17 @@ class _BuildRecord:
             self.state = "finished"
             self.exit_code = exit_code
             self.finished_mono = time.monotonic()
+
+    def note_service(self, admitted: float, done: float) -> None:
+        """The split of ``done - admitted``; a request whose command
+        never opened a root span was set-up all through."""
+        with self._mu:
+            opened = self._root_open_mono or done
+            closed = self._root_close_mono or done
+            self.service = {
+                "setup_seconds": round(opened - admitted, 6),
+                "teardown_seconds": round(done - closed, 6),
+                "service_seconds": round(done - admitted, 6)}
 
     def latency_seconds(self) -> float:
         """Queue wait + execution: arrival to completion."""
@@ -292,6 +318,7 @@ class _BuildRecord:
                     and self.started_mono is not None:
                 out["elapsed_seconds"] = round(
                     self.finished_mono - self.started_mono, 3)
+            out.update(self.service)
             return out
 
 
@@ -661,6 +688,7 @@ class _Handler(BaseHTTPRequestHandler):
             "exit_code": code,
             "elapsed_seconds": round(time.monotonic() - start, 3),
             "queue_wait_seconds": round(record.queue_wait_seconds, 3),
+            **record.service,
             "tenant": tenant,
         }))
         with emit_lock:
@@ -1336,6 +1364,7 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
             record = self.register_build(argv)
         queue_wait = self._admission.acquire()
         record.start_running(queue_wait)
+        admitted = time.monotonic()
         # Inbound trace context: bound for cli.main to adopt into the
         # build's registry (the build's spans, events, and outbound
         # traceparents all join the caller's trace). Parsed here too so
@@ -1470,6 +1499,7 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
             events.reset_sink(record_token)
             events.reset_sink(events_token)
             log.reset_build_sink(token)
+            record.note_service(admitted, time.monotonic())
 
     def _active_builds(self) -> int:
         with self._health_mu:

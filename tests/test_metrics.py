@@ -127,6 +127,131 @@ def test_spawned_thread_inherits_context():
     assert reg.counter_total("test_inherit_total") == 1
 
 
+# -- self time ---------------------------------------------------------------
+
+
+@pytest.fixture
+def clocked(monkeypatch):
+    """A registry bound to this context, a clock the test moves by hand
+    under ``metrics.span``, and the ``span_end`` events by name."""
+    import types
+
+    from makisu_tpu.utils import events
+    clock = types.SimpleNamespace(now=100.0)
+    monkeypatch.setattr(metrics, "time", types.SimpleNamespace(
+        monotonic=lambda: clock.now, time=lambda: 1e9 + clock.now))
+    reg = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(reg)
+    ended = {}
+    sink = events.add_sink(
+        lambda e: ended.update({e["name"]: e})
+        if e["type"] == "span_end" else None)
+    yield clock, reg, ended
+    events.reset_sink(sink)
+    metrics.reset_build_registry(token)
+
+
+def _two_children(clock):
+    clock.now += 1.0
+    with metrics.span("a"):
+        clock.now += 2.0
+    clock.now += 0.5
+    with metrics.span("b"):
+        clock.now += 3.0
+    clock.now += 0.25
+    return 1.75
+
+
+def _child_on_a_pool_thread(clock):
+    from concurrent.futures import ThreadPoolExecutor
+
+    from makisu_tpu.utils import concurrency
+
+    def pooled():
+        with metrics.span("pooled"):
+            clock.now += 4.0
+
+    clock.now += 1.0
+    with ThreadPoolExecutor(1) as pool:
+        concurrency.submit_ctx(pool, pooled).result()
+    clock.now += 1.0
+    # It ran beside its parent, under a copied context: not subtracted.
+    return 6.0
+
+
+def _child_that_raises(clock):
+    clock.now += 1.0
+    with pytest.raises(ValueError):
+        with metrics.span("failing"):
+            clock.now += 2.0
+            raise ValueError("boom")
+    clock.now += 1.0
+    return 2.0
+
+
+def _leaf(clock):
+    clock.now += 3.0
+    return None
+
+
+@pytest.mark.parametrize("body", [
+    _two_children, _child_on_a_pool_thread, _child_that_raises, _leaf],
+    ids=lambda f: f.__name__.strip("_"))
+def test_self_time_is_duration_less_the_children_on_the_spans_thread(
+        clocked, body):
+    clock, reg, ended = clocked
+    with metrics.span("parent"):
+        want = body(clock)
+    [parent] = reg.report()["spans"]
+    assert parent["duration"] == pytest.approx(clock.now - 100.0)
+    if want is None:
+        # A leaf's self time is its duration: nothing says it twice.
+        assert "self_seconds" not in parent
+        assert "self_seconds" not in ended["parent"]
+        return
+    assert parent["self_seconds"] == pytest.approx(want)
+    assert ended["parent"]["self_seconds"] == pytest.approx(want)
+    for child in parent["children"]:
+        assert "self_seconds" not in child
+        assert child["parent_id"] == parent["span_id"]
+
+
+def test_self_seconds_counter_grows_for_the_structural_spans_alone(clocked):
+    """The opener says which span is structural; the name says nothing."""
+    clock, reg, _ = clocked
+    in_process = metrics.global_registry().counter_by_label(
+        metrics.SPAN_SELF_SECONDS, "span")
+    with metrics.span("build", structural=True):
+        clock.now += 0.5
+        with metrics.span("context_scan"):
+            clock.now += 1.0
+            with metrics.span("copy_checksum"):
+                clock.now += 1.0
+            with metrics.span("step"):          # a namesake, not marked
+                clock.now += 8.0
+        with metrics.span("stage", structural=True):
+            clock.now += 0.25
+            with metrics.span("step", structural=True):     # a leaf
+                clock.now += 0.125
+            with metrics.span("step", structural=True):
+                clock.now += 0.0625
+                with metrics.span("commit_layer"):
+                    clock.now += 2.0
+    want = {"build": 0.5, "stage": 0.25, "step": 0.125 + 0.0625}
+    assert reg.counter_by_label(metrics.SPAN_SELF_SECONDS, "span") \
+        == pytest.approx(want)
+    # The mark is the opener's alone: it is no attribute of the span.
+    [root] = reg.report()["spans"]
+    assert "attrs" not in root
+    # The worker's /metrics serves the process's sum of the same.
+    after = metrics.global_registry().counter_by_label(
+        metrics.SPAN_SELF_SECONDS, "span")
+    assert {k: after[k] - in_process.get(k, 0.0) for k in after} \
+        == pytest.approx(want)
+    assert 'makisu_span_self_seconds_total{span="stage"}' \
+        in metrics.render_prometheus()
+
+
 # -- Prometheus text format ------------------------------------------------
 
 

@@ -269,7 +269,8 @@ def _new_metrics_list_their_cells():
         assert by_name[name]["better"] == "lower"
     # Appended, never inserted: the cell is the last of every list it
     # joined.
-    for m in BENCHMARK["per_layer"][:-6] + BENCHMARK["end_to_end"]:
+    for m in BENCHMARK["per_layer"][:first] \
+            + BENCHMARK["per_layer"][first + 6:] + BENCHMARK["end_to_end"]:
         if CELL in m.get("workloads", ()):
             assert m["workloads"][-1] == CELL, m["name"]
 

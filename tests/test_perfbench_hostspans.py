@@ -197,6 +197,109 @@ def test_reader_returns_nothing_for_a_program_without_the_span(run, metric):
     assert _reader(metric)(run) is None
 
 
+# -- PR 35: a build's service, whole ---------------------------------------
+
+SELF = "makisu_span_self_seconds_total"
+
+
+def _served(tmp_path, with_program_side=True, builds=True):
+    """Two builds that ended well and one that did not; the structural
+    spans' self seconds grew over the window."""
+    r = driver.Run(cell=None, seed=1, seconds=45.0, trace=True,
+                   work_dir=str(tmp_path))
+    if builds:
+        r.counted = [
+            _build([("build_setup", 0.25), ("session_begin", 0.125),
+                    ("wait_for_push", 0.5), ("save_manifest", 0.0625),
+                    ("build", 2.0)]),
+            _build([("build_setup", 0.75), ("session_begin", 0.375),
+                    ("wait_for_push", 1.0), ("save_manifest", 0.03125),
+                    ("build", 3.0)]),
+            _build([("build_setup", 99.0), ("session_begin", 99.0),
+                    ("wait_for_push", 99.0), ("save_manifest", 99.0)],
+                   ok=False)]
+        for b, (setup, teardown, service) in zip(r.counted, (
+                (0.0625, 0.03125, 2.09375), (0.125, 0.0625, 3.1875),
+                (9.0, 9.0, 99.0))):
+            b.terminal = {"queue_wait_seconds": 5.0, "setup_seconds": setup,
+                          "teardown_seconds": teardown,
+                          "service_seconds": service}
+    r.builds = list(r.counted)
+    r.counters_open = dict([
+        _series(SELF, 1.0, span="build"), _series(SELF, 0.5, span="stage"),
+        _series("makisu_session_dirty_paths_total", 4.0)])
+    r.counters_close = dict([
+        _series(SELF, 1.75, span="build"), _series(SELF, 0.875, span="stage"),
+        _series(SELF, 0.375, span="step"),
+        _series("makisu_session_dirty_paths_total", 13.0)])
+    if not with_program_side:
+        for b in r.counted:
+            b.spans = [("commit_layer", 2.0), ("session_finish", 0.5)]
+            b.terminal = {"queue_wait_seconds": 5.0, "elapsed_seconds": 7.0}
+        for counters in (r.counters_open, r.counters_close):
+            for key in [k for k in counters if k[0] == SELF]:
+                del counters[key]
+    return r
+
+
+@pytest.mark.parametrize("metric,want", [
+    # Counters grow over the window and are divided by the 3 counted;
+    # spans and terminal records are those of the 2 that ended well.
+    ("unspanned_s_per_build", (0.75 + 0.375 + 0.375) / 3),
+    ("service_s_per_build", (2.09375 + 3.1875) / 2),
+    ("request_overhead_s_per_build", (0.09375 + 0.1875) / 2),
+    ("build_setup_s_per_build", (0.25 + 0.75) / 2),
+    ("session_begin_s_per_build", (0.125 + 0.375) / 2),
+    ("wait_for_push_s_per_build", (0.5 + 1.0) / 2),
+    ("save_manifest_s_per_build", (0.0625 + 0.03125) / 2),
+])
+def test_service_reader_reads_a_run_and_nothing_from_an_older_program(
+        tmp_path, metric, want, capsys):
+    read = _reader(metric)
+    assert read(_served(tmp_path)) == pytest.approx(want)
+    if metric == "unspanned_s_per_build":
+        assert "build 0.2500  stage 0.1250  step 0.1250" \
+            in capsys.readouterr().out
+    # The parent's side of the driver's pair: no counter, field or span.
+    assert read(_served(tmp_path, with_program_side=False)) is None
+    # A window that counted no build.
+    assert read(_served(tmp_path, builds=False)) is None
+    if metric == "unspanned_s_per_build":
+        untraced = _served(tmp_path)
+        untraced.counters_open = untraced.counters_close = None
+        assert read(untraced) is None
+
+
+def test_service_metrics_list_their_cells():
+    import json
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json"),
+              encoding="utf-8") as f:
+        by_name = {m["name"]: m for m in json.load(f)["per_layer"]}
+    every = by_name["idle_unspanned_pct"]["workloads"]
+    for name in ("unspanned_s_per_build", "service_s_per_build",
+                 "request_overhead_s_per_build"):
+        assert by_name[name]["workloads"] == every, name
+    assert by_name["build_setup_s_per_build"]["workloads"] == [
+        "farm-churn", "farm-unchanged", "monorepo-edit",
+        "multi-stage-small-edit"]
+    assert by_name["session_begin_s_per_build"]["workloads"] == [
+        "farm-churn", "farm-unchanged", "monorepo-edit", "small-files-edit",
+        "huge-layer-edit", "multi-stage-small-edit"]
+    assert by_name["wait_for_push_s_per_build"]["workloads"] == [
+        "monorepo-cold", "monorepo-edit", "huge-layer-edit"]
+    assert by_name["save_manifest_s_per_build"]["workloads"] == [
+        "farm-churn", "farm-unchanged"]
+    for name in ("unspanned_s_per_build", "service_s_per_build",
+                 "request_overhead_s_per_build", "build_setup_s_per_build",
+                 "session_begin_s_per_build", "wait_for_push_s_per_build",
+                 "save_manifest_s_per_build"):
+        assert by_name[name]["moves"] == "build_p50_s"
+        assert (by_name[name]["unit"], by_name[name]["better"]) \
+            == ("s", "lower")
+    from makisu_tpu.utils import metrics
+    assert metrics.SPAN_SELF_SECONDS == SELF
+
+
 def test_every_new_metric_has_its_reader_and_its_cells():
     import json
     with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json"),

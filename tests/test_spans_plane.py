@@ -164,6 +164,65 @@ def test_the_sync_precedes_the_scan_and_the_wait_follows_the_tar(
         assert wait[3] >= 0.05
 
 
+def test_the_phases_of_a_build_once_each_under_the_root_in_order(
+        tmp_path, no_sleep):
+    """What ``build`` does itself has a name: the root span's children
+    are the build's phases, each once, and the root's own seconds (its
+    ``self_seconds``) are what is left between them."""
+    _context(tmp_path)
+    event_log, report = _build(tmp_path, "cpu", 1)
+    spans = _spans(event_log)
+    [root] = [s for s in spans if s[1] == ""]
+    assert root[0] == "build"
+    assert [s[0] for s in spans if s[5] == root[4]] == [
+        "build_setup", "session_begin", "context_scan", "stage",
+        "wait_for_push", "save_manifest", "session_finish",
+        "build_teardown"]
+    [top] = report["spans"]
+    covered = sum(c["duration"] for c in top["children"])
+    assert top["self_seconds"] == pytest.approx(
+        top["duration"] - covered, abs=1e-4)
+    [ended] = [e for e in event_log
+               if e["type"] == "span_end" and e["name"] == "build"]
+    assert ended["self_seconds"] == pytest.approx(top["self_seconds"],
+                                                  abs=1e-5)
+    [manifest] = [s for s in spans if s[0] == "save_manifest"]
+    assert manifest[2]["replicas"] == "0"
+    # The structural spans' self time, and no other span's, is counted.
+    by_span = {s["labels"]["span"]: s["value"] for s in
+               report["counters"][metrics.SPAN_SELF_SECONDS]}
+    assert sorted(by_span) == ["build", "stage", "step"]
+    assert by_span["build"] == pytest.approx(top["self_seconds"], abs=1e-5)
+
+
+def test_the_sessions_release_covers_the_setup_spans_close(tmp_path):
+    """The lease is taken inside ``build_setup``; an exit that lands in
+    that span's close (a signal handler's, during the frame's write)
+    still reaches the ``finally`` that releases the session."""
+    from makisu_tpu.worker import session as session_mod
+    _context(tmp_path)
+
+    def dies_at_the_close(event):
+        if event["type"] == "span_end" and event["name"] == "build_setup":
+            raise SystemExit("terminated")
+
+    mgr = session_mod.SessionManager()
+    mgr_token = session_mod.bind_manager(mgr)
+    sink_token = events.add_sink(dies_at_the_close)
+    try:
+        with pytest.raises(SystemExit, match="terminated"):
+            cli.main([
+                "--log-level", "error", "build", str(tmp_path / "ctx"),
+                "-t", "spans/plane:lease",
+                "--storage", str(tmp_path / "storage"),
+                "--root", str(tmp_path / "root"), "--hasher", "cpu"])
+    finally:
+        events.reset_sink(sink_token)
+        session_mod.reset_manager(mgr_token)
+    [leased] = mgr._sessions.values()
+    assert not leased.busy
+
+
 def _replay_counts(report):
     return {result: _counter(report, metrics.LAYER_REPLAY_TOTAL,
                              result=result)
@@ -274,7 +333,11 @@ def _walk(node):
     ("memfs_sync.mtime_wait", "hash"), ("layer_scan", "hash"),
     ("tar_write", "hash"), ("sink_finish", "hash"),
     ("chunk_index", "chunk"), ("apply_layer.inflate", "other"),
-    ("copy_checksum", "other"), ("session_begin", "other")])
+    ("copy_checksum", "other"), ("session_begin", "other"),
+    # PR 35: a build's set-up and tear-down are phases of their own, so
+    # `/builds` says where a build is from its first span on.
+    ("build_setup", "setup"), ("build_teardown", "teardown"),
+    ("save_manifest", "other")])
 def test_spans_under_commit_layer_keep_its_phase(name, phase):
     """`report`, `history` and the sampler split a build by phase; the
     spans that now sit inside ``commit_layer`` must not move its

@@ -127,6 +127,26 @@ def test_self_time_reconstructs_wall_time():
     assert total == pytest.approx(2.0)
 
 
+def test_self_time_is_the_recorded_one_where_a_span_carries_it():
+    """A child on a pool thread overlaps its parent: the program does
+    not subtract it (``Span.self_seconds``), and neither does anything
+    that reads the report, so the tree and the phases agree."""
+    report = {"spans": [{
+        "name": "build", "start": 0.0, "duration": 2.0,
+        "self_seconds": 0.5,
+        "children": [
+            {"name": "commit_layer", "start": 0.5, "duration": 1.5,
+             "self_seconds": 1.5,       # its one child ran beside it
+             "children": [{"name": "registry_push", "start": 0.6,
+                           "duration": 1.2}]},
+        ]}]}
+    assert traceexport.self_time_by_name(report) == {
+        "build": 0.5, "commit_layer": 1.5, "registry_push": 1.2}
+    assert [hop["self"] for hop in traceexport.critical_path(report)] \
+        == [0.5, 1.5, 1.2]
+    assert traceexport.phase_totals(report)["hash"] == 1.5
+
+
 def test_phase_totals():
     phases = traceexport.phase_totals(REPORT)
     assert phases["pull"] == pytest.approx(1.0)

@@ -438,3 +438,87 @@ def test_worker_survives_systemexit_with_message(tmp_path, worker):
     assert any("not a makisu-tpu metrics report" in p.get("msg", "")
                for p in lines)
     assert client.ready()  # handler thread survived
+
+
+# -- the request's own seconds (PR 35) ---------------------------------------
+
+
+def _small_build(tmp_path, client, n, **kwargs):
+    ctx = tmp_path / "sctx"
+    if not ctx.exists():
+        ctx.mkdir()
+        (ctx / "Dockerfile").write_text(
+            "FROM scratch\nCOPY data.txt /data.txt\n")
+        (ctx / "data.txt").write_text("service payload")
+    (tmp_path / f"sroot{n}").mkdir()
+    code = client.build([
+        "--log-level", "error", "build", str(ctx),
+        "-t", f"worker/service:{n}",
+        "--storage", str(tmp_path / "sstorage"),
+        "--root", str(tmp_path / f"sroot{n}")], **kwargs)
+    assert code == 0
+    return dict(client.last_build)
+
+
+def test_terminal_record_accounts_the_service_whole(tmp_path, worker):
+    """``service_seconds`` is admission to the end of ``run_build``:
+    set-up (before the root span opened), the root span, tear-down
+    (after it closed). Nothing of a request's service is outside the
+    three, and the queue wait is outside all of them."""
+    client = WorkerClient(worker.socket_path)
+    _small_build(tmp_path, client, 0)       # the process's first build
+    terminal = _small_build(tmp_path, client, 1)
+    [root] = [e for e in client.last_events
+              if e["type"] == "span_end" and e["name"] == "build"]
+    for field in ("setup_seconds", "teardown_seconds", "service_seconds"):
+        assert terminal[field] >= 0.0
+    assert terminal["service_seconds"] == pytest.approx(
+        terminal["setup_seconds"] + root["duration"]
+        + terminal["teardown_seconds"], abs=0.010)
+    assert terminal["service_seconds"] == pytest.approx(
+        terminal["elapsed_seconds"] - terminal["queue_wait_seconds"],
+        abs=0.010)
+    # /builds carries the same three once the request is done.
+    row = next(r for r in client.builds()["recent"]
+               if r["tag"] == "worker/service:1")
+    assert {k: row[k] for k in ("setup_seconds", "teardown_seconds",
+                                "service_seconds")} \
+        == {k: terminal[k] for k in ("setup_seconds", "teardown_seconds",
+                                     "service_seconds")}
+
+
+def test_a_request_that_opens_no_root_span_is_set_up_all_through(
+        tmp_path, worker):
+    client = WorkerClient(worker.socket_path)
+    assert client.build(["build", "--no-such-flag"]) != 0
+    terminal = client.last_build
+    assert terminal["teardown_seconds"] == 0.0
+    assert terminal["setup_seconds"] == terminal["service_seconds"] > 0.0
+
+
+def test_worker_builds_hang_nothing_on_the_process_registrys_root(
+        tmp_path, worker):
+    """The request's set-up and tear-down are clock reads, not spans:
+    no build registry is bound in ``run_build``, and what hangs on the
+    process registry's root is never pruned."""
+    from makisu_tpu.utils import metrics
+    client = WorkerClient(worker.socket_path)
+    _small_build(tmp_path, client, 0)
+    root = metrics.global_registry().root
+    before = len(root.children)
+    for n in range(1, 4):
+        _small_build(tmp_path, client, n)
+    assert len(root.children) == before
+
+
+def test_builds_phase_is_named_from_the_first_span_on():
+    from makisu_tpu.worker.server import _BuildRecord
+    record = _BuildRecord(1, "", ["build"])
+    seen = []
+    for name in ("build", "build_setup", "session_begin", "context_scan",
+                 "commit_layer", "wait_for_push", "save_manifest",
+                 "build_teardown"):
+        record.note_event({"type": "span_start", "name": name})
+        seen.append(record.to_dict()["phase"])
+    assert seen == ["", "setup", "setup", "setup", "hash", "push", "push",
+                    "teardown"]
