@@ -11,7 +11,7 @@ import base64
 import os
 
 from makisu_tpu.chunker import CPUHasher, Hasher
-from makisu_tpu.snapshot import MemFS
+from makisu_tpu.snapshot import MemFS, TreeListing
 from makisu_tpu.storage import ImageStore
 from makisu_tpu.utils import pathutils
 
@@ -74,6 +74,10 @@ class BuildContext:
         self.session = None
         self.dirty_paths: frozenset[str] = frozenset()
         self.dirty_exact = False
+        # This build's one listing of its context tree
+        # (snapshot/walk.py): the checksum pass, the layer scan and the
+        # session's checkpoint stat each entry once between them.
+        self.listing = TreeListing(context_dir)
 
     def source_unchanged(self, path: str) -> bool:
         """True when the resident session PROVES nothing under ``path``
@@ -148,4 +152,6 @@ class BuildContext:
         ctx.session = self.session
         ctx.dirty_paths = self.dirty_paths
         ctx.dirty_exact = self.dirty_exact
+        # One context tree, one listing: shared by reference.
+        ctx.listing = self.listing
         return ctx

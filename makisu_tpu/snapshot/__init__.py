@@ -10,6 +10,7 @@ from makisu_tpu.snapshot.memfs import FSDiff, MemFS, Node
 from makisu_tpu.snapshot.walk import (
     WHITEOUT_META_PREFIX,
     WHITEOUT_PREFIX,
+    TreeListing,
     create_tar_from_directory,
     eval_symlinks,
     tarinfo_from_stat,
@@ -18,7 +19,8 @@ from makisu_tpu.snapshot.walk import (
 
 __all__ = [
     "CopyOperation", "ContentEntry", "FSDiff", "Layer", "MemFS", "Node",
-    "WhiteoutEntry", "WHITEOUT_META_PREFIX", "WHITEOUT_PREFIX",
+    "TreeListing", "WhiteoutEntry", "WHITEOUT_META_PREFIX",
+    "WHITEOUT_PREFIX",
     "create_tar_from_directory", "eval_symlinks", "tarinfo_from_stat",
     "walk",
 ]

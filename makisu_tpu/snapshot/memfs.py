@@ -40,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import shutil
+import stat as statmod
 import tarfile
 import time
 from collections.abc import Callable
@@ -51,6 +52,7 @@ from makisu_tpu.snapshot.layer import ContentEntry, Layer, WhiteoutEntry
 from makisu_tpu.snapshot.walk import (
     WHITEOUT_META_PREFIX,
     WHITEOUT_PREFIX,
+    TreeListing,
     eval_symlinks,
     remove_all_children,
     tarinfo_from_stat,
@@ -260,13 +262,17 @@ class MemFS:
         return layer
 
     def add_layer_by_copy_ops(self, ops: list[CopyOperation],
-                              tw: tarfile.TarFile) -> Layer:
+                              tw: tarfile.TarFile,
+                              listing: TreeListing | None = None) -> Layer:
+        """``listing``: the build's listing of its context tree
+        (``BuildContext.listing``); an external op's source is walked
+        through it."""
         self.flush()
         self._sync()
         with metrics.span("layer_scan") as sp:
             layer = Layer()
-            newest = max((self._add_copy_to_layer(layer, op) for op in ops),
-                         default=0)
+            newest = max((self._add_copy_to_layer(layer, op, listing)
+                          for op in ops), default=0)
             sp.set(entries=len(layer))
         self._commit_layer(layer, tw)
         self._wait_out_mtime(newest)
@@ -316,7 +322,8 @@ class MemFS:
         def visit(path: str, st: os.stat_result) -> None:
             nonlocal newest
             dst = pathutils.trim_root(path, self.root)
-            hdr = tarinfo_from_stat(path, pathutils.rel_path(dst), self.root)
+            hdr = tarinfo_from_stat(path, pathutils.rel_path(dst), self.root,
+                                    st)
             newest = max(newest, hdr.mtime)
             self._maybe_add(layer, path, dst, hdr, create_whiteouts=True)
 
@@ -400,7 +407,8 @@ class MemFS:
             self._apply_entry(layer.add_header("", cur, hdr))
         return dst
 
-    def _add_copy_to_layer(self, layer: Layer, op: CopyOperation) -> int:
+    def _add_copy_to_layer(self, layer: Layer, op: CopyOperation,
+                           listing: TreeListing | None = None) -> int:
         """Returns the newest mtime of any source entry visited."""
         newest = 0
         create_dst = True
@@ -422,7 +430,7 @@ class MemFS:
                       src=src, dst=dst) -> None:
                 nonlocal newest
                 if cur == src:
-                    if os.path.isdir(cur) and not os.path.islink(cur):
+                    if statmod.S_ISDIR(st.st_mode):
                         return  # dir contents copy into dst, not dir itself
                     if not dst.endswith("/"):
                         cur_dst = dst
@@ -431,7 +439,7 @@ class MemFS:
                 else:
                     cur_dst = os.path.join(dst, cur[len(src):].lstrip("/"))
                 hdr = tarinfo_from_stat(
-                    cur, pathutils.rel_path(cur_dst), self.root)
+                    cur, pathutils.rel_path(cur_dst), self.root, st)
                 if op.preserve_owner:
                     pass  # keep source owners (--archive)
                 else:
@@ -444,8 +452,12 @@ class MemFS:
             # Same blacklist policy as the on-disk Copier (copy_op.py
             # _copier): external copies prune blacklisted sources —
             # incl. .dockerignore exclusions — internal (--from) copies
-            # see everything in their sandbox.
-            walk(src, None if op.internal else op.blacklist, visit)
+            # see everything in their sandbox, which the build itself
+            # wrote: never through the listing.
+            if op.internal:
+                walk(src, None, visit)
+            else:
+                walk(src, op.blacklist, visit, listing)
         return newest
 
     # ------------------------------------------------------------------

@@ -19,7 +19,7 @@ import tarfile
 import time
 
 from makisu_tpu import tario
-from makisu_tpu.utils import mountinfo, pathutils, sysutils
+from makisu_tpu.utils import metrics, mountinfo, pathutils, sysutils
 
 WHITEOUT_PREFIX = ".wh."
 WHITEOUT_META_PREFIX = ".wh..wh."
@@ -38,41 +38,174 @@ def should_skip(path: str, st: os.stat_result | None,
     return mountinfo.is_mountpoint(path)
 
 
-def walk(src_root: str, blacklist: list[str] | None, fn) -> None:
+def _list_dir(path: str) -> list[tuple[str, os.stat_result]]:
+    """``path``'s children as ``(name, lstat)``, sorted by name: one
+    ``scandir`` and one ``lstat`` a child. A child gone between the two
+    is left out, as if the listing had run a moment later."""
+    out = []
+    with os.scandir(path) as it:
+        for entry in it:
+            try:
+                out.append((entry.name, entry.stat(follow_symlinks=False)))
+            except FileNotFoundError:
+                continue
+    out.sort(key=lambda child: child[0])
+    return out
+
+
+class TreeListing:
+    """One build's memo of its context tree: what ``scandir`` and
+    ``lstat`` said of each directory and entry the first time any pass
+    of the build asked. A build's passes over its context (the
+    ``copy_checksum`` of ``AddCopyStep``, the layer scan's ``walk``,
+    the session checkpoint's ``snapshot_tree``) see one tree, statted
+    once. The listing is lazy and has no roles: whichever pass reaches
+    a directory first lists it, every later one replays it.
+
+    It serves only paths under ``root`` (the build's ``context_dir``),
+    which a build reads and does not write. ``close()`` ends that for
+    the rest of the build (a ``RUN`` step may write anywhere): from
+    then on ``serves`` is false and every pass lists live. It lives on
+    the ``BuildContext`` and goes with it, so no build sees another's.
+
+    A racing edit: a file edited after its stat was taken and before
+    the layer scan gets a header from the memoized stat, the one its
+    cache id was computed from ("a layer holds the tree's files as they
+    are on disk when the build starts"); the tar writer's handling of
+    a size that no longer matches is untouched, and the session's
+    watcher, armed before the first stat, puts the path in the next
+    build's dirty set. ``started_ns`` is the wall clock before the
+    first ``lstat``: a snapshot built from replayed stats certifies
+    against that moment, not against the time of the replay.
+
+    Holds a ``stat_result`` an entry listed until the build ends.
+    """
+
+    def __init__(self, root: str) -> None:
+        self.root = os.path.abspath(root)
+        self._prefix = self.root.rstrip("/") + "/"
+        self._dirs: dict[str, list[tuple[str, os.stat_result]]] | None = {}
+        self._stats: dict[str, os.stat_result] = {}
+        self.started_ns: int | None = None
+        # Directories read from disk and answered from the memo since
+        # the last ``flush_counts`` (added to the counter once a pass).
+        self.asked = {"listed": 0, "replayed": 0}
+
+    def _key(self, path: str) -> str | None:
+        """The memo's key for ``path``; None where it is not served.
+        "COPY . /app/" resolves to "<context>/." and "COPY app/" to a
+        trailing slash: the same directories, one key each. A path with
+        "..", which may cross a symlink, is not served."""
+        if self._dirs is None:
+            return None
+        parts = path.split("/")
+        if parts[0] or ".." in parts:
+            return None
+        path = "/" + "/".join(p for p in parts if p and p != ".")
+        if path == self.root or path.startswith(self._prefix):
+            return path
+        return None
+
+    def serves(self, path: str) -> bool:
+        return self._key(path) is not None
+
+    def _start(self) -> None:
+        if self.started_ns is None:
+            self.started_ns = time.time_ns()
+
+    def lstat(self, path: str) -> os.stat_result:
+        """``os.lstat(path)``, memoized where ``path`` is served: for
+        the top of a source, which no listed directory holds."""
+        key = self._key(path)
+        if key is None:
+            return os.lstat(path)
+        st = self._stats.get(key)
+        if st is None:
+            self._start()
+            st = self._stats[key] = os.lstat(path)
+        return st
+
+    def children(self, path: str
+                 ) -> list[tuple[str, str, os.stat_result]]:
+        """The children of directory ``path`` as ``(name, path,
+        lstat)``, sorted by name (the order of ``sorted(os.listdir)``);
+        the child paths are joined onto ``path`` as it was given."""
+        key = self._key(path)
+        if key is None:
+            listed = _list_dir(path)
+        elif key in self._dirs:
+            listed = self._dirs[key]
+            self.asked["replayed"] += 1
+        else:
+            self._start()
+            listed = self._dirs[key] = _list_dir(path)
+            self.asked["listed"] += 1
+        return [(name, os.path.join(path, name), st)
+                for name, st in listed]
+
+    def close(self) -> None:
+        """Drop the memo and serve nothing more: the build is about to
+        run something that may write the context."""
+        self._dirs = None
+        self._stats = {}
+        self.started_ns = None
+
+    def flush_counts(self) -> None:
+        """Add this pass's directories to
+        ``makisu_tree_listing_dirs_total`` (once a pass, never an
+        entry) and start the next pass's count."""
+        for result, n in self.asked.items():
+            if n:
+                metrics.counter_add(metrics.TREE_LISTING_DIRS_TOTAL, n,
+                                    result=result)
+                self.asked[result] = 0
+
+
+# What a walk with no listing goes through: closed, it serves nothing,
+# keeps nothing and counts nothing, and every call is the live one.
+_NO_LISTING = TreeListing("/")
+_NO_LISTING.close()
+
+
+def walk(src_root: str, blacklist: list[str] | None, fn,
+         listing: TreeListing | None = None) -> None:
     """Depth-first lexical walk calling ``fn(path, stat)``; prunes skipped
     directories. Includes ``src_root`` itself (like filepath.Walk).
 
-    Uses os.scandir so each entry's type/stat comes from the dirent
-    cache — on large trees (node_modules-style contexts, the reference's
-    "avoid unnecessary disk scans" hot loop) this roughly halves the
-    syscalls of a listdir+lstat walk."""
+    One ``scandir`` a directory and one ``lstat`` an entry. With a
+    ``listing`` (a build's, of its context tree) what lies under the
+    listing's root is asked of it instead: the walk fills what no
+    earlier pass of the build listed and replays the rest, with no
+    file-system call; anything else is listed live all the same. Visit
+    order and what ``fn`` receives are the same either way, and
+    ``should_skip`` runs on every entry with the caller's own
+    blacklist."""
     blacklist = blacklist or []
-
-    def sorted_entries(path):
-        return iter(sorted(os.scandir(path), key=lambda e: e.name))
-
-    st = os.lstat(src_root)
+    if listing is None:
+        listing = _NO_LISTING
+    st = listing.lstat(src_root)
     if should_skip(src_root, st, blacklist):
         return
     fn(src_root, st)
-    if not os.path.isdir(src_root) or os.path.islink(src_root):
+    if not statmod.S_ISDIR(st.st_mode):
         return
     # Explicit iterator stack (not recursion): trees deeper than
     # Python's ~1000-frame limit must not crash the layer scan. Visit
     # order is identical to the recursive form — each entry fires in
     # sorted order, descending into a directory before its siblings.
-    stack = [sorted_entries(src_root)]
+    stack = [iter(listing.children(src_root))]
     while stack:
-        entry = next(stack[-1], None)
-        if entry is None:
+        child = next(stack[-1], None)
+        if child is None:
             stack.pop()
             continue
-        st = entry.stat(follow_symlinks=False)
-        if should_skip(entry.path, st, blacklist):
+        _, path, st = child
+        if should_skip(path, st, blacklist):
             continue
-        fn(entry.path, st)
-        if entry.is_dir(follow_symlinks=False):
-            stack.append(sorted_entries(entry.path))
+        fn(path, st)
+        if statmod.S_ISDIR(st.st_mode):
+            stack.append(iter(listing.children(path)))
+    listing.flush_counts()
 
 
 # -- dirty-set primitives ---------------------------------------------------
@@ -135,12 +268,22 @@ def _racy_window_ns() -> int:
     return statcache.racy_window_ns()
 
 
-def snapshot_tree(root: str,
-                  blacklist: list[str] | None = None) -> TreeSnapshot:
+def snapshot_tree(root: str, blacklist: list[str] | None = None,
+                  listing: TreeListing | None = None) -> TreeSnapshot:
     """One scandir+lstat pass capturing every path's stat signature
     (the root itself excluded — its mtime churns with child churn and
-    carries no content identity of its own)."""
+    carries no content identity of its own).
+
+    With a build's ``listing`` (the session's checkpoint hands in the
+    one its build filled) the pass replays what the build's earlier
+    passes statted and lists only the rest. The snapshot then certifies
+    against the moment the first of those stats was taken:
+    ``captured_ns`` is the listing's ``started_ns``, so a same-tick
+    edit after a stat is still ``fresh`` and re-marked once."""
     captured_ns = time.time_ns()
+    if (listing is not None and listing.started_ns is not None
+            and listing.serves(root)):
+        captured_ns = listing.started_ns
     window = _racy_window_ns()
     sigs: dict[str, tuple] = {}
     fresh: set[str] = set()
@@ -152,7 +295,7 @@ def snapshot_tree(root: str,
         if captured_ns - max(st.st_mtime_ns, st.st_ctime_ns) < window:
             fresh.add(path)
 
-    walk(root, blacklist, visit)
+    walk(root, blacklist, visit, listing)
     # Rough accounting: path string + signature tuple per entry.
     return TreeSnapshot(root, captured_ns, sigs, fresh,
                         sum(len(p) + 120 for p in sigs))
@@ -259,7 +402,7 @@ def create_tar_from_directory(target: str, src_dir: str) -> None:
                         return
                     name = pathutils.rel_path(
                         pathutils.trim_root(path, src_dir))
-                    hdr = tarinfo_from_stat(path, name, src_dir)
+                    hdr = tarinfo_from_stat(path, name, src_dir, st)
                     if hdr.isreg():
                         if st.st_ino in inodes:
                             hdr.type = tarfile.LNKTYPE
@@ -272,14 +415,17 @@ def create_tar_from_directory(target: str, src_dir: str) -> None:
                 walk(src_dir, None, one)
 
 
-def tarinfo_from_stat(src: str, name: str, root: str) -> tarfile.TarInfo:
-    """Build a TarInfo from an on-disk path.
+def tarinfo_from_stat(src: str, name: str, root: str,
+                      st: os.stat_result | None = None) -> tarfile.TarInfo:
+    """Build a TarInfo from an on-disk path, from the ``lstat`` the
+    caller's walk already took of it where it hands one in.
 
     Directory names get docker's trailing slash; absolute symlink targets
     are rebased to be root-relative (reference: memLayer.createHeader,
     mem_layer.go:~110-140).
     """
-    st = os.lstat(src)
+    if st is None:
+        st = os.lstat(src)
     hdr = tarfile.TarInfo(name)
     hdr.mode = st.st_mode & 0o7777
     hdr.uid = st.st_uid

@@ -164,21 +164,30 @@ class AddCopyStep(BuildStep):
                 return checksum_out
         files_before = tally["files"]
         out = self._checksum_tree(ctx, source, checksum, tally)
+        ctx.listing.flush_counts()
         if session is not None:
             session.scan_store(source, checksum, out,
                                tally["files"] - files_before, 0)
         return out
 
     def _checksum_tree(self, ctx: BuildContext, path: str,
-                       checksum: int, tally: dict | None = None) -> int:
-        # ONE lstat per path: kind checks read its mode bits instead of
-        # stacking lexists/islink/isdir syscalls — at the 100k-file
-        # north-star scale those were three extra stats per path on
-        # every scan, warm or cold.
-        try:
-            st = os.lstat(path)
-        except OSError:
-            return checksum  # vanished/unstatable: same as lexists=False
+                       checksum: int, tally: dict | None = None,
+                       st: os.stat_result | None = None) -> int:
+        """The checksum contribution of ``path`` and all below it.
+
+        Reads the tree through the build's listing (``ctx.listing``):
+        as the first pass of a build over its context this is the pass
+        that fills it, one ``scandir`` a directory and ONE ``lstat`` an
+        entry (kind checks read the mode bits), and the layer scan and
+        the session's checkpoint replay it. Children come in the order
+        of ``sorted(os.listdir)``, so cache ids do not depend on who
+        listed. ``st`` is the child's stat out of its parent's listing;
+        the top of a source has none yet."""
+        if st is None:
+            try:
+                st = ctx.listing.lstat(path)
+            except OSError:
+                return checksum  # vanished/unstatable: as lexists=False
         if ctx.context_path_ignored(path):
             # Ignored files must not influence cache identity either —
             # editing them cannot change the build's output.
@@ -191,9 +200,9 @@ class AddCopyStep(BuildStep):
         if statmod.S_ISLNK(mode):
             return zlib.crc32(os.readlink(path).encode(), checksum)
         if statmod.S_ISDIR(mode):
-            for name in sorted(os.listdir(path)):
-                checksum = self._checksum_tree(
-                    ctx, os.path.join(path, name), checksum, tally)
+            for _, child, child_st in ctx.listing.children(path):
+                checksum = self._checksum_tree(ctx, child, checksum, tally,
+                                               child_st)
             return checksum
         # Per-file content summary, framed into the rolling checksum.
         # The summary (not the raw byte stream) is what chains, so a

@@ -133,7 +133,7 @@ def commit_layer(ctx: BuildContext, step: BuildStep) -> list[DigestPair]:
         ops = ctx.copy_ops
 
         def write_diffs(tw):
-            return ctx.memfs.add_layer_by_copy_ops(ops, tw)
+            return ctx.memfs.add_layer_by_copy_ops(ops, tw, ctx.listing)
     else:
         return []
 

@@ -33,5 +33,8 @@ class RunStep(BuildStep):
             raise RuntimeError(
                 "RUN step requires a modifiable filesystem (--modifyfs)")
         ctx.must_scan = True
+        # The command may write anywhere, the context included: what
+        # the build has listed of it is no longer what is on disk.
+        ctx.listing.close()
         shell.exec_command(self.working_dir, self.user, "sh", "-c", self.cmd,
                            env=ctx.exec_env)

@@ -176,6 +176,11 @@ LAYER_ENTRIES_TOTAL = "makisu_layer_entries_total"
 # (snapshot/memfs.py, one add a layer): result=slept (the newest mtime
 # scanned was still in the clock's current second) | clear.
 MTIME_WAIT_TOTAL = "makisu_mtime_wait_total"
+# Directories of a build's context tree by how a pass got their
+# children (snapshot/walk.py TreeListing, added once a pass):
+# result=listed (scandir and an lstat a child) | replayed (the build's
+# memo of an earlier pass, no file-system call).
+TREE_LISTING_DIRS_TOTAL = "makisu_tree_listing_dirs_total"
 SESSION_INVALIDATIONS = "makisu_session_invalidations_total"
 SESSION_RESIDENT_BYTES = "makisu_session_resident_bytes"
 

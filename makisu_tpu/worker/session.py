@@ -434,6 +434,11 @@ class BuildSession:
         self._snap_walk_all = True
         self._snap_baseline: walk_mod.TreeSnapshot | None = None
         self._snap_gap_paths = 0
+        # The running build's listing of the context tree
+        # (``BuildContext.listing``), held from begin_build to the end
+        # of finish_build so that the checkpoint's baseline walk
+        # replays the build's stats; None between builds.
+        self.build_listing: walk_mod.TreeListing | None = None
 
     # -- accounting --
 
@@ -636,6 +641,7 @@ class BuildSession:
         ctx.session = self
         ctx.dirty_paths = frozenset(self.pending_dirty)
         ctx.dirty_exact = self.exact
+        self.build_listing = ctx.listing
         if self.exact:
             self.hits += 1
             metrics.counter_add(SESSION_HITS)
@@ -690,12 +696,16 @@ class BuildSession:
             self.scan_memo.clear()
             self._snap_scan_dirty = True
             self._snap_walk_all = True
-        # The per-build context must not leak a dead session reference.
+        # The per-build context must not leak a dead session reference,
+        # nor the session the build's listing past its checkpoint.
         ctx.session = None
         ctx.dirty_paths = frozenset()
         ctx.dirty_exact = False
-        if ok:
-            self.checkpoint()
+        try:
+            if ok:
+                self.checkpoint()
+        finally:
+            self.build_listing = None
 
     def checkpoint(self, force: bool = False) -> dict | None:
         """Write this session's snapshot through the chunk CAS

@@ -347,8 +347,12 @@ def _write_snapshot(session, storage_dir: str) -> dict | None:
             # that shadows the submodule on a from-import.
             walk_mod = importlib.import_module(
                 "makisu_tpu.snapshot.walk")
+            # At a build's own checkpoint the build's listing replays
+            # what its passes statted (the watcher was armed before
+            # the first of them); a forced checkpoint has none.
             baseline = walk_mod.snapshot_tree(
-                session.context_dir, session._walk_blacklist)
+                session.context_dir, session._walk_blacklist,
+                session.build_listing)
             session._snap_baseline = baseline
             session._snap_gap_paths = 0
             session._snap_walk_all = True

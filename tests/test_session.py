@@ -170,6 +170,7 @@ class _MiniCtx:
         self.session = None
         self.dirty_paths: frozenset = frozenset()
         self.dirty_exact = False
+        self.listing = walk_mod.TreeListing(context_dir)
 
 
 @pytest.mark.parametrize("watcher_mode", ["inotify", "mtime-walk"])

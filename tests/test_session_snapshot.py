@@ -5,6 +5,7 @@ deliberately stale restored stat cache, the scan-memo LRU discipline,
 the lru_restore eviction label, the worker snapshot endpoints, and the
 census accounting for snapshot recipes."""
 
+import importlib
 import json
 import os
 import time
@@ -20,6 +21,8 @@ from makisu_tpu.storage import ImageStore
 from makisu_tpu.worker import WorkerClient, WorkerServer
 from makisu_tpu.worker import session as session_mod
 from makisu_tpu.worker import snapshots as snapshots_mod
+
+walk_mod = importlib.import_module("makisu_tpu.snapshot.walk")
 
 
 @pytest.fixture(autouse=True)
@@ -278,3 +281,175 @@ def test_census_accounts_snapshots_and_flags_orphans(tmp_path):
     assert snaps["orphaned"] == 1 and snaps["live"] == 0
     kinds = {f["kind"] for f in audit["findings"]}
     assert "orphaned_snapshot" in kinds
+
+
+# -- the checkpoint's baseline out of the build's listing -------------------
+
+
+def _walk_sigs(tmp_path, recipe, storage="storage"):
+    """The recipe's persisted walk baseline, all shards."""
+    chunks = snapshots_mod.SnapshotStore(
+        str(tmp_path / storage)).chunk_store()
+    sigs = {}
+    for b in range(snapshots_mod.WALK_BUCKETS):
+        sigs.update(snapshots_mod._load_shard(chunks, recipe,
+                                              f"walk/{b}") or {})
+    return sigs
+
+
+@pytest.fixture
+def watched(tmp_path):
+    """A context whose sessions get a healthy inotify watcher (only
+    those walk a persistence baseline at their first checkpoint)."""
+    ctx = _make_ctx(tmp_path)
+    probe = session_mod.InotifyWatcher(str(ctx), [])
+    healthy = probe.healthy
+    probe.close()
+    if not healthy:
+        pytest.skip("inotify unavailable on this host")
+    return ctx
+
+
+def test_first_checkpoint_replays_the_builds_listing(tmp_path, watched,
+                                                     monkeypatch):
+    """The baseline a first build's checkpoint persists holds what a
+    live ``snapshot_tree`` at that moment holds, took its stats from the
+    build's listing, and certifies against the moment before the first
+    of them; a cold acquire restores it to ``exact`` as before."""
+    ctx = watched
+    stat_times, taken = [], []
+    real_list_dir = walk_mod._list_dir
+    real_lstat = walk_mod.TreeListing.lstat
+    real_snapshot_tree = walk_mod.snapshot_tree
+
+    def list_dir(path):
+        out = real_list_dir(path)
+        if path.startswith(str(ctx)) and not taken:
+            stat_times.append(time.time_ns())
+        return out
+
+    def lstat(self, path):
+        st = real_lstat(self, path)
+        stat_times.append(time.time_ns())
+        return st
+
+    def snapshot_tree(root, blacklist=None, listing=None):
+        live = real_snapshot_tree(root, blacklist)
+        reads_before = len(stat_times)
+        taken.append((real_snapshot_tree(root, blacklist, listing),
+                      live, listing))
+        # src/ and top.txt came out of the listing: only the context's
+        # root was left to list.
+        assert len(stat_times) - reads_before <= 2
+        return taken[-1][0]
+
+    monkeypatch.setattr(walk_mod, "_list_dir", list_dir)
+    monkeypatch.setattr(walk_mod.TreeListing, "lstat", lstat)
+    monkeypatch.setattr(walk_mod, "snapshot_tree", snapshot_tree)
+    began = time.time_ns()
+    d1 = _build(tmp_path, ctx, "snap/listing:1")
+    [(snap, live, listing)] = taken
+    assert listing is not None and listing.started_ns is not None
+    assert snap.sigs == live.sigs and len(snap.sigs) == 7
+    assert began <= snap.captured_ns == listing.started_ns \
+        <= min(stat_times) < live.captured_ns
+    (recipe,) = _recipes(tmp_path)
+    assert recipe["exact"] is True
+    assert recipe["walk"]["captured_ns"] == snap.captured_ns
+    assert _walk_sigs(tmp_path, recipe) \
+        == {p: list(sig) for p, sig in live.sigs.items()}
+    mgr = session_mod.manager()
+    assert mgr.peek(str(ctx)).build_listing is None
+
+    mgr.reset()
+    assert _build(tmp_path, ctx, "snap/listing:2") == d1
+    assert mgr.snapshot_counts.get("restore", 0) == 1
+    assert mgr.peek(str(ctx)).exact is True
+    # The restore's gap delta, at begin_build, walks live: the new
+    # build's listing has nothing yet, and is not handed in.
+    assert [row[2] for row in taken[1:]] == [None]
+
+
+def test_touch_after_the_scan_is_dirty_or_fresh_in_the_recipe(
+        tmp_path, watched, monkeypatch):
+    """The checkpoint replays stats taken before the touch: the path
+    must come back dirty, from the watcher's event or from ``fresh``
+    (certified against the listing's start, not the checkpoint's)."""
+    from makisu_tpu.builder import BuildPlan
+    ctx = watched
+    victim = ctx / "src" / "m1.py"
+    real_execute = BuildPlan.execute
+
+    def execute(self):
+        manifest = real_execute(self)
+        victim.write_text("# touched after the scan\n")
+        return manifest
+
+    monkeypatch.setattr(BuildPlan, "execute", execute)
+    _build(tmp_path, ctx, "snap/touch:1")
+    (recipe,) = _recipes(tmp_path)
+    assert str(victim) in recipe["pending_dirty"] \
+        or str(victim) in recipe["walk"]["fresh"]
+    # The persisted signature is the scanned file's, so a restore's
+    # delta finds the touch as well.
+    assert _walk_sigs(tmp_path, recipe)[str(victim)] \
+        != list(walk_mod.stat_signature(os.lstat(victim)))
+
+
+@pytest.fixture(scope="module")
+def small_files_builds(tmp_path_factory):
+    """A ``small-files``-shaped tree (200 files, 40 directories, one
+    ``COPY``) built with the build's listing and with it closed from
+    the start, where every pass gets what ``listing=None`` gets."""
+    from makisu_tpu.builder import BuildPlan
+    from makisu_tpu.cache import NoopCacheManager
+    from makisu_tpu.context import BuildContext
+    from makisu_tpu.dockerfile import parse_file
+
+    base = tmp_path_factory.mktemp("listing-equality")
+    ctx_dir = base / "ctx"
+    for d in range(40):
+        (ctx_dir / "app" / f"pkg{d % 8}" / f"d{d}").mkdir(parents=True)
+    for i in range(200):
+        d = i % 40
+        body = (f"module {i}\n" * (1 + 37 * i % 900)).encode()
+        (ctx_dir / "app" / f"pkg{d % 8}" / f"d{d}"
+         / f"f{i}.js").write_bytes(body)
+    os.symlink("pkg0", ctx_dir / "app" / "current")
+    then = time.time() - 100
+    for parent, dirs, files in os.walk(ctx_dir):
+        for name in files + [parent]:
+            os.utime(os.path.join(parent, name), (then, then))
+    dockerfile = "FROM scratch\nCOPY app /app/\nCOPY app/pkg1 /one/\n"
+
+    def build(name, with_listing):
+        root = base / f"root-{name}"
+        root.mkdir()
+        store = ImageStore(str(base / f"store-{name}"))
+        ctx = BuildContext(str(root), str(ctx_dir), store, sync_wait=0.0)
+        if not with_listing:
+            ctx.listing.close()
+        plan = BuildPlan(ctx, ImageName("", "eq/app", name), [],
+                         NoopCacheManager(), parse_file(dockerfile),
+                         allow_modify_fs=False, force_commit=True)
+        manifest = plan.execute()
+        config = json.loads(store.layers.open(
+            manifest.config.digest.hex()).read())
+        return {
+            "cache_ids": [node.cache_id for stage in plan.stages
+                          for node in stage.nodes],
+            "tar_digests": config["rootfs"]["diff_ids"],
+            "gzip_digests": [str(l.digest) for l in manifest.layers],
+            "served": ctx.listing.serves(str(ctx_dir)),
+        }
+
+    return build("with", True), build("without", False)
+
+
+@pytest.mark.parametrize("what",
+                         ["cache_ids", "tar_digests", "gzip_digests"])
+def test_listing_changes_no_identity(small_files_builds, what):
+    with_listing, without = small_files_builds
+    assert with_listing["served"] and not without["served"]
+    assert len(with_listing[what]) >= 2
+    assert with_listing[what] == without[what]
