@@ -259,6 +259,17 @@ PROFILER_OVERHEAD = "makisu_profiler_overhead_ratio"
 # it does itself is what no named operation accounts for. One add a
 # close, label span=<name>.
 SPAN_SELF_SECONDS = "makisu_span_self_seconds_total"
+# The same spans' self time on the thread's own CPU clock
+# (``time.thread_time()``): self seconds less these is what the thread
+# waited while nothing it named was open (the interpreter lock, a file
+# system call, a lock), which with many builds in one process is most
+# of it. One add a close, label span=<name>.
+SPAN_SELF_CPU_SECONDS = "makisu_span_self_cpu_seconds_total"
+# worker/server.py:run_build: the building thread's CPU seconds from a
+# request's admission to the end of its tear-down, beside the record's
+# ``service_seconds``. One add a request.
+WORKER_BUILD_THREAD_CPU_SECONDS = \
+    "makisu_worker_build_thread_cpu_seconds_total"
 
 
 def stage_busy_add(stage: str, seconds: float) -> None:
@@ -396,7 +407,8 @@ class Span:
     __slots__ = ("name", "attrs", "start_unix", "duration", "error",
                  "children", "registry", "span_id", "parent_id", "_t0",
                  "peak_rss", "cpu_seconds", "late_attrs", "thread",
-                 "child_seconds")
+                 "child_seconds", "structural", "_cpu0",
+                 "child_cpu_seconds", "thread_cpu_self_seconds")
 
     def __init__(self, name: str, attrs: dict[str, Any],
                  registry: "MetricsRegistry") -> None:
@@ -416,6 +428,16 @@ class Span:
         # another thread (a copied context) overlaps and is left out.
         self.thread = threading.get_ident()
         self.child_seconds = 0.0
+        # Self time on the thread's CPU clock, of a structural span
+        # alone: it and the spans directly under it read the clock
+        # (``_cpu0`` is None on every other span), and it subtracts
+        # what those children burned on its thread. None where the
+        # span closed on another thread than it opened on: the two
+        # threads' clocks have nothing to do with each other.
+        self.structural = False
+        self._cpu0: float | None = None
+        self.child_cpu_seconds = 0.0
+        self.thread_cpu_self_seconds: float | None = None
         # Filled by the resource sampler (utils/resources.py) while the
         # span is open: peak process RSS observed, and the CPU seconds
         # charged to this span while it was an open LEAF. None = never
@@ -448,6 +470,9 @@ class Span:
             out["parent_id"] = self.parent_id
         if self.children and self.duration is not None:
             out["self_seconds"] = round(self.self_seconds, 6)
+        if self.thread_cpu_self_seconds is not None:
+            out["thread_cpu_self_seconds"] = round(
+                self.thread_cpu_self_seconds, 6)
         if self.attrs:
             out["attrs"] = dict(self.attrs)
         if self.error:
@@ -807,13 +832,17 @@ def span(name: str, *, structural: bool = False,
     mirror onto the build event bus (no-op unless a sink is bound),
     and onto the profiler's host timeline once a backend is up.
     ``structural`` is the opener's word that the span names a place
-    and no operation: its self time goes to ``SPAN_SELF_SECONDS``."""
+    and no operation: its self time goes to ``SPAN_SELF_SECONDS`` and,
+    on the thread's CPU clock, to ``SPAN_SELF_CPU_SECONDS``."""
     reg = active_registry()
     parent = _current_span.get()
     if parent is None or parent.registry is not reg:
         parent = reg.root
     s = Span(name, attrs, reg)
     s.parent_id = parent.span_id
+    s.structural = structural
+    if structural or parent.structural:
+        s._cpu0 = time.thread_time()
     with reg._lock:
         parent.children.append(s)
     _open_spans[id(s)] = s
@@ -836,6 +865,15 @@ def span(name: str, *, structural: bool = False,
             _current_span.reset(token)
             if parent.thread == s.thread:
                 parent.child_seconds += s.duration
+            if s._cpu0 is not None and threading.get_ident() == s.thread:
+                cpu = time.thread_time() - s._cpu0
+                if parent.structural and parent.thread == s.thread:
+                    parent.child_cpu_seconds += cpu
+                if structural:
+                    s.thread_cpu_self_seconds = max(
+                        cpu - s.child_cpu_seconds, 0.0)
+                    counter_add(SPAN_SELF_CPU_SECONDS,
+                                s.thread_cpu_self_seconds, span=name)
             if structural:
                 counter_add(SPAN_SELF_SECONDS, s.self_seconds, span=name)
             events.emit("span_end", name=name, span_id=s.span_id,
@@ -844,6 +882,9 @@ def span(name: str, *, structural: bool = False,
                         # A leaf's self time is its duration.
                         **({"self_seconds": round(s.self_seconds, 6)}
                            if s.children else {}),
+                        **({"thread_cpu_self_seconds":
+                            round(s.thread_cpu_self_seconds, 6)}
+                           if s.thread_cpu_self_seconds is not None else {}),
                         **({"attrs": s.late_attrs}
                            if s.late_attrs else {}),
                         **({"error": s.error} if s.error else {}))

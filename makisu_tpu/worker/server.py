@@ -258,16 +258,20 @@ class _BuildRecord:
             self.exit_code = exit_code
             self.finished_mono = time.monotonic()
 
-    def note_service(self, admitted: float, done: float) -> None:
+    def note_service(self, admitted: float, done: float,
+                     thread_cpu: float) -> None:
         """The split of ``done - admitted``; a request whose command
-        never opened a root span was set-up all through."""
+        never opened a root span was set-up all through.
+        ``thread_cpu`` is the building thread's own CPU seconds over
+        the same interval."""
         with self._mu:
             opened = self._root_open_mono or done
             closed = self._root_close_mono or done
             self.service = {
                 "setup_seconds": round(opened - admitted, 6),
                 "teardown_seconds": round(done - closed, 6),
-                "service_seconds": round(done - admitted, 6)}
+                "service_seconds": round(done - admitted, 6),
+                "thread_cpu_seconds": round(thread_cpu, 6)}
 
     def latency_seconds(self) -> float:
         """Queue wait + execution: arrival to completion."""
@@ -1343,7 +1347,18 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
         Admission happens here: past ``--max-concurrent-builds``
         executing builds, the request thread waits its FIFO turn. The
         wait lands on ``record`` (queue split in the terminal frame,
-        queue-wait histograms, ``/builds``)."""
+        queue-wait histograms, ``/builds``).
+
+        ``thread_cpu_seconds`` on the record is this thread's own CPU
+        (``time.thread_time()``) from admission to the end of the
+        ``finally`` below, the interval of ``service_seconds``: the
+        difference is what the thread waited (the interpreter lock
+        among the other builds' threads, the file system, the device,
+        the sink's ring). It leaves out the threads that work for the
+        build beside this one: the native sink's compressor (stage
+        ``compress``), the chunk store's probe and ingest pools
+        (``chunk_index``), the cache-push threads and the shared hash
+        service's dispatcher (the ``FeedClock`` stages)."""
         from makisu_tpu import cli
         from makisu_tpu.utils import events, metrics
         from makisu_tpu.utils import logging as log
@@ -1365,6 +1380,7 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
         queue_wait = self._admission.acquire()
         record.start_running(queue_wait)
         admitted = time.monotonic()
+        admitted_cpu = time.thread_time()
         # Inbound trace context: bound for cli.main to adopt into the
         # build's registry (the build's spans, events, and outbound
         # traceparents all join the caller's trace). Parsed here too so
@@ -1499,7 +1515,10 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
             events.reset_sink(record_token)
             events.reset_sink(events_token)
             log.reset_build_sink(token)
-            record.note_service(admitted, time.monotonic())
+            thread_cpu = time.thread_time() - admitted_cpu
+            record.note_service(admitted, time.monotonic(), thread_cpu)
+            metrics.counter_add(metrics.WORKER_BUILD_THREAD_CPU_SECONDS,
+                                thread_cpu)
 
     def _active_builds(self) -> int:
         with self._health_mu:

@@ -52,6 +52,20 @@ def _no_mounts():
     mountinfo.set_mountpoints_for_testing(None)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _shared_hash_stays_in_its_file():
+    """A ``WorkerServer`` turns the process's TPU hashers to the shared
+    hash service for good (``MAKISU_TPU_SHARED_HASH``). What one test
+    file's workers set must not decide the route of the next file's
+    builds: which files share a process is the scheduler's choice."""
+    before = os.environ.get("MAKISU_TPU_SHARED_HASH")
+    yield
+    if before is None:
+        os.environ.pop("MAKISU_TPU_SHARED_HASH", None)
+    else:
+        os.environ["MAKISU_TPU_SHARED_HASH"] = before
+
+
 def cas_entry_path(root, name: str) -> str:
     """Where the CAS directory ``root`` keeps ``name``, for a test that
     ages, corrupts or removes an entry behind the store's back. The

@@ -169,8 +169,9 @@ def _new_metrics_list_their_cells():
     assert cells_of["commit_mb_per_s"] == four
     assert cells_of["compress_s_per_build"] == four
     assert cells_of["feed_host_s_per_build"] == four
+    # PR 38 appended its cell: 32 sinks' rings at once.
     assert cells_of["process_rss_peak_mb"] == [
-        CELL, "monorepo-cold", "small-files-edit"]
+        CELL, "monorepo-cold", "small-files-edit", "farm-concurrent-churn"]
     # Put at the end of the list at their PR, together and in order;
     # later PRs append after them.
     names = [m["name"] for m in BENCHMARK["per_layer"]]

@@ -137,9 +137,10 @@ def clocked(monkeypatch):
     import types
 
     from makisu_tpu.utils import events
-    clock = types.SimpleNamespace(now=100.0)
+    clock = types.SimpleNamespace(now=100.0, cpu=10.0)
     monkeypatch.setattr(metrics, "time", types.SimpleNamespace(
-        monotonic=lambda: clock.now, time=lambda: 1e9 + clock.now))
+        monotonic=lambda: clock.now, time=lambda: 1e9 + clock.now,
+        thread_time=lambda: clock.cpu))
     reg = metrics.MetricsRegistry()
     token = metrics.set_build_registry(reg)
     ended = {}
@@ -223,31 +224,44 @@ def test_self_seconds_counter_grows_for_the_structural_spans_alone(clocked):
         metrics.SPAN_SELF_SECONDS, "span")
     with metrics.span("build", structural=True):
         clock.now += 0.5
+        clock.cpu += 0.25
         with metrics.span("context_scan"):
             clock.now += 1.0
+            clock.cpu += 0.5
             with metrics.span("copy_checksum"):
                 clock.now += 1.0
+                clock.cpu += 1.0
             with metrics.span("step"):          # a namesake, not marked
                 clock.now += 8.0
         with metrics.span("stage", structural=True):
             clock.now += 0.25
             with metrics.span("step", structural=True):     # a leaf
                 clock.now += 0.125
+                clock.cpu += 0.125
             with metrics.span("step", structural=True):
                 clock.now += 0.0625
+                clock.cpu += 0.03125
                 with metrics.span("commit_layer"):
                     clock.now += 2.0
+                    clock.cpu += 0.5
     want = {"build": 0.5, "stage": 0.25, "step": 0.125 + 0.0625}
     assert reg.counter_by_label(metrics.SPAN_SELF_SECONDS, "span") \
         == pytest.approx(want)
+    # The same on the thread's CPU clock: what a child burned, however
+    # deep, is the child's; `stage` waited all its own quarter second.
+    assert reg.counter_by_label(metrics.SPAN_SELF_CPU_SECONDS, "span") \
+        == pytest.approx({"build": 0.25, "stage": 0.0,
+                          "step": 0.125 + 0.03125})
     # The mark is the opener's alone: it is no attribute of the span.
     [root] = reg.report()["spans"]
     assert "attrs" not in root
     # The worker's /metrics serves the process's sum of the same.
     after = metrics.global_registry().counter_by_label(
         metrics.SPAN_SELF_SECONDS, "span")
-    assert {k: after[k] - in_process.get(k, 0.0) for k in after} \
-        == pytest.approx(want)
+    # (Other commands' root spans, from tests that ran before this one
+    # in the process, are in it too, and did not grow.)
+    grown = {k: after[k] - in_process.get(k, 0.0) for k in after}
+    assert {k: v for k, v in grown.items() if v} == pytest.approx(want)
     assert 'makisu_span_self_seconds_total{span="stage"}' \
         in metrics.render_prometheus()
 

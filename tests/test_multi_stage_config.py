@@ -176,8 +176,9 @@ def _entry_in_benchmark():
     assert len(cell["why"]) <= 200
     assert [w["name"] for w in BENCHMARK["workloads"]
             if w["config"] == "multi-stage-small"] == [CELL]
-    assert BENCHMARK["workloads"][-1] is cell
-    assert BENCHMARK["configs"][-1] is entry
+    # The last of both lists at its PR; PR 38 appended after it.
+    assert BENCHMARK["workloads"][6] is cell
+    assert BENCHMARK["configs"][4] is entry
 
 
 def _states_what_a_deployment_states():
@@ -263,16 +264,18 @@ def _new_metrics_list_their_cells():
         == [CELL, "monorepo-edit"]
     assert by_name["session_finish_s_per_build"]["workloads"] == [
         CELL, "farm-churn", "farm-unchanged", "monorepo-edit",
-        "small-files-edit"]
+        "small-files-edit", "farm-concurrent-churn"]
     for name in NEW_READERS:
         assert by_name[name]["moves"] == "build_p50_s"
         assert by_name[name]["better"] == "lower"
-    # Appended, never inserted: the cell is the last of every list it
-    # joined.
+    # Appended, never inserted: the cell was the last of every list it
+    # joined, and only PR 38's cell has been appended after it.
     for m in BENCHMARK["per_layer"][:first] \
             + BENCHMARK["per_layer"][first + 6:] + BENCHMARK["end_to_end"]:
-        if CELL in m.get("workloads", ()):
-            assert m["workloads"][-1] == CELL, m["name"]
+        listed = [w for w in m.get("workloads", ())
+                  if w != "farm-concurrent-churn"]
+        if CELL in listed:
+            assert listed[-1] == CELL, m["name"]
 
 
 @pytest.mark.parametrize("statement", [

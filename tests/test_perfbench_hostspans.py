@@ -281,14 +281,14 @@ def test_service_metrics_list_their_cells():
         assert by_name[name]["workloads"] == every, name
     assert by_name["build_setup_s_per_build"]["workloads"] == [
         "farm-churn", "farm-unchanged", "monorepo-edit",
-        "multi-stage-small-edit"]
+        "multi-stage-small-edit", "farm-concurrent-churn"]
     assert by_name["session_begin_s_per_build"]["workloads"] == [
         "farm-churn", "farm-unchanged", "monorepo-edit", "small-files-edit",
-        "huge-layer-edit", "multi-stage-small-edit"]
+        "huge-layer-edit", "multi-stage-small-edit", "farm-concurrent-churn"]
     assert by_name["wait_for_push_s_per_build"]["workloads"] == [
         "monorepo-cold", "monorepo-edit", "huge-layer-edit"]
     assert by_name["save_manifest_s_per_build"]["workloads"] == [
-        "farm-churn", "farm-unchanged"]
+        "farm-churn", "farm-unchanged", "farm-concurrent-churn"]
     for name in ("unspanned_s_per_build", "service_s_per_build",
                  "request_overhead_s_per_build", "build_setup_s_per_build",
                  "session_begin_s_per_build", "wait_for_push_s_per_build",
@@ -308,7 +308,7 @@ def test_every_new_metric_has_its_reader_and_its_cells():
     cells_of = {m["name"]: m["workloads"] for m in benchmark["per_layer"]}
     every = ["monorepo-cold", "farm-churn", "monorepo-edit",
              "farm-unchanged", "small-files-edit", "huge-layer-edit",
-             "multi-stage-small-edit"]
+             "multi-stage-small-edit", "farm-concurrent-churn"]
     assert cells_of["idle_unspanned_pct"] == every
     assert cells_of["sync_os_sync_s_per_build"] == every
     assert cells_of["chunk_index_s_per_build"] == [
@@ -320,7 +320,8 @@ def test_every_new_metric_has_its_reader_and_its_cells():
 
 @pytest.mark.parametrize("cell", [
     "monorepo-cold", "farm-churn", "monorepo-edit", "farm-unchanged",
-    "small-files-edit", "huge-layer-edit", "multi-stage-small-edit"])
+    "small-files-edit", "huge-layer-edit", "multi-stage-small-edit",
+    "farm-concurrent-churn"])
 def test_every_cell_finds_its_files_and_a_reader_for_each_metric(cell):
     """What ``run.py`` looks up by name for a cell: configuration, mix,
     reference, and a reader for every metric either kind of run

@@ -195,6 +195,172 @@ def test_the_phases_of_a_build_once_each_under_the_root_in_order(
     assert by_span["build"] == pytest.approx(top["self_seconds"], abs=1e-5)
 
 
+def _burn(cpu_seconds):
+    """Spin until this thread's own CPU clock has moved that far."""
+    end = time.thread_time() + cpu_seconds
+    while time.thread_time() < end:
+        pass
+
+
+def _self_cpu(body):
+    """Run ``body`` under a registry of its own; returns ({span name:
+    its ``span_end`` events}, growth of the self-CPU counter by span)."""
+    seen = []
+    reg = metrics.MetricsRegistry()
+    reg_token = metrics.set_build_registry(reg)
+    sink_token = events.add_sink(seen.append)
+    try:
+        body()
+    finally:
+        events.reset_sink(sink_token)
+        metrics.reset_build_registry(reg_token)
+    ends = {}
+    for e in seen:
+        if e["type"] == "span_end":
+            ends.setdefault(e["name"], []).append(e)
+    return ends, reg.counter_by_label(metrics.SPAN_SELF_CPU_SECONDS, "span")
+
+
+def _a_childs_cpu_is_the_childs():
+    def body():
+        with metrics.span("step", structural=True):
+            _burn(0.03)
+            with metrics.span("commit_layer"):
+                _burn(0.15)
+    ends, counted = _self_cpu(body)
+    [step] = ends["step"]
+    assert 0.03 <= step["thread_cpu_self_seconds"] < 0.10
+    assert step["self_seconds"] >= step["thread_cpu_self_seconds"] - 1e-3
+    # The child read the clock for its parent's sake and says nothing.
+    assert "thread_cpu_self_seconds" not in ends["commit_layer"][0]
+    assert counted == {"step": pytest.approx(
+        step["thread_cpu_self_seconds"], abs=1e-5)}
+
+
+def _a_sleep_is_self_time_and_no_cpu():
+    def body():
+        with metrics.span("stage", structural=True):
+            _burn(0.02)
+            time.sleep(0.2)
+            with metrics.span("pull_cache_layers"):
+                time.sleep(0.01)
+    ends, _ = _self_cpu(body)
+    [stage] = ends["stage"]
+    assert stage["self_seconds"] >= 0.22
+    assert 0.02 <= stage["thread_cpu_self_seconds"] < 0.12
+
+
+def _a_structural_child_keeps_its_own():
+    def body():
+        with metrics.span("build", structural=True):
+            _burn(0.02)
+            with metrics.span("stage", structural=True):
+                _burn(0.12)
+    ends, counted = _self_cpu(body)
+    assert 0.02 <= ends["build"][0]["thread_cpu_self_seconds"] < 0.08
+    assert ends["stage"][0]["thread_cpu_self_seconds"] >= 0.12
+    assert sorted(counted) == ["build", "stage"]
+
+
+def _only_a_structural_span_and_its_children_read_the_clock():
+    spans = {}
+
+    def body():
+        with metrics.span("plain") as plain:
+            with metrics.span("step", structural=True) as step:
+                with metrics.span("commit_layer") as commit:
+                    with metrics.span("tar_write") as tar:
+                        spans.update(plain=plain, step=step, commit=commit,
+                                     tar=tar)
+    ends, counted = _self_cpu(body)
+    assert {name: s._cpu0 is not None for name, s in spans.items()} \
+        == {"plain": False, "step": True, "commit": True, "tar": False}
+    assert sorted(counted) == ["step"]
+    for name in ("plain", "commit_layer", "tar_write"):
+        assert "thread_cpu_self_seconds" not in ends[name][0]
+
+
+def _a_span_closed_on_another_thread_records_none():
+    """Two threads' CPU clocks have nothing to do with each other: the
+    difference would be a number, and wrong."""
+    import contextvars
+    import threading
+    managers = {}
+    opened, closed = threading.Event(), threading.Event()
+
+    def open_them():
+        managers["outer"] = metrics.span("build", structural=True)
+        managers["outer"].__enter__()
+        managers["inner"] = metrics.span("stage", structural=True)
+        managers["inner"].__enter__()
+        _burn(0.05)
+
+    def opener(context):
+        context.run(open_them)
+        opened.set()
+        # Alive until the other has closed them: a thread's ident is
+        # given out again once it has ended.
+        assert closed.wait(timeout=30)
+
+    def closer(context):
+        assert opened.wait(timeout=30)
+        _burn(0.02)
+        for name in ("inner", "outer"):
+            context.run(managers[name].__exit__, None, None, None)
+        closed.set()
+
+    def body():
+        # One context for both threads, copied where the registry and
+        # the sink are bound: the second resets what the first set.
+        context = contextvars.copy_context()
+        threads = [threading.Thread(target=fn, args=(context,))
+                   for fn in (opener, closer)]
+        for thread in threads:
+            thread.start()
+        for thread in threads:
+            thread.join(timeout=60)
+            assert not thread.is_alive()
+        assert closed.is_set()
+    ends, counted = _self_cpu(body)
+    for name in ("build", "stage"):
+        [ended] = ends[name]
+        assert ended["duration"] > 0
+        assert "thread_cpu_self_seconds" not in ended
+    assert counted == {}
+
+
+@pytest.mark.parametrize("case", [
+    _a_childs_cpu_is_the_childs, _a_sleep_is_self_time_and_no_cpu,
+    _a_structural_child_keeps_its_own,
+    _only_a_structural_span_and_its_children_read_the_clock,
+    _a_span_closed_on_another_thread_records_none],
+    ids=lambda f: f.__name__.strip("_"))
+def test_a_structural_spans_self_time_on_the_threads_cpu_clock(case):
+    case()
+
+
+def test_a_builds_structural_spans_carry_their_cpu_self_time(
+        tmp_path, no_sleep):
+    _context(tmp_path)
+    event_log, report = _build(tmp_path, "cpu", 1)
+    ended = [e for e in event_log if e["type"] == "span_end"]
+    marked = [e for e in ended if "thread_cpu_self_seconds" in e]
+    assert sorted({e["name"] for e in marked}) == ["build", "stage", "step"]
+    for e in marked:
+        assert 0.0 <= e["thread_cpu_self_seconds"] \
+            <= e.get("self_seconds", e["duration"]) + 0.005
+    by_span = {s["labels"]["span"]: s["value"] for s in
+               report["counters"][metrics.SPAN_SELF_CPU_SECONDS]}
+    assert sorted(by_span) == ["build", "stage", "step"]
+    for name, value in by_span.items():
+        assert value == pytest.approx(sum(
+            e["thread_cpu_self_seconds"] for e in marked
+            if e["name"] == name), abs=1e-4)
+    [top] = report["spans"]
+    assert top["thread_cpu_self_seconds"] == pytest.approx(
+        by_span["build"], abs=1e-5)
+
+
 def test_the_sessions_release_covers_the_setup_spans_close(tmp_path):
     """The lease is taken inside ``build_setup``; an exit that lands in
     that span's close (a signal handler's, during the frame's write)

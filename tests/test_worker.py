@@ -478,13 +478,18 @@ def test_terminal_record_accounts_the_service_whole(tmp_path, worker):
     assert terminal["service_seconds"] == pytest.approx(
         terminal["elapsed_seconds"] - terminal["queue_wait_seconds"],
         abs=0.010)
-    # /builds carries the same three once the request is done.
+    # The building thread's own CPU over the same interval: some, and
+    # no more than the interval (the clocks differ in resolution).
+    assert 0.0 < terminal["thread_cpu_seconds"] \
+        <= terminal["service_seconds"] + 0.005
+    # /builds carries the same four once the request is done.
     row = next(r for r in client.builds()["recent"]
                if r["tag"] == "worker/service:1")
-    assert {k: row[k] for k in ("setup_seconds", "teardown_seconds",
-                                "service_seconds")} \
-        == {k: terminal[k] for k in ("setup_seconds", "teardown_seconds",
-                                     "service_seconds")}
+    fields = ("setup_seconds", "teardown_seconds", "service_seconds",
+              "thread_cpu_seconds")
+    assert {k: row[k] for k in fields} == {k: terminal[k] for k in fields}
+    from makisu_tpu.utils import metrics
+    assert f"{metrics.WORKER_BUILD_THREAD_CPU_SECONDS} " in client.metrics()
 
 
 def test_a_request_that_opens_no_root_span_is_set_up_all_through(
