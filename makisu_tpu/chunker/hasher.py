@@ -209,7 +209,9 @@ class LayerSink:
 class _NativeTarWriter:
     """tarfile.TarFile-shaped writer over the native pipeline: headers
     are rendered by Python's tarfile (byte-identical PAX output); file
-    content, padding, hashing, and compression run in C++."""
+    content, padding, hashing, and compression run in C++.
+    ``add_entries`` takes a layer's entries a batch at a time, and the
+    sink reads the batch's files ahead on threads of its own."""
 
     import tarfile as _tarfile
     _FMT = (_tarfile.PAX_FORMAT, _tarfile.ENCODING, "surrogateescape")
@@ -248,6 +250,29 @@ class _NativeTarWriter:
         self._sink._handle.write_file(path, tarinfo.size)
         pad = (512 - tarinfo.size % 512) % 512
         self._offset += len(buf) + tarinfo.size + pad
+
+    @property
+    def add_entries(self):
+        """``_add_entries``, or ``None`` with a prebuilt library from
+        before ``lsk_write_entries``: ``Layer.commit`` then goes entry
+        by entry."""
+        if self._sink._handle.takes_entries:
+            return self._add_entries
+        return None
+
+    def _add_entries(self, items: list[tuple]) -> None:
+        """A batch of ``(tarinfo, path | None)`` in tar order, in one
+        call into C++: each header as ``addfile`` renders it, and for a
+        path (a regular file with content) the file's content and
+        padding, as ``add_path`` writes them."""
+        headers = [tarinfo.tobuf(*self._FMT) for tarinfo, _ in items]
+        sizes = [0 if path is None else tarinfo.size
+                 for tarinfo, path in items]
+        self._sink._handle.write_entries(
+            headers, [path for _, path in items], sizes)
+        self._offset += sum(map(len, headers)) \
+            + sum(size + -size % 512 for size in sizes)
+        events.note_progress()  # hashing is progress (see LayerSink)
 
     def close(self) -> None:
         if self._closed:
@@ -320,8 +345,9 @@ class NativeLayerSink:
 
     def abort(self) -> None:
         """A commit that died between two entries: stop and join the
-        library's compressor thread now, while ``out`` is still open
-        (``__del__`` would, but only once the traceback lets go)."""
+        library's compressor and reader threads now, while ``out`` is
+        still open (``__del__`` would, but only once the traceback
+        lets go)."""
         self._handle.close()
 
     def finish(self) -> LayerCommit:
@@ -335,12 +361,22 @@ class NativeLayerSink:
             # the producer is the brake.
             busy = self._handle.compress_seconds()
             waited = self._handle.wait_seconds()
+            prefetch = self._handle.prefetch_stats()
         finally:
             self._handle.close()
         if busy is not None:
             metrics.stage_busy_add(metrics.COMPRESS_STAGE, busy)
         if waited is not None:
             metrics.stage_busy_add("compress_wait", waited)
+        if prefetch is not None:
+            # A part of tar_write: what this thread spent blocked on
+            # one of the sink's readers, and how each file's bytes came.
+            metrics.stage_busy_add("read_wait", prefetch[0])
+            for result, n in zip(("ready", "waited", "streamed"),
+                                 prefetch[1:]):
+                if n:
+                    metrics.counter_add(metrics.SINK_PREFETCH_FILES_TOTAL,
+                                        n, result=result)
         metrics.counter_add("makisu_bytes_hashed_total", self._nbytes,
                             backend="native", path="layer_sink")
         backend = self.backend_id.split("-", 1)[0]

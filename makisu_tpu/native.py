@@ -25,6 +25,7 @@ flags are probed per translation unit, see native/Makefile).
 from __future__ import annotations
 
 import ctypes
+import itertools
 import os
 import subprocess
 import threading
@@ -202,13 +203,23 @@ def _load_lsk() -> ctypes.CDLL | None:
             return _lsk_lib
         # Newer symbols, each bound separately: a prebuilt library from
         # before one still commits layers, and reports no such seconds.
-        for name in ("lsk_compress_seconds", "lsk_wait_seconds"):
+        # Without lsk_write_entries a layer commits entry by entry.
+        u64_p = ctypes.POINTER(ctypes.c_uint64)
+        for name, restype, argtypes in (
+                ("lsk_compress_seconds", ctypes.c_double, []),
+                ("lsk_wait_seconds", ctypes.c_double, []),
+                ("lsk_write_entries", ctypes.c_int, [
+                    ctypes.c_size_t, ctypes.c_char_p, u64_p,
+                    ctypes.POINTER(ctypes.c_char_p), u64_p,
+                    ctypes.POINTER(ctypes.c_int64)]),
+                ("lsk_prefetch_stats", None, [
+                    ctypes.POINTER(ctypes.c_double), u64_p])):
             try:
                 fn = getattr(lib, name)
             except AttributeError:
                 continue
-            fn.restype = ctypes.c_double
-            fn.argtypes = [ctypes.c_void_p]
+            fn.restype = restype
+            fn.argtypes = [ctypes.c_void_p] + argtypes
         return _lsk_lib
 
 
@@ -541,7 +552,12 @@ class LayerSinkHandle:
         ctypes callbacks cannot propagate exceptions into C; a failure
         is recorded and re-raised by the NEXT write/finish call, so a
         dying chunker fails the build instead of silently producing
-        wrong (cache-identity-bearing) fingerprints."""
+        wrong (cache-identity-bearing) fingerprints.
+
+        The sink hands ``fn`` the stream through a staging buffer, on
+        the writing thread: once a filled 256 KiB and once at the end
+        of every call, so each call returns with ``fn`` having seen all
+        it wrote."""
         self._tap_error: list = []
 
         def trampoline(ptr, n, _user):
@@ -565,8 +581,39 @@ class LayerSinkHandle:
         self._check_tap()
 
     def write_file(self, path: str, size: int) -> None:
-        rc = self._lib.lsk_write_file(
-            self._live(), os.fsencode(path), size)
+        self._raise_for(self._lib.lsk_write_file(
+            self._live(), os.fsencode(path), size), path, size)
+
+    @property
+    def takes_entries(self) -> bool:
+        """Whether the library has ``lsk_write_entries`` (a prebuilt one
+        from before it commits entry by entry)."""
+        return getattr(self._lib, "lsk_write_entries", None) is not None
+
+    def write_entries(self, headers: list[bytes],
+                      paths: list[str | None], sizes: list[int]) -> None:
+        """A batch of tar entries in stream order, in one call with the
+        interpreter lock let go: each entry's rendered header, then,
+        where its path is not ``None`` and its size not 0, the file's
+        first ``size`` bytes and the padding. The sink reads the
+        batch's files of up to 8 MiB ahead on threads of its own. A
+        file that cannot be read, or ends before its size, raises
+        ``OSError`` naming it; nothing of a later entry has reached the
+        stream then, and the sink stays failed."""
+        n = len(headers)
+        offsets = (ctypes.c_uint64 * (n + 1))(
+            0, *itertools.accumulate(map(len, headers)))
+        at_fault = ctypes.c_int64(-1)
+        rc = self._lib.lsk_write_entries(
+            self._live(), n, b"".join(headers), offsets,
+            (ctypes.c_char_p * n)(
+                *[None if p is None else os.fsencode(p) for p in paths]),
+            (ctypes.c_uint64 * n)(*sizes), ctypes.byref(at_fault))
+        i = at_fault.value
+        self._raise_for(rc, paths[i] if rc else None, sizes[i] if rc else 0)
+
+    def _raise_for(self, rc: int, path: str | None, size: int) -> None:
+        """What a file-writing call's return code means."""
         if rc == -2:
             raise OSError(f"native layer sink could not read {path}")
         if rc == -3:
@@ -603,13 +650,27 @@ class LayerSinkHandle:
         ``None`` from a library that predates the thread."""
         return self._seconds("lsk_wait_seconds")
 
+    def prefetch_stats(self) -> tuple[float, int, int, int] | None:
+        """(seconds the writer was blocked on one of the sink's reader
+        threads, files a reader had ready, files the writer waited for,
+        files it streamed itself); ``None`` from a library that
+        predates the readers."""
+        fn = getattr(self._lib, "lsk_prefetch_stats", None)
+        if fn is None:
+            return None
+        read_wait = ctypes.c_double(0)
+        counts = (ctypes.c_uint64 * 3)()
+        fn(self._live(), ctypes.byref(read_wait), counts)
+        return (read_wait.value, *counts)
+
     def _seconds(self, symbol: str) -> float | None:
         fn = getattr(self._lib, symbol, None)
         return float(fn(self._live())) if fn is not None else None
 
     def close(self) -> None:
         """Frees the sink; one that was never finished (its build died
-        between two entries) first stops and joins its compressor."""
+        between two entries) first stops and joins its compressor and
+        its readers."""
         if self._handle:
             self._lib.lsk_free(self._handle)
             self._handle = None

@@ -35,6 +35,12 @@ class ContentEntry:
                data: bytes | None = None) -> None:
         tario.write_entry(tw, self.src, self.hdr, data=data)
 
+    def header(self) -> tuple[tarfile.TarInfo, str | None]:
+        """The entry's tar header, and the file its content streams
+        from where it has any (``tario.write_entry``'s rule)."""
+        has_content = self.hdr.isreg() and self.hdr.size > 0
+        return self.hdr, self.src if has_content else None
+
 
 @dataclasses.dataclass
 class WhiteoutEntry:
@@ -44,10 +50,12 @@ class WhiteoutEntry:
 
     def commit(self, tw: tarfile.TarFile,
                data: bytes | None = None) -> None:
+        tw.addfile(self.header()[0])
+
+    def header(self) -> tuple[tarfile.TarInfo, None]:
         d, b = os.path.split(self.deleted)
-        hdr = tarfile.TarInfo(
-            pathutils.rel_path(os.path.join(d, WHITEOUT_PREFIX + b)))
-        tw.addfile(hdr)
+        return tarfile.TarInfo(
+            pathutils.rel_path(os.path.join(d, WHITEOUT_PREFIX + b))), None
 
 
 class _ReadAhead:
@@ -62,7 +70,10 @@ class _ReadAhead:
     - **warm** (the native ``add_path`` writer, whose C++ read path is
       faster than a Python bytes hand-off): the task reads and
       discards, purely to populate the page cache; the writer still
-      streams content in C++.
+      streams content in C++. Only with a prebuilt library from before
+      ``lsk_write_entries``: a native writer that has ``add_entries``
+      takes the entries by the batch, the sink reads ahead on threads
+      of its own, and no ``_ReadAhead`` is made.
 
     Prefetch results are advisory: any read error, or a file whose size
     changed since its header was recorded, yields ``None`` and the
@@ -202,13 +213,36 @@ class Layer:
             counts[kind] = counts.get(kind, 0) + 1
         return counts
 
+    # A batch handed to a native writer in one call: few enough entries
+    # that the progress stamp, a tap's error and an abort stay timely,
+    # and no more content than the sink's read-ahead ring holds.
+    _BATCH_ENTRIES = 256
+    _BATCH_BYTES = 16 * 1024 * 1024
+
     def commit(self, tw: tarfile.TarFile,
                workers: int | None = None) -> None:
         """Write entries in sorted path order (cache-identity-bearing).
-        With ``workers > 1`` (default: concurrency.hash_workers), file
-        content prefetches ahead of the writer on the commit pool; the
-        produced tar bytes are identical either way."""
+        A writer that has ``add_entries`` (the native sink) takes them
+        by the batch, whiteouts and header-only entries in their sorted
+        place, and reads the files ahead itself. Else, with ``workers >
+        1`` (default: concurrency.hash_workers), file content
+        prefetches ahead of the writer on the commit pool. The produced
+        tar bytes are identical either way."""
         keys = sorted(self.entries)
+        add_entries = getattr(tw, "add_entries", None)
+        if add_entries is not None:
+            batch, content = [], 0
+            for key in keys:
+                hdr, src = item = self.entries[key].header()
+                batch.append(item)
+                content += hdr.size if src is not None else 0
+                if (len(batch) == self._BATCH_ENTRIES
+                        or content >= self._BATCH_BYTES):
+                    add_entries(batch)
+                    batch, content = [], 0
+            if batch:
+                add_entries(batch)
+            return
         if workers is None:
             workers = concurrency.hash_workers()
         ra = None

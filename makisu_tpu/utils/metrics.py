@@ -43,10 +43,10 @@ _LabelKey = tuple[tuple[str, str], ...]
 DEFAULT_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 10.0,
                    30.0, 60.0)
 
-# The layer-commit pipeline's per-stage telemetry (read_ahead, gear_scan,
-# chunk_sha, compress, tar_write). One name pair shared by every stage —
-# and by the `makisu-tpu report` bottleneck section — so the series can
-# never drift apart.
+# The layer-commit pipeline's per-stage telemetry (read_ahead, read_wait,
+# gear_scan, chunk_sha, compress, tar_write). One name pair shared by
+# every stage — and by the `makisu-tpu report` bottleneck section — so
+# the series can never drift apart.
 COMMIT_STAGE_BUSY = "makisu_commit_stage_busy_seconds"
 COMMIT_QUEUE_DEPTH = "makisu_commit_queue_depth"
 
@@ -172,6 +172,13 @@ LAYER_REPLAY_TOTAL = "makisu_layer_replay_total"
 # Entries of each committed layer as its tar holds them, kind=file|dir|
 # symlink|other|whiteout (snapshot/memfs.py, added once a layer).
 LAYER_ENTRIES_TOTAL = "makisu_layer_entries_total"
+# Regular files with content a native sink put into a layer, by how
+# their bytes came (chunker/hasher.py, added once a layer at the sink's
+# finish): result=ready (one of the sink's reader threads had them
+# read when the writer reached them) | waited (the writer waited for a
+# reader) | streamed (the writer read them itself: over 8 MiB, a batch
+# with fewer than two files for the readers, the per-entry path).
+SINK_PREFETCH_FILES_TOTAL = "makisu_sink_prefetch_files_total"
 # Committed layers by what the wait for tar's one-second mtimes did
 # (snapshot/memfs.py, one add a layer): result=slept (the newest mtime
 # scanned was still in the clock's current second) | clear.

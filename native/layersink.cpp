@@ -25,18 +25,43 @@
 //                                    out_fd whenever it likes)
 //   lsk_write(h, data, n)            raw tar bytes (headers, inline data)
 //   lsk_write_file(h, path, size)    file content + 512-byte padding
+//   lsk_write_entries(h, n, headers, header_offsets, paths, sizes,
+//                     &failed_index) a batch of entries in stream order:
+//                                    each entry's rendered header, then
+//                                    for a path of size > 0 its content
+//                                    and padding, the bytes lsk_write +
+//                                    lsk_write_file write; the batch's
+//                                    files of up to 8 MiB are read ahead
+//                                    on the sink's reader threads
 //   lsk_finish(h, tar_sha32, gz_sha32, &gz_size, &tar_size)
 //   lsk_compress_seconds(h)          seconds the gzip stream kept a thread busy
 //   lsk_wait_seconds(h)              seconds the caller was blocked on that stream
+//   lsk_prefetch_stats(h, &read_wait_s, counts[3])
+//                                    seconds the caller was blocked on a
+//                                    reader; files it found ready, waited
+//                                    for, streamed itself
 //   lsk_free(h)                      also stops a sink that was never finished
 // All int-returning calls: 0 = ok, negative = error.
 //
 // Threads. The caller's thread runs the tap, the tar digest and the CRC.
+// The tap is a Python callback, and every call of it hands the
+// interpreter lock over: it gets the stream through a staging buffer,
+// once a filled 256 KiB and once at the end of every extern call, so
+// each call returns with the tap having seen all it wrote.
 // The zlib backend deflates on one thread of its own behind a bounded
 // ring (the fan-out of common.go:35-64, as the Python LayerSink's
 // compressor thread): the caller fills fixed-size slots and only waits
 // when all of them are full, so a commit costs max(gzip, producer) where
 // both ran in line. The pgzip backend deflates blocks on its pool.
+// lsk_write_entries is what tells the sink of files before it must write
+// them: at a batch's start its files of up to 8 MiB go to the sink's
+// reader threads ("lsk-read", at most kReaders a sink, started by the
+// first batch that has two such files, joined by lsk_finish or
+// lsk_free), which open, read to the header's size and
+// close each, in entry order, into a second ring of 16 MiB; the caller
+// takes them in order and waits only for the one it needs. A larger
+// file, a batch with fewer than two, or a sink that could start no
+// reader streams on the caller's thread as lsk_write_file does.
 
 #include <dlfcn.h>
 #include <fcntl.h>
@@ -101,6 +126,22 @@ struct Sink {
   // in consume, on the drain in finish. Caller's thread only.
   double wait_s = 0;
 
+  // The tap's staging buffer (made by lsk_set_tap): account() fills it
+  // and hands it over full, tap_flush() in part.
+  static constexpr size_t kTapBytes = 256 * 1024;
+  std::vector<uint8_t> tap_buf;
+  size_t tap_fill = 0;
+
+  // What lsk_prefetch_stats reports; caller's thread only. read_wait_s:
+  // seconds blocked on a reader that had not finished the next file.
+  // Regular files with content by how their bytes came: a reader had
+  // them ready; the caller waited for a reader; the caller streamed
+  // them itself.
+  double read_wait_s = 0;
+  uint64_t files_ready = 0;
+  uint64_t files_waited = 0;
+  uint64_t files_streamed = 0;
+
   static double since(std::chrono::steady_clock::time_point t0) {
     return std::chrono::duration<double>(
                std::chrono::steady_clock::now() - t0).count();
@@ -140,7 +181,42 @@ struct Sink {
   std::vector<std::thread> workers;
   bool stopping = false;
 
+  // Read-ahead for lsk_write_entries. rjobs holds the current batch's
+  // files for the readers, in entry order. A reader claims rjobs[rnext]
+  // once the read ring has room for the whole file, reads it, marks it
+  // done; the caller takes the jobs in the same order and gives their
+  // room back. The ring is cut and freed in entry order, so ralloc and
+  // rfree are positions in an endless stream that lies at pos % kReadRing;
+  // a file that would straddle the ring's end starts at 0 and the tail it
+  // skipped is freed with it. With every earlier job given back the ring
+  // is empty and any file of kReadAheadMax fits, so the caller never
+  // waits for a job no reader can claim. All of it is guarded by rmu;
+  // rcv_job says "a job may be claimable, or stop", rcv_done "a job is
+  // done".
+  static constexpr int kReaders = 4;
+  static constexpr uint64_t kReadAheadMax = 8 * 1024 * 1024;
+  static constexpr size_t kReadRing = 16 * 1024 * 1024;
+  struct ReadJob {
+    const char* path;   // the caller's, for the length of its call
+    uint64_t size;
+    size_t off = 0;     // where in rring
+    uint64_t span = 0;  // ring positions taken: size and a skipped tail
+    bool done = false;
+    int rc = 0;         // as consume_file: -2 unreadable, -3 too short
+  };
+  uint8_t* rring = nullptr;
+  std::vector<ReadJob> rjobs;
+  size_t rnext = 0;     // the next job a reader may claim
+  size_t rreading = 0;  // jobs claimed and not done
+  uint64_t ralloc = 0;
+  uint64_t rfree = 0;
+  bool rstop = false;
+  std::mutex rmu;
+  std::condition_variable rcv_job, rcv_done;
+  std::vector<std::thread> readers;
+
   ~Sink() {
+    stop_readers();
     if (!workers.empty()) {
       {
         std::lock_guard<std::mutex> lock(mu);
@@ -152,6 +228,7 @@ struct Sink {
     for (auto* j : jobs) delete j;
     if (zinit) deflateEnd(&zs);
     if (ring) ::munmap(ring, kSlots * kSlotBytes);
+    if (rring) ::munmap(rring, kReadRing);
     if (fd >= 0) ::close(fd);
   }
 
@@ -362,10 +439,22 @@ struct Sink {
     }
   }
 
+  void tap_flush() {
+    if (tap && tap_fill) tap(tap_buf.data(), tap_fill, tap_user);
+    tap_fill = 0;
+  }
+
   // Every uncompressed tar byte is counted here exactly once, in stream
-  // order, on the caller's thread (the tap is a Python callback).
+  // order, on the caller's thread (the tap gets it through tap_buf).
   void account(const uint8_t* data, size_t n) {
-    if (tap) tap(data, n, tap_user);
+    for (size_t off = 0; tap && off < n;) {
+      size_t room = kTapBytes - tap_fill;
+      size_t k = n - off < room ? n - off : room;
+      std::memcpy(tap_buf.data() + tap_fill, data + off, k);
+      tap_fill += k;
+      off += k;
+      if (tap_fill == kTapBytes) tap_flush();
+    }
     tar_sha.update(data, n);
     tar_size += n;
     size_t off = 0;  // crc32 takes uInt lengths; chunk for safety
@@ -381,12 +470,16 @@ struct Sink {
     if (failed || zfailed) return false;
     account(data, n);
     if (!pgzip) return ring_put(data, n);
-    pending.insert(pending.end(), data, data + n);
-    while (pending.size() >= block_size) {
-      std::vector<uint8_t> blk(pending.begin(),
-                               pending.begin() + block_size);
-      pending.erase(pending.begin(), pending.begin() + block_size);
-      if (!pgzip_submit(std::move(blk), false)) return false;
+    while (n > 0) {  // a file read ahead arrives whole: block by block
+      size_t room = block_size - pending.size();
+      size_t k = n < room ? n : room;
+      pending.insert(pending.end(), data, data + k);
+      data += k;
+      n -= k;
+      if (pending.size() == block_size) {
+        if (!pgzip_submit(std::move(pending), false)) return false;
+        pending.clear();
+      }
     }
     return true;
   }
@@ -428,9 +521,183 @@ struct Sink {
         remaining -= static_cast<uint64_t>(got);
       }
     }
+    return pad_to_block(size) ? 0 : -1;
+  }
+
+  bool pad_to_block(uint64_t size) {
     static const uint8_t zeros[512] = {0};
     size_t pad = (512 - (size % 512)) % 512;
-    return !pad || consume(zeros, pad) ? 0 : -1;
+    return !pad || consume(zeros, pad);
+  }
+
+  // One file opened, streamed and closed by the caller itself.
+  int file_streamed(const char* path, uint64_t size) {
+    int src = ::open(path, O_RDONLY | O_CLOEXEC);
+    if (src < 0) return -2;
+    ++files_streamed;
+    int rc = consume_file(src, size);
+    ::close(src);
+    return rc;
+  }
+
+  // A reader's whole job: the file's first `size` bytes into dst.
+  static int read_whole(const char* path, uint8_t* dst, uint64_t size) {
+    int src = ::open(path, O_RDONLY | O_CLOEXEC);
+    if (src < 0) return -2;
+    int rc = 0;
+    for (uint64_t have = 0; have < size && rc == 0;) {
+      ssize_t got = read_some(src, dst + have, size - have, size - have);
+      if (got <= 0) rc = got < 0 ? -2 : -3;
+      else have += static_cast<uint64_t>(got);
+    }
+    ::close(src);
+    return rc;
+  }
+
+  // With rmu held: whether the read ring has room for the next job, and
+  // where.
+  bool ring_fits(ReadJob& job) {
+    size_t at = ralloc % kReadRing;
+    uint64_t skip = at + job.size > kReadRing ? kReadRing - at : 0;
+    if (ralloc + skip + job.size - rfree > kReadRing) return false;
+    job.off = skip ? 0 : at;
+    job.span = skip + job.size;
+    return true;
+  }
+
+  void reader_loop() {
+    ::pthread_setname_np(::pthread_self(), "lsk-read");
+    std::unique_lock<std::mutex> lock(rmu);
+    for (;;) {
+      rcv_job.wait(lock, [&] {
+        return rstop || (rnext < rjobs.size() && ring_fits(rjobs[rnext]));
+      });
+      if (rstop) return;
+      ReadJob& job = rjobs[rnext++];
+      ralloc += job.span;
+      ++rreading;
+      lock.unlock();
+      rcv_job.notify_one();  // the room left may do for the next job too
+      int rc = read_whole(job.path, rring + job.off, job.size);
+      lock.lock();
+      job.rc = rc;
+      job.done = true;
+      --rreading;
+      rcv_done.notify_all();
+    }
+  }
+
+  // This sink's reader threads (per sink, with no cap across a process:
+  // 16 sinks at once read alike with one of 16 and with none, PERF.md
+  // section 6, PR 40). false: none to be had, the caller streams every
+  // file.
+  bool start_readers() {
+    if (!readers.empty()) return true;
+    if (!rring) {
+      void* m = ::mmap(nullptr, kReadRing, PROT_READ | PROT_WRITE,
+                       MAP_PRIVATE | MAP_ANONYMOUS, -1, 0);
+      if (m == MAP_FAILED) return false;
+      rring = static_cast<uint8_t*>(m);
+    }
+    rstop = false;  // none is running: stop_readers joined them all
+    try {
+      while (static_cast<int>(readers.size()) < kReaders) {
+        readers.emplace_back([this] { reader_loop(); });
+      }
+    } catch (const std::exception&) {  // no thread to be had
+    }
+    return !readers.empty();
+  }
+
+  void stop_readers() {
+    if (readers.empty()) return;
+    {
+      std::lock_guard<std::mutex> lock(rmu);
+      rstop = true;
+    }
+    rcv_job.notify_all();
+    for (auto& t : readers) t.join();
+    readers.clear();
+  }
+
+  // The batch is over, well or not: no reader touches its paths again.
+  void end_batch() {
+    std::unique_lock<std::mutex> lock(rmu);
+    rnext = rjobs.size();
+    rcv_done.wait(lock, [&] { return rreading == 0; });
+    rjobs.clear();
+    rnext = 0;
+    ralloc = rfree = 0;
+  }
+
+  // The batch's j-th file for the readers: its content and padding into
+  // the stream, its room in the read ring given back. Blocks while a
+  // reader has it in hand.
+  int file_from_reader(size_t j) {
+    const uint8_t* data;
+    uint64_t size;
+    int rc;
+    {
+      std::unique_lock<std::mutex> lock(rmu);
+      ReadJob& job = rjobs[j];
+      if (job.done) {
+        ++files_ready;
+      } else {
+        ++files_waited;
+        auto t0 = std::chrono::steady_clock::now();
+        rcv_done.wait(lock, [&] { return job.done; });
+        read_wait_s += since(t0);
+      }
+      data = rring + job.off;
+      size = job.size;
+      rc = job.rc;
+    }
+    if (rc == 0 && !(consume(data, size) && pad_to_block(size))) rc = -1;
+    {
+      std::lock_guard<std::mutex> lock(rmu);
+      rfree += rjobs[j].span;
+    }
+    rcv_job.notify_one();
+    return rc;
+  }
+
+  // lsk_write_entries. The rc of consume_file, and the index of the
+  // entry at fault in *failed_index.
+  int write_entries(size_t n, const uint8_t* headers,
+                    const uint64_t* header_offsets,
+                    const char* const* paths, const uint64_t* sizes,
+                    int64_t* failed_index) {
+    auto for_readers = [&](size_t i) {
+      return paths[i] && sizes[i] > 0 && sizes[i] <= kReadAheadMax;
+    };
+    size_t eligible = 0;
+    for (size_t i = 0; i < n; ++i) eligible += for_readers(i);
+    bool ahead = eligible >= 2 && start_readers();
+    if (ahead) {
+      {
+        std::lock_guard<std::mutex> lock(rmu);
+        rjobs.reserve(eligible);
+        for (size_t i = 0; i < n; ++i) {
+          if (for_readers(i)) rjobs.push_back(ReadJob{paths[i], sizes[i]});
+        }
+      }
+      rcv_job.notify_all();
+    }
+    int rc = 0;
+    for (size_t i = 0, j = 0; i < n && rc == 0; ++i) {
+      if (!consume(headers + header_offsets[i],
+                   header_offsets[i + 1] - header_offsets[i])) {
+        rc = -1;
+      } else if (paths[i] && sizes[i] > 0) {
+        rc = ahead && for_readers(i) ? file_from_reader(j++)
+                                     : file_streamed(paths[i], sizes[i]);
+      }
+      if (rc != 0) *failed_index = static_cast<int64_t>(i);
+    }
+    if (ahead) end_batch();
+    tap_flush();
+    if (rc != 0) failed = true;  // the stream ends in the middle of an entry
+    return rc;
   }
 
   bool finish_stream() {
@@ -508,15 +775,15 @@ void lsk_set_tap(void* handle,
   auto* s = static_cast<Sink*>(handle);
   s->tap = fn;
   s->tap_user = user;
+  if (fn) s->tap_buf.resize(Sink::kTapBytes);
 }
 
 int lsk_write(void* handle, const uint8_t* data, size_t n) {
   auto* s = static_cast<Sink*>(handle);
-  if (!s->consume(data, n)) {
-    s->failed = true;
-    return -1;
-  }
-  return 0;
+  bool ok = s->consume(data, n);
+  s->tap_flush();
+  if (!ok) s->failed = true;
+  return ok ? 0 : -1;
 }
 
 // Stream one regular file's content into the tar, then its 512 padding.
@@ -524,17 +791,30 @@ int lsk_write(void* handle, const uint8_t* data, size_t n) {
 // error (the tar framing would be corrupt).
 int lsk_write_file(void* handle, const char* path, uint64_t size) {
   auto* s = static_cast<Sink*>(handle);
-  int fd = ::open(path, O_RDONLY | O_CLOEXEC);
-  if (fd < 0) return -2;
-  int rc = s->consume_file(fd, size);
-  ::close(fd);
+  int rc = s->file_streamed(path, size);
+  s->tap_flush();
   if (rc == -1) s->failed = true;
   return rc;
+}
+
+// A batch of n entries in stream order, in one call. Entry i's rendered
+// header is headers[header_offsets[i] .. header_offsets[i + 1]); where
+// paths[i] is not NULL and sizes[i] > 0 the file's first sizes[i] bytes
+// and the padding to 512 follow. 0, or the rc of lsk_write_file with the
+// index of the entry at fault in *failed_index: nothing of a later entry
+// has reached the stream, and the sink stays failed.
+int lsk_write_entries(void* handle, size_t n, const uint8_t* headers,
+                      const uint64_t* header_offsets,
+                      const char* const* paths, const uint64_t* sizes,
+                      int64_t* failed_index) {
+  return static_cast<Sink*>(handle)->write_entries(
+      n, headers, header_offsets, paths, sizes, failed_index);
 }
 
 int lsk_finish(void* handle, uint8_t tar_sha[32], uint8_t gz_sha[32],
                uint64_t* gz_size, uint64_t* tar_size) {
   auto* s = static_cast<Sink*>(handle);
+  s->stop_readers();
   if (s->failed || !s->finish_stream()) return -1;
   s->tar_sha.final(tar_sha);
   s->gz_sha.final(gz_sha);
@@ -558,8 +838,23 @@ double lsk_wait_seconds(void* handle) {
   return static_cast<Sink*>(handle)->wait_s;
 }
 
+// Any time, on the caller's thread. read_wait_s: seconds the caller was
+// blocked on a reader that had not finished the file it needed next.
+// counts: regular files with content that a reader had ready, that the
+// caller waited for, that the caller streamed itself (over 8 MiB, a batch
+// with fewer than two files for the readers, lsk_write_file, no thread to
+// be had).
+void lsk_prefetch_stats(void* handle, double* read_wait_s,
+                        uint64_t counts[3]) {
+  auto* s = static_cast<Sink*>(handle);
+  *read_wait_s = s->read_wait_s;
+  counts[0] = s->files_ready;
+  counts[1] = s->files_waited;
+  counts[2] = s->files_streamed;
+}
+
 // Also for a sink that was never finished (a build that died between
-// two entries): the compressor thread is stopped and joined.
+// two entries): the compressor and reader threads are stopped and joined.
 void lsk_free(void* handle) { delete static_cast<Sink*>(handle); }
 
 }  // extern "C"

@@ -235,6 +235,103 @@ def test_read_ahead_warm_mode_returns_none(tmp_path):
     ra.close()
 
 
+class _BatchWriter:
+    """A tar writer that has ``add_entries`` and keeps what it got."""
+
+    def __init__(self):
+        self.batches = []
+        self.offset = 0
+
+    def add_entries(self, items):
+        self.batches.append(list(items))
+
+
+def _header_layer(sizes):
+    """A layer of regular files that exist as headers only."""
+    layer = Layer()
+    for i, size in enumerate(sizes):
+        hdr = tarfile.TarInfo(f"f{i:04d}")
+        hdr.size = size
+        layer.add_header(f"/nowhere/f{i:04d}", f"/f{i:04d}", hdr)
+    return layer
+
+
+def test_layer_commit_hands_a_batch_writer_runs_of_256_entries():
+    """A writer that has ``add_entries`` gets the sorted entries in
+    runs of at most 256, whiteouts and header-only entries in their
+    sorted place with no path, and no entry goes through ``addfile``."""
+    layer = _header_layer([100] * 600)
+    layer.entries["/f0003"].hdr.size = 0          # an empty file
+    layer.entries["/f0005"].hdr.type = tarfile.DIRTYPE
+    layer.add_whiteout("/f0004x")
+    tw = _BatchWriter()
+    layer.commit(tw, workers=8)
+    assert [len(b) for b in tw.batches] == [256, 256, 89]
+    flat = [item for b in tw.batches for item in b]
+    names = [f"f{i:04d}" for i in range(600)]
+    names.insert(5, ".wh.f0004x")  # where the deleted path sorts
+    assert [hdr.name for hdr, _ in flat] == names
+    paths = {hdr.name: path for hdr, path in flat}
+    assert paths["f0002"] == "/nowhere/f0002"
+    assert paths["f0003"] is None and paths["f0005"] is None
+    assert paths[".wh.f0004x"] is None
+
+
+def test_layer_commit_closes_a_batch_at_16_mib_of_content():
+    """A run also ends with the entry that takes its content to 16 MiB
+    (what the sink's read-ahead ring holds): a huge file closes the
+    batch it is in, header-only entries count for nothing."""
+    mib = 1 << 20
+    layer = _header_layer([6 * mib, 6 * mib, 6 * mib, 1, 64 * mib, 2, 3])
+    layer.entries["/f0005"].hdr.type = tarfile.SYMTYPE  # size is no content
+    tw = _BatchWriter()
+    layer.commit(tw)
+    assert [[hdr.name for hdr, _ in b] for b in tw.batches] == [
+        ["f0000", "f0001", "f0002"], ["f0003", "f0004"],
+        ["f0005", "f0006"]]
+
+
+@pytest.mark.skipif(not native.layersink_available()
+                    or not native.gear_scan_available(),
+                    reason="native libraries not built")
+@pytest.mark.parametrize("backend_id", ["zlib-6", "pgzip-6-131072"])
+def test_commit_by_the_batch_makes_no_read_ahead_and_the_same_layer(
+        tmp_path, monkeypatch, layersink_before_batches, backend_id):
+    """Through the native sink ``Layer.commit`` makes no ``_ReadAhead``
+    (the sink reads ahead on threads of its own) and hands the writer
+    two batches; tar, blob, digests and chunks are those of a library
+    from before ``lsk_write_entries``, which commits entry by entry
+    with the warm read-ahead."""
+    if backend_id.startswith("pgzip") and not native.pgzip_available():
+        pytest.skip("pgzip not built")
+    from makisu_tpu.snapshot import layer as layer_mod
+    root = _tree(tmp_path)
+    for i in range(300):
+        (root / "sub" / f"s{i:03d}").write_bytes(b"%03d" % i * (i + 1))
+    made, batches = [], []
+    init = _ReadAhead.__init__
+    monkeypatch.setattr(
+        layer_mod._ReadAhead, "__init__",
+        lambda self, items, buffer, workers: (
+            made.append(buffer), init(self, items, buffer, workers))[1])
+    from makisu_tpu.chunker.hasher import _NativeTarWriter
+    add = _NativeTarWriter._add_entries
+    monkeypatch.setattr(
+        _NativeTarWriter, "_add_entries",
+        lambda self, items: (batches.append(len(items)),
+                             add(self, items))[1])
+    batched = str(tmp_path / "batched.tar.gz")
+    by_batch = _commit(root, batched, backend_id, workers=8)
+    assert made == []
+    assert batches == [256, sum(batches) - 256]
+    layersink_before_batches()
+    entrywise = str(tmp_path / "entrywise.tar.gz")
+    by_entry = _commit(root, entrywise, backend_id, workers=8)
+    assert made == [False]  # warm mode, as before
+    assert by_batch.chunks
+    assert _identity(by_batch, batched) == _identity(by_entry, entrywise)
+
+
 @pytest.mark.skipif(not native.sha_batch_available(),
                     reason="libgear.so sha batch not built")
 def test_stage_metrics_recorded_for_pooled_commit():

@@ -157,6 +157,8 @@ def _cell_reports_its_metrics():
                        "commit_share_pct", "device_mb_per_build",
                        "chunk_store_share_pct", "queue_wait_p50_s",
                        "hash_batch_occupancy_pct"}
+    # Two files of 64 MiB: the sink's readers get none (PR 40).
+    assert not mine & {"sink_prefetch_ready_pct", "read_wait_s_per_build"}
     for name in mine:
         assert callable(cell.reader(name))
 

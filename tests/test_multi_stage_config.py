@@ -237,7 +237,8 @@ def _cell_reports_its_metrics():
     assert "new_chunk_bytes_share_pct" not in mine
     assert {"sha_hbm_roofline", "gear_hbm_roofline", "apply_layer_s_per_build",
             "sync_mtime_wait_s_per_build", "chunk_probe_hit_pct",
-            "idle_unspanned_pct", "compiles_in_window"} <= mine
+            "idle_unspanned_pct", "compiles_in_window",
+            "sink_prefetch_ready_pct", "read_wait_s_per_build"} <= mine
     # None queued for retirement, not the RSS level, none of the farm's.
     assert not mine & {"sync_wait_share_pct", "commit_share_pct",
                        "device_mb_per_build", "chunk_store_share_pct",

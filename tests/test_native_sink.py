@@ -16,6 +16,7 @@ import zlib
 import pytest
 
 from makisu_tpu import native, tario
+from makisu_tpu.chunker import hasher as hasher_mod
 from makisu_tpu.chunker.hasher import LayerSink, NativeLayerSink
 
 pytestmark = pytest.mark.skipif(
@@ -485,17 +486,21 @@ def test_close_on_an_unfinished_sink_returns(tmp_path, fed):
         f.write(b"still open")
 
 
-def _compressor_threads():
-    """Live compressor threads of zlib sinks, by the name they take."""
+def _sink_threads(name):
+    """Live threads of native sinks, by the name they take."""
     gc.collect()  # a sink some earlier test dropped in a cycle
     live = 0
     for task in os.listdir("/proc/self/task"):
         try:
             with open(f"/proc/self/task/{task}/comm") as f:
-                live += f.read().strip() == "lsk-zlib"
+                live += f.read().strip() == name
         except OSError:  # a thread that ended meanwhile
             pass
     return live
+
+
+def _compressor_threads():
+    return _sink_threads("lsk-zlib")
 
 
 def _more_than_the_ring_of_text():
@@ -576,6 +581,370 @@ def test_the_sink_writes_to_a_fd_of_its_own(tmp_path):
     assert len(blob) == gz_size
     assert hashlib.sha256(blob).hexdigest() == gz_hex
     assert zlib.decompress(blob, 31) == text + b"tail"
+
+
+# -- entries by the batch (lsk_write_entries) ---------------------------------
+
+_READ_AHEAD_MAX = 8 * 1024 * 1024   # native/layersink.cpp: kReadAheadMax
+_TAP = 256 * 1024                   # native/layersink.cpp: kTapBytes
+_READERS = 4                        # native/layersink.cpp: kReaders
+
+
+def _batch_layer(tmp_path):
+    """``_tree``'s corner cases (an empty file, a name long enough for
+    a PAX header, a symlink, a hard link, directories) with 300 small
+    files, a file over what the sink reads ahead and a whiteout: a
+    layer of two batches, and how many of its files have content."""
+    from makisu_tpu.snapshot.layer import Layer
+    root = _tree(tmp_path)
+    rnd = random.Random(40)
+    (root / "over.bin").write_bytes(rnd.randbytes(_READ_AHEAD_MAX + 1))
+    (root / "many").mkdir()
+    for i in range(300):
+        (root / "many" / f"s{i:03d}").write_bytes(
+            rnd.randbytes(rnd.choice([1, 511, 512, 513, 3_000, 70_000])))
+    layer = Layer()
+    for src, hdr in _entries(root):
+        layer.add_header(src, "/" + hdr.name, hdr)
+    layer.add_whiteout("/many/gone")  # sorts among the small files
+    with_content = sum(1 for e in layer.entries.values()
+                       if e.header()[1] is not None)
+    assert len(layer) > 256 and with_content == 305
+    return layer, with_content
+
+
+class _Recorder:
+    """A chunk session that keeps what the tap handed it."""
+
+    def __init__(self):
+        self.pieces = []
+
+    def update(self, data):
+        self.pieces.append(data)
+
+    def finish(self):
+        return []
+
+
+def _commit_layer(layer, path, backend_id, session=None):
+    """``Layer.commit`` through a native sink (the TPU hasher's, unless
+    a ``session`` is given): the commit, the blob, the writer."""
+    from makisu_tpu.chunker import TPUHasher
+    with open(path, "wb") as f:
+        if session is None:
+            sink = TPUHasher().open_layer(f, backend_id=backend_id)
+            assert isinstance(sink, NativeLayerSink)
+        else:
+            sink = NativeLayerSink(f, backend_id=backend_id,
+                                   session=session)
+        with sink.open_tar() as tw:
+            layer.commit(tw)
+        commit = sink.finish()
+    with open(path, "rb") as f:
+        return commit, f.read(), tw
+
+
+_BACKENDS = ["zlib-6", "pgzip-6-131072"]
+
+
+@pytest.mark.parametrize("backend_id", _BACKENDS)
+def test_the_batch_path_gives_the_per_entry_paths_bytes(
+        tmp_path, monkeypatch, layersink_before_batches, backend_id):
+    """Tar bytes, blob bytes, both digests and the chunk list are the
+    same whether the layer's entries cross into the sink a batch at a
+    time or one by one (a library without ``lsk_write_entries``), and
+    the Python sink's."""
+    if backend_id.startswith("pgzip") and not native.pgzip_available():
+        pytest.skip("pgzip not built")
+    layer, _ = _batch_layer(tmp_path)
+    batch, batch_blob, tw = _commit_layer(layer, tmp_path / "batch.gz",
+                                          backend_id)
+    assert tw.add_entries is not None
+    layersink_before_batches()
+    entry, entry_blob, tw = _commit_layer(layer, tmp_path / "entry.gz",
+                                          backend_id)
+    assert tw.add_entries is None
+    assert batch_blob == entry_blob
+    assert batch.digest_pair == entry.digest_pair
+    assert batch.chunks == entry.chunks and batch.chunks
+    tar = zlib.decompress(batch_blob, 31)
+    assert len(tar) == tw.offset
+    assert hashlib.sha256(tar).hexdigest() \
+        == batch.digest_pair.tar_digest.hex()
+    monkeypatch.setenv("MAKISU_TPU_NATIVE_SINK", "0")
+    from makisu_tpu.chunker import TPUHasher
+    with open(tmp_path / "py.gz", "wb") as f:
+        sink = TPUHasher().open_layer(f, backend_id=backend_id)
+        with sink.open_tar() as ptw:
+            layer.commit(ptw)
+        py = sink.finish()
+    assert py.digest_pair == batch.digest_pair
+    assert py.chunks == batch.chunks
+    with tarfile.open(fileobj=io.BytesIO(batch_blob), mode="r:gz") as tf:
+        members = {m.name: m for m in tf}
+        assert len(members) == len(layer)
+        assert members["many/.wh.gone"].size == 0
+        assert members["small"].islnk() and members["link"].issym()
+        assert tf.extractfile(members["over.bin"]).read() \
+            == random.Random(40).randbytes(_READ_AHEAD_MAX + 1)
+
+
+@pytest.mark.parametrize("backend_id", _BACKENDS)
+def test_the_tap_is_called_by_the_slot_not_by_the_piece(
+        tmp_path, layersink_before_batches, backend_id):
+    """By the batch the tap gets the very stream it gets entry by
+    entry, every byte once and in order, a filled 256 KiB at a time
+    and once at the end of a call: in far fewer calls."""
+    if backend_id.startswith("pgzip") and not native.pgzip_available():
+        pytest.skip("pgzip not built")
+    layer, _ = _batch_layer(tmp_path)
+    by_batch, by_entry = _Recorder(), _Recorder()
+    commit, blob, _ = _commit_layer(layer, tmp_path / "batch.gz",
+                                    backend_id, session=by_batch)
+    layersink_before_batches()
+    _commit_layer(layer, tmp_path / "entry.gz", backend_id,
+                  session=by_entry)
+    tar = b"".join(by_batch.pieces)
+    assert tar == b"".join(by_entry.pieces) == zlib.decompress(blob, 31)
+    assert hashlib.sha256(tar).hexdigest() \
+        == commit.digest_pair.tar_digest.hex()
+    assert max(map(len, by_batch.pieces)) == _TAP
+    # A call a filled slot, one more at the end of each of the two
+    # batches and of the archive's end; entry by entry two or three a
+    # file.
+    assert len(by_batch.pieces) <= len(tar) // _TAP + 3
+    assert len(by_entry.pieces) > 2 * len(layer)
+
+
+def _small_files(tmp_path, n=12, size=5_000):
+    """``n`` files of ``size`` marked bytes, as a batch for
+    ``add_entries``: [(tarinfo, path)]."""
+    batch = []
+    for i in range(n):
+        path = tmp_path / f"f{i:02d}"
+        path.write_bytes(((b"<file %02d>" % i) * (size // 9 + 1))[:size])
+        hdr = tarfile.TarInfo(f"f{i:02d}")
+        hdr.size = size
+        hdr.mode = 0o644
+        batch.append((hdr, str(path)))
+    return batch
+
+
+@pytest.mark.parametrize("fault", ["missing", "shrunk", "grown"])
+def test_a_file_that_changed_under_a_batch_does_what_it_did_entry_by_entry(
+        tmp_path, fault):
+    """A file that is gone or shrank below its header raises ``OSError``
+    naming its path, nothing of a later entry has reached the stream
+    and the sink stays failed; a file that grew gives its first
+    ``size`` bytes."""
+    batch = _small_files(tmp_path)
+    victim = batch[5][1]
+    if fault == "missing":
+        os.unlink(victim)
+    elif fault == "shrunk":
+        with open(victim, "wb") as f:
+            f.write(b"x")
+    else:
+        with open(victim, "ab") as f:
+            f.write(b"<grown>" * 1000)
+    recorder = _Recorder()
+    with open(tmp_path / "out.gz", "wb") as out:
+        sink = NativeLayerSink(out, backend_id="zlib-6", session=recorder)
+        tw = sink.open_tar()
+        if fault == "grown":
+            tw.add_entries(batch)
+            tw.close()
+            sink.finish()
+        else:
+            with pytest.raises(OSError) as err:
+                tw.add_entries(batch)
+            assert victim in str(err.value)
+            assert ("shrank below its header size 5000"
+                    in str(err.value)) == (fault == "shrunk")
+            assert ("could not read" in str(err.value)) \
+                == (fault == "missing")
+            with pytest.raises(RuntimeError, match="write failed"):
+                tw.add_entries(batch[6:])
+            with pytest.raises(RuntimeError, match="write failed"):
+                sink._handle.write(b"more")
+            with pytest.raises(RuntimeError, match="finish failed"):
+                sink._handle.finish()
+            sink.abort()
+    stream = b"".join(recorder.pieces)
+    assert b"<file 04>" in stream and b"f05" in stream
+    if fault == "grown":
+        with tarfile.open(tmp_path / "out.gz") as tf:
+            assert [m.size for m in tf] == [5_000] * 12
+            assert b"<grown>" not in tf.extractfile("f05").read()
+            assert tf.extractfile("f06").read().startswith(b"<file 06>")
+    else:
+        assert b"<file 06>" not in stream and b"f06" not in stream
+
+
+def test_a_tap_that_raises_fails_the_commit_at_the_batch_call(tmp_path):
+    batch = _small_files(tmp_path, n=80, size=10_000)  # three tap slots
+
+    class BadSession(_Recorder):
+        def update(self, data):
+            raise RuntimeError("device fell over")
+
+    with open(tmp_path / "out.gz", "wb") as out:
+        sink = NativeLayerSink(out, backend_id="zlib-6",
+                               session=BadSession())
+        with pytest.raises(RuntimeError, match="chunk tap failed"):
+            sink.open_tar().add_entries(batch)
+        sink.abort()
+
+
+def test_abort_in_the_middle_of_a_batch_joins_the_readers(tmp_path):
+    """A commit that dies in a batch (a file is gone) leaves through
+    the writer's ``__exit__``, which aborts the sink: no thread of it
+    is alive after, no descriptor open, and ``out`` closes on nothing."""
+    batch = _small_files(tmp_path, n=40)
+    os.unlink(batch[30][1])
+    readers = _sink_threads("lsk-read")
+    compressors = _compressor_threads()
+    fds = len(os.listdir("/proc/self/fd"))
+    with open(tmp_path / "out.gz", "wb") as out:
+        sink = NativeLayerSink(out, backend_id="zlib-6")
+        with pytest.raises(OSError, match="f30"):
+            with sink.open_tar() as tw:
+                tw.add_entries(batch[:20])
+                assert _sink_threads("lsk-read") == readers + _READERS
+                tw.add_entries(batch[20:])
+        assert _sink_threads("lsk-read") == readers
+        assert _compressor_threads() == compressors
+        assert len(os.listdir("/proc/self/fd")) == fds + 1  # ``out``
+        number = out.fileno()
+    _stays_empty(tmp_path / "other", number)
+
+
+def test_a_library_without_the_batch_call_commits_entry_by_entry(
+        tmp_path, monkeypatch, layersink_before_batches):
+    """A prebuilt library from before ``lsk_write_entries``: the writer
+    has no ``add_entries``, ``Layer.commit`` goes through ``add_path``
+    with the warm read-ahead, and nothing is said of a prefetch."""
+    from makisu_tpu.snapshot import layer as layer_mod
+    from makisu_tpu.utils import concurrency, metrics
+    layer, with_content = _batch_layer(tmp_path)
+    layersink_before_batches()
+    calls, modes = [], []
+    add_path = hasher_mod._NativeTarWriter.add_path
+    monkeypatch.setattr(
+        hasher_mod._NativeTarWriter, "add_path",
+        lambda self, hdr, path: (calls.append(path),
+                                 add_path(self, hdr, path)))
+    init = layer_mod._ReadAhead.__init__
+    monkeypatch.setattr(
+        layer_mod._ReadAhead, "__init__",
+        lambda self, items, buffer, workers: (
+            modes.append(buffer), init(self, items, buffer, workers))[1])
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
+    workers = concurrency.set_hash_workers(4)
+    try:
+        _commit_layer(layer, tmp_path / "old.gz", "zlib-6",
+                      session=_Recorder())
+    finally:
+        concurrency.reset_hash_workers(workers)
+        metrics.reset_build_registry(token)
+    assert len(calls) == with_content
+    assert modes == [False]  # warm
+    busy = registry.counter_by_label(metrics.COMMIT_STAGE_BUSY, "stage")
+    assert "read_wait" not in busy and busy["compress"] > 0
+    assert registry.counter_total(metrics.SINK_PREFETCH_FILES_TOTAL) == 0
+
+
+@pytest.mark.parametrize("backend_id", _BACKENDS)
+def test_prefetch_counts_add_up_to_the_files_with_content_once_a_layer(
+        tmp_path, backend_id):
+    """``ready`` + ``waited`` + ``streamed`` is every regular file with
+    content, added at the sink's ``finish``; the one file over 8 MiB
+    streams on the writer's thread; the seconds blocked on a reader
+    are a stage of their own."""
+    if backend_id.startswith("pgzip") and not native.pgzip_available():
+        pytest.skip("pgzip not built")
+    from makisu_tpu.utils import metrics
+    layer, with_content = _batch_layer(tmp_path)
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
+    try:
+        with open(tmp_path / "out.gz", "wb") as f:
+            sink = NativeLayerSink(f, backend_id=backend_id)
+            with sink.open_tar() as tw:
+                layer.commit(tw)
+            assert registry.counter_total(
+                metrics.SINK_PREFETCH_FILES_TOTAL) == 0
+            assert sink._handle.prefetch_stats()[3] == 1
+            sink.finish()
+    finally:
+        metrics.reset_build_registry(token)
+    files = registry.counter_by_label(metrics.SINK_PREFETCH_FILES_TOTAL,
+                                      "result")
+    assert set(files) <= {"ready", "waited", "streamed"}
+    assert files["streamed"] == 1
+    assert sum(files.values()) == with_content
+    busy = registry.counter_by_label(metrics.COMMIT_STAGE_BUSY, "stage")
+    assert 0 <= busy["read_wait"] < 5
+
+
+def test_a_batch_of_one_file_streams_it_and_starts_no_reader(tmp_path):
+    """What the sink adapts to is what it sees: a batch with fewer than
+    two files for the readers goes the per-entry way."""
+    batch = _small_files(tmp_path, n=3)
+    readers = _sink_threads("lsk-read")
+    with open(tmp_path / "out.gz", "wb") as out:
+        sink = NativeLayerSink(out, backend_id="zlib-6")
+        with sink.open_tar() as tw:
+            for item in batch:
+                tw.add_entries([(tarfile.TarInfo("d"), None), item])
+            assert _sink_threads("lsk-read") == readers
+            assert sink._handle.prefetch_stats()[1:] == (0, 0, 3)
+        sink.finish()
+    with tarfile.open(tmp_path / "out.gz") as tf:
+        assert [m.name for m in tf] == ["d", "f00", "d", "f01", "d", "f02"]
+
+
+def test_many_threads_commit_batches_at_once(tmp_path):
+    """Twelve building threads, each with a sink and readers of its
+    own, on an interpreter that switches often: every blob is the one
+    a lone commit gives, no sink runs more than its four readers, and
+    none is left."""
+    import sys
+    import time
+    batch = _small_files(tmp_path, n=40, size=30_000)
+
+    def commit(i):
+        with open(tmp_path / f"t{i}.gz", "wb") as out:
+            sink = NativeLayerSink(out, backend_id="zlib-6",
+                                   session=_Recorder())
+            with sink.open_tar() as tw:
+                for at in range(0, len(batch), 8):
+                    tw.add_entries(batch[at:at + 8])
+            return sink.finish().digest_pair
+
+    alone = commit(0)
+    baseline = _sink_threads("lsk-read")
+    results, most = [], 0
+    workers = [threading.Thread(target=lambda i=i: results.append(commit(i)),
+                                daemon=True) for i in range(1, 13)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in workers:
+            t.start()
+        deadline = time.monotonic() + 60
+        while any(t.is_alive() for t in workers):
+            assert time.monotonic() < deadline, "a commit hangs"
+            most = max(most, _sink_threads("lsk-read") - baseline)
+            time.sleep(0.005)
+    finally:
+        sys.setswitchinterval(interval)
+    assert results == [alone] * 12
+    # (How many the polling saw is the scheduler's: none, when the
+    # commits end between two looks.)
+    assert most <= 12 * _READERS
+    assert _sink_threads("lsk-read") == baseline
 
 
 # What the parent commit (86bb072, zlib in line with the writer) gave

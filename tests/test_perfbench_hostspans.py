@@ -270,6 +270,85 @@ def test_service_reader_reads_a_run_and_nothing_from_an_older_program(
         assert read(untraced) is None
 
 
+PREFETCH = "makisu_sink_prefetch_files_total"
+BUSY = "makisu_commit_stage_busy_seconds"
+
+
+def _prefetched(tmp_path, program_side=True, handed=True):
+    """``_served``'s three counted builds, whose sinks handed files to
+    their readers over the window (PR 40)."""
+    r = _served(tmp_path)
+    if program_side:
+        r.counters_open.update([
+            _series(PREFETCH, 100.0, result="ready"),
+            _series(PREFETCH, 7.0, result="streamed"),
+            _series(BUSY, 1.0, stage="read_wait"),
+            _series(BUSY, 5.0, stage="compress_wait")])
+        r.counters_close.update([
+            _series(PREFETCH, 100.0 + (7_000 if handed else 0),
+                    result="ready"),
+            _series(PREFETCH, 1_000.0 if handed else 0.0, result="waited"),
+            _series(PREFETCH, 16.0, result="streamed"),
+            _series(BUSY, 1.375, stage="read_wait"),
+            _series(BUSY, 50.0, stage="compress_wait")])
+    return r
+
+
+@pytest.mark.parametrize("metric,want", [
+    # ``streamed`` files were never a reader's: 7,000 of 8,000.
+    ("sink_prefetch_ready_pct", 87.5),
+    ("read_wait_s_per_build", 0.375 / 3),
+])
+def test_prefetch_reader_reads_a_run_and_nothing_from_an_older_program(
+        tmp_path, metric, want):
+    read = _reader(metric)
+    assert read(_prefetched(tmp_path)) == pytest.approx(want)
+    # The parent's side of the driver's pair: no such series.
+    assert read(_prefetched(tmp_path, program_side=False)) is None
+    untraced = _prefetched(tmp_path)
+    untraced.counters_open = untraced.counters_close = None
+    assert read(untraced) is None
+    # A window whose builds handed no file to a reader (huge-layer):
+    # no share to speak of, and no second waited.
+    bypassed = _prefetched(tmp_path, handed=False)
+    if metric == "sink_prefetch_ready_pct":
+        assert read(bypassed) is None
+    else:
+        assert read(bypassed) == pytest.approx(want)
+        none_counted = _prefetched(tmp_path)
+        none_counted.counted = []
+        assert read(none_counted) is None
+
+
+def test_prefetch_metrics_list_their_cells():
+    import json
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json"),
+              encoding="utf-8") as f:
+        per_layer = json.load(f)["per_layer"]
+    by_name = {m["name"]: m for m in per_layer}
+    assert [m["name"] for m in per_layer[-2:]] == [
+        "sink_prefetch_ready_pct", "read_wait_s_per_build"]
+    assert len(per_layer) == 55
+    commit = by_name["tar_write_s_per_build"]
+    for name, unit, better in (("sink_prefetch_ready_pct", "%", "higher"),
+                               ("read_wait_s_per_build", "s", "lower")):
+        m = by_name[name]
+        # Where a layer has two files for the readers: not huge-layer
+        # (two files of 64 MiB), not the two churning lanes of
+        # farm-unchanged.
+        assert m["workloads"] == [
+            "small-files-edit", "monorepo-edit", "monorepo-cold",
+            "farm-churn", "farm-concurrent-churn", "multi-stage-small-edit"]
+        assert set(m["workloads"]) < set(commit["workloads"])
+        assert (m["layer"], m["moves"], m["source"]) == (
+            commit["layer"], "build_p50_s", "program_counter")
+        assert (m["unit"], m["better"]) == (unit, better)
+        assert sorted(m) == sorted(commit)
+    from makisu_tpu.utils import metrics
+    assert metrics.SINK_PREFETCH_FILES_TOTAL == PREFETCH
+    assert metrics.COMMIT_STAGE_BUSY == BUSY
+
+
 def test_service_metrics_list_their_cells():
     import json
     with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json"),

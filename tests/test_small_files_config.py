@@ -145,6 +145,9 @@ def _cell_reports_its_metrics():
         == {"build_p50_s", "stored_per_user_byte", "setup_s"}
     mine = {m["name"] for m in cell.per_layer()}
     assert set(NEW_READERS) <= mine
+    # The cell where the sink's read-ahead engages most (PR 40).
+    assert {"sink_prefetch_ready_pct", "read_wait_s_per_build",
+            "tar_write_s_per_build", "compress_wait_s_per_build"} <= mine
     # No cached layer here, and the four PERF.md marks "to be retired".
     assert not mine & {"apply_layer_s_per_build", "sync_wait_share_pct",
                        "commit_share_pct", "device_mb_per_build",
