@@ -253,9 +253,15 @@ class MemFS:
     def add_layer_by_scan(self, tw: tarfile.TarFile) -> Layer:
         self.flush()
         self._sync()
-        with metrics.span("layer_scan") as sp:
-            layer, newest = self._create_layer_by_scan()
-            sp.set(entries=len(layer))
+        with metrics.span("layer_scan", kind="scan") as sp:
+            layer, newest, visited = self._create_layer_by_scan()
+            sp.set(entries=len(layer), visited=visited)
+        whiteouts = sum(isinstance(e, WhiteoutEntry)
+                        for e in layer.entries.values())
+        for result, n in (("visited", visited),
+                          ("added", len(layer) - whiteouts),
+                          ("whiteout", whiteouts)):
+            metrics.counter_add(metrics.SCAN_ENTRIES_TOTAL, n, result=result)
         self._commit_layer(layer, tw)
         self._wait_out_mtime(newest)
         log.info("created layer by scan: %d entries", len(layer))
@@ -269,7 +275,7 @@ class MemFS:
         through it."""
         self.flush()
         self._sync()
-        with metrics.span("layer_scan") as sp:
+        with metrics.span("layer_scan", kind="copy_ops") as sp:
             layer = Layer()
             newest = max((self._add_copy_to_layer(layer, op, listing)
                           for op in ops), default=0)
@@ -314,13 +320,15 @@ class MemFS:
         self.chain_tainted = True
         self.layers.append(layer)
 
-    def _create_layer_by_scan(self) -> tuple[Layer, int]:
-        """The layer, and the newest mtime of any entry visited."""
+    def _create_layer_by_scan(self) -> tuple[Layer, int, int]:
+        """The layer, the newest mtime of any entry visited, and how
+        many were."""
         layer = Layer()
-        newest = 0
+        newest = visited = 0
 
         def visit(path: str, st: os.stat_result) -> None:
-            nonlocal newest
+            nonlocal newest, visited
+            visited += 1
             dst = pathutils.trim_root(path, self.root)
             hdr = tarinfo_from_stat(path, pathutils.rel_path(dst), self.root,
                                     st)
@@ -328,7 +336,7 @@ class MemFS:
             self._maybe_add(layer, path, dst, hdr, create_whiteouts=True)
 
         walk(self.root, self.blacklist, visit)
-        return layer, newest
+        return layer, newest, visited
 
     def _maybe_add(self, layer: Layer, src: str, dst: str,
                    hdr: tarfile.TarInfo, create_whiteouts: bool) -> None:

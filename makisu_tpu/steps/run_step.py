@@ -5,10 +5,13 @@ Reference: lib/builder/step/run_step.go (RequireOnDisk:46, Execute:63-71).
 
 from __future__ import annotations
 
+import subprocess
+
 from makisu_tpu import shell
 from makisu_tpu.context import BuildContext
 from makisu_tpu.docker.image import ImageConfig
 from makisu_tpu.steps.base import BuildStep
+from makisu_tpu.utils import metrics
 
 
 class RunStep(BuildStep):
@@ -36,5 +39,12 @@ class RunStep(BuildStep):
         # The command may write anywhere, the context included: what
         # the build has listed of it is no longer what is on disk.
         ctx.listing.close()
-        shell.exec_command(self.working_dir, self.user, "sh", "-c", self.cmd,
-                           env=ctx.exec_env)
+        # The command's seconds, fork to exit, apart from `step`'s own.
+        with metrics.span("run_exec") as sp:
+            try:
+                shell.exec_command(self.working_dir, self.user, "sh", "-c",
+                                   self.cmd, env=ctx.exec_env)
+            except subprocess.CalledProcessError as e:
+                sp.set(exit=e.returncode)
+                raise
+            sp.set(exit=0)

@@ -172,6 +172,12 @@ LAYER_REPLAY_TOTAL = "makisu_layer_replay_total"
 # Entries of each committed layer as its tar holds them, kind=file|dir|
 # symlink|other|whiteout (snapshot/memfs.py, added once a layer).
 LAYER_ENTRIES_TOTAL = "makisu_layer_entries_total"
+# Entries of each layer made by a scan of the root (after a RUN,
+# snapshot/memfs.py, one add a result a layer): result=visited (what
+# the walk handed to the header compare) | added (what differed from
+# the tree: the layer's content entries, ancestors written again among
+# them) | whiteout (tree children gone from disk).
+SCAN_ENTRIES_TOTAL = "makisu_scan_entries_total"
 # Regular files with content a native sink put into a layer, by how
 # their bytes came (chunker/hasher.py, added once a layer at the sink's
 # finish): result=ready (one of the sink's reader threads had them

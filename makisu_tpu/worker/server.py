@@ -1491,7 +1491,12 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
                 lock.release()
             self._admission.release()
             self._retire_build(record, code)
-            if flags["storage"] and record.tenant:
+            # A storage its owner removed while the build ended (a
+            # k8s job's scratch volume) is not made anew for a sidecar
+            # or an eviction pass over nothing.
+            storage_there = bool(flags["storage"]) \
+                and os.path.isdir(flags["storage"])
+            if storage_there and record.tenant:
                 # Ledger → census join: persist this build's layer
                 # hexes under its tenant so the storage census can
                 # attribute the bytes those layers put on disk.
@@ -1499,7 +1504,7 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
                 census_mod.record_attribution(
                     flags["storage"], record.tenant,
                     record.layer_hexes())
-            if flags["storage"]:
+            if storage_there:
                 # Budget enforcement at the moment disk grows: build
                 # end is when new chunks/blobs landed. Throttled and
                 # a no-op when unbudgeted; never fails the build.

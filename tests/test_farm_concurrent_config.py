@@ -109,7 +109,8 @@ def _states_what_a_deployment_states():
 
 
 def _entry_and_cell_in_benchmark():
-    entry = BENCHMARK["configs"][-1]
+    # The last of both lists at its PR; PR 41 appended after it.
+    entry = BENCHMARK["configs"][5]
     assert entry["name"] == "farm-concurrent"
     assert entry["file"] == "perfbench/configs/farm-concurrent.json"
     assert entry["reduced"] == CONFIG["reduced"]
@@ -118,12 +119,12 @@ def _entry_and_cell_in_benchmark():
     assert _load("BASELINE.json")["configs"][4] in entry["source"]
     [farm] = [c for c in BENCHMARK["configs"] if c["name"] == "farm"]
     assert entry["source"] != farm["source"]
-    cell = BENCHMARK["workloads"][-1]
+    cell = BENCHMARK["workloads"][7]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
         == (CELL, "farm-concurrent", "churn", 1)
     assert len(cell["why"]) <= 200
-    assert len(BENCHMARK["configs"]) == 6
-    assert len(BENCHMARK["workloads"]) == 8
+    assert len(BENCHMARK["configs"]) == 7
+    assert len(BENCHMARK["workloads"]) == 9
     assert all(w["chips"] == 1 for w in BENCHMARK["workloads"])
     # The mix is `farm-churn`'s, as it stands.
     assert (CHURN["count"], CHURN["prime_cold"], CHURN["prime_rebuilds"],
@@ -151,8 +152,9 @@ def _cell_reports_what_farm_churn_reports_and_the_six():
 
 def _new_metrics_list_the_three_farm_cells():
     names = [m["name"] for m in BENCHMARK["per_layer"]]
-    assert names[47:53] == list(NEW_READERS)  # PR 40 added two after
-    assert len(names) == 55
+    # PR 40 added two after, PR 41 four.
+    assert names[47:53] == list(NEW_READERS)
+    assert len(names) == 59
     by_name = {m["name"]: m for m in BENCHMARK["per_layer"]}
     for name in NEW_READERS:
         assert by_name[name]["workloads"] == [
@@ -173,7 +175,8 @@ def _new_metrics_list_the_three_farm_cells():
     # Appended, never inserted: the cell is the last of every list it
     # joined, and it joined every list `farm-churn` is on.
     for m in BENCHMARK["per_layer"][:47] + BENCHMARK["end_to_end"]:
-        listed = m.get("workloads", ())
+        listed = [w for w in m.get("workloads", ())
+                  if w != "run-steps-edit"]
         if CELL in listed:
             assert listed[-1] == CELL, m["name"]
         if "workloads" in m:

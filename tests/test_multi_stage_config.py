@@ -14,6 +14,7 @@ and the new readers read a run record.
 Needs no ``/root/reference``, no C compiler, no inotify and no root.
 """
 
+import contextlib
 import hashlib
 import json
 import os
@@ -33,6 +34,7 @@ sys.path.insert(0, PERFBENCH)
 from pbharness import cells, check, driver, gen, stats  # noqa: E402
 
 from makisu_tpu import cli  # noqa: E402
+from makisu_tpu.snapshot import memfs  # noqa: E402
 from makisu_tpu.utils import metrics  # noqa: E402
 from makisu_tpu.worker import WorkerClient, WorkerServer  # noqa: E402
 
@@ -256,25 +258,32 @@ def _new_metrics_list_their_cells():
     names = [m["name"] for m in BENCHMARK["per_layer"]]
     first = names.index(NEW_READERS[0])
     assert names[first:first + 6] == list(NEW_READERS)
+    # PR 41's cell has a RUN: it executes a COPY on disk, commits a
+    # layer, waits out an mtime and ends a session, and joined those
+    # five; it has one stage, and checkpoints none.
+    later = ["run-steps-edit"]
+    assert by_name[NEW_READERS[0]]["workloads"] == [CELL]
     for name in NEW_READERS[:3]:
-        assert by_name[name]["workloads"] == [CELL]
+        assert by_name[name]["workloads"] == [CELL] + later * (
+            name != NEW_READERS[0])
         assert by_name[name]["layer"].startswith("on-disk stage tree")
     assert by_name["layer_commits_per_build"]["workloads"] \
-        == [CELL, "monorepo-cold", "monorepo-edit"]
+        == [CELL, "monorepo-cold", "monorepo-edit"] + later
     assert by_name["mtime_wait_slept_per_build"]["workloads"] \
-        == [CELL, "monorepo-edit"]
+        == [CELL, "monorepo-edit"] + later
     assert by_name["session_finish_s_per_build"]["workloads"] == [
         CELL, "farm-churn", "farm-unchanged", "monorepo-edit",
-        "small-files-edit", "farm-concurrent-churn"]
+        "small-files-edit", "farm-concurrent-churn"] + later
     for name in NEW_READERS:
         assert by_name[name]["moves"] == "build_p50_s"
         assert by_name[name]["better"] == "lower"
     # Appended, never inserted: the cell was the last of every list it
-    # joined, and only PR 38's cell has been appended after it.
+    # joined, and only PR 38's and PR 41's cells have been appended
+    # after it.
     for m in BENCHMARK["per_layer"][:first] \
             + BENCHMARK["per_layer"][first + 6:] + BENCHMARK["end_to_end"]:
         listed = [w for w in m.get("workloads", ())
-                  if w != "farm-concurrent-churn"]
+                  if w not in ("farm-concurrent-churn", "run-steps-edit")]
         if CELL in listed:
             assert listed[-1] == CELL, m["name"]
 
@@ -391,6 +400,43 @@ def _image_tars(b):
     return out
 
 
+class _Clock:
+    """What ``snapshot/memfs.py`` reads the time from and sleeps on, in
+    the test's hands: it stands still but for the sleeps asked of it,
+    which take no time."""
+
+    def __init__(self, now: float) -> None:
+        self.now = now
+        self.sleeps: list[float] = []
+
+    def time(self) -> float:
+        return self.now
+
+    def sleep(self, seconds: float) -> None:
+        self.sleeps.append(seconds)
+        self.now += seconds
+
+
+@contextlib.contextmanager
+def _edited_a_quarter_second_ago(tree):
+    """The one file of ``tree`` written since ``_age`` is stamped at the
+    top of a second, and the mtime guard's clock reads a quarter of a
+    second later for as long as nothing sleeps: the layer that scans
+    that file waits the second out, however long the machine takes to
+    reach it, and every layer after it finds the clock past it."""
+    [edited] = [os.path.join(parent, name)
+                for parent, _, names in os.walk(tree) for name in names
+                if os.lstat(os.path.join(parent, name)).st_mtime != _OLD]
+    second = int(time.time())
+    os.utime(edited, (second, second))
+    clock = _Clock(second + 0.25)
+    real, memfs.time = memfs.time, clock
+    try:
+        yield clock
+    finally:
+        memfs.time = real
+
+
 @pytest.fixture(scope="module")
 def built(tmp_path_factory):
     return _build_both(str(tmp_path_factory.mktemp("multistage")))
@@ -415,8 +461,10 @@ def _build_both(work):
         out["touched"] = gen.apply_edit(
             _SMALL_EDIT, context, ctx, np.random.default_rng([1, 0, 7]),
             "000001")
-        out["edited"], out["edited_events"], out["edited_counters"] = \
-            worker.build(ctx, storage, CONFIG["build_flags"])
+        with _edited_a_quarter_second_ago(ctx) as clock:
+            out["edited"], out["edited_events"], out["edited_counters"] = \
+                worker.build(ctx, storage, CONFIG["build_flags"])
+        out["edited_sleeps"] = clock.sleeps
         out["edited_check"] = _held_to_reference(context, out["edited"])
         out["edited_tars"] = _image_tars(out["edited"])
     finally:
@@ -475,9 +523,12 @@ def test_rebuild_after_one_edit_commits_four_layers_and_unpacks_one(built):
     assert _delta(counters, COMMITS) == 4
     assert _delta(counters, REPLAY, result="inflate") == 1
     assert _delta(counters, REPLAY) == 1
-    # The edit landed an instant before the build: its layer slept.
+    # The edit landed a quarter of a second before the clock the guard
+    # reads: its layer slept the rest of that second and the margin,
+    # and the three after it found the second over.
     assert _delta(counters, SLEPT, result="slept") == 1
     assert _delta(counters, SLEPT) == 4
+    assert built["edited_sleeps"] == [pytest.approx(0.75 + 0.02)]
     grown = _SCALED + _SMALL_EDIT["bytes"]
     assert _delta(counters, ON_DISK, op="copy") == grown
     assert _delta(counters, ON_DISK, op="untar") == _SCALED

@@ -326,9 +326,9 @@ def test_prefetch_metrics_list_their_cells():
               encoding="utf-8") as f:
         per_layer = json.load(f)["per_layer"]
     by_name = {m["name"]: m for m in per_layer}
-    assert [m["name"] for m in per_layer[-2:]] == [
+    assert [m["name"] for m in per_layer[53:55]] == [
         "sink_prefetch_ready_pct", "read_wait_s_per_build"]
-    assert len(per_layer) == 55
+    assert len(per_layer) == 59  # PR 41 appended four
     commit = by_name["tar_write_s_per_build"]
     for name, unit, better in (("sink_prefetch_ready_pct", "%", "higher"),
                                ("read_wait_s_per_build", "s", "lower")):
@@ -363,7 +363,8 @@ def test_service_metrics_list_their_cells():
         "multi-stage-small-edit", "farm-concurrent-churn"]
     assert by_name["session_begin_s_per_build"]["workloads"] == [
         "farm-churn", "farm-unchanged", "monorepo-edit", "small-files-edit",
-        "huge-layer-edit", "multi-stage-small-edit", "farm-concurrent-churn"]
+        "huge-layer-edit", "multi-stage-small-edit", "farm-concurrent-churn",
+        "run-steps-edit"]
     assert by_name["wait_for_push_s_per_build"]["workloads"] == [
         "monorepo-cold", "monorepo-edit", "huge-layer-edit"]
     assert by_name["save_manifest_s_per_build"]["workloads"] == [
@@ -387,7 +388,8 @@ def test_every_new_metric_has_its_reader_and_its_cells():
     cells_of = {m["name"]: m["workloads"] for m in benchmark["per_layer"]}
     every = ["monorepo-cold", "farm-churn", "monorepo-edit",
              "farm-unchanged", "small-files-edit", "huge-layer-edit",
-             "multi-stage-small-edit", "farm-concurrent-churn"]
+             "multi-stage-small-edit", "farm-concurrent-churn",
+             "run-steps-edit"]
     assert cells_of["idle_unspanned_pct"] == every
     assert cells_of["sync_os_sync_s_per_build"] == every
     assert cells_of["chunk_index_s_per_build"] == [
@@ -400,7 +402,7 @@ def test_every_new_metric_has_its_reader_and_its_cells():
 @pytest.mark.parametrize("cell", [
     "monorepo-cold", "farm-churn", "monorepo-edit", "farm-unchanged",
     "small-files-edit", "huge-layer-edit", "multi-stage-small-edit",
-    "farm-concurrent-churn"])
+    "farm-concurrent-churn", "run-steps-edit"])
 def test_every_cell_finds_its_files_and_a_reader_for_each_metric(cell):
     """What ``run.py`` looks up by name for a cell: configuration, mix,
     reference, and a reader for every metric either kind of run
