@@ -71,6 +71,10 @@ _MAX_SYMLINK_DEPTH = 64
 # §6, PR 27). Twice the longest tick.
 _CLOCK_MARGIN = 0.02
 
+# Bytes of a member read from its tar and written under the root at a
+# time.
+_UNTAR_BLOCK = 1 << 20
+
 
 class Node:
     """One path in the merged view: header + disk source + children."""
@@ -498,6 +502,7 @@ class MemFS:
         hardlinks: list[tuple[str, tarfile.TarInfo]] = []
         parent_mtimes: dict[str, float] = {}
         unpacked = 0
+        members = {"created": 0, "probed": 0}
         if record is not None:
             self._record_ops = record
         try:
@@ -509,20 +514,28 @@ class MemFS:
                 if untar:
                     parent = os.path.dirname(disk_path)
                     if parent not in parent_mtimes:
-                        parent_mtimes[parent] = \
-                            os.lstat(parent).st_mtime
+                        try:
+                            parent_mtimes[parent] = \
+                                os.lstat(parent).st_mtime
+                        except FileNotFoundError:
+                            # The tar names no such directory: it is made
+                            # with the member and has no mtime to keep.
+                            pass
                 if hdr.islnk():
                     hdr.linkname = pathutils.abs_path(hdr.linkname)
                     hardlinks.append((disk_path, hdr))
                     continue
                 if untar:
-                    unpacked += self._untar_one(disk_path, hdr, tf)
+                    written, result = self._untar_one(disk_path, hdr, tf)
+                    unpacked += written
+                    members[result] += 1
                 self._maybe_add(layer, disk_path,
                                 pathutils.abs_path(hdr.name),
                                 hdr, create_whiteouts=False)
             for disk_path, hdr in hardlinks:
                 if untar:
-                    self._untar_one(disk_path, hdr, None)
+                    _, result = self._untar_one(disk_path, hdr, None)
+                    members[result] += 1
                 self._maybe_add(layer, disk_path,
                                 pathutils.abs_path(hdr.name),
                                 hdr, create_whiteouts=False)
@@ -533,6 +546,9 @@ class MemFS:
         if untar:
             metrics.counter_add(metrics.ON_DISK_BYTES_TOTAL, unpacked,
                                 op="untar")
+            for result, n in members.items():
+                metrics.counter_add(metrics.UNTAR_MEMBERS_TOTAL, n,
+                                    result=result)
         if chain_key is not None:
             self.extend_chain(chain_key)
         else:
@@ -573,9 +589,16 @@ class MemFS:
         return mountinfo.is_mounted(disk_path)
 
     def _untar_one(self, path: str, hdr: tarfile.TarInfo,
-                   tf: tarfile.TarFile | None) -> int:
+                   tf: tarfile.TarFile | None) -> tuple[int, str]:
         """Materialize one member under the root; returns the bytes of
-        file content written."""
+        file content written and how it went: ``created`` (the first
+        write made it) or ``probed`` (the file system was asked first).
+
+        A file, a directory or a symlink is written without asking what
+        is there: an exclusive create that fails is the answer an
+        ``lexists`` would have given, and only a member that collides
+        pays for the comparison with what it found. An empty root never
+        does; ``FROM`` an image onto ``/`` does for most members."""
         base = os.path.basename(path)
         if base.startswith(WHITEOUT_PREFIX):
             victim = os.path.join(
@@ -585,22 +608,44 @@ class MemFS:
                     shutil.rmtree(victim, ignore_errors=True)
                 else:
                     os.remove(victim)
-            return 0
+            return 0, "probed"
+        if not hdr.islnk():
+            try:
+                return self._untar_create(path, hdr, tf), "created"
+            except FileExistsError:
+                pass
         if os.path.lexists(path):
             local = tarinfo_from_stat(path, hdr.name, self.root)
             if tario.is_similar_header(local, hdr):
-                return 0
+                return 0, "probed"
             if hdr.isdir() and local.isdir():
                 # Never delete an existing dir (it may shelter mounts);
                 # just update its metadata.
                 tario.apply_header(path, hdr)
-                return 0
+                return 0, "probed"
             if os.path.isdir(path) and not os.path.islink(path):
                 shutil.rmtree(path)
             else:
                 os.remove(path)
+        return self._untar_create(path, hdr, tf), "probed"
+
+    def _untar_create(self, path: str, hdr: tarfile.TarInfo,
+                      tf: tarfile.TarFile | None) -> int:
+        """Make a member where nothing is; ``FileExistsError`` where
+        something is. A parent the tar never named is made on the
+        create's ``FileNotFoundError``, not looked for before it."""
+        try:
+            return self._untar_write(path, hdr, tf)
+        except FileNotFoundError:
+            if hdr.islnk():
+                raise  # the link's target is what is missing
+            os.makedirs(os.path.dirname(path), exist_ok=True)
+            return self._untar_write(path, hdr, tf)
+
+    def _untar_write(self, path: str, hdr: tarfile.TarInfo,
+                     tf: tarfile.TarFile | None) -> int:
         if hdr.isdir():
-            os.makedirs(path, exist_ok=True)
+            os.mkdir(path)
             tario.apply_header(path, hdr)
         elif hdr.issym():
             target = hdr.linkname
@@ -614,13 +659,22 @@ class MemFS:
         elif hdr.islnk():
             os.link(pathutils.join_root(self.root, hdr.linkname), path)
         else:
-            os.makedirs(os.path.dirname(path), exist_ok=True)
-            with open(path, "wb") as out:
-                if tf is not None and hdr.size > 0:
-                    reader = tf.extractfile(hdr)
-                    if reader is not None:
-                        shutil.copyfileobj(reader, out)
-            tario.apply_header(path, hdr)
+            # O_EXCL follows no link: a dangling one collides too.
+            fd = os.open(path, os.O_WRONLY | os.O_CREAT | os.O_EXCL
+                         | os.O_CLOEXEC, 0o666)
+            try:
+                reader = (tf.extractfile(hdr)
+                          if tf is not None and hdr.size > 0 else None)
+                if reader is not None:
+                    # Straight to the descriptor: a file object over it
+                    # starts with an fstat of the file just made.
+                    while block := reader.read(_UNTAR_BLOCK):
+                        view = memoryview(block)
+                        while view:
+                            view = view[os.write(fd, view):]
+                tario.apply_header_fd(fd, hdr)
+            finally:
+                os.close(fd)
             return hdr.size
         return 0
 

@@ -328,7 +328,7 @@ def remove_all_children(src_root: str, blacklist: list[str]) -> None:
     a surviving child simply fails its rmdir and is kept, which is
     exactly the recursive semantics."""
     stack = [os.path.join(src_root, name) for name in os.listdir(src_root)]
-    order: list[str] = []
+    order: list[tuple[str, bool]] = []
     while stack:
         path = stack.pop()
         try:
@@ -337,8 +337,10 @@ def remove_all_children(src_root: str, blacklist: list[str]) -> None:
             continue  # already gone
         if should_skip(path, st, blacklist):
             continue  # kept; its ancestors fail rmdir and survive too
-        order.append(path)
-        if os.path.isdir(path) and not os.path.islink(path):
+        # The lstat says "a directory and not a link": nothing asks again.
+        is_dir = statmod.S_ISDIR(st.st_mode)
+        order.append((path, is_dir))
+        if is_dir:
             # An unreadable dir (EACCES) must fail the cleanup loudly —
             # silently keeping its contents would leak stage-1 files
             # into stage-2 layers. A dir deleted since lstat is a benign
@@ -348,9 +350,9 @@ def remove_all_children(src_root: str, blacklist: list[str]) -> None:
             except (FileNotFoundError, NotADirectoryError):
                 continue  # deleted/replaced since lstat: benign race
             stack.extend(os.path.join(path, name) for name in names)
-    for path in reversed(order):
+    for path, is_dir in reversed(order):
         try:
-            if os.path.isdir(path) and not os.path.islink(path):
+            if is_dir:
                 os.rmdir(path)
             else:
                 os.remove(path)

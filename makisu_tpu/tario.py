@@ -477,6 +477,18 @@ def apply_header(path: str, h: tarfile.TarInfo) -> None:
         os.utime(path, (h.mtime, h.mtime))
 
 
+def apply_header_fd(fd: int, h: tarfile.TarInfo) -> None:
+    """:func:`apply_header` for a regular file through its open
+    descriptor, after the last write: the same three settings with no
+    path walked for them."""
+    os.fchmod(fd, h.mode)
+    try:
+        os.fchown(fd, h.uid, h.gid)
+    except PermissionError:
+        pass  # unprivileged runs keep the current owner
+    os.utime(fd, (h.mtime, h.mtime))
+
+
 def write_entry(tw, src: str, h: tarfile.TarInfo,
                 data: bytes | None = None) -> None:
     """Write one entry; regular-file content streams from ``src``.

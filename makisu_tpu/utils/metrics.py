@@ -178,6 +178,12 @@ LAYER_ENTRIES_TOTAL = "makisu_layer_entries_total"
 # the tree: the layer's content entries, ancestors written again among
 # them) | whiteout (tree children gone from disk).
 SCAN_ENTRIES_TOTAL = "makisu_scan_entries_total"
+# Members of each cached layer unpacked under the root (snapshot/
+# memfs.py, one add a result a layer): result=created (its first write
+# made it: nothing was in its place and nothing was asked) | probed
+# (the file system was asked first: something was in its place and was
+# compared, kept or replaced; a hard link; a whiteout).
+UNTAR_MEMBERS_TOTAL = "makisu_untar_members_total"
 # Regular files with content a native sink put into a layer, by how
 # their bytes came (chunker/hasher.py, added once a layer at the sink's
 # finish): result=ready (one of the sink's reader threads had them

@@ -58,6 +58,7 @@ NEW_READERS = ("stage_transition_s_per_build", "copy_on_disk_s_per_build",
                "on_disk_mb_per_build", "layer_commits_per_build",
                "mtime_wait_slept_per_build", "session_finish_s_per_build")
 ON_DISK = "makisu_on_disk_bytes_total"
+UNTARRED = "makisu_untar_members_total"
 COMMITS = "makisu_layer_commits_total"
 REPLAY = "makisu_layer_replay_total"
 SLEPT = "makisu_mtime_wait_total"
@@ -501,6 +502,11 @@ def test_image_is_the_final_stages_three_layers_alone(built):
                - image) == 2
 
 
+def _ancestors(path):
+    parts = path.split("/")[:-1]
+    return ["/".join(parts[:k + 1]) for k in range(len(parts))]
+
+
 def _delta(counters, name, **labels):
     before, after = counters
     return stats.counter_delta(before, after, name, **labels)
@@ -533,6 +539,14 @@ def test_rebuild_after_one_edit_commits_four_layers_and_unpacks_one(built):
     assert _delta(counters, ON_DISK, op="copy") == grown
     assert _delta(counters, ON_DISK, op="untar") == _SCALED
     assert _delta(counters, ON_DISK, op="checkpoint") == _SCALED + grown
+    # The one cached layer lands under an empty root: nothing is in a
+    # member's way, so none is looked for before it is written.
+    deps = [e["path"] for e in built["plan"] if e["layer"] == "deps"]
+    dirs = {d for p in deps for d in _ancestors("workspace/" + p)}
+    assert _delta(counters, UNTARRED, result="created") \
+        == len(deps) + len(dirs) == 105
+    assert _delta(counters, UNTARRED, result="probed") == 0
+    assert _delta(built["cold_counters"], UNTARRED) == 0
     # Three of the four committed layers are blobs the storage had: the
     # final stage's cache ids follow the builder stage's seed.
     cold, edited = _digests(built["cold"])[0], _digests(built["edited"])[0]
@@ -904,14 +918,18 @@ def _record(tmp_path, with_program_side):
         _series(ON_DISK, 2e6, op="checkpoint"),
         _series(COMMITS, 10.0),
         _series(SLEPT, 3.0, result="slept"),
-        _series(SLEPT, 30.0, result="clear")])
+        _series(SLEPT, 30.0, result="clear"),
+        _series(UNTARRED, 40.0, result="created"),
+        _series(UNTARRED, 0.0, result="probed")])
     r.counters_close = dict([
         _series(ON_DISK, 4e6, op="copy"),
         _series(ON_DISK, 8e6, op="checkpoint"),
         _series(ON_DISK, 3e6, op="untar"),
         _series(COMMITS, 22.0),
         _series(SLEPT, 5.0, result="slept"),
-        _series(SLEPT, 40.0, result="clear")])
+        _series(SLEPT, 40.0, result="clear"),
+        _series(UNTARRED, 340.0, result="created"),
+        _series(UNTARRED, 0.0, result="probed")])
     if not with_program_side:
         for b in r.counted:
             b.spans = [("apply_layer", 0.5)]
@@ -930,6 +948,7 @@ def _record(tmp_path, with_program_side):
     ("layer_commits_per_build", 4.0),
     ("mtime_wait_slept_per_build", 2 / 3),
     ("session_finish_s_per_build", 2.0),
+    ("untar_probe_free_pct", 100.0),  # PR 42's, this cell its second
 ])
 def test_new_reader_reads_a_run_and_nothing_from_an_older_program(
         tmp_path, metric, want):

@@ -328,7 +328,7 @@ def test_prefetch_metrics_list_their_cells():
     by_name = {m["name"]: m for m in per_layer}
     assert [m["name"] for m in per_layer[53:55]] == [
         "sink_prefetch_ready_pct", "read_wait_s_per_build"]
-    assert len(per_layer) == 59  # PR 41 appended four
+    assert len(per_layer) == 60  # PR 41 appended four, PR 42 one
     commit = by_name["tar_write_s_per_build"]
     for name, unit, better in (("sink_prefetch_ready_pct", "%", "higher"),
                                ("read_wait_s_per_build", "s", "lower")):

@@ -71,6 +71,7 @@ COMMITS = "makisu_layer_commits_total"
 REPLAY = "makisu_layer_replay_total"
 SLEPT = "makisu_mtime_wait_total"
 SCANNED = "makisu_scan_entries_total"
+UNTARRED = "makisu_untar_members_total"
 
 _BLOCK, _RECORD, _GEAR_QUANTUM = 512, 10240, 64 << 10
 # A time well before any test runs: no copied file waits out an mtime.
@@ -239,21 +240,22 @@ def _cell_reports_its_metrics():
     assert {m["name"] for m in cell.end_to_end()} \
         == {"build_p50_s", "setup_s"}
     mine = {m["name"] for m in cell.per_layer()}
-    assert mine == set(NEW_READERS) | set(JOINED)
+    # PR 42's one: what the unpack under the root asks the disk.
+    assert mine == set(NEW_READERS) | set(JOINED) | {"untar_probe_free_pct"}
     for name in mine:
         assert callable(cell.reader(name))
 
 
 def _new_metrics_list_their_cell():
     names = [m["name"] for m in BENCHMARK["per_layer"]]
-    assert names[-4:] == list(NEW_READERS)
-    layers = {m["layer"] for m in BENCHMARK["per_layer"][:-4]}
-    for m in BENCHMARK["per_layer"][-4:]:
+    assert names[55:59] == list(NEW_READERS)
+    layers = {m["layer"] for m in BENCHMARK["per_layer"][:55]}
+    for m in BENCHMARK["per_layer"][55:59]:
         assert m["workloads"] == [CELL]
         assert (m["moves"], m["better"]) == ("build_p50_s", "lower")
-    assert BENCHMARK["per_layer"][-4]["layer"] \
+    assert BENCHMARK["per_layer"][55]["layer"] \
         == "RUN step (steps/run_step.py, shell.py)"
-    for m in BENCHMARK["per_layer"][-3:]:
+    for m in BENCHMARK["per_layer"][56:59]:
         assert m["layer"] in layers
     # Appended, never inserted: the cell is the last of every list it
     # joined.
@@ -534,6 +536,12 @@ def test_rebuild_after_one_edit_unpacks_two_layers_and_executes_one_run(
     assert [(p, s["untar"]) for p, s, _ in _named(edited, "apply_layer")] \
         == [("step", "True")] * 2
     assert _delta(edited, ON_DISK, op="untar") == 700_000
+    # Under the fresh root a member's first write makes it: rootfs' 40
+    # files and 37 directories, node_modules, its 60 files and 37
+    # directories; only `app`, which WORKDIR made, was in a member's way.
+    assert _delta(edited, UNTARRED, result="created") == 77 + 98
+    assert _delta(edited, UNTARRED, result="probed") == 1
+    assert _delta(built["cold"], UNTARRED) == 0
     assert _delta(edited, ON_DISK, op="copy") \
         == 200_000 + _SMALL_EDIT["bytes"]
     [(parent, _, at_end)] = _named(edited, "run_exec")
@@ -857,11 +865,15 @@ def _record(tmp_path, with_program_side):
     r.counters_open = dict([
         _series(SCANNED, 100.0, result="visited"),
         _series(SCANNED, 10.0, result="added"),
-        _series(SCANNED, 0.0, result="whiteout")])
+        _series(SCANNED, 0.0, result="whiteout"),
+        _series(UNTARRED, 1000.0, result="created"),
+        _series(UNTARRED, 50.0, result="probed")])
     r.counters_close = dict([
         _series(SCANNED, 6400.0, result="visited"),
         _series(SCANNED, 310.0, result="added"),
-        _series(SCANNED, 6.0, result="whiteout")])
+        _series(SCANNED, 6.0, result="whiteout"),
+        _series(UNTARRED, 7300.0, result="created"),
+        _series(UNTARRED, 60.0, result="probed")])
     if not with_program_side:
         for b in r.counted:
             b.spans = [("layer_scan", 0.5), ("commit_layer", 1.0)]
@@ -877,6 +889,7 @@ def _record(tmp_path, with_program_side):
     ("fs_scan_s_per_build", 1.5),
     ("scan_visited_per_build", 2100.0),
     ("scan_whiteouts_per_build", 2.0),
+    ("untar_probe_free_pct", 100 * 6300 / 6310),
 ])
 def test_new_reader_reads_a_run_and_nothing_from_an_older_program(
         tmp_path, metric, want):
@@ -895,6 +908,31 @@ def test_whiteout_reader_reads_zero_where_no_scan_found_one(tmp_path):
             del counters[key]
     read = _module("readers", "scan_whiteouts_per_build.py").read
     assert read(r) == 0.0
+
+
+def test_probe_free_reader_reads_nothing_where_nothing_was_unpacked(
+        tmp_path):
+    """A window whose builds unpacked no layer on disk has the series
+    and no growth: no share to give."""
+    r = _record(tmp_path, True)
+    r.counters_close.update(
+        {k: v for k, v in r.counters_open.items() if k[0] == UNTARRED})
+    assert _module("readers", "untar_probe_free_pct.py").read(r) is None
+
+
+def test_probe_free_metric_lists_the_two_cells_that_unpack_on_disk():
+    assert metrics.UNTAR_MEMBERS_TOTAL == UNTARRED
+    [m] = [m for m in BENCHMARK["per_layer"]
+           if m["name"] == "untar_probe_free_pct"]
+    assert m is BENCHMARK["per_layer"][59]  # appended at PR 42
+    on_disk = {x["name"]: x for x in BENCHMARK["per_layer"]}[
+        "on_disk_mb_per_build"]
+    assert m == {"name": "untar_probe_free_pct", "unit": "%",
+                 "better": "higher", "source": "program_counter",
+                 "layer": on_disk["layer"], "moves": "build_p50_s",
+                 "workloads": ["multi-stage-small-edit", CELL]}
+    # The cells with --modifyfs: no other unpacks a layer under a root.
+    assert m["workloads"] == on_disk["workloads"]
 
 
 def test_scan_counter_is_named_once_and_adds_once_a_result_a_layer(

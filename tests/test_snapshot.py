@@ -6,6 +6,7 @@ Modeled on the reference's heaviest suite (lib/snapshot/mem_fs_test.go,
 
 import io
 import os
+import shutil
 import tarfile
 
 import pytest
@@ -28,7 +29,7 @@ def scan_layer(fs: MemFS):
     return names, layer
 
 
-def make_tar(entries) -> tarfile.TarFile:
+def tar_bytes(entries) -> bytes:
     """entries: list of (name, type, content/linkname, extra-attrs dict)."""
     buf = io.BytesIO()
     with tarfile.open(fileobj=buf, mode="w|") as tw:
@@ -48,8 +49,11 @@ def make_tar(entries) -> tarfile.TarFile:
                 tw.addfile(ti, io.BytesIO(data))
             else:
                 tw.addfile(ti)
-    buf.seek(0)
-    return tarfile.open(fileobj=buf, mode="r|")
+    return buf.getvalue()
+
+
+def make_tar(entries) -> tarfile.TarFile:
+    return tarfile.open(fileobj=io.BytesIO(tar_bytes(entries)), mode="r|")
 
 
 # ---------------------------------------------------------------------------
@@ -223,6 +227,345 @@ def test_update_without_untar_only_builds_tree(tmp_path):
     fs.update_from_tar(tf, untar=False)
     assert not (tmp_path / "x").exists()
     assert fs._lookup("/x/f") is not None
+
+
+# -- untar=True against a plain reference: stdlib extraction plus the
+# whiteout rule, on a twin root seeded the same way ------------------------
+
+_ME = {"uid": os.getuid(), "gid": os.getgid()}
+
+
+def _f(name, content, mode=0o644, mtime=1000):
+    return (name, tarfile.REGTYPE, content,
+            dict(_ME, mode=mode, mtime=mtime))
+
+
+def _d(name, mode=0o755, mtime=2000):
+    return (name, tarfile.DIRTYPE, None, dict(_ME, mode=mode, mtime=mtime))
+
+
+def _l(name, target):
+    return (name, tarfile.SYMTYPE, target, dict(_ME))
+
+
+def _remove(path):
+    if os.path.isdir(path) and not os.path.islink(path):
+        shutil.rmtree(path)
+    elif os.path.lexists(path):
+        os.remove(path)
+
+
+def _plain_untar(root, blob):
+    """The reference: members in the tar's order; a ``.wh.`` member
+    removes its victim; a member replaces what is in its place unless
+    both are directories; directory times are set last, as the tar
+    states them."""
+    stated = {}
+    with tarfile.open(fileobj=io.BytesIO(blob)) as tf:
+        for m in tf.getmembers():
+            dest = os.path.join(root, m.name)
+            head, base = os.path.split(dest)
+            if base.startswith(".wh."):
+                _remove(os.path.join(head, base[len(".wh."):]))
+                continue
+            on_disk_dir = os.path.isdir(dest) and not os.path.islink(dest)
+            if not (m.isdir() and on_disk_dir):
+                _remove(dest)
+            tf.extract(m, root, filter="fully_trusted")
+            if m.isdir():
+                stated[dest] = m.mtime
+    for dest, mtime in stated.items():
+        os.utime(dest, (mtime, mtime))
+
+
+def _disk(root, unstated=()):
+    """path → (kind, mode, whole seconds of mtime, content or target);
+    a symlink has no mode or time of its own to compare, a directory no
+    tar member stated no time."""
+    out = {}
+    for cur, dirs, files in os.walk(root):
+        for name in dirs + files:
+            path = os.path.join(cur, name)
+            rel = os.path.relpath(path, root)
+            st = os.lstat(path)
+            mode, mtime = st.st_mode & 0o7777, int(st.st_mtime)
+            if os.path.islink(path):
+                out[rel] = ("link", None, None, os.readlink(path))
+            elif os.path.isdir(path):
+                out[rel] = ("dir", mode,
+                            None if rel in unstated else mtime, None)
+            else:
+                with open(path, "rb") as f:
+                    out[rel] = ("file", mode, mtime, f.read())
+    return out
+
+
+def _entries(layer):
+    """dst → what the layer holds for it."""
+    out = {}
+    for dst, e in layer.entries.items():
+        if hasattr(e, "deleted"):
+            out[dst] = ("whiteout",)
+        else:
+            h = e.hdr
+            out[dst] = (h.type, h.mode, int(h.mtime), h.size, h.linkname,
+                        h.uid, h.gid)
+    return out
+
+
+def _members(blob):
+    """What the tar itself says of each path, in the shape of
+    ``_entries``."""
+    out = {}
+    with tarfile.open(fileobj=io.BytesIO(blob)) as tf:
+        for m in tf.getmembers():
+            head, base = os.path.split("/" + m.name.rstrip("/"))
+            if base.startswith(".wh."):
+                out[os.path.join(head, base[len(".wh."):])] = ("whiteout",)
+            else:
+                out["/" + m.name.rstrip("/")] = (
+                    m.type, m.mode, int(m.mtime), m.size, m.linkname,
+                    m.uid, m.gid)
+    return out
+
+
+def _seed_nothing(root):
+    pass
+
+
+def _seed_etc_with_a_stranger(root):
+    os.mkdir(os.path.join(root, "etc"))
+    with open(os.path.join(root, "etc", "keep.conf"), "w") as f:
+        f.write("keep me")
+    os.utime(os.path.join(root, "etc", "keep.conf"), (500, 500))
+    os.utime(os.path.join(root, "etc"), (600, 600))
+
+
+def _seed(kind):
+    def seed(root):
+        os.mkdir(os.path.join(root, "app"))
+        path = os.path.join(root, "app", "x")
+        if kind == "dir":
+            os.mkdir(path)
+            with open(os.path.join(path, "inner"), "w") as f:
+                f.write("inner")
+        elif kind == "file":
+            with open(path, "w") as f:
+                f.write("old")
+        elif kind == "dangling":
+            os.symlink("nowhere", path)
+        os.utime(os.path.join(root, "app"), (2000, 2000))
+    return seed
+
+
+_APP = _d("app/")
+_TREE = [_d("app/"), _f("app/bin", "#!/bin/sh\n", mode=0o755),
+         _d("app/lib/", mode=0o750, mtime=3000),
+         _f("app/lib/a.js", "a" * 300), _f("app/lib/empty", ""),
+         _l("app/link", "bin"), _d("var/")]
+_LOWER = [_d("a/"), _f("a/victim", "v"), _f("a/keep", "k"), _d("b/"),
+          _d("b/gone/"), _f("b/gone/f", "f")]
+_UPPER = [_d("a/"), _f("a/.wh.victim", ""), _f("a/new", "new"),
+          _d("b/", mtime=4000), _f("b/.wh.gone", "")]
+
+# name: (seed, layers applied before, the layer, created, probed, bytes
+# written, directories no member states)
+_UNTAR_CASES = {
+    "empty_root": (_seed_nothing, [], _TREE, 7, 0, 310, ()),
+    # Applied twice: the second time every member finds its like.
+    "every_member_exists_similar": (
+        _seed_nothing, [_TREE], _TREE, 0, 7, 0, ()),
+    "file_over_directory": (
+        _seed("dir"), [], [_APP, _f("app/x", "now a file")], 0, 2, 10, ()),
+    "directory_over_file": (
+        _seed("file"), [], [_APP, _d("app/x/"), _f("app/x/y", "y")],
+        1, 2, 1, ()),
+    "file_over_dangling_symlink": (
+        _seed("dangling"), [], [_APP, _f("app/x", "real")], 0, 2, 4, ()),
+    "symlink_over_file": (
+        _seed("file"), [], [_APP, _f("app/bin", "b"), _l("app/x", "bin")],
+        1, 2, 1, ()),
+    "directory_over_directory_that_holds_a_stranger": (
+        _seed_etc_with_a_stranger, [],
+        [_d("etc/", mode=0o700, mtime=7000), _f("etc/new.conf", "n")],
+        1, 1, 1, ()),
+    "no_directory_member_for_a_parent": (
+        _seed_nothing, [],
+        [_f("opt/pkg/bin/tool", "tool", mode=0o755), _l("opt/alt/t", "x"),
+         _d("srv/www/", mtime=5000)],
+        3, 0, 4, ("opt", "opt/pkg", "opt/pkg/bin", "opt/alt", "srv")),
+    "second_layer_with_whiteouts": (
+        _seed_nothing, [_LOWER], _UPPER, 1, 4, 3, ()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_UNTAR_CASES))
+def test_untar_gives_what_plain_extraction_gives(tmp_path, monkeypatch, case):
+    from makisu_tpu.utils import metrics
+    seed, before, entries, created, probed, written, unstated = \
+        _UNTAR_CASES[case]
+    ours, plain = str(tmp_path / "ours"), str(tmp_path / "plain")
+    for root in (ours, plain):
+        os.mkdir(root)
+        seed(root)
+    os.mkdir(tmp_path / "stays_empty")
+    fs, folded = new_fs(ours), new_fs(tmp_path / "stays_empty")
+    for lower in before:
+        fs.update_from_tar(make_tar(lower), untar=True)
+        folded.update_from_tar(make_tar(lower), untar=False)
+        _plain_untar(plain, tar_bytes(lower))
+    kept = {rel: os.lstat(os.path.join(ours, rel)).st_ino
+            for rel in _disk(ours)}
+    adds = []
+    monkeypatch.setattr(metrics, "counter_add",
+                        lambda name, value=1.0, **labels:
+                        adds.append((name, value, labels)))
+    blob = tar_bytes(entries)
+    layer = fs.update_from_tar(make_tar(entries), untar=True)
+    monkeypatch.undo()
+    _plain_untar(plain, blob)
+
+    assert _disk(ours, unstated) == _disk(plain, unstated)
+    assert [a for a in adds if a[0] != metrics.CACHED_LAYERS_APPLIED_TOTAL] \
+        == [(metrics.ON_DISK_BYTES_TOTAL, written, {"op": "untar"}),
+            (metrics.UNTAR_MEMBERS_TOTAL, created, {"result": "created"}),
+            (metrics.UNTAR_MEMBERS_TOTAL, probed, {"result": "probed"})]
+    if case == "every_member_exists_similar":
+        # Nothing was written again: each path is the inode it was.
+        assert {rel: os.lstat(os.path.join(ours, rel)).st_ino
+                for rel in _disk(ours)} == kept
+    # The layer: what the in-memory fold of the same tars holds, entry
+    # for entry; each member as the tar states it; the rest ancestors.
+    mine, stated = _entries(layer), _members(blob)
+    assert mine == _entries(
+        folded.update_from_tar(make_tar(entries), untar=False))
+    for dst, fields in mine.items():
+        if dst in stated:
+            assert fields == stated[dst], dst
+        else:
+            assert fields[0] == tarfile.DIRTYPE
+            assert any(m.startswith(dst + "/") for m in stated), dst
+    assert before or set(stated) <= set(mine)
+    assert os.listdir(tmp_path / "stays_empty") == []
+
+
+def _counting(monkeypatch, module, names):
+    """Count the calls of ``module.<name>`` from here on."""
+    counts = dict.fromkeys(names, 0)
+
+    def wrap(name, real):
+        def counted(*args, **kwargs):
+            counts[name] += 1
+            return real(*args, **kwargs)
+        return counted
+    for name in names:
+        monkeypatch.setattr(module, name, wrap(name, getattr(module, name)))
+    return counts
+
+
+def test_unpack_and_wipe_ask_nothing_the_loop_already_knows(
+        tmp_path, monkeypatch):
+    """Under an empty root a member is its writes: the whole unpack
+    makes no ``lexists``, ``exists``, ``isdir`` or ``makedirs``; the
+    wipe of that tree takes one ``lstat`` an entry and no ``isdir`` or
+    ``islink``."""
+    from makisu_tpu.snapshot.walk import remove_all_children
+    files, dirs = 24, 6
+    # The last file takes more than one read of its tar to write.
+    big = 2 * (1 << 20) + 5
+    entries = [_d(f"d{k}/") for k in range(dirs)] + [
+        _f(f"d{k % dirs}/f{k}", "x" * (k + 1 if k < files - 1 else big))
+        for k in range(files)]
+    fs = new_fs(tmp_path)
+    with monkeypatch.context() as m:
+        probes = _counting(m, os.path, ["lexists", "exists", "isdir",
+                                        "islink"])
+        made = _counting(m, os, ["makedirs", "fdopen"])
+        fs.update_from_tar(make_tar(entries), untar=True)
+    on_disk = _disk(str(tmp_path))
+    assert len(on_disk) == files + dirs
+    assert on_disk[f"d{(files - 1) % dirs}/f{files - 1}"][3] == b"x" * big
+    assert probes == {"lexists": 0, "exists": 0, "isdir": 0, "islink": 0}
+    assert made == {"makedirs": 0, "fdopen": 0}
+    with monkeypatch.context() as m:
+        probes = _counting(m, os.path, ["isdir", "islink", "lexists",
+                                        "exists"])
+        stats = _counting(m, os, ["lstat", "stat"])
+        remove_all_children(str(tmp_path), [])
+    assert os.listdir(tmp_path) == []
+    assert probes == {"isdir": 0, "islink": 0, "lexists": 0, "exists": 0}
+    assert stats == {"lstat": files + dirs, "stat": 0}
+
+
+def _wipe_tree(root):
+    for rel in ("a/b/c", "keep/deep", "other"):
+        os.makedirs(os.path.join(root, rel))
+    for rel in ("a/f", "a/b/c/g", "keep/deep/precious", "keep/h", "other/i"):
+        with open(os.path.join(root, rel), "w") as f:
+            f.write(rel)
+
+
+def _wipe_blacklisted(root, monkeypatch):
+    return [os.path.join(root, "keep", "deep")], \
+        {"keep", "keep/deep", "keep/deep/precious"}, None
+
+
+def _wipe_symlink_to_directory(root, monkeypatch):
+    outside = root + "-outside"
+    os.mkdir(outside)
+    with open(os.path.join(outside, "theirs"), "w") as f:
+        f.write("theirs")
+    os.symlink(outside, os.path.join(root, "a", "out"))
+    os.symlink("missing", os.path.join(root, "a", "dangling"))
+    return [], set(), lambda: os.listdir(outside) == ["theirs"]
+
+
+def _wipe_raced(root, monkeypatch):
+    """A file goes between its ``lstat`` and its delete, a directory
+    between its ``lstat`` and its listing."""
+    real = os.lstat
+    going = {os.path.join(root, "a", "f"): os.remove,
+             os.path.join(root, "other"): shutil.rmtree}
+
+    def lstat(path, *args, **kwargs):
+        st = real(path, *args, **kwargs)
+        going.pop(path, lambda gone: None)(path)
+        return st
+    monkeypatch.setattr(os, "lstat", lstat)
+    return [], set(), None
+
+
+@pytest.mark.parametrize("arrange", [
+    _wipe_blacklisted, _wipe_symlink_to_directory, _wipe_raced],
+    ids=lambda f: f.__name__[len("_wipe_"):])
+def test_remove_all_children_keeps_and_tolerates_what_it_did(
+        tmp_path, monkeypatch, arrange):
+    from makisu_tpu.snapshot.walk import remove_all_children
+    root = str(tmp_path / "root")
+    _wipe_tree(root)
+    blacklist, survivors, also = arrange(root, monkeypatch)
+    remove_all_children(root, blacklist)
+    monkeypatch.undo()
+    assert set(_disk(root)) == survivors
+    assert also is None or also()
+
+
+def test_remove_all_children_fails_on_a_directory_it_cannot_list(
+        tmp_path, monkeypatch):
+    """Keeping an unreadable directory's contents in silence would leak
+    one stage's files into the next stage's layers."""
+    from makisu_tpu.snapshot.walk import remove_all_children
+    root = str(tmp_path / "root")
+    _wipe_tree(root)
+    real = os.listdir
+
+    def listdir(path):
+        if path == os.path.join(root, "a", "b"):
+            raise PermissionError(13, "Permission denied", path)
+        return real(path)
+    monkeypatch.setattr(os, "listdir", listdir)
+    with pytest.raises(PermissionError):
+        remove_all_children(root, [])
 
 
 # ---------------------------------------------------------------------------
