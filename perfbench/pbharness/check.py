@@ -1,12 +1,17 @@
 """Decide ``correct``: after the window, untimed, a sample of the
-counted builds drawn from the seed (the last one always in it) is held
-against the configuration's reference. For each layer of a sampled
+counted builds the lane kept, drawn from the seed (the last one always
+in it), is held against the configuration's reference. What is in the
+sample follows from the lane's own record (``Build.kept``), never from
+what the file system holds: a kept build whose outputs are gone is
+drawn and counts ``missing_outputs``. For each layer of a sampled
 build:
 
 (a) the chunk list's cut points equal the reference's sequential gear
     scan over the stored tar stream;
 (b) every chunk fingerprint the build recorded equals hashlib's SHA-256
-    of those bytes, and the chunk store holds those bytes under it;
+    of those bytes, and the chunk store, asked through the owner of its
+    layout (``makisu_tpu.storage.cas.CASDir``), holds those bytes under
+    it;
 (c) the blob's and the tar's digests, as manifest, image config and
     cache entry state them, equal hashlib's over the stored blob;
 (d) for the last build of a lane (the tree on disk is then the tree it
@@ -31,19 +36,33 @@ _TAGS_KEPT = 12
 _MEMO_BYTES = 160 << 20
 
 
+def manifest_path(build) -> str:
+    repo, tag = build.tag.rsplit(":", 1)
+    return os.path.join(build.storage, "manifests", repo, tag + ".json")
+
+
+def chunk_store(build):
+    """The build's chunk store as a bare directory: no store is opened,
+    nothing is created or listed. Where a chunk lies in it is the
+    program's to say."""
+    from makisu_tpu.storage.cas import CASDir
+    return CASDir(os.path.join(build.storage, "chunks"))
+
+
 class Checker:
     def __init__(self, reference, context: dict) -> None:
         self.ref = reference
         self.context = context
         self.found = dict.fromkeys(LIMITS, 0)
         self.checked = {"builds": 0, "layers": 0, "chunks": 0, "members": 0}
+        self.sampled: list[str] = []
         self._layer_memo: dict = {}
         self.notes: list[str] = []
 
     def _manifest(self, build):
-        repo, tag = build.tag.rsplit(":", 1)
-        path = os.path.join(build.storage, "manifests", repo, tag + ".json")
-        with open(path, encoding="utf-8") as f:
+        """The manifest a build's tag names, the image config it names,
+        and the storage's cache entries by blob digest."""
+        with open(manifest_path(build), encoding="utf-8") as f:
             manifest = json.load(f)
         hexd = manifest["config"]["digest"].split(":", 1)[1]
         with open(os.path.join(build.storage, "layers", hexd[:2], hexd),
@@ -62,6 +81,7 @@ class Checker:
 
     def check_build(self, build, tree_is_current: bool) -> None:
         self.checked["builds"] += 1
+        self.sampled.append(build.tag)
         try:
             manifest, config, entries = self._manifest(build)
         except (OSError, ValueError, KeyError) as e:
@@ -117,18 +137,20 @@ class Checker:
                     len(set(ends) ^ set(want)), 1)
             # (b)
             view = memoryview(tar)
-            store = os.path.join(build.storage, "chunks")
+            store = chunk_store(build)
             for off, n, hexdigest in chunks:
                 self.checked["chunks"] += 1
                 piece = view[off:off + n]
                 if ref.sha256_hex(piece) != hexdigest:
                     self.found["chunk_digests_differing"] += 1
                 try:
-                    with open(os.path.join(store, hexdigest[:2], hexdigest),
-                              "rb") as f:
-                        if f.read() != piece:
-                            self.found["stored_chunks_differing"] += 1
-                except OSError:
+                    stored = store.read(hexdigest)
+                except Exception as e:  # noqa: BLE001 - whatever the
+                    # owner raises for an entry it does not hold
+                    stored = None
+                    self.notes.append(f"{build.tag}: chunk {hexdigest}: "
+                                      f"{e!r}")
+                if stored != piece:
                     self.found["stored_chunks_differing"] += 1
             # A layer that several sampled builds share (the edit cell's
             # lower layer, an unchanged rebuild's both) is looked at once.
@@ -147,25 +169,37 @@ class Checker:
     def verdict(self) -> bool:
         return all(self.found[k] <= LIMITS[k] for k in LIMITS)
 
+    def numbers(self) -> dict:
+        """Each number compared beside its limit, and how much was
+        looked at: the result line's ``check``."""
+        out = {k: {"value": self.found[k], "limit": limit}
+               for k, limit in LIMITS.items()}
+        out["checked"] = dict(self.checked)
+        out["sampled"] = list(self.sampled)
+        return out
+
     def lines(self) -> list[str]:
+        """What was looked at, what was found wanting, and last each
+        number compared beside its limit."""
         out = ["check: " + ", ".join(f"{v} {k}"
-                                     for k, v in self.checked.items())]
-        for k, limit in LIMITS.items():
-            out.append(f"check: {k} {self.found[k]} (limit {limit})")
-        return out + [f"check: {note}" for note in self.notes[:8]]
+                                     for k, v in self.checked.items())
+               + ": " + " ".join(self.sampled)]
+        out += [f"check: {note}" for note in self.notes[:8]]
+        return out + [f"check: {k} {self.found[k]} (limit {limit})"
+                      for k, limit in LIMITS.items()]
 
 
 def sample(run, rng: np.random.Generator, wanted: int) -> list:
     """(build, tree_is_current) pairs: ``wanted`` counted builds drawn
-    from the seed among those whose outputs are still on disk, the last
-    counted build always among them, and the last build of each lane
-    drawn (its tree on disk is the tree it built)."""
+    from the seed among those the lane kept, the last counted build
+    always among them, and the last build of each lane drawn (its tree
+    on disk is the tree it built)."""
     newest = {}
     for b in run.builds:
         newest[b.lane] = max(newest.get(b.lane, 0), b.index)
     # The program's manifest store keeps a storage's 16 newest tags, so
     # only a lane's last dozen builds can still be looked up.
-    have = [b for b in run.counted if b.ok and os.path.isdir(b.storage)
+    have = [b for b in run.counted if b.ok and b.kept
             and newest[b.lane] - b.index < _TAGS_KEPT]
     if not have:
         return []
@@ -186,6 +220,6 @@ def sample(run, rng: np.random.Generator, wanted: int) -> list:
         # tree is current; otherwise only the lane's last build's is.
         fresh = run.cell.traffic.get("fresh_storage", False)
         out.append((b, fresh or b is last))
-        if not fresh and b is not last and os.path.isdir(last.storage):
+        if not fresh and b is not last and last.kept:
             out.append((last, True))
     return out

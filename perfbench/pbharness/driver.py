@@ -4,10 +4,14 @@ socket, and every end-to-end number is read on the client's side.
 
 How a window counts builds (``traffic["count"]``):
 
-- ``started``: after set-up every lane waits for the window to open, a
-  build is counted if it *started* inside the window, and the last one
-  runs to its end. Each build is timed by itself; no number depends on
-  where the window closed.
+- ``started``: after set-up every lane waits for the window to open,
+  then builds one build after another and reads the clock once a build,
+  as the build ends. While that reading is inside the window one more
+  build follows the untimed work between two builds; the build whose
+  end reads past it is the last, and the check always has it. So a
+  build is counted if the window was open when the one before it ended,
+  and the last one runs to its end. Each build is timed by itself; no
+  number depends on where the window closed.
 - ``completed``: the lanes run without pause from priming on, the
   window opens once every lane has completed its rebuilds of the cell's
   kind, and a build is counted if it *completed* inside the window, with
@@ -40,6 +44,7 @@ _SUBMIT_RETRY_LIMIT = 1500
 _CONNECT_ERRORS = (BlockingIOError, ConnectionRefusedError,
                    FileNotFoundError, socket.timeout)
 _WINDOW_OPEN_MARK = "perfbench_window_open"
+_REMOVE_PASSES = 3
 
 
 @dataclasses.dataclass
@@ -59,6 +64,7 @@ class Build:
     retries: int = 0
     storage_growth: int | None = None
     counted: bool = False
+    kept: bool = False     # the lane left its outputs for the check
 
     @property
     def seconds(self) -> float:
@@ -197,19 +203,43 @@ class _Lane:
         return b
 
     def after_build(self, b: Build, keep: bool) -> None:
-        """Untimed: what the mix asks for between two builds."""
+        """Untimed: what the mix asks for between two builds. Only a
+        mix of fresh storages removes one; ``b.kept`` is the record the
+        check goes by."""
+        b.kept = keep or not self.fresh
         if self.traffic.get("drop_sessions"):
             try:
                 self.client.invalidate_sessions(b.context)
             except (OSError, RuntimeError, http.client.HTTPException):
                 pass
-        if self.fresh and not keep:
-            shutil.rmtree(b.storage, ignore_errors=True)
+        if not b.kept:
+            # A thread of the worker that outlives the request (the
+            # stat cache's deferred save) can create a file under the
+            # walk, and the directory then stands: remove again.
+            for _ in range(_REMOVE_PASSES):
+                shutil.rmtree(b.storage, ignore_errors=True)
+                if not os.path.lexists(b.storage):
+                    break
         if self.traffic.get("sync_between"):
             os.sync()
 
 
 _BUILDS_LOCK = threading.Lock()
+
+
+def drive_started(lane, kind: str, deadline: float, keep_rng,
+                  clock=time.monotonic) -> None:
+    """A ``started`` lane's window: builds until one ends past the
+    deadline. One clock reading a build decides both that the build is
+    the last, which is always kept, and that the loop ends; of the
+    others a cold mix keeps those ``keep_rng`` draws, one draw a build
+    in the builds' order."""
+    more = clock() < deadline
+    while more:
+        b = lane.build(kind)
+        b.counted = True
+        more = clock() < deadline
+        lane.after_build(b, keep=not more or keep_rng.random() < 0.5)
 
 
 def template_of(lane: int, config: dict) -> int:
@@ -277,12 +307,8 @@ def run_cell(run: Run, progress) -> None:
                     lane.build(kind)
                 primed.release()
                 open_gate.wait()
-                while time.monotonic() < run.t_open + run.seconds:
-                    b = lane.build(kind)
-                    b.counted = True
-                    last = time.monotonic() >= run.t_open + run.seconds
-                    lane.after_build(
-                        b, keep=last or keep_rng.random() < 0.5)
+                drive_started(lane, kind, run.t_open + run.seconds,
+                              keep_rng)
             else:
                 signalled = prime_rebuilds == 0
                 if signalled:

@@ -2,11 +2,15 @@
 harness, in this process only, so that the self-check and the chip
 controls can see ``correct`` come out false. Nothing in makisu_tpu
 knows of them; ``run.py --fault NAME`` applies one before the worker
-starts. A benchmark run never passes ``--fault``."""
+starts or, of ``AFTER_BUILDS``, after the window and before the check.
+``remade_storage`` is the one that must leave ``correct`` true: the
+check goes by the lane's record, not by what stands on disk. A
+benchmark run never passes ``--fault``."""
 
 from __future__ import annotations
 
 import os
+import sys
 
 
 def cut_mask() -> None:
@@ -56,5 +60,48 @@ def stale_tree(run) -> None:
                 return
 
 
+def _told(fault: str, build) -> None:
+    print(f"FAULT {fault} {build.tag}", file=sys.stderr, flush=True)
+
+
+def remade_storage(run) -> None:
+    """Every cold build the lane removed has its directory again, with
+    the one file the worker's deferred stat-cache save makes there when
+    it runs under the removal. The check must draw none of them."""
+    removed = [b for b in run.counted if b.ok and not b.kept]
+    if not removed:
+        raise SystemExit("remade_storage: the lane removed no counted "
+                         "build; take another seed")
+    for b in removed:
+        os.makedirs(b.storage, exist_ok=True)
+        with open(os.path.join(b.storage, "content_id_cache.json"), "w",
+                  encoding="utf-8") as f:
+            f.write("{}")
+        _told("remade_storage", b)
+
+
+def lost_manifest(run) -> None:
+    """The last counted build, which the lane kept, has lost its
+    manifest."""
+    from pbharness import check
+    os.unlink(check.manifest_path(run.counted[-1]))
+    _told("lost_manifest", run.counted[-1])
+
+
+def stored_chunk_byte(run) -> None:
+    """One byte of one chunk the last counted build recorded is altered
+    in its chunk store, through the store's owner."""
+    from pbharness import check
+    last = run.counted[-1]
+    manifest, _, entries = check.Checker(None, {})._manifest(last)
+    name = entries[manifest["layers"][0]["digest"]]["chunks"][0][2]
+    store = check.chunk_store(last)
+    data = store.read(name)
+    store.put(name, bytes([data[0] ^ 1]) + data[1:])
+    _told("stored_chunk_byte", last)
+
+
 BEFORE_WORKER = {"cut_mask": cut_mask, "digest_bit": digest_bit}
-AFTER_BUILDS = {"stale_tree": stale_tree}
+AFTER_BUILDS = {"stale_tree": stale_tree, "remade_storage": remade_storage,
+                "lost_manifest": lost_manifest,
+                "stored_chunk_byte": stored_chunk_byte}

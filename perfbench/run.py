@@ -6,10 +6,11 @@ One process owns the chip: it starts a makisu-tpu ``WorkerServer``,
 drives it through ``WorkerClient`` over the Unix socket, and prints, as
 the last line of its standard output, one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics`` and ``device`` (and, traced,
-``breakdown``). ``--trace 0`` reports the cell's end-to-end metrics with
-no poller, sampler or profiler in the process; ``--trace 1`` reports its
-per-layer metrics. It exits non-zero, with no result line, where JAX
-finds no TPU."""
+``breakdown``), then ``check``: each count the check compared beside
+its limit, also the last lines of the standard error. ``--trace 0``
+reports the cell's end-to-end metrics with no poller, sampler or
+profiler in the process; ``--trace 1`` reports its per-layer metrics.
+It exits non-zero, with no result line, where JAX finds no TPU."""
 
 import time
 T_START = time.monotonic()
@@ -127,8 +128,6 @@ def _measure(args, cell, run, devices) -> int:
                           int(cell.traffic.get("check_builds", 2)))
     for build, tree_is_current in picked:
         checker.check_build(build, tree_is_current)
-    for line in checker.lines():
-        say(line)
     say(f"check took {time.monotonic() - t:.1f}s")
     failed = sum(1 for b in run.counted if not b.ok)
     correct = bool(picked) and checker.verdict()
@@ -161,6 +160,11 @@ def _measure(args, cell, run, devices) -> int:
             "idle_gaps": xplane.charge_gaps(
                 trace, run.samples, run.t_open,
                 lambda t: sum(1 for s, e in spans if s <= t <= e))}
+    # Each number compared, beside its limit: the last key of the
+    # result and the last lines of the standard error.
+    result["check"] = checker.numbers()
+    print("\n".join(f"[perfbench] {line}" for line in checker.lines()),
+          file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

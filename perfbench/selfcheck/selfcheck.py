@@ -14,7 +14,12 @@ It proves nothing about the device. It checks that
    the same ``stored_per_user_byte`` over the same builds;
 6. with the timed path broken underneath (``--fault``: the control
    ``cut_mask``, and ``digest_bit``, ``stale_tree``) ``correct`` comes
-   out false.
+   out false; so it does where a kept build has lost its manifest
+   (``lost_manifest``: ``missing_outputs``) or a stored chunk a byte
+   (``stored_chunk_byte``: ``stored_chunks_differing``);
+7. where the storages the lane removed stand again with one file in
+   them (``remade_storage``) ``correct`` stays true and none of them is
+   among the builds checked.
 """
 
 import json
@@ -75,6 +80,7 @@ def check(name: str, ok: bool, detail: str = "") -> bool:
 def main() -> int:
     import numpy as np
     from pbharness import kernels, stats, xplane
+    from pbharness.check import LIMITS
     from reference import cdc
     good = True
 
@@ -177,16 +183,41 @@ def main() -> int:
                   proc.stdout[-300:] if not result else "")
 
     # 6. broken underneath
-    for workload, fault in (("monorepo-cold", "cut_mask"),
-                            ("farm-unchanged", "cut_mask"),
-                            ("monorepo-cold", "digest_bit"),
-                            ("monorepo-edit", "stale_tree")):
+    for workload, fault, count in (
+            ("monorepo-cold", "cut_mask", "cut_points_differing"),
+            ("farm-unchanged", "cut_mask", "cut_points_differing"),
+            # the program's own chunk store refuses the chunk, every
+            # build fails and none is left to check
+            ("monorepo-cold", "digest_bit", None),
+            ("monorepo-edit", "stale_tree", "tar_members_differing"),
+            ("monorepo-cold", "lost_manifest", "missing_outputs"),
+            ("monorepo-edit", "stored_chunk_byte",
+             "stored_chunks_differing")):
         rc, result, _, proc = run_inner(
             "--workload", workload, "--seed", "43", "--seconds", "4",
             "--trace", "0", "--fault", fault)
-        good &= check(f"{workload} with {fault}: correct comes out false",
-                      bool(result) and result["correct"] is False,
-                      proc.stdout[-300:] if not result else "")
+        good &= check(f"{workload} with {fault}: correct comes out false, "
+                      f"by {count or 'no build to check'}",
+                      bool(result) and result["correct"] is False
+                      and (result["check"][count]["value"] >= 1 if count
+                           else result["check"]["checked"]["builds"] == 0),
+                      proc.stdout[-300:] if not result else " ".join(
+                          f"{k} {result['check'][k]['value']}"
+                          for k in LIMITS if result["check"][k]["value"]))
+
+    # 7. what stands on disk without the lane's leave is not checked
+    rc, result, _, proc = run_inner(
+        "--workload", "monorepo-cold", "--seed", "43", "--seconds", "4",
+        "--trace", "0", "--fault", "remade_storage")
+    remade = [ln.split()[2] for ln in proc.stderr.splitlines()
+              if ln.startswith("FAULT remade_storage ")]
+    good &= check("monorepo-cold with remade_storage: correct stays true, "
+                  "no remade build is checked",
+                  bool(result) and result["correct"] is True and bool(remade)
+                  and not set(remade) & set(result["check"]["sampled"]),
+                  proc.stdout[-300:] if not result
+                  else f"remade {remade}, checked "
+                       f"{result['check']['sampled']}")
     print("self-check " + ("passed" if good else "FAILED"))
     return 0 if good else 1
 
