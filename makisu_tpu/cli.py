@@ -14,6 +14,7 @@ import contextvars
 import cProfile
 import os
 import sys
+import threading
 
 import makisu_tpu
 from makisu_tpu import tario
@@ -32,6 +33,16 @@ invocation_mode: "contextvars.ContextVar[str]" = contextvars.ContextVar(
 
 
 def make_parser() -> argparse.ArgumentParser:
+    """A fresh tree, for whoever wants one of their own. ``main`` and
+    the worker's request path parse through ``parse_args``, whose tree
+    the process builds once."""
+    return _parser_tree()[0]
+
+
+def _parser_tree() -> tuple[argparse.ArgumentParser,
+                            argparse.ArgumentParser]:
+    """The parser and its ``build`` sub-parser (whose two environment
+    defaults ``parse_args`` reads anew each parse)."""
     parser = argparse.ArgumentParser(
         prog="makisu-tpu",
         description="TPU-native daemonless container image builder.")
@@ -168,10 +179,9 @@ def make_parser() -> argparse.ArgumentParser:
     build.add_argument("--http-cache-addr", default="")
     build.add_argument("--http-cache-header", action="append", default=[])
     build.add_argument("--docker-host",
-                       default=os.environ.get("DOCKER_HOST",
-                                              "unix:///var/run/docker.sock"))
+                       default=_docker_env_defaults()["docker_host"])
     build.add_argument("--docker-version",
-                       default=os.environ.get("DOCKER_VERSION", "1.21"))
+                       default=_docker_env_defaults()["docker_version"])
     build.add_argument("--load", action="store_true",
                        help="load the image into the local docker daemon")
     build.add_argument("--storage", default="",
@@ -705,7 +715,40 @@ def make_parser() -> argparse.ArgumentParser:
                               "artifact to FILE")
 
     sub.add_parser("version", help="print the build version")
-    return parser
+    return parser, build
+
+
+def _docker_env_defaults() -> dict[str, str]:
+    return {"docker_host": os.environ.get("DOCKER_HOST",
+                                          "unix:///var/run/docker.sock"),
+            "docker_version": os.environ.get("DOCKER_VERSION", "1.21")}
+
+
+# The process's one parser tree: ~150 ``add_argument``s and, through
+# argparse's ``_()``, some sixty ``stat``s a tree (``gettext.find``),
+# which a worker paid three times a request. argparse does not promise
+# that one parser parses on two threads at once, and the environment's
+# two defaults are set on the tree before each parse: both under the
+# lock (a parse is ~0.2 ms).
+_shared_tree: tuple[argparse.ArgumentParser,
+                    argparse.ArgumentParser] | None = None
+_parse_lock = threading.Lock()
+
+
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
+    """``argv`` parsed by the parser the process built once. Raises
+    ``SystemExit`` with argparse's message on the standard error for a
+    malformed ``argv``, as ``make_parser().parse_args`` does."""
+    global _shared_tree
+    with _parse_lock:
+        if _shared_tree is None:
+            _shared_tree = _parser_tree()
+        parser, build = _shared_tree
+        build.set_defaults(**_docker_env_defaults())
+        args = parser.parse_args(argv)
+    metrics.counter_add(metrics.REQUEST_RESOLVE_TOTAL, kind="parse",
+                        result="done")
+    return args
 
 
 def _storage_dir(flag: str) -> str:
@@ -861,6 +904,8 @@ def _build_once(args) -> int:
     # release covers the set-up span's own close as well.
     store = ctx = preserver = build_session = None
     build_ok = False
+    storage_dir = _storage_dir(args.storage)
+    abs_context = os.path.abspath(args.context)
     try:
         try:
             # What a build does before its plan exists: the builder's
@@ -906,8 +951,8 @@ def _build_once(args) -> int:
                 target = ImageName.parse(args.tag)
                 replicas = [ImageName.parse(r) for r in args.replica]
 
-                store = ImageStore(_storage_dir(args.storage))
-                ctx = BuildContext(args.root, os.path.abspath(args.context),
+                store = ImageStore(storage_dir)
+                ctx = BuildContext(args.root, abs_context,
                                    store, hasher=get_hasher(args.hasher),
                                    blacklist=blacklist,
                                    gzip_backend_id=gzip_backend_id)
@@ -943,7 +988,6 @@ def _build_once(args) -> int:
                 # did not cover would leak the session busy forever.
                 from makisu_tpu.utils import ledger as ledger_mod
                 from makisu_tpu.worker import session as session_mod
-                abs_context = os.path.abspath(args.context)
                 if session_mod.enabled():
                     # The restore spec (storage dir + PORTABLE flag
                     # identity) lets a cold acquire consult the
@@ -953,9 +997,9 @@ def _build_once(args) -> int:
                     # excludes it.
                     build_session, verdict = session_mod.manager().acquire(
                         abs_context, session_mod.identity_from_build_args(
-                            args, _storage_dir(args.storage), gzip_backend_id),
+                            args, storage_dir, gzip_backend_id),
                         restore_spec=(
-                            _storage_dir(args.storage),
+                            storage_dir,
                             session_mod.portable_identity_from_build_args(
                                 args, gzip_backend_id)))
                 else:
@@ -1987,9 +2031,16 @@ def _device_identity() -> dict | None:
     return ops_backend.device_identity() if ops_backend else None
 
 
-def main(argv: list[str] | None = None) -> int:
-    parser = make_parser()
-    args = parser.parse_args(argv)
+def main(argv: list[str] | None = None,
+         args: argparse.Namespace | None = None) -> int:
+    """Run one command. ``args`` is ``argv`` already parsed by
+    ``parse_args`` (the worker parses a request once, at admission, and
+    hands the namespace on); without it ``argv`` is parsed here."""
+    if args is None:
+        args = parse_args(argv)
+    else:
+        metrics.counter_add(metrics.REQUEST_RESOLVE_TOTAL, kind="parse",
+                            result="reused")
     log.configure(args.log_level.replace("warn", "warning"), args.log_fmt,
                   args.log_output)
     if args.transfer_concurrency or args.transfer_memory_budget:
@@ -2024,7 +2075,7 @@ def main(argv: list[str] | None = None) -> int:
                 "du": cmd_du, "profile": cmd_profile}
     handler = handlers.get(args.command)
     if handler is None:
-        parser.print_help()
+        make_parser().print_help()
         return 1
     profiler = None
     if args.cpu_profile:

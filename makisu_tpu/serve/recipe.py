@@ -54,6 +54,7 @@ import threading
 from makisu_tpu.utils import fileio
 from makisu_tpu.utils import logging as log
 from makisu_tpu.utils import metrics
+from makisu_tpu.utils import pathutils
 
 RECIPE_SCHEMA = "makisu-tpu.recipe.v1"
 
@@ -219,7 +220,7 @@ class RecipeStore:
 
     def __init__(self, root: str, chunk_root: str) -> None:
         self.root = root
-        self.chunk_root = os.path.realpath(chunk_root)
+        self.chunk_root = pathutils.real_path(chunk_root)
         self._recipes_dir = os.path.join(root, "recipes")
         self._packs_dir = os.path.join(root, "packs")
         self._zpacks_dir = os.path.join(root, "zpacks")

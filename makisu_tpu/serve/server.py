@@ -37,6 +37,7 @@ from makisu_tpu.serve import recipe as recipe_mod
 from makisu_tpu.utils import events
 from makisu_tpu.utils import logging as log
 from makisu_tpu.utils import metrics
+from makisu_tpu.utils import pathutils
 
 # Prometheus text exposition content type (format 0.0.4).
 _METRICS_CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"
@@ -74,7 +75,7 @@ def register_store(storage_dir: str) -> recipe_mod.RecipeStore:
     """Idempotently create/fetch the RecipeStore for a storage dir
     (recipes+packs under ``<storage>/serve/``, chunk bytes from
     ``<storage>/chunks``)."""
-    key = os.path.realpath(storage_dir)
+    key = pathutils.real_path(storage_dir)
     with _stores_mu:
         store = _stores.get(key)
         if store is None:
@@ -87,7 +88,7 @@ def register_store(storage_dir: str) -> recipe_mod.RecipeStore:
 
 def store_for(storage_dir: str) -> recipe_mod.RecipeStore | None:
     with _stores_mu:
-        return _stores.get(os.path.realpath(storage_dir))
+        return _stores.get(pathutils.real_path(storage_dir))
 
 
 def stores(roots=None) -> list[recipe_mod.RecipeStore]:

@@ -24,6 +24,8 @@ import threading
 import time
 from typing import BinaryIO, Callable, Iterable, Iterator
 
+from makisu_tpu.utils import pathutils
+
 _SHARD_CHARS = 2
 
 
@@ -485,7 +487,7 @@ _live_lock = threading.Lock()
 
 def register_live(store: CASStore) -> None:
     with _live_lock:
-        _live[os.path.realpath(store.root)] = store
+        _live[pathutils.real_path(store.root)] = store
 
 
 def live_stores(roots=None) -> list[CASStore]:
@@ -500,5 +502,5 @@ def store_for(root: str) -> CASDir:
     process has one open (its recency then hears of what is read, put
     and deleted), else the bare directory."""
     with _live_lock:
-        live = _live.get(os.path.realpath(root))
+        live = _live.get(pathutils.real_path(root))
     return live if live is not None else CASDir(root)

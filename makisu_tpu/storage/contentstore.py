@@ -65,6 +65,7 @@ from makisu_tpu.storage import cas
 from makisu_tpu.utils import events
 from makisu_tpu.utils import logging as log
 from makisu_tpu.utils import metrics
+from makisu_tpu.utils import pathutils
 
 TIERS = ("hot", "pack", "remote")
 
@@ -104,7 +105,7 @@ def configure(budget_mb: int | None = None, remote: str | None = None,
 def set_budget_for(storage_dir: str, budget_bytes: int | None) -> None:
     """Per-directory budget override (the eviction soak runs a
     budgeted worker and an unbudgeted oracle in one process)."""
-    key = os.path.realpath(storage_dir)
+    key = pathutils.real_path(storage_dir)
     with _config_mu:
         if budget_bytes is None:
             _dir_budgets.pop(key, None)
@@ -125,7 +126,7 @@ def _env_mb(name: str) -> int | None:
 
 def budget_bytes_for(storage_dir: str) -> int:
     """Resolved hot-tier budget for this dir; 0 = unbounded."""
-    key = os.path.realpath(storage_dir)
+    key = pathutils.real_path(storage_dir)
     with _config_mu:
         if key in _dir_budgets:
             return _dir_budgets[key]
@@ -217,7 +218,7 @@ _boards: dict[str, PinBoard] = {}
 
 
 def board_for(storage_dir: str) -> PinBoard:
-    key = os.path.realpath(storage_dir)
+    key = pathutils.real_path(storage_dir)
     with _boards_mu:
         board = _boards.get(key)
         if board is None:
@@ -230,7 +231,7 @@ def storage_dir_for_chunk_root(chunk_root: str) -> str:
     parent storage dir (the same disambiguation the worker's
     ``add_served_chunk_root`` applies); a bare nonstandard CAS path
     keys by itself."""
-    root = os.path.realpath(chunk_root)
+    root = pathutils.real_path(chunk_root)
     if os.path.basename(root) == "chunks":
         return os.path.dirname(root)
     return root
@@ -484,7 +485,7 @@ class ContentStore:
     def __init__(self, storage_dir: str,
                  budget_bytes: int | None = None,
                  remote_dir: str | None = None) -> None:
-        self.storage_dir = os.path.realpath(storage_dir)
+        self.storage_dir = pathutils.real_path(storage_dir)
         self._budget = budget_bytes
         self._remote = remote_dir
         self.board = board_for(self.storage_dir)
@@ -993,7 +994,7 @@ _stores: dict[str, ContentStore] = {}
 
 
 def store_for(storage_dir: str) -> ContentStore:
-    key = os.path.realpath(storage_dir)
+    key = pathutils.real_path(storage_dir)
     with _stores_mu:
         store = _stores.get(key)
         if store is None:

@@ -289,6 +289,15 @@ SPAN_SELF_CPU_SECONDS = "makisu_span_self_cpu_seconds_total"
 # ``service_seconds``. One add a request.
 WORKER_BUILD_THREAD_CPU_SECONDS = \
     "makisu_worker_build_thread_cpu_seconds_total"
+# What a request asked for more than once, by how it was answered
+# (cli.py:parse_args and main, worker/server.py:run_build,
+# utils/pathutils.py:real_path; one add a question): kind=parse (the
+# request's argv turned into flags) | realpath (a directory of the
+# request canonicalised through its symlinks); result=done (a parser
+# ran, a path was walked: an ``lstat`` a component) | reused (the
+# record the worker made at the request's admission answered). Outside
+# a worker's request everything reads ``done``.
+REQUEST_RESOLVE_TOTAL = "makisu_request_resolve_total"
 
 
 def stage_busy_add(stage: str, seconds: float) -> None:

@@ -7,8 +7,11 @@ bind-mounts read-only.
 
 from __future__ import annotations
 
+import contextvars
 import functools
 import os
+
+from makisu_tpu.utils import metrics
 
 DEFAULT_STORAGE_DIR = "/makisu-storage"
 DEFAULT_INTERNAL_DIR = "/makisu-internal"
@@ -87,3 +90,69 @@ def ancestors(p: str) -> list[str]:
     """All proper ancestor directories of p, outermost first ('/a', '/a/b')."""
     parts = split_path(p)
     return ["/" + "/".join(parts[:i]) for i in range(1, len(parts))]
+
+
+# -- a request's directories, resolved once ----------------------------------
+
+# The real paths of the directories a worker's request names (--root,
+# --storage, the context), as given, absolute and resolved, each to its
+# real path: made at the request's admission, bound to its context like
+# its log sink, and gone with it. Nothing here outlives a request: the
+# next build's storage may be a directory made anew, or a symlink
+# retargeted since.
+_request_dirs: "contextvars.ContextVar[dict[str, str] | None]" = \
+    contextvars.ContextVar("makisu_request_dirs", default=None)
+
+
+def _asked(result: str) -> None:
+    metrics.counter_add(metrics.REQUEST_RESOLVE_TOTAL, kind="realpath",
+                        result=result)
+
+
+def resolve_request_dirs(dirs) -> dict[str, str]:
+    """Walk each directory of ``dirs`` through its symlinks, once: the
+    map ``bind_request_dirs`` takes."""
+    known: dict[str, str] = {}
+    for given in dirs:
+        if given in known:
+            continue
+        real = os.path.realpath(given)
+        _asked("done")
+        for form in (given, os.path.abspath(given), real):
+            known.setdefault(form, real)
+    return known
+
+
+def bind_request_dirs(known: dict[str, str]):
+    """Returns a token for ``reset_request_dirs``."""
+    return _request_dirs.set(known)
+
+
+def reset_request_dirs(token) -> None:
+    _request_dirs.reset(token)
+
+
+def real_path(path: str) -> str:
+    """``os.path.realpath(path)``, answered by the request where it
+    has resolved ``path`` already. A path one component below a
+    directory the request knows (``<storage>/chunks``) costs that
+    component's ``lstat`` the first time and is known from then on.
+    Any other path, and every path outside a request, is walked."""
+    known = _request_dirs.get()
+    if known is None:
+        _asked("done")
+        return os.path.realpath(path)
+    real = known.get(path)
+    if real is not None:
+        _asked("reused")
+        return real
+    _asked("done")
+    parent, name = os.path.split(path)
+    real_parent = known.get(parent)
+    if real_parent is None or name in ("", ".", ".."):
+        return os.path.realpath(path)
+    real = os.path.join(real_parent, name)
+    if os.path.islink(real):
+        real = os.path.realpath(real)
+    known[path] = known[real] = real
+    return real

@@ -24,7 +24,9 @@ fairness (ROADMAP item 1).
 
 from __future__ import annotations
 
+import argparse
 import collections
+import dataclasses
 import io
 import json
 import os
@@ -34,6 +36,8 @@ import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler
+
+from makisu_tpu.utils import pathutils
 
 # Prometheus text exposition content type (format 0.0.4).
 _METRICS_CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"
@@ -712,27 +716,52 @@ class _Handler(BaseHTTPRequestHandler):
             pass  # client hung up; not our problem
 
 
-def _effective_flags(argv: list[str]) -> dict:
+@dataclasses.dataclass(frozen=True, slots=True)
+class _Resolved:
+    """What one request resolved at its admission, once: its flags and
+    the real paths of the directories it names. ``args`` is ``None``
+    for a malformed ``argv``, which ``cli.main`` parses itself so that
+    argparse's message and exit code are what a one-shot CLI gives."""
+
+    args: argparse.Namespace | None = None
+    log_level: str = "info"
+    root: str | None = None
+    storage: str | None = None
+    # --root, --storage and the context, as given / absolute / real,
+    # each to its real path (``pathutils.real_path`` while bound).
+    dirs: dict[str, str] = dataclasses.field(default_factory=dict)
+
+
+def _effective_flags(argv: list[str]) -> _Resolved:
     """Resolve the flags the worker cares about through the REAL CLI
     parser — hand-rolled argv scanning would miss argparse's equals
-    form, abbreviations ('--stor'), and defaults, any of which would
-    punch holes in path-lock serialization or per-build log levels."""
+    form, abbreviations ('--roo'), and defaults, any of which would
+    punch holes in path-lock serialization or per-build log levels.
+
+    The one parse and the one resolution of a request: ``run_build``
+    calls this right after admission, hands ``args`` to ``cli.main``
+    (which then parses nothing) and the record to everything that
+    asked ``argv`` before; --root, --storage and the context are walked
+    through their symlinks here and nowhere else in the request
+    (``pathutils.real_path`` answers from ``dirs`` while it is
+    bound)."""
     from makisu_tpu import cli
-    out = {"root": None, "storage": None, "log_level": "info"}
     try:
-        args, _ = cli.make_parser().parse_known_args(argv)
+        args = cli.parse_args(argv)
     except SystemExit:
-        return out  # malformed argv: cli.main will report the error
-    out["log_level"] = getattr(args, "log_level", "info")
+        return _Resolved()  # malformed argv: cli.main will report it
     root = getattr(args, "root", None)
-    if root is not None:
-        out["root"] = root
     storage = getattr(args, "storage", None)
     if storage is not None:
         # "" means the computed default storage dir; resolve it so an
-        # explicit --storage of the same path shares the lock.
-        out["storage"] = cli._storage_dir(storage)
-    return out
+        # explicit --storage of the same path shares the lock, and so
+        # the command reads it from the namespace, computed.
+        storage = args.storage = cli._storage_dir(storage)
+    dirs = pathutils.resolve_request_dirs(
+        d for d in (root, storage, getattr(args, "context", None))
+        if d is not None)
+    return _Resolved(args, getattr(args, "log_level", "info"),
+                     root, storage, dirs)
 
 
 def _peer_map_version() -> int:
@@ -981,9 +1010,9 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
         degrading this worker's peer exchange to per-chunk GETs. A
         bare nonstandard CAS path has no recipe metadata to find and
         serves per-chunk only."""
-        root = os.path.realpath(storage_dir)
-        chunk_root = os.path.realpath(os.path.join(storage_dir,
-                                                   "chunks"))
+        root = pathutils.real_path(storage_dir)
+        chunk_root = pathutils.real_path(os.path.join(storage_dir,
+                                                      "chunks"))
         from makisu_tpu.serve import server as serve_server
         if os.path.basename(root) == "chunks":
             # Ambiguous shape: a CAS dir handed directly (the common
@@ -1017,7 +1046,7 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
 
     def _add_storage_dir(self, storage_dir: str) -> None:
         with self._storage_mu:
-            self._storage_dirs.add(os.path.realpath(storage_dir))
+            self._storage_dirs.add(pathutils.real_path(storage_dir))
             if self._scrub_thread is None:
                 interval = _scrub_interval_seconds()
                 if interval > 0:
@@ -1417,11 +1446,12 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
         # The sink honors this build's own --log-level (the shared
         # console logger's level is process-global and can't).
         flags = _effective_flags(argv)
-        level = flags["log_level"]
-        if flags["storage"]:
+        dirs_token = pathutils.bind_request_dirs(flags.dirs)
+        if flags.storage:
             # This build's chunk CAS becomes servable to fleet peers.
-            self.add_served_chunk_root(flags["storage"])
-        token = log.set_build_sink(sink, level.replace("warn", "warning"))
+            self.add_served_chunk_root(flags.storage)
+        token = log.set_build_sink(
+            sink, flags.log_level.replace("warn", "warning"))
         events_token = events.add_sink(event_sink)
         record_token = events.add_sink(record.note_event)
         mode_token = cli.invocation_mode.set("worker")
@@ -1456,12 +1486,12 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
                 "makisu_worker_active_builds",
                 self._builds_started - self._builds_succeeded
                 - self._builds_failed)
-        locks = self._shared_path_locks(argv)
+        locks = self._shared_path_locks(flags)
         for lock in locks:
             lock.acquire()
         code = 1
         try:
-            code = cli.main(argv)
+            code = cli.main(argv, flags.args)
             return code
         except SystemExit as e:
             # argparse exits with an int; cmd_report exits with a
@@ -1494,23 +1524,22 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
             # A storage its owner removed while the build ended (a
             # k8s job's scratch volume) is not made anew for a sidecar
             # or an eviction pass over nothing.
-            storage_there = bool(flags["storage"]) \
-                and os.path.isdir(flags["storage"])
+            storage_there = bool(flags.storage) \
+                and os.path.isdir(flags.storage)
             if storage_there and record.tenant:
                 # Ledger → census join: persist this build's layer
                 # hexes under its tenant so the storage census can
                 # attribute the bytes those layers put on disk.
                 from makisu_tpu.cache import census as census_mod
                 census_mod.record_attribution(
-                    flags["storage"], record.tenant,
+                    flags.storage, record.tenant,
                     record.layer_hexes())
             if storage_there:
                 # Budget enforcement at the moment disk grows: build
                 # end is when new chunks/blobs landed. Throttled and
                 # a no-op when unbudgeted; never fails the build.
                 from makisu_tpu.storage import contentstore
-                contentstore.store_for(
-                    flags["storage"]).maybe_evict()
+                contentstore.store_for(flags.storage).maybe_evict()
             fleet_peers.reset_self_socket(peers_token)
             session_mod.reset_manager(session_token)
             if fleet_token is not None:
@@ -1520,6 +1549,7 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
             events.reset_sink(record_token)
             events.reset_sink(events_token)
             log.reset_build_sink(token)
+            pathutils.reset_request_dirs(dirs_token)
             thread_cpu = time.thread_time() - admitted_cpu
             record.note_service(admitted, time.monotonic(), thread_cpu)
             metrics.counter_add(metrics.WORKER_BUILD_THREAD_CPU_SECONDS,
@@ -1792,19 +1822,22 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
         events.remove_global_sink(self._recorder_sink)
         super().server_close()
 
-    def _shared_path_locks(self, argv: list[str]) -> list:
+    def _shared_path_locks(self, flags: _Resolved) -> list:
         """Locks for this build's --root/--storage dirs (created on
         demand, acquired in sorted order so overlapping sets can't
         deadlock). Builds with disjoint paths share no locks and run
-        fully in parallel. Both ``--flag PATH`` and ``--flag=PATH``
-        spellings resolve, and paths canonicalize through symlinks —
-        missing either would let two builds race on one filesystem."""
-        flags = _effective_flags(argv)
+        fully in parallel. ``flags`` is the request's one resolution
+        (``_effective_flags``): both ``--flag PATH`` and ``--flag=PATH``
+        spellings resolved by the real parser, and the paths
+        canonicalized through symlinks at admission — missing either
+        would let two builds race on one filesystem."""
+        from makisu_tpu.utils import metrics
+        metrics.counter_add(metrics.REQUEST_RESOLVE_TOTAL, kind="parse",
+                            result="reused")
         paths = set()
         for name in ("root", "storage"):
-            value = flags[name]
-            key = (os.path.realpath(value) if value is not None
-                   else "<none>")
+            value = getattr(flags, name)
+            key = flags.dirs[value] if value is not None else "<none>"
             paths.add(f"--{name}={key}")
         with self._path_locks_mu:
             return [self._path_locks.setdefault(p, threading.Lock())

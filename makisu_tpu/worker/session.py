@@ -56,6 +56,7 @@ import importlib
 
 from makisu_tpu.utils import ledger, metrics
 from makisu_tpu.utils import logging as log
+from makisu_tpu.utils import pathutils
 
 # The snapshot package re-exports the walk FUNCTION under the module's
 # own name; resolve the MODULE explicitly.
@@ -844,7 +845,7 @@ class SessionManager:
         portable_identity)``; without it the snapshot plane is never
         consulted."""
         context_dir = os.path.abspath(context_dir)
-        key = os.path.realpath(context_dir)
+        key = pathutils.real_path(context_dir)
         now = time.monotonic()
         with self._mu:
             session = self._sessions.get(key)
@@ -951,7 +952,7 @@ class SessionManager:
         return None
 
     def release(self, session: BuildSession) -> None:
-        key = os.path.realpath(session.context_dir)
+        key = pathutils.real_path(session.context_dir)
         budget = max_resident_bytes()
         with self._mu:
             session.busy = False
@@ -984,7 +985,7 @@ class SessionManager:
         """The context's live session, if any — no lease, no
         invalidation checks (the watch loop polls change state through
         it between builds)."""
-        key = os.path.realpath(os.path.abspath(context_dir))
+        key = pathutils.real_path(os.path.abspath(context_dir))
         with self._mu:
             return self._sessions.get(key)
 
@@ -993,7 +994,7 @@ class SessionManager:
         bound to ("" when no resident session, or none has built yet)
         — the snapshot endpoints use it to pick the recipe's home
         among a multi-storage worker's dirs."""
-        key = os.path.realpath(os.path.abspath(context_dir))
+        key = pathutils.real_path(os.path.abspath(context_dir))
         with self._mu:
             session = self._sessions.get(key)
             return session.storage_dir or "" if session else ""
@@ -1004,7 +1005,7 @@ class SessionManager:
         dropped = 0
         with self._mu:
             if context_dir:
-                keys = [os.path.realpath(os.path.abspath(context_dir))]
+                keys = [pathutils.real_path(os.path.abspath(context_dir))]
             else:
                 keys = list(self._sessions)
             for key in keys:
@@ -1022,7 +1023,7 @@ class SessionManager:
         the snapshot plane NOW — the worker's POST /sessions/snapshot
         and the fleet's drain hand-off. Writes run outside the lock;
         returns the number of sessions checkpointed."""
-        want = (os.path.realpath(os.path.abspath(context_dir))
+        want = (pathutils.real_path(os.path.abspath(context_dir))
                 if context_dir else "")
         with self._mu:
             candidates = [s for k, s in self._sessions.items()

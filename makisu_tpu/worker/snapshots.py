@@ -51,6 +51,7 @@ import zlib
 
 from makisu_tpu.utils import fileio, metrics
 from makisu_tpu.utils import logging as log
+from makisu_tpu.utils import pathutils
 
 SNAPSHOT_SCHEMA = "makisu-tpu.session-snapshot.v1"
 SNAPSHOT_SUBDIR = os.path.join("serve", "snapshots")
@@ -74,7 +75,7 @@ def snapshots_dir(storage_dir: str) -> str:
 def snap_key(context_dir: str, portable_identity: str) -> str:
     """Recipe filename key: one recipe per (context, portable flag
     identity) — a checkpoint overwrites its predecessor atomically."""
-    blob = (os.path.realpath(os.path.abspath(context_dir))
+    blob = (pathutils.real_path(os.path.abspath(context_dir))
             + "\n" + portable_identity).encode()
     return hashlib.sha256(blob).hexdigest()
 
@@ -183,7 +184,7 @@ class SnapshotStore:
         """Newest recipe for a context regardless of identity — the
         prewarm pull path, where the front door knows the context key
         but not the resolved flag identity."""
-        key = os.path.realpath(os.path.abspath(context_dir))
+        key = pathutils.real_path(os.path.abspath(context_dir))
         best = None
         try:
             names = os.listdir(self.dir)
@@ -393,7 +394,7 @@ def _write_snapshot(session, storage_dir: str) -> dict | None:
 
     recipe = {
         "schema": SNAPSHOT_SCHEMA,
-        "context": os.path.realpath(session.context_dir),
+        "context": pathutils.real_path(session.context_dir),
         "identity": session.identity,
         "portable_identity": session.portable_identity,
         "isa": session.isa,
@@ -445,7 +446,7 @@ def restore_from_recipe(store: SnapshotStore, recipe: dict,
                         context_dir: str, identity: str,
                         portable_identity: str):
     from makisu_tpu.worker import session as session_mod
-    key = os.path.realpath(os.path.abspath(context_dir))
+    key = pathutils.real_path(os.path.abspath(context_dir))
     if recipe.get("context") != key:
         return None, "context_mismatch"
     if recipe.get("portable_identity") != portable_identity:

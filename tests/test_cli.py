@@ -218,3 +218,117 @@ def test_jax_profile_flag_writes_trace(tmp_path, context):
     assert rc == 0
     files = [p for p in trace.rglob("*") if p.is_file()]
     assert files  # xplane/trace artifacts written
+
+
+# -- one parser a process (PR 45) --------------------------------------------
+
+
+def test_parse_args_builds_its_tree_once(monkeypatch):
+    built = []
+    tree = cli._parser_tree
+    monkeypatch.setattr(cli, "_parser_tree",
+                        lambda: built.append(1) or tree())
+    monkeypatch.setattr(cli, "_shared_tree", None)
+    for tag in ("a:1", "b:2", "c:3"):
+        assert cli.parse_args(["build", "ctx", "-t", tag]).tag == tag
+    assert built == [1]
+    # make_parser still hands out a tree of one's own.
+    assert cli.make_parser() is not cli.make_parser()
+    assert len(built) == 3
+
+
+def test_parse_args_reads_the_environments_defaults_each_parse(
+        monkeypatch):
+    """DOCKER_HOST and DOCKER_VERSION are defaults read from the
+    environment: the kept tree must not hand out the values of the
+    parse that built it."""
+    argv = ["build", "ctx", "-t", "e:1"]
+    monkeypatch.delenv("DOCKER_HOST", raising=False)
+    monkeypatch.delenv("DOCKER_VERSION", raising=False)
+    args = cli.parse_args(argv)
+    assert (args.docker_host, args.docker_version) == (
+        "unix:///var/run/docker.sock", "1.21")
+    monkeypatch.setenv("DOCKER_HOST", "tcp://10.0.0.7:2375")
+    monkeypatch.setenv("DOCKER_VERSION", "1.40")
+    args = cli.parse_args(argv)
+    assert (args.docker_host, args.docker_version) == (
+        "tcp://10.0.0.7:2375", "1.40")
+    # A flag still wins over the environment.
+    args = cli.parse_args(argv + ["--docker-host", "unix:///x.sock"])
+    assert (args.docker_host, args.docker_version) == (
+        "unix:///x.sock", "1.40")
+    fresh = cli.make_parser().parse_args(argv)
+    assert fresh.docker_host == "tcp://10.0.0.7:2375"
+    monkeypatch.delenv("DOCKER_HOST")
+    assert cli.parse_args(argv).docker_host == \
+        "unix:///var/run/docker.sock"
+
+
+def test_sixteen_threads_parse_their_own_flags():
+    """One parser, sixteen threads, different ``argv``s, a few hundred
+    parses each: every namespace is its own thread's."""
+    import threading
+    wrong = []
+    start = threading.Barrier(16)
+
+    def one(i):
+        argv = ["--log-level", ("debug", "info", "warn", "error")[i % 4],
+                "--hash-workers", str(i), "build", f"/ctx/{i}",
+                "-t", f"t/{i}:1", f"--storage=/s/{i}", "--roo", f"/r/{i}",
+                "--build-arg", f"N={i}", "--blacklist", f"/b/{i}"]
+        if i % 2:
+            argv += ["--modifyfs", "--commit", "explicit"]
+        start.wait()
+        for _ in range(200):
+            args = cli.parse_args(argv)
+            got = (args.log_level, args.hash_workers, args.command,
+                   args.context, args.tag, args.storage, args.root,
+                   args.build_arg, args.blacklist, args.modifyfs,
+                   args.commit)
+            want = (("debug", "info", "warn", "error")[i % 4], i, "build",
+                    f"/ctx/{i}", f"t/{i}:1", f"/s/{i}", f"/r/{i}",
+                    [f"N={i}"], [f"/b/{i}"], bool(i % 2),
+                    "explicit" if i % 2 else "implicit")
+            if got != want:
+                wrong.append((i, got))
+
+    threads = [threading.Thread(target=one, args=(i,)) for i in range(16)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)     # hand the interpreter on mid-parse
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert wrong == []
+
+
+def test_main_takes_a_parsed_namespace(tmp_path, context, monkeypatch):
+    """Handed ``args``, ``main`` parses nothing: ``argv`` is not even
+    looked at."""
+    dest = tmp_path / "parsed.tar"
+    args = cli.parse_args([
+        "build", str(context), "-t", "test/parsed:1",
+        "--storage", str(tmp_path / "storage"),
+        "--root", str(tmp_path / "root"), "--dest", str(dest)])
+    (tmp_path / "root").mkdir()
+    monkeypatch.setattr(cli, "parse_args", None)
+    assert cli.main(["no", "such", "command"], args) == 0
+    assert dest.exists()
+
+
+@pytest.mark.parametrize("argv, code, message", [
+    (["build", "ctx"], 2, "the following arguments are required: -t"),
+    (["build", "ctx", "-t", "x:1", "--hasher", "gpu"], 2,
+     "invalid choice: 'gpu'"),
+    (["frobnicate"], 2, "invalid choice: 'frobnicate'"),
+])
+def test_malformed_argv_exits_with_argparses_message(capsys, argv, code,
+                                                     message):
+    with pytest.raises(SystemExit) as raised:
+        cli.main(argv)
+    assert raised.value.code == code
+    assert message in capsys.readouterr().err

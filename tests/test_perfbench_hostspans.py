@@ -328,7 +328,8 @@ def test_prefetch_metrics_list_their_cells():
     by_name = {m["name"]: m for m in per_layer}
     assert [m["name"] for m in per_layer[53:55]] == [
         "sink_prefetch_ready_pct", "read_wait_s_per_build"]
-    assert len(per_layer) == 60  # PR 41 appended four, PR 42 one
+    # PR 41 appended four, PR 42 one, PR 45 one
+    assert len(per_layer) == 61
     commit = by_name["tar_write_s_per_build"]
     for name, unit, better in (("sink_prefetch_ready_pct", "%", "higher"),
                                ("read_wait_s_per_build", "s", "lower")):
@@ -416,3 +417,63 @@ def test_every_cell_finds_its_files_and_a_reader_for_each_metric(cell):
     assert len(names) == len(set(names)) > 10
     for name in names:
         assert callable(found.reader(name)), name
+
+
+# -- PR 45: what a request asked about itself more than once -----------------
+
+RESOLVED = "makisu_request_resolve_total"
+
+
+def _resolved(tmp_path, program_side=True, asked=True):
+    """``_served``'s run over a window of 100 requests: each parsed once
+    (its locks and ``cli.main`` answered by the record), walked four
+    paths and was answered twenty."""
+    r = _served(tmp_path)
+    if program_side:
+        r.counters_open.update([
+            _series(RESOLVED, 40.0, kind="parse", result="done"),
+            _series(RESOLVED, 80.0, kind="parse", result="reused"),
+            _series(RESOLVED, 160.0, kind="realpath", result="done"),
+            _series(RESOLVED, 800.0, kind="realpath", result="reused")])
+        grown = 100.0 if asked else 0.0
+        r.counters_close.update([
+            _series(RESOLVED, 40.0 + grown, kind="parse", result="done"),
+            _series(RESOLVED, 80.0 + 2 * grown, kind="parse",
+                    result="reused"),
+            _series(RESOLVED, 160.0 + 4 * grown, kind="realpath",
+                    result="done"),
+            _series(RESOLVED, 800.0 + 20 * grown, kind="realpath",
+                    result="reused")])
+    return r
+
+
+def test_resolve_reuse_reader_reads_a_run_and_nothing_from_an_older_program(
+        tmp_path):
+    read = _reader("request_resolve_reuse_pct")
+    # 2,200 answered of 2,700 asked, both kinds together.
+    assert read(_resolved(tmp_path)) == pytest.approx(100 * 22 / 27)
+    # The parent's side of the driver's pair: no such series.
+    assert read(_resolved(tmp_path, program_side=False)) is None
+    untraced = _resolved(tmp_path)
+    untraced.counters_open = untraced.counters_close = None
+    assert read(untraced) is None
+    # The series is there and did not grow: no share to give.
+    assert read(_resolved(tmp_path, asked=False)) is None
+
+
+def test_resolve_reuse_metric_lists_the_cells_that_report_the_request():
+    import json
+
+    from makisu_tpu.utils import metrics
+    assert metrics.REQUEST_RESOLVE_TOTAL == RESOLVED
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json"),
+              encoding="utf-8") as f:
+        per_layer = json.load(f)["per_layer"]
+    m = per_layer[60]  # appended at PR 45
+    request = {x["name"]: x for x in per_layer}[
+        "request_overhead_s_per_build"]
+    assert m == {"name": "request_resolve_reuse_pct", "unit": "%",
+                 "better": "higher", "source": "program_counter",
+                 "layer": request["layer"], "moves": "build_p50_s",
+                 "workloads": request["workloads"]}
+    assert len(m["workloads"]) == 9

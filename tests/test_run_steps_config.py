@@ -240,8 +240,10 @@ def _cell_reports_its_metrics():
     assert {m["name"] for m in cell.end_to_end()} \
         == {"build_p50_s", "setup_s"}
     mine = {m["name"] for m in cell.per_layer()}
-    # PR 42's one: what the unpack under the root asks the disk.
-    assert mine == set(NEW_READERS) | set(JOINED) | {"untar_probe_free_pct"}
+    # PR 42's one: what the unpack under the root asks the disk; PR
+    # 45's one: what a request asks about itself more than once.
+    assert mine == set(NEW_READERS) | set(JOINED) | {
+        "untar_probe_free_pct", "request_resolve_reuse_pct"}
     for name in mine:
         assert callable(cell.reader(name))
 
@@ -981,7 +983,7 @@ def test_worker_makes_no_directory_for_a_storage_that_is_gone(
             "--root", str(tmp_path / "root")]
     kept = []
 
-    def build_then_lose_the_storage(_argv):
+    def build_then_lose_the_storage(_argv, _args=None):
         storage.mkdir()
         (storage / "layers").mkdir()
         if not kept:

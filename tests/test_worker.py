@@ -527,3 +527,226 @@ def test_builds_phase_is_named_from_the_first_span_on():
         seen.append(record.to_dict()["phase"])
     assert seen == ["", "setup", "setup", "setup", "hash", "push", "push",
                     "teardown"]
+
+
+# -- a request is resolved once (PR 45) --------------------------------------
+
+
+@pytest.fixture
+def server(tmp_path):
+    """A worker nobody connects to: ``run_build`` is called directly,
+    on the test's thread."""
+    srv = WorkerServer(str(tmp_path / "direct.sock"))
+    yield srv
+    srv.server_close()
+
+
+def _direct_argv(tmp_path, storage="dstorage", root="droot"):
+    ctx = tmp_path / "dctx"
+    if not ctx.exists():
+        ctx.mkdir()
+        (ctx / "Dockerfile").write_text(
+            "FROM scratch\nCOPY data.txt /data.txt\n")
+        (ctx / "data.txt").write_text("resolved once")
+    (tmp_path / root).mkdir(exist_ok=True)
+    return ["--log-level", "error", "build", str(ctx), "-t", "w/direct:1",
+            "--storage", str(tmp_path / storage),
+            "--root", str(tmp_path / root)]
+
+
+def _resolve_total(kind, result):
+    from makisu_tpu.utils import metrics
+    return metrics.global_registry().counter_total(
+        metrics.REQUEST_RESOLVE_TOTAL, kind=kind, result=result)
+
+
+def test_a_request_builds_no_parser_and_parses_once(
+        tmp_path, server, monkeypatch):
+    """Once the process has its parser, a request through ``run_build``
+    builds no tree (neither ``make_parser`` nor what it is made of) and
+    ``argv`` is parsed once: ``cli.main`` takes the namespace."""
+    import argparse
+
+    from makisu_tpu import cli
+    argv = _direct_argv(tmp_path)
+    assert server.run_build(argv, lambda line: None) == 0
+    calls = {"make_parser": 0, "_parser_tree": 0, "parse": 0}
+
+    def counted(name, fn):
+        def wrapper(*args, **kwargs):
+            calls[name] += 1
+            return fn(*args, **kwargs)
+        return wrapper
+
+    monkeypatch.setattr(cli, "make_parser",
+                        counted("make_parser", cli.make_parser))
+    monkeypatch.setattr(cli, "_parser_tree",
+                        counted("_parser_tree", cli._parser_tree))
+    # A sub-parser is entered through parse_known_args, so this counts
+    # whole parses of a command line.
+    monkeypatch.setattr(argparse.ArgumentParser, "parse_args", counted(
+        "parse", argparse.ArgumentParser.parse_args))
+    before = {(k, r): _resolve_total(k, r) for k in ("parse", "realpath")
+              for r in ("done", "reused")}
+    assert server.run_build(argv, lambda line: None) == 0
+    assert calls == {"make_parser": 0, "_parser_tree": 0, "parse": 1}
+    grown = {key: _resolve_total(*key) - was
+             for key, was in before.items()}
+    # One parse; the locks and cli.main were answered by the record.
+    assert grown["parse", "done"] == 1
+    assert grown["parse", "reused"] == 2
+    # --root, --storage and the context walked once each, and one
+    # lstat for <storage>/chunks; every other question was answered.
+    assert grown["realpath", "done"] == 4
+    assert grown["realpath", "reused"] >= 10
+
+
+@pytest.mark.parametrize("spelling", [
+    "separate", "equals", "abbreviated", "ambiguous", "default",
+    "symlinked_root", "relative", "no_paths"])
+def test_lock_keys_are_the_parents(tmp_path, server, monkeypatch,
+                                   spelling):
+    """``_shared_path_locks`` keys, as at 4589821: the real parser's
+    reading of --root and --storage (equals form, abbreviations, the
+    computed default), canonicalised through symlinks."""
+    import os
+
+    from makisu_tpu import cli
+    from makisu_tpu.worker import server as server_mod
+    storage = tmp_path / "lock storage"
+    storage.mkdir()
+    root = tmp_path / "lockroot"
+    root.mkdir()
+    real_storage = os.path.realpath(storage)
+    real_root = os.path.realpath(root)
+    head = ["build", str(tmp_path), "-t", "w/l:1"]
+    if spelling == "separate":
+        argv = head + ["--storage", str(storage), "--root", str(root)]
+    elif spelling == "equals":
+        argv = head + [f"--storage={storage}", f"--root={root}"]
+    elif spelling == "abbreviated":
+        # (--stor is ambiguous since --storage-budget: "ambiguous".)
+        argv = head + ["--storage", str(storage), "--roo", str(root)]
+    elif spelling == "ambiguous":
+        # Malformed for argparse: cli.main reports it, nothing is
+        # touched, and the keys are those of a request with no paths.
+        argv = head + ["--stor", str(storage), "--root", str(root)]
+        real_root = real_storage = None
+    elif spelling == "default":
+        argv = head + ["--root", str(root)]
+        real_storage = os.path.realpath(cli._storage_dir(""))
+    elif spelling == "symlinked_root":
+        link = tmp_path / "rootlink"
+        link.symlink_to(root)
+        argv = head + ["--storage", str(storage), "--root", str(link)]
+    elif spelling == "relative":
+        monkeypatch.chdir(tmp_path)
+        argv = head + ["--storage", "lock storage", "--root", "lockroot"]
+    else:
+        argv = ["version"]
+        real_root = real_storage = None
+    flags = server_mod._effective_flags(argv)
+    locks = server._shared_path_locks(flags)
+    # The table is the process's: read the keys these locks stand under.
+    keys = {key for key, lock in server._path_locks.items()
+            if any(lock is mine for mine in locks)}
+    assert keys == {"--root=" + (real_root or "<none>"),
+                    "--storage=" + (real_storage or "<none>")}
+    assert len(locks) == 2
+    # The same locks for the same request, whoever asks again.
+    assert server._shared_path_locks(
+        server_mod._effective_flags(argv)) == locks
+    if spelling == "default":
+        # The computed default reaches the command in the namespace.
+        assert flags.args.storage == cli._storage_dir("")
+
+
+def test_malformed_argv_ends_with_argparses_message(tmp_path, worker,
+                                                    capfd):
+    """A malformed ``argv`` reaches ``cli.main``'s own parse: argparse's
+    message on the worker's standard error, its exit code (2) in the
+    client's terminal record, and the worker keeps serving."""
+    client = WorkerClient(worker.socket_path)
+    code = client.build(["build", str(tmp_path), "-t", "w/m:1",
+                         "--commit", "sometimes"])
+    assert code == 2
+    assert client.last_build["exit_code"] == 2
+    err = capfd.readouterr().err
+    assert "invalid choice: 'sometimes'" in err
+    assert "usage: makisu-tpu build" in err
+    assert client.ready()
+
+
+def test_what_a_request_resolved_dies_with_it(tmp_path, server):
+    """Build into storage ``S``, remove ``S``, make ``S`` a symlink to
+    another directory, build again through the same worker: the second
+    build's outputs land in the new target and its lock key is the new
+    real path. Nothing resolved for one request is seen by the next."""
+    import os
+    import shutil
+
+    from makisu_tpu.utils import pathutils
+    from makisu_tpu.worker import server as server_mod
+    argv = _direct_argv(tmp_path, storage="S")
+    storage = tmp_path / "S"
+    assert server.run_build(argv, lambda line: None) == 0
+    assert pathutils._request_dirs.get() is None
+    assert os.listdir(storage / "layers")
+    assert f"--storage={storage}" in server._path_locks
+    shutil.rmtree(storage)
+    target = tmp_path / "elsewhere"
+    target.mkdir()
+    storage.symlink_to(target)
+    assert server.run_build(argv, lambda line: None) == 0
+    assert os.listdir(target / "layers")
+    assert f"--storage={target}" in server._path_locks
+    flags = server_mod._effective_flags(argv)
+    assert flags.dirs[flags.storage] == str(target)
+    assert str(target) in server.storage_dirs()
+
+
+def test_a_warm_requests_setup_stats_a_third_of_the_parents(
+        tmp_path, server, monkeypatch):
+    """``os.stat`` + ``os.lstat`` on the building thread from admission
+    to the root span's open, over a worker's third request for one
+    context under pytest's ``tmp_path``. At 4589821 this read 211:
+    180 from three parser trees (``gettext.find``'s ``exists``, 60 a
+    tree where ``LANG`` names a language, as here and on the chip's
+    machine: ``C.UTF-8``; none where it is unset) and 31 from seven
+    ``realpath`` walks of --root, --storage and ``<storage>/chunks``. Now it reads 16: the three directories are
+    walked once (five components each here) and ``<storage>/chunks``
+    costs one ``lstat``."""
+    import os
+    import threading
+
+    argv = _direct_argv(tmp_path)
+    for _ in range(2):
+        assert server.run_build(argv, lambda line: None) == 0
+    counting = {"on": False, "n": 0}
+    me = threading.get_ident()
+
+    def counted(fn):
+        def wrapper(*args, **kwargs):
+            if counting["on"] and threading.get_ident() == me:
+                counting["n"] += 1
+            return fn(*args, **kwargs)
+        return wrapper
+
+    monkeypatch.setattr(os, "stat", counted(os.stat))
+    monkeypatch.setattr(os, "lstat", counted(os.lstat))
+    acquire = server._admission.acquire
+
+    def admitted():
+        wait = acquire()
+        counting["on"] = True
+        return wait
+
+    monkeypatch.setattr(server._admission, "acquire", admitted)
+
+    def emit(line):
+        if '"build_start"' in line:     # the root span opens
+            counting["on"] = False
+
+    assert server.run_build(argv, emit) == 0
+    assert not counting["on"]
+    assert 0 < counting["n"] <= 211 // 3
