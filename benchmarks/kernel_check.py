@@ -2,7 +2,7 @@
 
     python benchmarks/kernel_check.py          # on the chip
 
-Runs the XLA and Pallas gear scans (v1 at both halo offsets, v2) on one
+Runs the XLA and Pallas gear scans (the kernel at both halo offsets) on one
 ``chunker.cdc.BLOCK`` of seeded bytes and the XLA and Pallas lane
 SHA-256 at both ``chunker.cdc._BUCKETS`` shapes, and compares each with
 its plain reference: ``gear.gear_hash_ref`` (the sequential recurrence)
@@ -86,7 +86,7 @@ def programs() -> list[Program]:
         _same_bits("gear xla", gear.unpack_bits_np(words, len(buf)),
                    _want_bits(buf, bits))
 
-    def check_v1(start):
+    def check_pallas_gear(start):
         def check(inputs, words):
             (buf,) = inputs
             nrows = gear_pallas.nrows_for(block)
@@ -97,15 +97,9 @@ def programs() -> list[Program]:
             # halo makes the first WINDOW-1 positions differ; they sit
             # below the minimum chunk size and never become cuts.
             skip = 0 if start else gear.WINDOW
-            _same_bits(f"gear pallas v1 start={start}",
+            _same_bits(f"gear pallas start={start}",
                        got[skip:], want[skip:])
         return check
-
-    def check_v2(inputs, words):
-        (buf,) = inputs
-        _same_bits("gear pallas v2",
-                   gear.unpack_bits_np(words, len(buf)),
-                   _want_bits(buf, bits))
 
     def check_sha(name):
         def check(inputs, words):
@@ -118,19 +112,16 @@ def programs() -> list[Program]:
                         "hashlib.sha256")
         return check
 
-    v2_len = -(-(halo + block) // gear_pallas.V2_TILE) * gear_pallas.V2_TILE
     out = [
         Program("gear_xla", gear.gear_bitmap,
                 (((halo + block,), u8),), {"avg_bits": bits},
                 check_xla_gear),
-        Program("gear_pallas_v1_start0", gear_pallas.gear_bitmap_flat,
+        Program("gear_pallas_start0", gear_pallas.gear_bitmap_flat,
                 (((block,), u8),), {"start": 0, "avg_bits": bits},
-                check_v1(0)),
-        Program("gear_pallas_v1_start128", gear_pallas.gear_bitmap_flat,
+                check_pallas_gear(0)),
+        Program("gear_pallas_start128", gear_pallas.gear_bitmap_flat,
                 (((halo + block,), u8),),
-                {"start": halo, "avg_bits": bits}, check_v1(halo)),
-        Program("gear_pallas_v2", gear_pallas.gear_bitmap_flat2,
-                (((v2_len,), u8),), {"avg_bits": bits}, check_v2),
+                {"start": halo, "avg_bits": bits}, check_pallas_gear(halo)),
     ]
     for cap, lanes in cdc._BUCKETS:
         shapes = (((lanes, cap), u8), ((lanes,), np.int32))

@@ -61,11 +61,10 @@ KNOWN_DEVICE_KINDS = ("TPU v5 lite",)
 
 # Options that select a route. The smoke never sets them and drops any
 # it inherits.
-ROUTE_OPTION_PREFIXES = ("MAKISU_TPU_PALLAS", "MAKISU_TPU_SHA_")
-ROUTE_OPTIONS = ("MAKISU_TPU_CHUNK_NATIVE", "MAKISU_TPU_SHARED_HASH",
-                 "MAKISU_TPU_CHUNK_STRICT")
+ROUTE_OPTIONS = ("MAKISU_TPU_PALLAS", "MAKISU_TPU_CHUNK_NATIVE",
+                 "MAKISU_TPU_SHARED_HASH", "MAKISU_TPU_CHUNK_STRICT")
 
-DEVICE_BACKENDS = ("pallas", "pallas_v2", "xla")
+DEVICE_BACKENDS = ("pallas", "xla")
 MAX_CHUNK = 64 * 1024   # gear.DEFAULT_MAX_SIZE, restated: no JAX here
 
 # Whole-script budget: the contract allows 1200s, compilation included.
@@ -82,9 +81,7 @@ def say(msg: str) -> None:
 
 
 def child_env(**extra: str) -> dict:
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith(ROUTE_OPTION_PREFIXES)
-           and k not in ROUTE_OPTIONS}
+    env = {k: v for k, v in os.environ.items() if k not in ROUTE_OPTIONS}
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.update(extra)
     return env

@@ -88,17 +88,6 @@ def reset_chunk_observer(token) -> None:
     _chunk_observer.reset(token)
 
 
-def _sha_batch_route() -> bool:
-    """Whether the pooled multicore route can engage: it needs the
-    native batch hasher (libgear.so gear_sha256_batch — one
-    GIL-released call per ~MiB batch). Per-chunk hashlib on pool
-    threads is NOT a substitute: at ~8KiB chunk sizes the GIL
-    ping-pong against the producer thread scales negatively (measured
-    0.6x on 2 cores), so without the symbol the session stays
-    serial."""
-    from makisu_tpu import native
-    return native.sha_batch_available()
-
 # Lane-buffer buckets: (capacity, lanes). Chunk avg is 8 KiB and max
 # 64 KiB, so most chunks hash in the 16 KiB bucket; each bucket is one
 # compiled XLA program reused forever.
@@ -322,15 +311,10 @@ class ChunkSession:
         self._depth = self.PIPELINE_DEPTH
         self._pool = None
         self._sha_slots = None
-        # Serial native route ALSO batches chunk SHA when the native
-        # batch hasher exists: one GIL-released call per ~MiB batch
-        # (SHA-NI multi-buffer when the CPU has it) instead of ~128
-        # per-chunk hashlib round trips — same digests, same order.
-        self._sha_sync = False
         if self._native:
             self._workers = (concurrency.hash_workers()
                              if workers is None else max(1, workers))
-            if self._workers > 1 and _sha_batch_route():
+            if self._workers > 1:
                 import threading
                 self._pool = concurrency.hash_pool()
                 # Scan deep enough that every worker can hold a block.
@@ -345,8 +329,6 @@ class ChunkSession:
                     self._workers)
                 self._sha_depth = 0
                 self._sha_depth_lock = threading.Lock()
-            if self._pool is None:
-                self._sha_sync = _sha_batch_route()
 
     # -- failure discipline ----------------------------------------------
 
@@ -437,7 +419,7 @@ class ChunkSession:
                     self._degrade("lane dispatch", e)
         if self._degraded is None:
             try:
-                if self._pool is not None or self._sha_sync:
+                if self._native:
                     self._flush_sha_batch()
                 if self._pool is not None:
                     for meta, fut in self._sha_pending:
@@ -526,25 +508,6 @@ class ChunkSession:
                 entry = ("native",
                          self._scan_positions(hblk, halo_len, live),
                          halo_len, live, hblk, self._scanned)
-        elif route.gear == "pallas_v2":
-            # Opt-in natural-layout kernel (MAKISU_TPU_PALLAS_V2=1):
-            # pure-reshape staging, full-buffer bitmap (XLA-contract
-            # slicing) — see gear_pallas.py v2 block.
-            with self._clock.stage("gear_dispatch"):
-                buf = np.frombuffer(hblk, dtype=np.uint8)
-                need = ((len(buf) + gear_pallas.V2_TILE - 1)
-                        // gear_pallas.V2_TILE) * gear_pallas.V2_TILE
-                if need != len(buf):
-                    qbuf = np.zeros(need, dtype=np.uint8)
-                    qbuf[:len(buf)] = buf
-                else:
-                    qbuf = buf
-                words = gear_pallas.gear_bitmap_flat2(
-                    qbuf, self.avg_bits, interpret=route.interpret)
-            self._clock.moved("h2d", "gear", qbuf.nbytes)
-            # entry[0] is the READBACK layout tag (v2 words decode
-            # like XLA's), not the executing backend.
-            entry = ("xla", words, halo_len, live, hblk, self._scanned)
         elif route.gear == "pallas":
             # Fused kernel (the default on TPU). Restaging runs on
             # device inside the same program. The live region is
@@ -663,9 +626,11 @@ class ChunkSession:
         n = end - self._prev_cut
         if n <= 0:
             return
-        if ((self._pool is not None or self._sha_sync)
-                and self._degraded is None):
-            # Batched fast path (pooled AND serial-native): no per-chunk
+        if self._native:
+            # Batched path (the native route, pooled and serial: one
+            # GIL-released call per ~MiB batch, SHA-NI multi-buffer
+            # when the CPU has it, instead of ~128 per-chunk hashlib
+            # round trips — same digests, same order): no per-chunk
             # byte shuffling at all. Chunks tile the stream, so the
             # pending batch IS the tail's prefix [_tail_offset,
             # _prev_cut) — _take just records (offset, length) and the
@@ -678,11 +643,11 @@ class ChunkSession:
             if end - self._tail_offset >= SHA_BATCH_BYTES:
                 self._flush_sha_batch()
             return
-        # Immediate path (device lanes / service / per-chunk hashlib):
-        # nothing defers here, so the tail starts at the chunk start
-        # (_prev_cut == _tail_offset) and is consumed chunk by chunk.
-        # The memoryview must close before the del: a bytearray with an
-        # exported buffer cannot resize.
+        # Immediate path (device lanes / service): nothing defers
+        # here, so the tail starts at the chunk start (_prev_cut ==
+        # _tail_offset) and is consumed chunk by chunk. The memoryview
+        # must close before the del: a bytearray with an exported
+        # buffer cannot resize.
         with memoryview(self._tail) as mv:
             data = bytes(mv[:n])
         del self._tail[:n]
@@ -776,17 +741,6 @@ class ChunkSession:
             metrics.stage_busy_add("chunk_sha", time.monotonic() - t0)
 
     def _emit(self, data: bytes, offset: int) -> None:
-        if self._native:
-            # Per-chunk hashlib: the no-batch-symbol fallback (a stale
-            # library without gear_sha256_batch). The batched routes
-            # never reach here — _take records chunks for the prefix
-            # flush instead of materializing per-chunk bytes.
-            import hashlib
-            self._native_hashed += len(data)
-            digest = hashlib.sha256(data).digest()
-            self._chunks.append(Chunk(offset, len(data), digest))
-            self._notify(digest.hex())
-            return
         if self.service is not None:
             # A full service queue blocks the build here (backpressure).
             t0 = time.monotonic()

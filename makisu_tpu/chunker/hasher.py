@@ -226,45 +226,11 @@ class _NativeTarWriter:
         """Bytes written so far, as ``tarfile.TarFile.offset``."""
         return self._offset
 
-    def addfile(self, tarinfo, fileobj=None) -> None:
-        buf = tarinfo.tobuf(*self._FMT)
-        self._sink._handle.write(buf)
-        self._offset += len(buf)
-        if fileobj is not None:
-            remaining = tarinfo.size
-            while remaining > 0:
-                chunk = fileobj.read(min(remaining, 1 << 20))
-                if not chunk:
-                    raise OSError(f"{tarinfo.name}: short read")
-                self._sink._handle.write(chunk)
-                remaining -= len(chunk)
-            pad = (512 - tarinfo.size % 512) % 512
-            if pad:
-                self._sink._handle.write(b"\0" * pad)
-            self._offset += tarinfo.size + pad
-
-    def add_path(self, tarinfo, path: str) -> None:
-        """Fast path: content streams through C++ (no Python bytes)."""
-        buf = tarinfo.tobuf(*self._FMT)
-        self._sink._handle.write(buf)
-        self._sink._handle.write_file(path, tarinfo.size)
-        pad = (512 - tarinfo.size % 512) % 512
-        self._offset += len(buf) + tarinfo.size + pad
-
-    @property
-    def add_entries(self):
-        """``_add_entries``, or ``None`` with a prebuilt library from
-        before ``lsk_write_entries``: ``Layer.commit`` then goes entry
-        by entry."""
-        if self._sink._handle.takes_entries:
-            return self._add_entries
-        return None
-
-    def _add_entries(self, items: list[tuple]) -> None:
+    def add_entries(self, items: list[tuple]) -> None:
         """A batch of ``(tarinfo, path | None)`` in tar order, in one
-        call into C++: each header as ``addfile`` renders it, and for a
-        path (a regular file with content) the file's content and
-        padding, as ``add_path`` writes them."""
+        call into C++: each header as ``tarfile.TarFile.addfile``
+        renders it, and for a path (a regular file with content) the
+        file's first ``tarinfo.size`` bytes and the padding to 512."""
         headers = [tarinfo.tobuf(*self._FMT) for tarinfo, _ in items]
         sizes = [0 if path is None else tarinfo.size
                  for tarinfo, path in items]
@@ -364,19 +330,16 @@ class NativeLayerSink:
             prefetch = self._handle.prefetch_stats()
         finally:
             self._handle.close()
-        if busy is not None:
-            metrics.stage_busy_add(metrics.COMPRESS_STAGE, busy)
-        if waited is not None:
-            metrics.stage_busy_add("compress_wait", waited)
-        if prefetch is not None:
-            # A part of tar_write: what this thread spent blocked on
-            # one of the sink's readers, and how each file's bytes came.
-            metrics.stage_busy_add("read_wait", prefetch[0])
-            for result, n in zip(("ready", "waited", "streamed"),
-                                 prefetch[1:]):
-                if n:
-                    metrics.counter_add(metrics.SINK_PREFETCH_FILES_TOTAL,
-                                        n, result=result)
+        metrics.stage_busy_add(metrics.COMPRESS_STAGE, busy)
+        metrics.stage_busy_add("compress_wait", waited)
+        # A part of tar_write: what this thread spent blocked on one of
+        # the sink's readers, and how each file's bytes came.
+        metrics.stage_busy_add("read_wait", prefetch[0])
+        for result, n in zip(("ready", "waited", "streamed"),
+                             prefetch[1:]):
+            if n:
+                metrics.counter_add(metrics.SINK_PREFETCH_FILES_TOTAL,
+                                    n, result=result)
         metrics.counter_add("makisu_bytes_hashed_total", self._nbytes,
                             backend="native", path="layer_sink")
         backend = self.backend_id.split("-", 1)[0]

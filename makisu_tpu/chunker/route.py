@@ -22,9 +22,6 @@ Routes:
   both kernels off/on; on the CPU the gear kernel then runs in
   interpret mode (tests) and SHA stays on XLA, because XLA:CPU takes
   minutes to compile the kernel's 64 inlined rounds.
-- gear ``pallas_v2``: the natural-layout kernel, opt-in with
-  ``MAKISU_TPU_PALLAS_V2=1`` until it has been compared with v1 on the
-  chip (ROADMAP Queue 1 item 5).
 - ``xla``: everything else.
 """
 
@@ -38,7 +35,7 @@ from makisu_tpu.utils import logging as log
 
 
 class ChunkRoute(typing.NamedTuple):
-    gear: str            # native | xla | pallas | pallas_v2
+    gear: str            # native | xla | pallas
     sha: str             # native | xla | pallas
     platform: str        # jax.devices()[0].platform
     device_kind: str
@@ -65,9 +62,7 @@ def select(platform: str, shared: bool, native_ok: bool,
     from makisu_tpu.ops import gear_pallas
     if not gear_pallas.env_enabled(platform, environ):
         return "xla", "xla"
-    gear = ("pallas_v2" if environ.get("MAKISU_TPU_PALLAS_V2", "") == "1"
-            else "pallas")
-    return gear, ("pallas" if platform != "cpu" else "xla")
+    return "pallas", ("pallas" if platform != "cpu" else "xla")
 
 
 @functools.lru_cache(maxsize=None)

@@ -33,10 +33,10 @@ _FILL_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0,
                  512.0)
 
 # Occupancy histogram buckets: lanes filled ÷ lane capacity per
-# dispatched program. The fleet-batching signal (ROADMAP item 1): a
-# worker whose occupancy sits near 1.0 is amortizing device programs
-# across builds; near 1/lanes it is dispatching half-empty batches and
-# more concurrency (or a longer linger) would pay.
+# dispatched program. The fleet-batching signal: a worker whose
+# occupancy sits near 1.0 is amortizing device programs across builds;
+# near 1/lanes it is dispatching half-empty batches and more
+# concurrency (or a longer linger) would pay.
 _OCCUPANCY_BUCKETS = (0.05, 0.1, 0.25, 0.5, 0.75, 0.9, 1.0)
 
 class HashService:

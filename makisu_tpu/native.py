@@ -42,7 +42,6 @@ _GEAR_PATH = os.path.join(_NATIVE_DIR, "libgear.so")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _load_failed = False
-_pgz_blocks = False  # multi-block entry present in the loaded library
 _lsk_lib: ctypes.CDLL | None = None
 _lsk_failed = False
 
@@ -114,49 +113,56 @@ def _warn_if_stale(lib_path: str) -> None:
             ", ".join(sorted(stale)))
 
 
+def _open(path: str, abi_fn: str, abi: int,
+          symbols: dict[str, tuple]) -> ctypes.CDLL | None:
+    """The library at ``path`` with every symbol this module calls
+    bound (``name: (restype, argtypes)``), or ``None``. A library is
+    the one this tree builds or it is absent: one that cannot be
+    loaded, lacks a symbol (ctypes raises ``AttributeError``, not
+    ``OSError``, on a dlsym miss) or answers another ABI number is
+    refused whole, and the log says so. With ``_lock`` held."""
+    if not _ensure_built(path):
+        return None
+    try:
+        lib = ctypes.CDLL(path)
+        for name, (restype, argtypes) in symbols.items():
+            fn = getattr(lib, name)
+            fn.restype, fn.argtypes = restype, argtypes
+        got = getattr(lib, abi_fn)()
+        if got != abi:
+            raise OSError(f"{abi_fn}() is {got}, this tree's is {abi}")
+    except (OSError, AttributeError) as e:
+        from makisu_tpu.utils import logging as log
+        log.error("%s is not this tree's (%s) and stays unused — run "
+                  "`make -C native clean all` to rebuild",
+                  os.path.basename(path), e)
+        return None
+    return lib
+
+
+_U8_P = ctypes.POINTER(ctypes.c_uint8)
+_U32_P = ctypes.POINTER(ctypes.c_uint32)
+_U64_P = ctypes.POINTER(ctypes.c_uint64)
+_SIZE_P = ctypes.POINTER(ctypes.c_size_t)
+
+_PGZ_SYMBOLS = {
+    "pgz_compress": (_U8_P, [ctypes.c_char_p, ctypes.c_size_t,
+                             ctypes.c_int, ctypes.c_size_t, ctypes.c_int,
+                             _SIZE_P]),
+    "pgz_block": (_U8_P, [ctypes.c_char_p, ctypes.c_size_t, ctypes.c_int,
+                          ctypes.c_int, _SIZE_P]),
+    "pgz_blocks": (_U8_P, [ctypes.c_char_p, ctypes.c_size_t, ctypes.c_int,
+                           ctypes.c_size_t, ctypes.c_int, _SIZE_P]),
+    "pgz_free": (None, [_U8_P]),
+}
+
+
 def _load() -> ctypes.CDLL | None:
-    global _lib, _load_failed, _pgz_blocks
+    global _lib, _load_failed
     with _lock:
-        if _lib is not None or _load_failed:
-            return _lib
-        if not _ensure_built(_LIB_PATH):
-            _load_failed = True
-            return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-            lib.pgz_compress.restype = ctypes.POINTER(ctypes.c_uint8)
-            lib.pgz_compress.argtypes = [
-                ctypes.c_char_p, ctypes.c_size_t, ctypes.c_int,
-                ctypes.c_size_t, ctypes.c_int,
-                ctypes.POINTER(ctypes.c_size_t)]
-            lib.pgz_block.restype = ctypes.POINTER(ctypes.c_uint8)
-            lib.pgz_block.argtypes = [
-                ctypes.c_char_p, ctypes.c_size_t, ctypes.c_int,
-                ctypes.c_int, ctypes.POINTER(ctypes.c_size_t)]
-            lib.pgz_free.argtypes = [ctypes.POINTER(ctypes.c_uint8)]
-            if lib.pgz_abi_version() != 1:
-                raise OSError("pgzip ABI mismatch")
-            _lib = lib
-        except (OSError, AttributeError):
-            # AttributeError: stale .so missing a symbol — degrade, not
-            # crash (ctypes raises it, not OSError, on dlsym misses).
-            _load_failed = True
-            return _lib
-        try:
-            # Newer symbol, bound separately: with a prebuilt library
-            # from before the multi-block entry, the block-compress
-            # stage degrades to the stdlib-zlib codec (byte-identical
-            # output, just without the one-call batch amortization —
-            # see tario._deflate_blocks); PgzipWriter keeps its
-            # per-block pgz_block route either way.
-            lib.pgz_blocks.restype = ctypes.POINTER(ctypes.c_uint8)
-            lib.pgz_blocks.argtypes = [
-                ctypes.c_char_p, ctypes.c_size_t, ctypes.c_int,
-                ctypes.c_size_t, ctypes.c_int,
-                ctypes.POINTER(ctypes.c_size_t)]
-            _pgz_blocks = True
-        except AttributeError:
-            _pgz_blocks = False
+        if _lib is None and not _load_failed:
+            _lib = _open(_LIB_PATH, "pgz_abi_version", 1, _PGZ_SYMBOLS)
+            _load_failed = _lib is None
         return _lib
 
 
@@ -164,62 +170,32 @@ def pgzip_available() -> bool:
     return _load() is not None
 
 
+_LSK_SYMBOLS = {
+    "lsk_new": (ctypes.c_void_p, [ctypes.c_int, ctypes.c_int, ctypes.c_int,
+                                  ctypes.c_size_t, ctypes.c_int]),
+    "lsk_set_tap": (None, [ctypes.c_void_p, _TAP_FN, ctypes.c_void_p]),
+    "lsk_write": (ctypes.c_int, [ctypes.c_void_p, ctypes.c_char_p,
+                                 ctypes.c_size_t]),
+    "lsk_write_entries": (ctypes.c_int, [
+        ctypes.c_void_p, ctypes.c_size_t, ctypes.c_char_p, _U64_P,
+        ctypes.POINTER(ctypes.c_char_p), _U64_P,
+        ctypes.POINTER(ctypes.c_int64)]),
+    "lsk_finish": (ctypes.c_int, [ctypes.c_void_p, _U8_P, _U8_P, _U64_P,
+                                  _U64_P]),
+    "lsk_compress_seconds": (ctypes.c_double, [ctypes.c_void_p]),
+    "lsk_wait_seconds": (ctypes.c_double, [ctypes.c_void_p]),
+    "lsk_prefetch_stats": (None, [ctypes.c_void_p,
+                                  ctypes.POINTER(ctypes.c_double), _U64_P]),
+    "lsk_free": (None, [ctypes.c_void_p]),
+}
+
+
 def _load_lsk() -> ctypes.CDLL | None:
     global _lsk_lib, _lsk_failed
     with _lock:
-        if _lsk_lib is not None or _lsk_failed:
-            return _lsk_lib
-        if not _ensure_built(_LSK_PATH):
-            _lsk_failed = True
-            return None
-        try:
-            lib = ctypes.CDLL(_LSK_PATH)
-            lib.lsk_new.restype = ctypes.c_void_p
-            lib.lsk_new.argtypes = [ctypes.c_int, ctypes.c_int,
-                                    ctypes.c_int, ctypes.c_size_t,
-                                    ctypes.c_int]
-            lib.lsk_write.restype = ctypes.c_int
-            lib.lsk_write.argtypes = [ctypes.c_void_p, ctypes.c_char_p,
-                                      ctypes.c_size_t]
-            lib.lsk_write_file.restype = ctypes.c_int
-            lib.lsk_write_file.argtypes = [ctypes.c_void_p,
-                                           ctypes.c_char_p,
-                                           ctypes.c_uint64]
-            lib.lsk_set_tap.restype = None
-            lib.lsk_set_tap.argtypes = [ctypes.c_void_p, _TAP_FN,
-                                        ctypes.c_void_p]
-            lib.lsk_finish.restype = ctypes.c_int
-            lib.lsk_finish.argtypes = [
-                ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_uint64),
-                ctypes.POINTER(ctypes.c_uint64)]
-            lib.lsk_free.argtypes = [ctypes.c_void_p]
-            if lib.lsk_abi_version() != 1:
-                raise OSError("layersink ABI mismatch")
-            _lsk_lib = lib
-        except (OSError, AttributeError):
-            _lsk_failed = True
-            return _lsk_lib
-        # Newer symbols, each bound separately: a prebuilt library from
-        # before one still commits layers, and reports no such seconds.
-        # Without lsk_write_entries a layer commits entry by entry.
-        u64_p = ctypes.POINTER(ctypes.c_uint64)
-        for name, restype, argtypes in (
-                ("lsk_compress_seconds", ctypes.c_double, []),
-                ("lsk_wait_seconds", ctypes.c_double, []),
-                ("lsk_write_entries", ctypes.c_int, [
-                    ctypes.c_size_t, ctypes.c_char_p, u64_p,
-                    ctypes.POINTER(ctypes.c_char_p), u64_p,
-                    ctypes.POINTER(ctypes.c_int64)]),
-                ("lsk_prefetch_stats", None, [
-                    ctypes.POINTER(ctypes.c_double), u64_p])):
-            try:
-                fn = getattr(lib, name)
-            except AttributeError:
-                continue
-            fn.restype = restype
-            fn.argtypes = [ctypes.c_void_p] + argtypes
+        if _lsk_lib is None and not _lsk_failed:
+            _lsk_lib = _open(_LSK_PATH, "lsk_abi_version", 2, _LSK_SYMBOLS)
+            _lsk_failed = _lsk_lib is None
         return _lsk_lib
 
 
@@ -229,8 +205,6 @@ def layersink_available() -> bool:
 
 _gear_lib: ctypes.CDLL | None = None
 _gear_failed = False
-_gear_sha_batch = False
-_gear_pos2 = False
 _isa_route: tuple[str, str] | None = None  # resolved (gear, sha) names
 
 # Combined ISA ladder the MAKISU_TPU_NATIVE_ISA knob selects from. Each
@@ -261,83 +235,41 @@ def _apply_isa(lib: ctypes.CDLL, level: str) -> tuple[str, str]:
     return (lib.gear_gear_isa().decode(), lib.gear_sha_isa().decode())
 
 
+_GEAR_SYMBOLS = {
+    "gear_scan": (None, [_U8_P, ctypes.c_size_t, _U32_P, ctypes.c_uint32,
+                         _U8_P]),
+    "gear_scan_pos2": (ctypes.c_int, [
+        _U8_P, ctypes.c_size_t, _U32_P, ctypes.c_uint32, _U32_P,
+        ctypes.c_size_t, _U32_P, ctypes.c_size_t]),
+    "gear_sha256_batch": (ctypes.c_int, [_U8_P, _U64_P, _U64_P,
+                                         ctypes.c_size_t, _U8_P]),
+    "gear_set_gear_isa": (ctypes.c_int, [ctypes.c_char_p]),
+    "gear_set_sha_isa": (ctypes.c_int, [ctypes.c_char_p]),
+    "gear_isa_supported": (ctypes.c_int, [ctypes.c_char_p]),
+    "gear_gear_isa": (ctypes.c_char_p, []),
+    "gear_sha_isa": (ctypes.c_char_p, []),
+}
+
+
 def _load_gear() -> ctypes.CDLL | None:
-    global _gear_lib, _gear_failed, _gear_sha_batch, _gear_pos2
-    global _isa_route
+    global _gear_lib, _gear_failed, _isa_route
     with _lock:
         if _gear_lib is not None or _gear_failed:
             return _gear_lib
-        if not _ensure_built(_GEAR_PATH):
+        lib = _open(_GEAR_PATH, "gear_abi_version", 2, _GEAR_SYMBOLS)
+        if lib is None:
             _gear_failed = True
             return None
-        try:
-            lib = ctypes.CDLL(_GEAR_PATH)
-            lib.gear_scan.restype = None
-            lib.gear_scan.argtypes = [
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_size_t,
-                ctypes.POINTER(ctypes.c_uint32), ctypes.c_uint32,
-                ctypes.POINTER(ctypes.c_uint8)]
-            lib.gear_scan_pos.restype = ctypes.c_int
-            lib.gear_scan_pos.argtypes = [
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_size_t,
-                ctypes.POINTER(ctypes.c_uint32), ctypes.c_uint32,
-                ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t,
-                ctypes.POINTER(ctypes.c_uint32)]
-            _gear_lib = lib
-        except (OSError, AttributeError):
-            _gear_failed = True
-            return _gear_lib
-        try:
-            # Newer symbol, bound separately: a prebuilt library from
-            # before the batch hasher must still serve gear scans.
-            lib.gear_sha256_batch.restype = ctypes.c_int
-            lib.gear_sha256_batch.argtypes = [
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_uint64),
-                ctypes.POINTER(ctypes.c_uint64), ctypes.c_size_t,
-                ctypes.POINTER(ctypes.c_uint8)]
-            _gear_sha_batch = True
-        except AttributeError:
-            _gear_sha_batch = False
-        try:
-            # ABI-2 surface: runtime ISA dispatch. A stale pre-SIMD
-            # library still serves the striped routes above; it just
-            # has no dispatch to introspect — the staleness gate in
-            # _ensure_built already shouted about it.
-            if lib.gear_abi_version() != 2:
-                raise OSError("libgear ABI mismatch")
-            lib.gear_scan_pos2.restype = ctypes.c_int
-            lib.gear_scan_pos2.argtypes = [
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_size_t,
-                ctypes.POINTER(ctypes.c_uint32), ctypes.c_uint32,
-                ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t,
-                ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t]
-            for fn in (lib.gear_set_gear_isa, lib.gear_set_sha_isa,
-                       lib.gear_isa_supported):
-                fn.restype = ctypes.c_int
-                fn.argtypes = [ctypes.c_char_p]
-            lib.gear_gear_isa.restype = ctypes.c_char_p
-            lib.gear_gear_isa.argtypes = []
-            lib.gear_sha_isa.restype = ctypes.c_char_p
-            lib.gear_sha_isa.argtypes = []
-            _gear_pos2 = True
-        except (OSError, AttributeError) as e:
+        level = os.environ.get("MAKISU_TPU_NATIVE_ISA", "auto")
+        if level not in _ISA_MAP:
             from makisu_tpu.utils import logging as log
-            log.error(
-                "libgear.so predates the SIMD dispatch ABI (%s); "
-                "serving the striped routes only — run "
-                "`make -C native clean all` to rebuild", e)
-            _gear_pos2 = False
-        if _gear_pos2:
-            level = os.environ.get("MAKISU_TPU_NATIVE_ISA", "auto")
-            if level not in _ISA_MAP:
-                from makisu_tpu.utils import logging as log
-                log.warning(
-                    "unknown MAKISU_TPU_NATIVE_ISA=%r (valid: %s); "
-                    "using auto", level, "/".join(ISA_LEVELS))
-                level = "auto"
-            _isa_route = _apply_isa(lib, level)
-            _note_isa_route(level)
+            log.warning(
+                "unknown MAKISU_TPU_NATIVE_ISA=%r (valid: %s); "
+                "using auto", level, "/".join(ISA_LEVELS))
+            level = "auto"
+        _isa_route = _apply_isa(lib, level)
+        _note_isa_route(level)
+        _gear_lib = lib
         return _gear_lib
 
 
@@ -363,14 +295,14 @@ def gear_scan_available() -> bool:
 
 
 def sha_batch_available() -> bool:
-    return _load_gear() is not None and _gear_sha_batch
+    return gear_scan_available()
 
 
 def isa_route() -> str | None:
     """The resolved ISA route string, e.g. ``"gear=avx2,sha=shani"`` —
     what the build_info label and the bench record carry. None when the
-    native library (or its dispatch ABI) is unavailable."""
-    if _load_gear() is None or _isa_route is None:
+    native library is unavailable."""
+    if _load_gear() is None:
         return None
     return f"gear={_isa_route[0]},sha={_isa_route[1]}"
 
@@ -393,7 +325,7 @@ def set_native_isa(level: str) -> str | None:
         raise ValueError(f"unknown ISA level {level!r}; "
                          f"valid: {'/'.join(ISA_LEVELS)}")
     lib = _load_gear()
-    if lib is None or not _gear_pos2:
+    if lib is None:
         return None
     old = isa_route()
     _isa_route = _apply_isa(lib, level)
@@ -416,7 +348,7 @@ def isa_supported(name: str) -> bool:
     """Whether this host/build can run a specific route half
     ("avx2", "shani", "evp", "striped", "scalar")."""
     lib = _load_gear()
-    return bool(lib is not None and _gear_pos2
+    return bool(lib is not None
                 and lib.gear_isa_supported(name.encode()))
 
 
@@ -432,8 +364,8 @@ def sha256_batch(buf, lengths):
     import numpy as np
 
     lib = _load_gear()
-    if lib is None or not _gear_sha_batch:
-        raise OSError("libgear.so sha256 batch unavailable")
+    if lib is None:
+        raise OSError("libgear.so unavailable")
     lengths64 = np.ascontiguousarray(lengths, dtype=np.uint64)
     offsets = np.zeros(len(lengths64), dtype=np.uint64)
     np.cumsum(lengths64[:-1], out=offsets[1:])
@@ -442,11 +374,9 @@ def sha256_batch(buf, lengths):
     # route hands its batch bytearray straight through).
     buf_arr = np.frombuffer(buf, dtype=np.uint8)
     rc = lib.gear_sha256_batch(
-        buf_arr.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-        lengths64.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-        len(lengths64),
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)))
+        buf_arr.ctypes.data_as(_U8_P), offsets.ctypes.data_as(_U64_P),
+        lengths64.ctypes.data_as(_U64_P), len(lengths64),
+        out.ctypes.data_as(_U8_P))
     if rc != 0:
         raise RuntimeError("gear_sha256_batch failed")
     return out
@@ -466,10 +396,8 @@ def gear_scan_bits(buf, table, mask: int):
     table = np.ascontiguousarray(table, dtype=np.uint32)
     out = np.empty(len(buf), dtype=np.uint8)
     lib.gear_scan(
-        buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), len(buf),
-        table.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-        ctypes.c_uint32(mask),
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)))
+        buf.ctypes.data_as(_U8_P), len(buf), table.ctypes.data_as(_U32_P),
+        ctypes.c_uint32(mask), out.ctypes.data_as(_U8_P))
     return out
 
 
@@ -480,9 +408,8 @@ def gear_scan_positions(buf, table, mask: int):
     expected hit rate; the (adversarial-data) overflow case falls back
     to the bit scan, so the result is always complete.
 
-    Routes through the library's runtime ISA dispatch (gear_scan_pos2,
-    8 output slots so the AVX2 kernel's 8 lanes map 1:1); a stale
-    pre-dispatch library serves the classic 4-slot striped entry."""
+    Routes through the library's runtime ISA dispatch: 8 output slots,
+    so the AVX2 kernel's 8 lanes map 1:1."""
     import numpy as np
 
     lib = _load_gear()
@@ -492,27 +419,14 @@ def gear_scan_positions(buf, table, mask: int):
     table = np.ascontiguousarray(table, dtype=np.uint32)
     n = len(buf)
     expected = n // max(mask, 1) + 1
-    nslots = 8 if _gear_pos2 else 4
+    nslots = 8
     slot_cap = max(64, expected)  # per-slot ~nslots-x margin overall
     out = np.empty(nslots * slot_cap, dtype=np.uint32)
     counts = np.zeros(nslots, dtype=np.uint32)
-    if _gear_pos2:
-        rc = lib.gear_scan_pos2(
-            buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), n,
-            table.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            ctypes.c_uint32(mask),
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            slot_cap,
-            counts.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            nslots)
-    else:
-        rc = lib.gear_scan_pos(
-            buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), n,
-            table.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            ctypes.c_uint32(mask),
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            slot_cap,
-            counts.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)))
+    rc = lib.gear_scan_pos2(
+        buf.ctypes.data_as(_U8_P), n, table.ctypes.data_as(_U32_P),
+        ctypes.c_uint32(mask), out.ctypes.data_as(_U32_P), slot_cap,
+        counts.ctypes.data_as(_U32_P), nslots)
     if rc != 0:
         bits = gear_scan_bits(buf, table, mask)
         return np.nonzero(bits)[0].astype(np.uint32)
@@ -580,16 +494,6 @@ class LayerSinkHandle:
             raise RuntimeError("native layer sink write failed")
         self._check_tap()
 
-    def write_file(self, path: str, size: int) -> None:
-        self._raise_for(self._lib.lsk_write_file(
-            self._live(), os.fsencode(path), size), path, size)
-
-    @property
-    def takes_entries(self) -> bool:
-        """Whether the library has ``lsk_write_entries`` (a prebuilt one
-        from before it commits entry by entry)."""
-        return getattr(self._lib, "lsk_write_entries", None) is not None
-
     def write_entries(self, headers: list[bytes],
                       paths: list[str | None], sizes: list[int]) -> None:
         """A batch of tar entries in stream order, in one call with the
@@ -609,15 +513,11 @@ class LayerSinkHandle:
             (ctypes.c_char_p * n)(
                 *[None if p is None else os.fsencode(p) for p in paths]),
             (ctypes.c_uint64 * n)(*sizes), ctypes.byref(at_fault))
-        i = at_fault.value
-        self._raise_for(rc, paths[i] if rc else None, sizes[i] if rc else 0)
-
-    def _raise_for(self, rc: int, path: str | None, size: int) -> None:
-        """What a file-writing call's return code means."""
-        if rc == -2:
-            raise OSError(f"native layer sink could not read {path}")
-        if rc == -3:
-            raise OSError(f"{path} shrank below its header size {size}")
+        if rc in (-2, -3):
+            i = at_fault.value
+            raise OSError(
+                f"native layer sink could not read {paths[i]}" if rc == -2
+                else f"{paths[i]} shrank below its header size {sizes[i]}")
         if rc != 0:
             raise RuntimeError("native layer sink write failed")
         # After the rc checks: a tap failure must not mask the
@@ -639,33 +539,25 @@ class LayerSinkHandle:
         return (bytes(tar_sha).hex(), bytes(gz_sha).hex(),
                 gz_size.value, tar_size.value)
 
-    def compress_seconds(self) -> float | None:
+    def compress_seconds(self) -> float:
         """Seconds the gzip stream kept a thread busy (summed over the
-        pgzip lanes); ``None`` from a library that predates the count."""
-        return self._seconds("lsk_compress_seconds")
+        pgzip lanes)."""
+        return self._lib.lsk_compress_seconds(self._live())
 
-    def wait_seconds(self) -> float | None:
+    def wait_seconds(self) -> float:
         """Seconds the writer was blocked on the zlib backend's
-        compressor thread (its ring full, or draining in ``finish``);
-        ``None`` from a library that predates the thread."""
-        return self._seconds("lsk_wait_seconds")
+        compressor thread (its ring full, or draining in ``finish``)."""
+        return self._lib.lsk_wait_seconds(self._live())
 
-    def prefetch_stats(self) -> tuple[float, int, int, int] | None:
+    def prefetch_stats(self) -> tuple[float, int, int, int]:
         """(seconds the writer was blocked on one of the sink's reader
         threads, files a reader had ready, files the writer waited for,
-        files it streamed itself); ``None`` from a library that
-        predates the readers."""
-        fn = getattr(self._lib, "lsk_prefetch_stats", None)
-        if fn is None:
-            return None
+        files it streamed itself)."""
         read_wait = ctypes.c_double(0)
         counts = (ctypes.c_uint64 * 3)()
-        fn(self._live(), ctypes.byref(read_wait), counts)
+        self._lib.lsk_prefetch_stats(self._live(), ctypes.byref(read_wait),
+                                     counts)
         return (read_wait.value, *counts)
-
-    def _seconds(self, symbol: str) -> float | None:
-        fn = getattr(self._lib, symbol, None)
-        return float(fn(self._live())) if fn is not None else None
 
     def close(self) -> None:
         """Frees the sink; one that was never finished (its build died
@@ -718,12 +610,6 @@ def _block_compress(data: bytes, level: int, last: bool) -> bytes:
         lib.pgz_free(buf)
 
 
-def pgz_blocks_available() -> bool:
-    """Whether the loaded libpgzip.so has the multi-block entry (newer
-    symbol; a prebuilt pre-batch library still serves pgz_block)."""
-    return _load() is not None and _pgz_blocks
-
-
 def deflate_blocks(data: bytes, level: int, block_size: int,
                    last: bool) -> bytes:
     """Compress ``data`` as consecutive ``block_size`` raw-deflate
@@ -732,8 +618,9 @@ def deflate_blocks(data: bytes, level: int, block_size: int,
     stage's per-lane unit (tario.BlockGzipWriter). Byte-identical to
     compressing the slices one ``pgz_block`` call at a time."""
     lib = _load()
-    if lib is None or not _pgz_blocks:
-        raise RuntimeError("libpgzip.so multi-block entry unavailable")
+    if lib is None:
+        raise RuntimeError("native pgzip library unavailable; run "
+                           "`make -C native`")
     out_n = ctypes.c_size_t(0)
     buf = lib.pgz_blocks(data, len(data), level, block_size,
                          1 if last else 0, ctypes.byref(out_n))

@@ -36,7 +36,6 @@ cheap greedy pass over candidate positions on the host (makisu_tpu/chunker).
 from __future__ import annotations
 
 import functools
-import os as _os
 
 import jax
 import jax.numpy as jnp
@@ -143,15 +142,11 @@ def unpack_bits_np(words: np.ndarray, n: int) -> np.ndarray:
 # Scan-block size for the bandwidth-lean bitmap path. 64KiB of input
 # makes each in-flight intermediate a 256KiB uint32 tile — comfortably
 # VMEM-resident on every TPU generation, large enough to amortize the
-# scan-step overhead. Env-tunable for hardware sweeps (bench.py records
-# a device A/B): NOT cache identity — outputs are bit-identical at any
-# block size.
-SCAN_BLOCK = int(_os.environ.get("MAKISU_TPU_GEAR_SCAN_BLOCK",
-                                 str(64 * 1024)))
-if SCAN_BLOCK <= 0 or SCAN_BLOCK % 32:
-    raise ValueError(
-        f"MAKISU_TPU_GEAR_SCAN_BLOCK={SCAN_BLOCK} must be a positive "
-        "multiple of 32 (pack_bits works in 32-bit words)")
+# scan-step overhead. An earlier round's builders chose it (no record
+# of a sweep survives; no ledger line ranks another size). NOT cache
+# identity — outputs are bit-identical at any multiple of 32
+# (pack_bits works in 32-bit words).
+SCAN_BLOCK = 64 * 1024
 
 
 def _gear_bitmap_blocked(data: jax.Array, avg_bits: int, block: int,

@@ -41,13 +41,13 @@ true zero-history hashes, but those sit far below the minimum chunk size
 and can never become cuts, so selected chunks are identical (asserted in
 tests against the XLA path).
 
-Status: the v1 kernel (``gear_bitmap_flat``) is the gear route a TPU
+Status: the kernel (``gear_bitmap_flat``) is the gear route a TPU
 build takes by default (chunker/route.py); MAKISU_TPU_PALLAS=0/1
 forces. It ran on a TPU v5e at the production shape (one 4 MiB block,
 with and without a halo prefix) bit-identical to ``gear.gear_hash_ref``
 (``benchmarks/kernel_check.py``, PR 21). Its rate against the XLA path
-and against v2 is not measured. v5e is the one device these kernels
-have met: the tile sizes below are that generation's.
+is not measured. v5e is the one device the kernel has met: the tile
+sizes below are that generation's.
 """
 
 from __future__ import annotations
@@ -242,107 +242,6 @@ def gear_bitmap_flat(buf: jax.Array, start: int,
     rows = (jnp.concatenate([halos, live_m], axis=1)
             .reshape(R, _HCOLS + _CCOLS, 32).transpose(0, 2, 1))
     return _invoke_kernel(rows, avg_bits, interpret, "gear_bitmap_flat")
-
-
-# ---------------------------------------------------------------------------
-# v2: natural-layout kernel (no restage transpose).
-#
-# The same per-group factorization works with rows of 128 CONSECUTIVE
-# bytes along the lane axis: h[s, l] = P[s, l] + Q[s-1] * 2^(l+1)
-# (mod 2^32), where P is the log-doubling window scan with pure LANE
-# shifts (zero fill) and Q[s] = P[s, 127] is the row's weighted tail.
-# Contributions older than the 32-byte window self-vanish in the
-# 2^(l+1) factor exactly as in v1 — and since lanes l >= 31 never
-# receive a borrow, the weight is just zeroed there (no >= 32-bit
-# shifts). The input is a PURE RESHAPE of the stream ([R, 128] rows),
-# so the v1 restage transpose disappears.
-# Cross-tile history rides an SMEM carry across the sequential grid,
-# which also makes v2 bit-identical to gear.gear_hash INCLUDING the
-# zero-history head (no byte-halo approximation at all).
-#
-# Status: opt-in (MAKISU_TPU_PALLAS_V2=1, chunker/route.py). Ran on a
-# TPU v5e at the production shape bit-identical to gear.gear_hash_ref
-# (benchmarks/kernel_check.py, PR 21); v1 stays the default until the
-# two have been compared on the chip (ROADMAP Queue 1 item 5).
-
-V2_ROWS = 256                 # sublane rows per grid step (32 KiB live)
-V2_TILE = V2_ROWS * 128       # bytes per grid step
-
-
-def _gear_kernel2(avg_bits: int, rows_ref, out_ref, q_ref) -> None:
-    from jax.experimental import pallas as pl
-
-    j = pl.program_id(0)
-    d = rows_ref[:]                            # [V2_ROWS, 128] uint8
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (1, 128), 1)
-
-    def lane_shift(h, m):
-        return jnp.pad(h[:, :128 - m], ((0, 0), (m, 0)))
-
-    p = gear._windowed_sum(gear._gear_value(d), shift=lane_shift)
-    p_i = jax.lax.bitcast_convert_type(p, jnp.int32)
-    qcol = jnp.sum(jnp.where(lane == 127, p_i, 0), axis=1,
-                   keepdims=True, dtype=jnp.int32)   # [V2_ROWS, 1]
-    q_top = jnp.where(j == 0, 0, q_ref[0])
-    q_prev = jnp.pad(qcol[:-1], ((1, 0), (0, 0)))
-    srow = jax.lax.broadcasted_iota(jnp.int32, qcol.shape, 0)
-    q_prev = jax.lax.bitcast_convert_type(
-        jnp.where(srow == 0, q_top, q_prev), jnp.uint32)
-    # weight[l] = 2^(l+1) for l <= 30, else 0 (out-of-window terms).
-    # The clamp that keeps the masked-off shifts < 32 bits runs on a
-    # SIGNED iota: Mosaic on v5e has no unsigned vector minimum
-    # ("failed to legalize operation 'arith.minui'").
-    lane_i = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
-    weight = jnp.where(
-        lane_i <= 30,
-        jnp.uint32(2) << jnp.minimum(lane_i, 30).astype(jnp.uint32),
-        jnp.uint32(0))
-    h = p + q_prev * weight
-    mask_i = ((h & jnp.uint32((1 << avg_bits) - 1)) == 0).astype(
-        jnp.int32)
-    # Pack: word w of a row covers its lanes [32*(w), 32*w+32); four
-    # masked lane reductions (a lane-split reshape is not lowerable).
-    words = []
-    for k in range(4):
-        sub = (lane >= 32 * k) & (lane < 32 * (k + 1))
-        wbit = jnp.where(sub, mask_i << (lane.astype(jnp.int32)
-                                         - 32 * k), 0)
-        words.append(jnp.sum(wbit, axis=1, keepdims=True,
-                             dtype=jnp.int32))
-    out_ref[:] = jax.lax.bitcast_convert_type(
-        jnp.concatenate(words, axis=1), jnp.uint32)
-    q_ref[0] = qcol[V2_ROWS - 1, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("avg_bits", "interpret"))
-@jax.named_scope("gear_scan")
-def gear_bitmap_flat2(buf: jax.Array,
-                      avg_bits: int = gear.DEFAULT_AVG_BITS,
-                      interpret: bool = False) -> jax.Array:
-    """Natural-layout kernel over a flat uint8 stream (length a
-    multiple of V2_TILE; callers zero-pad and slice the bitmap).
-    Returns packed words [len(buf)//32], zero-history at position 0 —
-    the exact gear.gear_bitmap contract, including head positions."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n = buf.shape[0]
-    if n % V2_TILE:
-        raise ValueError(f"stream length {n} not a multiple of "
-                         f"{V2_TILE}")
-    rows = buf.reshape(n // 128, 128)
-    kernel = functools.partial(_gear_kernel2, avg_bits)
-    words = pl.pallas_call(
-        kernel,
-        grid=(n // V2_TILE,),
-        in_specs=[pl.BlockSpec((V2_ROWS, 128), lambda j: (j, 0))],
-        out_specs=pl.BlockSpec((V2_ROWS, 4), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n // 128, 4), jnp.uint32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        interpret=interpret,
-        name="gear_bitmap_flat2",
-    )(rows)
-    return words.reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("avg_bits", "interpret"))

@@ -25,7 +25,6 @@ Layout choices (TPU-first):
 from __future__ import annotations
 
 import functools
-import os as _os
 
 import jax
 import jax.numpy as jnp
@@ -126,41 +125,31 @@ def bytes_to_words(msg: jax.Array) -> jax.Array:
     )
 
 
-# Note on history: the first formulation ran the 64 rounds as a
-# lax.scan (tunable via a MAKISU_TPU_SHA_UNROLL knob, now retired)
-# whose carry stacked the state ([8, L]) and shifted the 16-word message
-# schedule ([16, L]) with a concatenate EVERY round — ~256KB of pure
-# relayout copies per round per 4096 lanes. The SSA formulation below
-# keeps every word in its own loop-carried variable (the schedule
-# window rotates by variable renaming: zero copies, no gather, static
-# round indices) with HLO size bounded by peeling rounds 0-15 and
-# scanning 3 groups of 16 schedule rounds — a 16-round group rotates
-# the window exactly once, so the scan carry maps positionally.
+# The SSA formulation below keeps every word in its own loop-carried
+# variable (the schedule window rotates by variable renaming: zero
+# copies, no gather, static round indices; a carry that stacked the
+# state and shifted the schedule with a concatenate every round cost
+# ~256KB of pure relayout copies per round per 4096 lanes) with HLO
+# size bounded by peeling rounds 0-15 and scanning 3 groups of 16
+# schedule rounds — a 16-round group rotates the window exactly once,
+# so the scan carry maps positionally.
 
-# Unroll factors for the two scans: the inner 16-round-group scan and
-# the outer block scan. 3 and 4 are one v5e's optimum as an earlier
-# round's builders swept it (no record of the sweep survives; the
-# figures are "not measured" until a ledger row carries them). v5e is
-# the one device there is, so every backend that is not the CPU gets
-# them; another TPU generation has to be swept, not assumed
-# (chip_smoke.py refuses a device_kind it does not know). On the CPU
-# the unrolled body (192 inlined rounds per scan step) explodes XLA:CPU
-# compile time, so it runs 1/1. Chosen at trace time from the
-# process's backend; env-tunable. NOT cache identity — digests are
-# identical at any unroll.
-def _unroll(env_key: str, tpu_default: int) -> int:
-    val = _os.environ.get(env_key, "")
-    if val:
-        return int(val)
-    return tpu_default if jax.default_backend() != "cpu" else 1
+# Unroll factors for the two scans, (inner 16-round-group scan, outer
+# block scan). 3 and 4 are one v5e's optimum as an earlier round's
+# builders swept it (no record of the sweep survives; the figures are
+# "not measured" until a ledger row carries them). v5e is the one
+# device there is, so every backend that is not the CPU gets them;
+# another TPU generation has to be swept, not assumed (chip_smoke.py
+# refuses a device_kind it does not know). On the CPU the unrolled body
+# (192 inlined rounds per scan step) explodes XLA:CPU compile time, so
+# it runs 1 and 1. Chosen at trace time from the process's backend. NOT
+# cache identity — digests are identical at any unroll.
+_UNROLL_DEVICE = (3, 4)
+_UNROLL_CPU = (1, 1)
 
 
-def _inner_unroll() -> int:
-    return _unroll("MAKISU_TPU_SHA_INNER_UNROLL", 3)
-
-
-def _block_unroll() -> int:
-    return _unroll("MAKISU_TPU_SHA_BLOCK_UNROLL", 4)
+def _unrolls() -> tuple[int, int]:
+    return _UNROLL_CPU if jax.default_backend() == "cpu" else _UNROLL_DEVICE
 
 
 def _round(a, b, c, d, e, f, g, h, k, wt):
@@ -212,7 +201,7 @@ def _compress(state, w16):
 
     ks = jnp.asarray(_K[16:]).reshape(3, 16)
     (v, _), _ = jax.lax.scan(sixteen, (v, tuple(W)), ks,
-                             unroll=_inner_unroll())
+                             unroll=_unrolls()[0])
     return state + jnp.stack(v)
 
 
@@ -280,7 +269,7 @@ def sha256_lanes_impl(data: jax.Array, lengths: jax.Array,
 
     state, _ = jax.lax.scan(step, state0,
                             jnp.arange(cap // 64, dtype=jnp.int32),
-                            unroll=_block_unroll())
+                            unroll=_unrolls()[1])
     return jnp.transpose(state)
 
 

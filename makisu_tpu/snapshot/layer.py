@@ -59,21 +59,11 @@ class WhiteoutEntry:
 
 
 class _ReadAhead:
-    """File read-ahead for the tar writer: upcoming ContentEntry bytes
-    prefetch on the commit pool so the (strictly ordered) writer never
-    blocks on a cold page-cache read.
-
-    Two modes, chosen by the writer:
-
-    - **buffer** (Python tar writers): prefetched bytes are handed to
-      the writer directly — the disk read happens ahead, off-thread.
-    - **warm** (the native ``add_path`` writer, whose C++ read path is
-      faster than a Python bytes hand-off): the task reads and
-      discards, purely to populate the page cache; the writer still
-      streams content in C++. Only with a prebuilt library from before
-      ``lsk_write_entries``: a native writer that has ``add_entries``
-      takes the entries by the batch, the sink reads ahead on threads
-      of its own, and no ``_ReadAhead`` is made.
+    """File read-ahead for a Python tar writer: upcoming ContentEntry
+    bytes prefetch on the commit pool, off the (strictly ordered)
+    writer's thread, and are handed to the writer directly, so it never
+    blocks on a cold page-cache read. (The native sink takes entries by
+    the batch and reads ahead on threads of its own.)
 
     Prefetch results are advisory: any read error, or a file whose size
     changed since its header was recorded, yields ``None`` and the
@@ -86,10 +76,9 @@ class _ReadAhead:
     BUDGET_BYTES = 64 * 1024 * 1024    # in-flight prefetch cap
 
     def __init__(self, items: list[tuple[str, "ContentEntry"]],
-                 buffer: bool, workers: int) -> None:
+                 workers: int) -> None:
         self._queue = list(items)  # (key, entry), commit order
         self._queue.reverse()      # pop() from the front cheaply
-        self._buffer = buffer
         self._pool = concurrency.hash_pool()
         # Bounded by TASKS as well as bytes: a layer of 50k tiny files
         # must not enqueue 50k reads ahead of the SHA/scan stages on
@@ -116,11 +105,6 @@ class _ReadAhead:
         t0 = time.monotonic()
         try:
             with open(src, "rb") as f:
-                if not self._buffer:
-                    # Warm mode: touch every page, keep nothing.
-                    while f.read(1 << 20):
-                        pass
-                    return None
                 data = f.read(size + 1)
         except OSError:
             return None  # writer re-reads and surfaces the real error
@@ -133,18 +117,13 @@ class _ReadAhead:
         return data if len(data) == size else None
 
     def take(self, key: str) -> bytes | None:
-        """Prefetched bytes for ``key`` (buffer mode), else None. Tops
-        the pipeline back up as the writer consumes entries. Warm mode
-        never waits: the result is discarded by construction, so
-        blocking the native writer behind a saturated pool for it
-        would make read-ahead a slowdown."""
+        """Prefetched bytes for ``key``, else None. Tops the pipeline
+        back up as the writer consumes entries."""
         fut, size = self._futs.pop(key, (None, 0))
         if fut is None:
             return None
         self._inflight -= size
         self._top_up()
-        if not self._buffer:
-            return None  # advisory warm; the task completes on its own
         try:
             data = fut.result()
         except Exception:  # noqa: BLE001 - advisory stage
@@ -222,15 +201,15 @@ class Layer:
     def commit(self, tw: tarfile.TarFile,
                workers: int | None = None) -> None:
         """Write entries in sorted path order (cache-identity-bearing).
-        A writer that has ``add_entries`` (the native sink) takes them
-        by the batch, whiteouts and header-only entries in their sorted
-        place, and reads the files ahead itself. Else, with ``workers >
-        1`` (default: concurrency.hash_workers), file content
-        prefetches ahead of the writer on the commit pool. The produced
+        The native sink's writer takes them by the batch
+        (``add_entries``), whiteouts and header-only entries in their
+        sorted place, and reads the files ahead itself. A
+        ``tarfile.TarFile`` (the Python sink) takes them one by one;
+        with ``workers > 1`` (default: concurrency.hash_workers), file
+        content prefetches ahead of it on the commit pool. The produced
         tar bytes are identical either way."""
         keys = sorted(self.entries)
-        add_entries = getattr(tw, "add_entries", None)
-        if add_entries is not None:
+        if not isinstance(tw, tarfile.TarFile):
             batch, content = [], 0
             for key in keys:
                 hdr, src = item = self.entries[key].header()
@@ -238,10 +217,10 @@ class Layer:
                 content += hdr.size if src is not None else 0
                 if (len(batch) == self._BATCH_ENTRIES
                         or content >= self._BATCH_BYTES):
-                    add_entries(batch)
+                    tw.add_entries(batch)
                     batch, content = [], 0
             if batch:
-                add_entries(batch)
+                tw.add_entries(batch)
             return
         if workers is None:
             workers = concurrency.hash_workers()
@@ -253,10 +232,7 @@ class Layer:
                 and e.hdr.isreg()
                 and 0 < e.hdr.size <= _ReadAhead.MAX_FILE_BYTES]
             if len(eligible) > 1:
-                ra = _ReadAhead(
-                    eligible,
-                    buffer=getattr(tw, "add_path", None) is None,
-                    workers=workers)
+                ra = _ReadAhead(eligible, workers=workers)
         try:
             for key in keys:
                 data = ra.take(key) if ra is not None else None

@@ -272,10 +272,10 @@ def _py_deflate_blocks(data: bytes, level: int, block_size: int,
 def _deflate_blocks(data: bytes, level: int, block_size: int,
                     last: bool) -> bytes:
     """One batch of pgzip blocks: native multi-block entry when the
-    library has it (one GIL-released call), stdlib zlib otherwise —
+    library is there (one GIL-released call), stdlib zlib otherwise —
     identical bytes either way."""
     from makisu_tpu import native
-    if native.pgz_blocks_available():
+    if native.pgzip_available():
         return native.deflate_blocks(data, level, block_size, last)
     return _py_deflate_blocks(data, level, block_size, last)
 
@@ -491,17 +491,12 @@ def apply_header_fd(fd: int, h: tarfile.TarInfo) -> None:
 
 def write_entry(tw, src: str, h: tarfile.TarInfo,
                 data: bytes | None = None) -> None:
-    """Write one entry; regular-file content streams from ``src``.
-    Writers exposing ``add_path`` (the native pipeline) stream content
-    in C++ without the bytes ever entering Python. ``data`` is the
-    read-ahead pool's prefetched content (exactly ``h.size`` bytes,
+    """Write one entry into a ``tarfile.TarFile``; regular-file
+    content streams from ``src``. ``data`` is the read-ahead pool's
+    prefetched content (exactly ``h.size`` bytes,
     snapshot/layer._ReadAhead): byte-identical to the disk read, minus
     the cold-cache stall on the writer's thread."""
     if h.isreg() and h.size > 0:
-        add_path = getattr(tw, "add_path", None)
-        if add_path is not None:
-            add_path(h, src)
-            return
         if data is not None and len(data) == h.size:
             import io
             tw.addfile(h, io.BytesIO(data))

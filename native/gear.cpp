@@ -309,22 +309,6 @@ int gear_scan_pos2(const uint8_t *data, size_t n, const uint32_t *table,
                       sbounds, nslots);
 }
 
-// Pre-ABI-2 entry (4 fixed slots): kept so older callers keep working
-// against a fresh library. The AVX2 route cannot target 4 slots, so
-// this path tops out at striped — new callers use gear_scan_pos2.
-int gear_scan_pos(const uint8_t *data, size_t n, const uint32_t *table,
-                  uint32_t mask, uint32_t *out_pos, size_t stripe_cap,
-                  uint32_t *counts) {
-  size_t sbounds[kStripes + 1];
-  for (size_t t = 0; t <= kStripes; ++t) sbounds[t] = n * t / kStripes;
-  std::memset(counts, 0, kStripes * sizeof(uint32_t));
-  if (gear_isa() >= kGearStriped && n >= kStripedMin)
-    return scan_pos_striped(data, n, table, mask, out_pos, stripe_cap,
-                            counts, sbounds, kStripes);
-  return scan_pos_seq(data, n, table, mask, out_pos, stripe_cap, counts,
-                      sbounds, kStripes);
-}
-
 // out[i] = 1 iff position i is a boundary candidate ((h_i & mask) == 0).
 // The caller hands the same halo-prefixed buffer the device path scans
 // and slices off the halo positions itself.

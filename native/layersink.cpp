@@ -24,13 +24,11 @@
 //                                    until lsk_free: the caller may close
 //                                    out_fd whenever it likes)
 //   lsk_write(h, data, n)            raw tar bytes (headers, inline data)
-//   lsk_write_file(h, path, size)    file content + 512-byte padding
 //   lsk_write_entries(h, n, headers, header_offsets, paths, sizes,
 //                     &failed_index) a batch of entries in stream order:
 //                                    each entry's rendered header, then
 //                                    for a path of size > 0 its content
-//                                    and padding, the bytes lsk_write +
-//                                    lsk_write_file write; the batch's
+//                                    and its padding to 512; the batch's
 //                                    files of up to 8 MiB are read ahead
 //                                    on the sink's reader threads
 //   lsk_finish(h, tar_sha32, gz_sha32, &gz_size, &tar_size)
@@ -61,7 +59,7 @@
 // close each, in entry order, into a second ring of 16 MiB; the caller
 // takes them in order and waits only for the one it needs. A larger
 // file, a batch with fewer than two, or a sink that could start no
-// reader streams on the caller's thread as lsk_write_file does.
+// reader is opened, streamed and closed on the caller's thread.
 
 #include <dlfcn.h>
 #include <fcntl.h>
@@ -109,7 +107,7 @@ struct Sink {
   // Optional tap: every uncompressed tar byte is also handed to this
   // callback (the TPU chunker consumes the stream for CDC while the
   // native pipeline owns framing/hashing/compression). Invoked on the
-  // lsk_write/lsk_write_file caller's thread.
+  // lsk_write/lsk_write_entries caller's thread.
   void (*tap)(const uint8_t*, size_t, void*) = nullptr;
   void* tap_user = nullptr;
   uint64_t gz_size = 0;
@@ -729,7 +727,7 @@ struct Sink {
 
 extern "C" {
 
-int lsk_abi_version() { return 1; }
+int lsk_abi_version() { return 2; }
 
 void* lsk_new(int out_fd, int pgzip, int level, size_t block_size,
               int nthreads) {
@@ -786,23 +784,15 @@ int lsk_write(void* handle, const uint8_t* data, size_t n) {
   return ok ? 0 : -1;
 }
 
-// Stream one regular file's content into the tar, then its 512 padding.
-// `size` is the header's size field; a file that shrank since stat is an
-// error (the tar framing would be corrupt).
-int lsk_write_file(void* handle, const char* path, uint64_t size) {
-  auto* s = static_cast<Sink*>(handle);
-  int rc = s->file_streamed(path, size);
-  s->tap_flush();
-  if (rc == -1) s->failed = true;
-  return rc;
-}
-
 // A batch of n entries in stream order, in one call. Entry i's rendered
 // header is headers[header_offsets[i] .. header_offsets[i + 1]); where
 // paths[i] is not NULL and sizes[i] > 0 the file's first sizes[i] bytes
-// and the padding to 512 follow. 0, or the rc of lsk_write_file with the
-// index of the entry at fault in *failed_index: nothing of a later entry
-// has reached the stream, and the sink stays failed.
+// and the padding to 512 follow; `sizes[i]` is the header's size field,
+// and a file that shrank since stat is an error (the tar framing would be
+// corrupt). 0, or -1 the sink failed, -2 a file could not be read, -3 it
+// ends before its size, with the index of the entry at fault in
+// *failed_index: nothing of a later entry has reached the stream, and the
+// sink stays failed.
 int lsk_write_entries(void* handle, size_t n, const uint8_t* headers,
                       const uint64_t* header_offsets,
                       const char* const* paths, const uint64_t* sizes,
@@ -831,7 +821,7 @@ double lsk_compress_seconds(void* handle) {
 }
 
 // After lsk_finish: seconds the caller was blocked on the zlib stream (a
-// full ring in lsk_write / lsk_write_file, the drain in lsk_finish). Near
+// full ring in lsk_write / lsk_write_entries, the drain in lsk_finish). Near
 // 0 where the producer is the brake, near the stream's seconds less the
 // producer's own where gzip is. 0 for pgzip.
 double lsk_wait_seconds(void* handle) {
@@ -842,8 +832,7 @@ double lsk_wait_seconds(void* handle) {
 // blocked on a reader that had not finished the file it needed next.
 // counts: regular files with content that a reader had ready, that the
 // caller waited for, that the caller streamed itself (over 8 MiB, a batch
-// with fewer than two files for the readers, lsk_write_file, no thread to
-// be had).
+// with fewer than two files for the readers, no thread to be had).
 void lsk_prefetch_stats(void* handle, double* read_wait_s,
                         uint64_t counts[3]) {
   auto* s = static_cast<Sink*>(handle);

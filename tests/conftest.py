@@ -163,32 +163,6 @@ def fs_calls(monkeypatch):
     return install
 
 
-class _LayersinkBefore:
-    """``liblayersink.so`` as a prebuilt one from before
-    ``lsk_write_entries`` and ``lsk_prefetch_stats`` (PR 40): the rest
-    is there."""
-
-    def __init__(self, lib):
-        self._lib = lib
-
-    def __getattr__(self, name):
-        if name in ("lsk_write_entries", "lsk_prefetch_stats"):
-            raise AttributeError(name)
-        return getattr(self._lib, name)
-
-
-@pytest.fixture
-def layersink_before_batches(monkeypatch):
-    """Calling it hides the batch call from every sink made from then
-    on in the test: layers commit entry by entry."""
-    from makisu_tpu import native
-
-    def install():
-        monkeypatch.setattr(native, "_lsk_lib",
-                            _LayersinkBefore(native._load_lsk()))
-    return install
-
-
 def _store_tree(root: str) -> dict[str, tuple[int, bytes]]:
     """What a store directory holds: relative path -> (mode, bytes) per
     file, ``"<dir>/" -> (0, b"")`` per empty directory."""

@@ -142,9 +142,8 @@ def test_chip_smoke_result_line_has_the_contract_keys_and_no_others():
     assert text.rindex("print(result_line(ident)") > text.rindex("say(")
 
 
-@pytest.mark.parametrize("option", ["MAKISU_TPU_PALLAS", "MAKISU_TPU_PALLAS_V2",
+@pytest.mark.parametrize("option", ["MAKISU_TPU_PALLAS",
                                     "MAKISU_TPU_CHUNK_NATIVE",
-                                    "MAKISU_TPU_SHA_BLOCK_UNROLL",
                                     "MAKISU_TPU_SHARED_HASH",
                                     "MAKISU_TPU_CHUNK_STRICT"])
 def test_chip_smoke_children_inherit_no_route_option(monkeypatch, option):

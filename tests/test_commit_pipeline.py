@@ -214,24 +214,12 @@ def test_read_ahead_buffer_and_fallback(tmp_path):
     e_good, e_shrunk = entry(good), entry(shrunk)
     e_shrunk.hdr.size = 9_999  # header no longer matches the content
     ra = _ReadAhead([("/good.bin", e_good), ("/shrunk.bin", e_shrunk)],
-                    buffer=True, workers=4)
+                    workers=4)
     assert ra.take("/good.bin") == b"g" * 10_000
     # Mismatched size: advisory prefetch yields None — the writer falls
     # back to streaming, which owns that failure mode.
     assert ra.take("/shrunk.bin") is None
     assert ra.take("/never-queued") is None
-    ra.close()
-
-
-def test_read_ahead_warm_mode_returns_none(tmp_path):
-    from makisu_tpu.snapshot.layer import ContentEntry
-    from makisu_tpu.snapshot.walk import tarinfo_from_stat
-    f = tmp_path / "f.bin"
-    f.write_bytes(b"x" * 4_096)
-    hdr = tarinfo_from_stat(str(f), "f.bin", str(tmp_path))
-    ra = _ReadAhead([("/f.bin", ContentEntry(str(f), "/f.bin", hdr))],
-                    buffer=False, workers=4)
-    assert ra.take("/f.bin") is None  # warm mode never hands bytes
     ra.close()
 
 
@@ -296,12 +284,12 @@ def test_layer_commit_closes_a_batch_at_16_mib_of_content():
                     reason="native libraries not built")
 @pytest.mark.parametrize("backend_id", ["zlib-6", "pgzip-6-131072"])
 def test_commit_by_the_batch_makes_no_read_ahead_and_the_same_layer(
-        tmp_path, monkeypatch, layersink_before_batches, backend_id):
+        tmp_path, monkeypatch, backend_id):
     """Through the native sink ``Layer.commit`` makes no ``_ReadAhead``
     (the sink reads ahead on threads of its own) and hands the writer
-    two batches; tar, blob, digests and chunks are those of a library
-    from before ``lsk_write_entries``, which commits entry by entry
-    with the warm read-ahead."""
+    two batches; tar, blob, digests and chunks are those of the Python
+    sink (``MAKISU_TPU_NATIVE_SINK=0``), which takes the entries one
+    by one with the read-ahead handing it their bytes."""
     if backend_id.startswith("pgzip") and not native.pgzip_available():
         pytest.skip("pgzip not built")
     from makisu_tpu.snapshot import layer as layer_mod
@@ -312,22 +300,22 @@ def test_commit_by_the_batch_makes_no_read_ahead_and_the_same_layer(
     init = _ReadAhead.__init__
     monkeypatch.setattr(
         layer_mod._ReadAhead, "__init__",
-        lambda self, items, buffer, workers: (
-            made.append(buffer), init(self, items, buffer, workers))[1])
+        lambda self, items, workers: (
+            made.append(len(items)), init(self, items, workers))[1])
     from makisu_tpu.chunker.hasher import _NativeTarWriter
-    add = _NativeTarWriter._add_entries
+    add = _NativeTarWriter.add_entries
     monkeypatch.setattr(
-        _NativeTarWriter, "_add_entries",
+        _NativeTarWriter, "add_entries",
         lambda self, items: (batches.append(len(items)),
                              add(self, items))[1])
     batched = str(tmp_path / "batched.tar.gz")
     by_batch = _commit(root, batched, backend_id, workers=8)
     assert made == []
     assert batches == [256, sum(batches) - 256]
-    layersink_before_batches()
+    monkeypatch.setenv("MAKISU_TPU_NATIVE_SINK", "0")
     entrywise = str(tmp_path / "entrywise.tar.gz")
     by_entry = _commit(root, entrywise, backend_id, workers=8)
-    assert made == [False]  # warm mode, as before
+    assert len(batches) == 2 and made == [342]  # the files with content
     assert by_batch.chunks
     assert _identity(by_batch, batched) == _identity(by_entry, entrywise)
 
@@ -443,7 +431,7 @@ def test_block_codecs_byte_identical():
     replayable on hosts without the native library (cache identity
     must not depend on which codec ran). Swept over the seams: empty,
     sub-block, exact block multiples, ragged tails."""
-    if not native.pgz_blocks_available():
+    if not native.pgzip_available():
         pytest.skip("libpgzip.so predates the multi-block entry")
     rng = np.random.default_rng(37)
     blob = rng.integers(0, 256, size=131072 * 3 + 17,
@@ -595,7 +583,6 @@ def device_formulation(monkeypatch):
     test_spans_plane.py, so the compiled programs are shared)."""
     monkeypatch.setenv("MAKISU_TPU_CHUNK_NATIVE", "0")
     monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
-    monkeypatch.delenv("MAKISU_TPU_PALLAS_V2", raising=False)
 
 
 def _observed_session(seed, service=None):

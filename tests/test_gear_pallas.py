@@ -64,12 +64,19 @@ def test_stage_rows_shapes():
         buf[gear_pallas.ROW - gear_pallas.HALO:gear_pallas.ROW])
 
 
-@pytest.mark.parametrize("start,live", [(0, 1000), (0, 8192),
-                                        (128, 3 * 8192 + 777),
-                                        (50, 9000)])
+@pytest.mark.parametrize(
+    "start,live",
+    [(0, 1000), (0, 8192), (128, 3 * 8192 + 777), (50, 9000)]
+    + [(start, live) for live in (1, 100, 33_000, 200_000)
+       for start in (0, 128)])
 def test_gear_bitmap_flat_matches_staged_rows(start, live):
     """The fused on-device restage must cut exactly where the numpy
-    stage_rows path does (production vs test-oracle staging)."""
+    stage_rows path does (production vs test-oracle staging), and
+    where the XLA reference (``gear.gear_bitmap``) does: with a whole
+    window of true history (a 128-byte halo prefix) at every position,
+    without one from ``WINDOW`` on (the kernel's halo is zero bytes,
+    the reference's zero G-values; both sit far below the minimum chunk
+    size and never become cuts)."""
     rng = np.random.default_rng(start + live)
     buf = rng.integers(0, 256, size=start + live, dtype=np.uint8)
     words = np.asarray(gear_pallas.gear_bitmap_flat(
@@ -83,20 +90,23 @@ def test_gear_bitmap_flat_matches_staged_rows(start, live):
     want = gear.unpack_bits_np(
         w2[:nr], nr * gear_pallas.ROW).reshape(-1)[:live]
     np.testing.assert_array_equal(got, want)
+    padded = np.concatenate(
+        [buf, np.zeros(-len(buf) % 32, dtype=np.uint8)])
+    xla = gear.unpack_bits_np(
+        np.asarray(gear.gear_bitmap(padded)), len(buf))[start:]
+    skip = 0 if start >= gear.WINDOW else gear.WINDOW
+    np.testing.assert_array_equal(got[skip:], xla[skip:])
 
 
-@pytest.mark.parametrize("v2", ["", "1"])
-def test_kernel_failure_fails_the_session(monkeypatch, v2):
+def test_kernel_failure_fails_the_session(monkeypatch):
     """A kernel the compiler refuses propagates: the session raises
-    with the kernel's reason. No breaker hands the scan to another
-    route (v2 to v1, v1 to XLA) — that would change what a build
-    measures without a word — and only MAKISU_TPU_CHUNK_STRICT=0
-    degrades the layer."""
+    with the kernel's reason. No breaker hands the scan to the XLA
+    route — that would change what a build measures without a word —
+    and only MAKISU_TPU_CHUNK_STRICT=0 degrades the layer."""
     # Kernel-route test: pin off the native CPU route (it never
     # touches Pallas, so the simulated failure would not fire).
     monkeypatch.setenv("MAKISU_TPU_CHUNK_NATIVE", "0")
     monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
-    monkeypatch.setenv("MAKISU_TPU_PALLAS_V2", v2)
     from makisu_tpu.chunker.cdc import ChunkSession
 
     payload = np.random.default_rng(11).integers(
@@ -110,10 +120,7 @@ def test_kernel_failure_fails_the_session(monkeypatch, v2):
         other_routes.append(1)
         raise AssertionError("the scan moved to another route")
 
-    failing = "gear_bitmap_flat2" if v2 else "gear_bitmap_flat"
-    standby = "gear_bitmap_flat" if v2 else "gear_bitmap_flat2"
-    monkeypatch.setattr(gear_pallas, failing, boom)
-    monkeypatch.setattr(gear_pallas, standby, other)
+    monkeypatch.setattr(gear_pallas, "gear_bitmap_flat", boom)
     monkeypatch.setattr(gear, "gear_bitmap", other)
 
     monkeypatch.delenv("MAKISU_TPU_CHUNK_STRICT", raising=False)
@@ -128,45 +135,6 @@ def test_kernel_failure_fails_the_session(monkeypatch, v2):
     assert s.finish() == []
     assert "synthetic Mosaic rejection" in s._degraded
     assert not other_routes
-
-
-@pytest.mark.parametrize("n_live", [1, 100, 33000, 200000])
-def test_gear_bitmap_flat2_identical_to_xla(n_live):
-    """v2 (natural layout + SMEM carry) is bit-identical to
-    gear.gear_hash INCLUDING head positions — no halo approximation."""
-    rng = np.random.default_rng(n_live)
-    need = ((n_live + gear_pallas.V2_TILE - 1)
-            // gear_pallas.V2_TILE) * gear_pallas.V2_TILE
-    buf = np.zeros(need, dtype=np.uint8)
-    buf[:n_live] = rng.integers(0, 256, size=n_live, dtype=np.uint8)
-    words = np.asarray(gear_pallas.gear_bitmap_flat2(
-        buf, interpret=True))
-    got = np.nonzero(gear.unpack_bits_np(words, need)[:n_live])[0]
-    h = np.asarray(gear.gear_hash(buf))[:n_live]
-    want = np.nonzero(
-        (h & ((1 << gear.DEFAULT_AVG_BITS) - 1)) == 0)[0]
-    np.testing.assert_array_equal(got, want)
-
-
-def test_chunk_session_v2_path_matches(monkeypatch):
-    """MAKISU_TPU_PALLAS_V2=1 must produce identical chunks end to
-    end (the v2 route slices the full-buffer bitmap like the XLA
-    path)."""
-    from makisu_tpu.chunker.cdc import ChunkSession
-
-    payload = np.random.default_rng(77).integers(
-        0, 256, size=500_000, dtype=np.uint8).tobytes()
-
-    def run():
-        s = ChunkSession(block=128 * 1024)
-        for i in range(0, len(payload), 50_000):
-            s.update(payload[i:i + 50_000])
-        return [(c.offset, c.length, c.digest) for c in s.finish()]
-
-    baseline = run()
-    monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
-    monkeypatch.setenv("MAKISU_TPU_PALLAS_V2", "1")
-    assert run() == baseline
 
 
 def test_gear_bitmap_batch_matches_xla_above_window():

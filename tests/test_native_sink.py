@@ -15,9 +15,8 @@ import zlib
 
 import pytest
 
-from makisu_tpu import native, tario
-from makisu_tpu.chunker import hasher as hasher_mod
-from makisu_tpu.chunker.hasher import LayerSink, NativeLayerSink
+from makisu_tpu import native
+from makisu_tpu.chunker.hasher import LayerSink, NativeLayerSink, _TPUSink
 
 pytestmark = pytest.mark.skipif(
     not native.layersink_available(),
@@ -67,13 +66,23 @@ def _entries(root):
     return out
 
 
+def _layer(root):
+    """The tree as a layer: ``Layer.commit`` writes it in sorted path
+    order, by the batch into a native writer and entry by entry into a
+    ``tarfile.TarFile``."""
+    from makisu_tpu.snapshot.layer import Layer
+    layer = Layer()
+    for src, hdr in _entries(root):
+        layer.add_header(src, "/" + hdr.name, hdr)
+    return layer
+
+
 def _commit(sink_cls, root, path, backend_id):
-    entries = _entries(root)
+    layer = _layer(root)
     with open(path, "wb") as f:
         sink = sink_cls(f, backend_id=backend_id)
         with sink.open_tar() as tw:
-            for src, hdr in entries:
-                tario.write_entry(tw, src, hdr)
+            layer.commit(tw)
         return sink.finish()
 
 
@@ -107,15 +116,16 @@ def _stream_bytes(n, seed=11):
 
 def _feed(handle, tmp_path, plan):
     """Write a plan of ``("write", bytes)`` and ``("file", bytes)``
-    steps into a native handle, a file through ``lsk_write_file``
-    (content, then padding to 512); yields the stream bytes sent after
-    each step."""
+    steps into a native handle, a file as a batch of one entry with no
+    header (content, then padding to 512; the sink streams a lone file
+    on the caller's thread); yields the stream bytes sent after each
+    step."""
     sent = 0
     for i, (kind, data) in enumerate(plan):
         if kind == "file":
             src = tmp_path / f"src{i}"
             src.write_bytes(data)
-            handle.write_file(str(src), len(data))
+            handle.write_entries([b""], [str(src)], [len(data)])
         else:
             handle.write(data)
         sent += len(data) + (-len(data) % 512 if kind == "file" else 0)
@@ -299,7 +309,8 @@ def test_native_sink_error_on_shrunk_file(tmp_path):
         sink = NativeLayerSink(f, backend_id="zlib-6")
         tw = sink.open_tar()
         with pytest.raises(OSError, match="shrank"):
-            tw.add_path(hdr, str(victim))
+            tw.add_entries([(hdr, str(victim))])
+        sink.abort()
 
 
 def test_native_tpu_sink_matches_python_chunks(tmp_path, monkeypatch):
@@ -314,14 +325,12 @@ def test_native_tpu_sink_matches_python_chunks(tmp_path, monkeypatch):
         monkeypatch.setenv("MAKISU_TPU_NATIVE_SINK",
                            "1" if native_on else "0")
         path = str(tmp_path / out_name)
-        entries = _entries(root)
+        layer = _layer(root)
         with open(path, "wb") as f:
             sink = TPUHasher().open_layer(f, backend_id="zlib-6")
-            if native_on:
-                assert isinstance(sink, NativeLayerSink)
+            assert isinstance(sink, NativeLayerSink) == native_on
             with sink.open_tar() as tw:
-                for src, hdr in entries:
-                    tario.write_entry(tw, src, hdr)
+                layer.commit(tw)
             return sink.finish(), path
 
     py, py_path = commit(False, "py.tgz")
@@ -383,8 +392,8 @@ def test_tap_sees_every_byte_once_in_order_on_the_callers_thread(
         tmp_path, backend):
     """The tap is the chunker's intake (a Python callback, clocked on
     the building thread): it runs synchronously in the writer's calls,
-    never on the sink's compressor thread, also for bytes that
-    ``write_file`` read straight into the ring."""
+    never on the sink's compressor thread, also for a file's bytes
+    that the sink read straight into the ring."""
     if backend == "pgzip" and not native.pgzip_available():
         pytest.skip("pgzip not built")
     seen, idents = [], set()
@@ -419,13 +428,13 @@ def _broken_pipe_handle():
     return handle, w
 
 
-@pytest.mark.parametrize("where", ["header", "write", "write_file",
+@pytest.mark.parametrize("where", ["header", "write", "write_entries",
                                    "finish"])
 def test_failing_output_fd_fails_the_sink_and_does_not_hang(tmp_path,
                                                             where):
     """An output that stops taking bytes fails the commit: ``lsk_new``
     where not even the header goes out, else the first ``write`` /
-    ``write_file`` after the compressor thread met the error (not
+    ``write_entries`` after the compressor thread met the error (not
     blocked on a ring nobody empties any more), ``finish`` at the
     latest; and the failed sink can be closed."""
     if where == "header":
@@ -452,7 +461,8 @@ def test_failing_output_fd_fails_the_sink_and_does_not_hang(tmp_path,
                     if where == "write":
                         handle.write(data)
                     else:
-                        handle.write_file(str(src), len(data))
+                        handle.write_entries([b""], [str(src)],
+                                             [len(data)])
             _, err = _within(60, feed)
             assert isinstance(err, RuntimeError)
             assert "write failed" in str(err)
@@ -460,7 +470,7 @@ def test_failing_output_fd_fails_the_sink_and_does_not_hang(tmp_path,
             with pytest.raises(RuntimeError, match="write failed"):
                 handle.write(b"y")
             with pytest.raises(RuntimeError, match="write failed"):
-                handle.write_file(str(src), len(data))
+                handle.write_entries([b""], [str(src)], [len(data)])
             _, err = _within(30, handle.finish)
             assert isinstance(err, RuntimeError)
         _within(30, handle.close)
@@ -543,6 +553,7 @@ def test_a_commit_that_dies_stops_the_sink_before_out_closes(tmp_path,
             return []
 
     text = _more_than_the_ring_of_text()
+    (tmp_path / "text").write_bytes(text)
     threads = _compressor_threads()
     session = Session()
     with pytest.raises((_Died, RuntimeError)):
@@ -554,7 +565,8 @@ def test_a_commit_that_dies_stops_the_sink_before_out_closes(tmp_path,
                 assert _compressor_threads() == threads + 1
                 hdr = tarfile.TarInfo("text")
                 hdr.size = len(text)
-                tw.addfile(hdr, io.BytesIO(text))  # slots deflate slowly
+                # slots deflate slowly
+                tw.add_entries([(hdr, str(tmp_path / "text"))])
                 if dies_in == "write_diffs":
                     raise _Died
                 session.dies_in = dies_in
@@ -581,6 +593,90 @@ def test_the_sink_writes_to_a_fd_of_its_own(tmp_path):
     assert len(blob) == gz_size
     assert hashlib.sha256(blob).hexdigest() == gz_hex
     assert zlib.decompress(blob, 31) == text + b"tail"
+
+
+# -- a library is this tree's, or it is absent ---------------------------------
+
+# library -> (the module's handle, its failed flag, the ABI call, a
+# symbol the module binds, the question callers ask).
+_LIBRARIES = {
+    "libpgzip": ("_lib", "_load_failed", "pgz_abi_version", "pgz_blocks",
+                 native.pgzip_available),
+    "liblayersink": ("_lsk_lib", "_lsk_failed", "lsk_abi_version",
+                     "lsk_prefetch_stats", native.layersink_available),
+    "libgear": ("_gear_lib", "_gear_failed", "gear_abi_version",
+                "gear_sha256_batch", native.gear_scan_available),
+}
+
+
+class _AnotherTrees:
+    """A built library as one from another tree would load: a symbol
+    the module binds is missing, or the ABI call answers another
+    number. The rest is there."""
+
+    def __init__(self, lib, hidden, abi_fn):
+        self._lib, self._hidden, self._abi_fn = lib, hidden, abi_fn
+
+    def __getattr__(self, name):
+        if name == self._hidden:
+            raise AttributeError(f"undefined symbol: {name}")
+        if name == self._abi_fn:
+            return lambda: 99
+        return getattr(self._lib, name)
+
+
+@pytest.mark.parametrize("fault", ["abi", "symbol"])
+@pytest.mark.parametrize("library", list(_LIBRARIES))
+def test_a_library_that_is_not_this_trees_is_refused_whole(
+        tmp_path, monkeypatch, library, fault):
+    """Another ABI number, or one bound symbol missing: the library is
+    unavailable (no half-loaded state), the log says so once with the
+    command that rebuilds it, and a layer commits through what stands
+    in for it (the Python sink and its stdlib deflate, the ``xla``
+    chunk route) to the tar digest, blob digest and chunk list of the
+    native build."""
+    import ctypes
+
+    from makisu_tpu.chunker import TPUHasher, route
+    from makisu_tpu.utils import logging as log
+    if not native.pgzip_available() or not native.gear_scan_available():
+        pytest.skip("native libraries not built")
+    layer = _layer(_tree(tmp_path))
+    backend_id = "pgzip-6-131072"
+    assert route.chunk_route().native
+    want, want_blob, _ = _commit_layer(layer, tmp_path / "native.gz",
+                                       backend_id)
+
+    handle, failed, abi_fn, symbol, available = _LIBRARIES[library]
+    monkeypatch.setattr(native, handle, None)
+    monkeypatch.setattr(native, failed, False)
+    cdll = ctypes.CDLL
+    monkeypatch.setattr(
+        ctypes, "CDLL",
+        lambda path, *a, **k: cdll(path, *a, **k)
+        if f"{library}.so" not in str(path)
+        else _AnotherTrees(cdll(path), *(
+            (None, abi_fn) if fault == "abi" else (symbol, None))))
+    said = []
+    monkeypatch.setattr(log, "error",
+                        lambda msg, *a: said.append(msg % a))
+    assert not available() and not available()
+    assert getattr(native, handle) is None
+    [line] = said
+    assert f"{library}.so" in line and "make -C native clean all" in line
+    assert ("99" if fault == "abi" else symbol) in line
+
+    if library == "libpgzip":
+        # The native sink deflates its blocks itself: the library's
+        # stand-in is the Python sink's stdlib codec.
+        monkeypatch.setenv("MAKISU_TPU_NATIVE_SINK", "0")
+    sink_cls = NativeLayerSink if library == "libgear" else _TPUSink
+    assert route.chunk_route().native == (library != "libgear")
+    got, got_blob, _ = _commit_layer(layer, tmp_path / "other.gz",
+                                     backend_id, sink_cls=sink_cls)
+    assert got_blob == want_blob
+    assert got.digest_pair == want.digest_pair
+    assert got.chunks == want.chunks and got.chunks
 
 
 # -- entries by the batch (lsk_write_entries) ---------------------------------
@@ -626,17 +722,21 @@ class _Recorder:
         return []
 
 
-def _commit_layer(layer, path, backend_id, session=None):
-    """``Layer.commit`` through a native sink (the TPU hasher's, unless
-    a ``session`` is given): the commit, the blob, the writer."""
+def _commit_layer(layer, path, backend_id, session=None,
+                  sink_cls=NativeLayerSink):
+    """``Layer.commit`` through a sink of ``sink_cls``, the native one
+    or the Python one (``_TPUSink``): the TPU hasher's own choice,
+    unless a ``session`` is given. The commit, the blob, the writer."""
     from makisu_tpu.chunker import TPUHasher
     with open(path, "wb") as f:
         if session is None:
             sink = TPUHasher().open_layer(f, backend_id=backend_id)
-            assert isinstance(sink, NativeLayerSink)
-        else:
+            assert isinstance(sink, sink_cls)
+        elif sink_cls is NativeLayerSink:
             sink = NativeLayerSink(f, backend_id=backend_id,
                                    session=session)
+        else:
+            sink = sink_cls(f, session, backend_id=backend_id)
         with sink.open_tar() as tw:
             layer.commit(tw)
         commit = sink.finish()
@@ -649,21 +749,22 @@ _BACKENDS = ["zlib-6", "pgzip-6-131072"]
 
 @pytest.mark.parametrize("backend_id", _BACKENDS)
 def test_the_batch_path_gives_the_per_entry_paths_bytes(
-        tmp_path, monkeypatch, layersink_before_batches, backend_id):
+        tmp_path, monkeypatch, backend_id):
     """Tar bytes, blob bytes, both digests and the chunk list are the
-    same whether the layer's entries cross into the sink a batch at a
-    time or one by one (a library without ``lsk_write_entries``), and
-    the Python sink's."""
+    same whether the layer's entries cross into the native sink a
+    batch at a time or go one by one into the Python sink's
+    ``tarfile.TarFile`` (the reference that stays), and they are what
+    ``hashlib`` and ``zlib`` say of the tar."""
     if backend_id.startswith("pgzip") and not native.pgzip_available():
         pytest.skip("pgzip not built")
     layer, _ = _batch_layer(tmp_path)
     batch, batch_blob, tw = _commit_layer(layer, tmp_path / "batch.gz",
                                           backend_id)
-    assert tw.add_entries is not None
-    layersink_before_batches()
-    entry, entry_blob, tw = _commit_layer(layer, tmp_path / "entry.gz",
-                                          backend_id)
-    assert tw.add_entries is None
+    assert not isinstance(tw, tarfile.TarFile)
+    monkeypatch.setenv("MAKISU_TPU_NATIVE_SINK", "0")
+    entry, entry_blob, ptw = _commit_layer(layer, tmp_path / "entry.gz",
+                                           backend_id, sink_cls=_TPUSink)
+    assert isinstance(ptw, tarfile.TarFile)
     assert batch_blob == entry_blob
     assert batch.digest_pair == entry.digest_pair
     assert batch.chunks == entry.chunks and batch.chunks
@@ -671,15 +772,15 @@ def test_the_batch_path_gives_the_per_entry_paths_bytes(
     assert len(tar) == tw.offset
     assert hashlib.sha256(tar).hexdigest() \
         == batch.digest_pair.tar_digest.hex()
-    monkeypatch.setenv("MAKISU_TPU_NATIVE_SINK", "0")
-    from makisu_tpu.chunker import TPUHasher
-    with open(tmp_path / "py.gz", "wb") as f:
-        sink = TPUHasher().open_layer(f, backend_id=backend_id)
-        with sink.open_tar() as ptw:
-            layer.commit(ptw)
-        py = sink.finish()
-    assert py.digest_pair == batch.digest_pair
-    assert py.chunks == batch.chunks
+    assert hashlib.sha256(batch_blob).hexdigest() \
+        == batch.digest_pair.gzip_descriptor.digest.hex()
+    at = 0
+    for chunk in batch.chunks:  # they tile the tar, each its sha-256
+        assert chunk.offset == at
+        assert hashlib.sha256(
+            tar[at:at + chunk.length]).hexdigest() == chunk.hex_digest
+        at += chunk.length
+    assert at == len(tar)
     with tarfile.open(fileobj=io.BytesIO(batch_blob), mode="r:gz") as tf:
         members = {m.name: m for m in tf}
         assert len(members) == len(layer)
@@ -690,28 +791,28 @@ def test_the_batch_path_gives_the_per_entry_paths_bytes(
 
 
 @pytest.mark.parametrize("backend_id", _BACKENDS)
-def test_the_tap_is_called_by_the_slot_not_by_the_piece(
-        tmp_path, layersink_before_batches, backend_id):
-    """By the batch the tap gets the very stream it gets entry by
-    entry, every byte once and in order, a filled 256 KiB at a time
-    and once at the end of a call: in far fewer calls."""
+def test_the_tap_is_called_by_the_slot_not_by_the_piece(tmp_path,
+                                                        backend_id):
+    """By the batch the tap gets the very stream the Python sink's
+    chunker gets write by write, every byte once and in order, a
+    filled 256 KiB at a time and once at the end of a call: in far
+    fewer calls."""
     if backend_id.startswith("pgzip") and not native.pgzip_available():
         pytest.skip("pgzip not built")
     layer, _ = _batch_layer(tmp_path)
     by_batch, by_entry = _Recorder(), _Recorder()
     commit, blob, _ = _commit_layer(layer, tmp_path / "batch.gz",
                                     backend_id, session=by_batch)
-    layersink_before_batches()
     _commit_layer(layer, tmp_path / "entry.gz", backend_id,
-                  session=by_entry)
+                  session=by_entry, sink_cls=_TPUSink)
     tar = b"".join(by_batch.pieces)
     assert tar == b"".join(by_entry.pieces) == zlib.decompress(blob, 31)
     assert hashlib.sha256(tar).hexdigest() \
         == commit.digest_pair.tar_digest.hex()
     assert max(map(len, by_batch.pieces)) == _TAP
     # A call a filled slot, one more at the end of each of the two
-    # batches and of the archive's end; entry by entry two or three a
-    # file.
+    # batches and of the archive's end; the Python sink's tarfile
+    # writes a header and then its content 16 KiB at a time.
     assert len(by_batch.pieces) <= len(tar) // _TAP + 3
     assert len(by_entry.pieces) > 2 * len(layer)
 
@@ -817,42 +918,6 @@ def test_abort_in_the_middle_of_a_batch_joins_the_readers(tmp_path):
         assert len(os.listdir("/proc/self/fd")) == fds + 1  # ``out``
         number = out.fileno()
     _stays_empty(tmp_path / "other", number)
-
-
-def test_a_library_without_the_batch_call_commits_entry_by_entry(
-        tmp_path, monkeypatch, layersink_before_batches):
-    """A prebuilt library from before ``lsk_write_entries``: the writer
-    has no ``add_entries``, ``Layer.commit`` goes through ``add_path``
-    with the warm read-ahead, and nothing is said of a prefetch."""
-    from makisu_tpu.snapshot import layer as layer_mod
-    from makisu_tpu.utils import concurrency, metrics
-    layer, with_content = _batch_layer(tmp_path)
-    layersink_before_batches()
-    calls, modes = [], []
-    add_path = hasher_mod._NativeTarWriter.add_path
-    monkeypatch.setattr(
-        hasher_mod._NativeTarWriter, "add_path",
-        lambda self, hdr, path: (calls.append(path),
-                                 add_path(self, hdr, path)))
-    init = layer_mod._ReadAhead.__init__
-    monkeypatch.setattr(
-        layer_mod._ReadAhead, "__init__",
-        lambda self, items, buffer, workers: (
-            modes.append(buffer), init(self, items, buffer, workers))[1])
-    registry = metrics.MetricsRegistry()
-    token = metrics.set_build_registry(registry)
-    workers = concurrency.set_hash_workers(4)
-    try:
-        _commit_layer(layer, tmp_path / "old.gz", "zlib-6",
-                      session=_Recorder())
-    finally:
-        concurrency.reset_hash_workers(workers)
-        metrics.reset_build_registry(token)
-    assert len(calls) == with_content
-    assert modes == [False]  # warm
-    busy = registry.counter_by_label(metrics.COMMIT_STAGE_BUSY, "stage")
-    assert "read_wait" not in busy and busy["compress"] > 0
-    assert registry.counter_total(metrics.SINK_PREFETCH_FILES_TOTAL) == 0
 
 
 @pytest.mark.parametrize("backend_id", _BACKENDS)

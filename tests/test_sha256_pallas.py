@@ -8,6 +8,7 @@ the dispatch logic; bit-level kernel validation runs on the chip
 production shapes) and via the per-process parity probe in production.
 """
 
+import itertools
 import os
 
 import numpy as np
@@ -51,17 +52,11 @@ def test_select_is_a_pure_function_of_backend_and_options():
     """The whole decision table: no breaker, no history, nothing read
     but the arguments."""
     sel = route.select
-    # A TPU backend rides both kernels; v2 is opt-in.
+    # A TPU backend rides both kernels.
     assert sel("tpu", False, False, {}) == ("pallas", "pallas")
     assert sel("tpu", True, False, {}) == ("pallas", "pallas")
     assert sel("tpu", False, False,
-               {"MAKISU_TPU_PALLAS_V2": "1"}) == ("pallas_v2", "pallas")
-    assert sel("tpu", False, False,
                {"MAKISU_TPU_PALLAS": "0"}) == ("xla", "xla")
-    # v2 rides the shared gate: off with the kernels.
-    assert sel("tpu", False, False, {"MAKISU_TPU_PALLAS": "0",
-                                     "MAKISU_TPU_PALLAS_V2": "1"}) \
-        == ("xla", "xla")
     # The native route never engages on an accelerator, even with the
     # library present.
     assert sel("tpu", False, True, {}) == ("pallas", "pallas")
@@ -76,6 +71,24 @@ def test_select_is_a_pure_function_of_backend_and_options():
     # XLA (the kernel's unrolled body explodes XLA:CPU compile time).
     assert sel("cpu", True, True,
                {"MAKISU_TPU_PALLAS": "1"}) == ("pallas", "xla")
+
+
+def test_select_answers_three_names_over_its_whole_domain():
+    """Platform × shared × library × ``MAKISU_TPU_PALLAS``: ``native``,
+    ``xla`` and ``pallas`` come back and no other name, and the retired
+    option for a second gear kernel changes nothing."""
+    sel = route.select
+    retired = "MAKISU_TPU_PALLAS" + "_V2"
+    for platform, shared, native_ok, forced in itertools.product(
+            ("cpu", "tpu"), (False, True), (False, True), (None, "0", "1")):
+        environ = {} if forced is None else {"MAKISU_TPU_PALLAS": forced}
+        gear, sha = sel(platform, shared, native_ok, environ)
+        assert (gear, sha) in {("native", "native"), ("xla", "xla"),
+                               ("pallas", "pallas"), ("pallas", "xla")}
+        assert (gear == "native") == (
+            platform == "cpu" and not shared and native_ok)
+        assert sel(platform, shared, native_ok,
+                   {**environ, retired: "1"}) == (gear, sha)
 
 
 def test_route_is_logged_once_and_labels_the_counters(monkeypatch):
@@ -234,3 +247,26 @@ def test_kernel_interpret_matches_hashlib():
         data, lengths, interpret=True))
     assert [g.astype(">u4").tobytes() for g in got] == _hashlib_digests(
         data, lengths)
+
+
+def test_the_commit_paths_sources_name_only_the_options_that_stay():
+    """``makisu_tpu/ops/``, ``makisu_tpu/chunker/`` and ``native.py``
+    read these ``MAKISU_TPU_*`` options and name no other, in code or
+    in a comment: a tuning with one value in use is a constant, and a
+    kernel nobody measured has no switch."""
+    import glob
+    import re
+
+    import makisu_tpu
+    pkg = os.path.dirname(makisu_tpu.__file__)
+    found = set()
+    for path in (glob.glob(os.path.join(pkg, "ops", "*.py"))
+                 + glob.glob(os.path.join(pkg, "chunker", "*.py"))
+                 + [os.path.join(pkg, "native.py")]):
+        with open(path, encoding="utf-8") as f:
+            found.update(re.findall(r"MAKISU_TPU_[A-Z0-9_]+", f.read()))
+    assert found == {"MAKISU_TPU_" + name for name in (
+        "BACKEND_INIT_TIMEOUT", "CHUNK_NATIVE", "CHUNK_STRICT",
+        "DEVICE_SESSIONS_DIR", "HASH_LINGER_MS", "NATIVE_DIR",
+        "NATIVE_ISA", "NATIVE_SINK", "PALLAS", "PROBE_SAMPLE_INTERVAL",
+        "PROBE_TIMEOUT", "SHARED_HASH", "SYNC_TIMEOUT")}

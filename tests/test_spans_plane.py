@@ -550,7 +550,6 @@ def device_route(monkeypatch):
     kernel in interpret mode, SHA lanes through XLA."""
     monkeypatch.setenv("MAKISU_TPU_CHUNK_NATIVE", "0")
     monkeypatch.setenv("MAKISU_TPU_PALLAS", "1")
-    monkeypatch.delenv("MAKISU_TPU_PALLAS_V2", raising=False)
     registry = metrics.MetricsRegistry()
     token = metrics.set_build_registry(registry)
     yield registry

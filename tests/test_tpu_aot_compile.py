@@ -64,11 +64,8 @@ def traced_as_tpu():
     compilation_cache.reset_cache()
     with pytest.MonkeyPatch.context() as monkeypatch:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        for var in ("MAKISU_TPU_SHA_INNER_UNROLL",
-                    "MAKISU_TPU_SHA_BLOCK_UNROLL",
-                    "MAKISU_TPU_PALLAS", "MAKISU_TPU_PALLAS_V2"):
-            monkeypatch.delenv(var, raising=False)
-        assert (sha256._inner_unroll(), sha256._block_unroll()) == (3, 4)
+        monkeypatch.delenv("MAKISU_TPU_PALLAS", raising=False)
+        assert sha256._unrolls() == (3, 4)
         yield
     jax.config.update("jax_enable_compilation_cache", cache_was_on)
     compilation_cache.reset_cache()
@@ -93,22 +90,21 @@ def test_program_compiles_for_v5e(v5e, traced_as_tpu, program):
     paths = re.findall(r'op_name="(jit\([^"]*)"', text)
     assert paths and all(f"/{scope}" in p for p in paths)
     if "pallas" in program.name:
-        kernel = {"gear_pallas_v2": "gear_bitmap_flat2"}.get(
-            program.name, "gear_bitmap_flat" if scope == "gear_scan"
-            else "sha256_lanes_pallas")
+        kernel = ("gear_bitmap_flat" if scope == "gear_scan"
+                  else "sha256_lanes_pallas")
         assert re.search(rf"%{kernel}\.\d+ = [^\n]*custom-call\(", text)
 
 
 def test_table_covers_the_production_shapes():
     """The table is the production shapes, not a copy of them."""
     names = {p.name for p in _PROGRAMS}
-    assert {"gear_xla", "gear_pallas_v1_start0", "gear_pallas_v1_start128",
-            "gear_pallas_v2"} <= names
+    assert {"gear_xla", "gear_pallas_start0", "gear_pallas_start128"} \
+        <= names
     for cap, lanes in cdc._BUCKETS:
         assert {f"sha_xla_{lanes}x{cap}", f"sha_pallas_{lanes}x{cap}"} \
             <= names
     by_name = {p.name: p for p in _PROGRAMS}
-    assert by_name["gear_pallas_v1_start128"].shapes[0][0] \
+    assert by_name["gear_pallas_start128"].shapes[0][0] \
         == (128 + cdc.BLOCK,)
 
 
