@@ -582,9 +582,11 @@ class ChunkStore:
                 reap()
             if not batch or failure:
                 return
+            # The batch just handed over is in flight by definition,
+            # also where its writer finished before this line ran.
+            peak = max(peak, 1 + sum(not f.done() for f in window))
             window.append(concurrency.submit_ctx(
                 pool, self._ingest_batch, batch))
-            peak = max(peak, sum(not f.done() for f in window))
             batch, batch_bytes = [], 0
 
         try:

@@ -12,6 +12,7 @@ ConcurrentMultiWriters).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import BinaryIO, Protocol
 
@@ -23,6 +24,26 @@ from makisu_tpu.docker.image import (
     DigestPair,
 )
 from makisu_tpu.utils import events, metrics
+from makisu_tpu.utils import logging as log
+
+# Under ``sink_finish``: the gzip stream's end (its last block, the pool
+# or ring drained, the trailer) and, with a chunk session, the device's
+# (the last dispatches, the readbacks, the chunk-SHA tail).
+STREAM_JOIN_SPAN = "sink_finish.stream_join"
+DEVICE_DRAIN_SPAN = "sink_finish.device_drain"
+
+
+@functools.lru_cache(maxsize=None)
+def _announce(sink: str, backend_id: str, lanes: int) -> None:
+    """Log what commits this process's layers the first time it does:
+    once per process and decision (as ``chunk route:``). ``lanes`` is
+    the context's ``compress_workers()``, which only the block format
+    can use."""
+    backend, _, rest = backend_id.partition("-")
+    level, _, block = rest.partition("-")
+    log.info("layer sink: %s backend=%s level=%s lanes=%d block=%s", sink,
+             backend, level, lanes if backend == "pgzip" else 1,
+             block or "none (one stream)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,6 +94,8 @@ class LayerSink:
         self.backend_id = backend_id or tario.gzip_backend_id()
         self._gz = tario.gzip_writer(self._tee, backend_id=self.backend_id)
         self._closed = False
+        from makisu_tpu.utils import concurrency
+        _announce("python", self.backend_id, concurrency.compress_workers())
         if threaded is None:
             threaded = (_os.cpu_count() or 1) > 1
         self._queue = None
@@ -182,14 +205,15 @@ class LayerSink:
         if self._closed:
             raise RuntimeError("layer sink already finished")
         self._closed = True
-        if self._queue is not None:
-            self._put_checked(None)
-            self._worker.join()
-            if self._worker_error:
-                raise RuntimeError("layer compression failed") \
-                    from self._worker_error[0]
-        self._gz.close()
-        self._tee.flush()
+        with metrics.span(STREAM_JOIN_SPAN):
+            if self._queue is not None:
+                self._put_checked(None)
+                self._worker.join()
+                if self._worker_error:
+                    raise RuntimeError("layer compression failed") \
+                        from self._worker_error[0]
+            self._gz.close()
+            self._tee.flush()
         pair = DigestPair(
             tar_digest=Digest.from_hex(self._tar_digest.hexdigest()),
             gzip_descriptor=Descriptor(
@@ -293,9 +317,11 @@ class NativeLayerSink:
         # The compress-workers knob governs the C++ block pool too —
         # same worker-count-is-throughput-only contract as the Python
         # stage (block bytes are a pure function of level/block size).
+        lanes = concurrency.compress_workers()
         self._handle = native.LayerSinkHandle(
             out.fileno(), backend, level, block or native.DEFAULT_BLOCK,
-            nthreads=concurrency.compress_workers())
+            nthreads=lanes)
+        _announce("native", self.backend_id, lanes)
         self._session = session
         if session is not None:
             self._handle.set_tap(session.update)
@@ -318,20 +344,32 @@ class NativeLayerSink:
 
     def finish(self) -> LayerCommit:
         try:
-            tar_hex, gz_hex, gz_size, _ = self._handle.finish()
+            with metrics.span(STREAM_JOIN_SPAN):
+                tar_hex, gz_hex, gz_size, _ = self._handle.finish()
             # The stage the Python sink's compressor thread reports:
             # here deflate runs on the library's own thread (zlib) or
-            # block pool (pgzip), and the library keeps the seconds;
-            # compress_wait is what this thread spent blocked on that
-            # stream (a full ring, the drain just now): near 0 where
-            # the producer is the brake.
+            # block pool (pgzip), and the library keeps the seconds.
+            # compress is the seconds the stream kept threads busy,
+            # summed over the pool's lanes; compress_wall the seconds
+            # it had a block queued or deflating (one thread: the
+            # same); compress_wait what this thread spent blocked on
+            # it (a full ring or pool, the drain just now): near 0
+            # where the producer is the brake; blob_write what this
+            # thread spent digesting and writing the pool's blocks
+            # (0 under zlib, whose compressor thread does that itself:
+            # no such stage there).
             busy = self._handle.compress_seconds()
+            wall = self._handle.wall_seconds()
             waited = self._handle.wait_seconds()
+            blob_write = self._handle.blob_write_seconds()
             prefetch = self._handle.prefetch_stats()
         finally:
             self._handle.close()
         metrics.stage_busy_add(metrics.COMPRESS_STAGE, busy)
+        metrics.stage_busy_add("compress_wall", wall)
         metrics.stage_busy_add("compress_wait", waited)
+        if blob_write:
+            metrics.stage_busy_add("blob_write", blob_write)
         # A part of tar_write: what this thread spent blocked on one of
         # the sink's readers, and how each file's bytes came.
         metrics.stage_busy_add("read_wait", prefetch[0])
@@ -353,8 +391,9 @@ class NativeLayerSink:
                                        Digest.from_hex(gz_hex)))
         chunks = []
         if self._session is not None:
-            chunks = [ChunkFingerprint(c.offset, c.length, c.hex)
-                      for c in self._session.finish()]
+            with metrics.span(DEVICE_DRAIN_SPAN):
+                chunks = [ChunkFingerprint(c.offset, c.length, c.hex)
+                          for c in self._session.finish()]
         return LayerCommit(pair, chunks, gzip_backend_id=self.backend_id)
 
 
@@ -420,8 +459,9 @@ class _TPUSink(LayerSink):
         self._session.update(data)
 
     def _finish_chunks(self) -> list[ChunkFingerprint]:
-        return [ChunkFingerprint(c.offset, c.length, c.hex)
-                for c in self._session.finish()]
+        with metrics.span(DEVICE_DRAIN_SPAN):
+            return [ChunkFingerprint(c.offset, c.length, c.hex)
+                    for c in self._session.finish()]
 
 
 class TPUHasher:

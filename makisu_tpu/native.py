@@ -183,7 +183,9 @@ _LSK_SYMBOLS = {
     "lsk_finish": (ctypes.c_int, [ctypes.c_void_p, _U8_P, _U8_P, _U64_P,
                                   _U64_P]),
     "lsk_compress_seconds": (ctypes.c_double, [ctypes.c_void_p]),
+    "lsk_wall_seconds": (ctypes.c_double, [ctypes.c_void_p]),
     "lsk_wait_seconds": (ctypes.c_double, [ctypes.c_void_p]),
+    "lsk_blob_write_seconds": (ctypes.c_double, [ctypes.c_void_p]),
     "lsk_prefetch_stats": (None, [ctypes.c_void_p,
                                   ctypes.POINTER(ctypes.c_double), _U64_P]),
     "lsk_free": (None, [ctypes.c_void_p]),
@@ -194,7 +196,7 @@ def _load_lsk() -> ctypes.CDLL | None:
     global _lsk_lib, _lsk_failed
     with _lock:
         if _lsk_lib is None and not _lsk_failed:
-            _lsk_lib = _open(_LSK_PATH, "lsk_abi_version", 2, _LSK_SYMBOLS)
+            _lsk_lib = _open(_LSK_PATH, "lsk_abi_version", 3, _LSK_SYMBOLS)
             _lsk_failed = _lsk_lib is None
         return _lsk_lib
 
@@ -544,10 +546,25 @@ class LayerSinkHandle:
         pgzip lanes)."""
         return self._lib.lsk_compress_seconds(self._live())
 
+    def wall_seconds(self) -> float:
+        """Seconds the gzip stream had a block queued or deflating:
+        the wall time of the pgzip pool's work (``compress_seconds`` is
+        its CPU time); under zlib the compressor thread's busy
+        seconds."""
+        return self._lib.lsk_wall_seconds(self._live())
+
     def wait_seconds(self) -> float:
-        """Seconds the writer was blocked on the zlib backend's
-        compressor thread (its ring full, or draining in ``finish``)."""
+        """Seconds the writer was blocked on the gzip stream: the zlib
+        backend's ring full or draining in ``finish``; the pgzip pool's
+        oldest block not yet deflated, with more than 2 x lanes + 2 in
+        flight or in ``finish``."""
         return self._lib.lsk_wait_seconds(self._live())
+
+    def blob_write_seconds(self) -> float:
+        """Seconds the writer itself digested and wrote compressed
+        blocks (pgzip; 0 under zlib, whose compressor thread does
+        both)."""
+        return self._lib.lsk_blob_write_seconds(self._live())
 
     def prefetch_stats(self) -> tuple[float, int, int, int]:
         """(seconds the writer was blocked on one of the sink's reader

@@ -39,6 +39,8 @@ _PHASE_RULES: tuple[tuple[str, str], ...] = (
     ("memfs_sync", "hash"),
     ("layer_scan", "hash"),
     ("tar_write", "hash"),
+    # Also its two children, sink_finish.stream_join and
+    # sink_finish.device_drain (chunker/hasher.py).
     ("sink_finish", "hash"),
     ("push", "push"),
     # What a build does before its plan exists and after its exports:

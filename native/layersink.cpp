@@ -32,8 +32,13 @@
 //                                    files of up to 8 MiB are read ahead
 //                                    on the sink's reader threads
 //   lsk_finish(h, tar_sha32, gz_sha32, &gz_size, &tar_size)
-//   lsk_compress_seconds(h)          seconds the gzip stream kept a thread busy
+//   lsk_compress_seconds(h)          seconds the gzip stream kept a thread busy,
+//                                    summed over the pgzip lanes
+//   lsk_wall_seconds(h)              seconds the stream had a block queued or
+//                                    deflating (zlib: lsk_compress_seconds)
 //   lsk_wait_seconds(h)              seconds the caller was blocked on that stream
+//   lsk_blob_write_seconds(h)        seconds the caller digested and wrote
+//                                    compressed blocks itself (pgzip; zlib: 0)
 //   lsk_prefetch_stats(h, &read_wait_s, counts[3])
 //                                    seconds the caller was blocked on a
 //                                    reader; files it found ready, waited
@@ -50,7 +55,10 @@
 // ring (the fan-out of common.go:35-64, as the Python LayerSink's
 // compressor thread): the caller fills fixed-size slots and only waits
 // when all of them are full, so a commit costs max(gzip, producer) where
-// both ran in line. The pgzip backend deflates blocks on its pool.
+// both ran in line. The pgzip backend deflates blocks on its pool
+// (nthreads lanes; one lane deflates in line); the caller takes the done
+// blocks in order, digests and writes them itself, and waits for the pool
+// only with more than 2 * lanes + 2 blocks in flight and at the end.
 // lsk_write_entries is what tells the sink of files before it must write
 // them: at a batch's start its files of up to 8 MiB go to the sink's
 // reader threads ("lsk-read", at most kReaders a sink, started by the
@@ -120,9 +128,22 @@ struct Sink {
   // compressor thread counts them), the pgzip backend's block deflates
   // summed over its lanes. Guarded by mu where workers run.
   double compress_s = 0;
-  // Seconds the caller was blocked on the zlib stream: on a full ring
-  // in consume, on the drain in finish. Caller's thread only.
+  // Seconds the caller was blocked on the gzip stream. zlib: on a full
+  // ring in consume, on the drain in finish. pgzip: in drain(), on the
+  // oldest block in flight, over the cap and at the end. Caller's thread
+  // only.
   double wait_s = 0;
+  // pgzip: seconds during which the stream had at least one block queued
+  // or deflating (a lane's busy seconds are compress_s; with several
+  // lanes at once this is their union, not their sum). inflight counts
+  // the blocks submitted and not yet deflated; both guarded by mu.
+  double wall_s = 0;
+  size_t inflight = 0;
+  std::chrono::steady_clock::time_point wall_t0;
+  // pgzip: seconds the caller spent in write_fd under drain(): the
+  // blob's digest and write(2), which the zlib backend's compressor
+  // thread does. Caller's thread only.
+  double blob_write_s = 0;
 
   // The tap's staging buffer (made by lsk_set_tap): account() fills it
   // and hands it over full, tap_flush() in part.
@@ -386,6 +407,7 @@ struct Sink {
       {
         std::lock_guard<std::mutex> lock(mu);
         compress_s += busy;
+        if (--inflight == 0) wall_s += since(wall_t0);
         job->done = true;
         job->failed = !ok;
       }
@@ -401,12 +423,15 @@ struct Sink {
       auto t0 = std::chrono::steady_clock::now();
       job->failed = !DeflateSlice(job->in.data(), job->in.size(), level,
                                    job->last, job->out);
-      compress_s += since(t0);
+      double busy = since(t0);
+      compress_s += busy;
+      wall_s += busy;
       job->done = true;
       jobs.push_back(job);
     } else {
       {
         std::lock_guard<std::mutex> lock(mu);
+        if (inflight++ == 0) wall_t0 = std::chrono::steady_clock::now();
         jobs.push_back(job);
         claim_queue.push_back(job);
       }
@@ -417,7 +442,8 @@ struct Sink {
 
   // Write completed jobs in order; with all=true, wait for everything.
   // Without it, only pop already-done fronts, blocking solely when the
-  // in-flight count exceeds the memory bound.
+  // in-flight count exceeds the memory bound. What the caller waits for
+  // the pool is wait_s, what it digests and writes is blob_write_s.
   bool drain(bool all) {
     size_t cap = workers.empty() ? 0 : workers.size() * 2 + 2;
     for (;;) {
@@ -425,13 +451,19 @@ struct Sink {
       {
         std::unique_lock<std::mutex> lock(mu);
         if (jobs.empty()) return true;
-        if (!all && !jobs.front()->done && jobs.size() <= cap) return true;
-        cv_done.wait(lock, [&] { return jobs.front()->done; });
+        if (!jobs.front()->done) {
+          if (!all && jobs.size() <= cap) return true;
+          auto t0 = std::chrono::steady_clock::now();
+          cv_done.wait(lock, [&] { return jobs.front()->done; });
+          wait_s += since(t0);
+        }
         front = jobs.front();
         jobs.pop_front();
       }
+      auto t0 = std::chrono::steady_clock::now();
       bool ok = !front->failed &&
                 write_fd(front->out.data(), front->out.size());
+      blob_write_s += since(t0);
       delete front;
       if (!ok) return false;
     }
@@ -727,7 +759,7 @@ struct Sink {
 
 extern "C" {
 
-int lsk_abi_version() { return 2; }
+int lsk_abi_version() { return 3; }
 
 void* lsk_new(int out_fd, int pgzip, int level, size_t block_size,
               int nthreads) {
@@ -820,12 +852,31 @@ double lsk_compress_seconds(void* handle) {
   return s->compress_s;
 }
 
-// After lsk_finish: seconds the caller was blocked on the zlib stream (a
-// full ring in lsk_write / lsk_write_entries, the drain in lsk_finish). Near
-// 0 where the producer is the brake, near the stream's seconds less the
-// producer's own where gzip is. 0 for pgzip.
+// After lsk_finish: seconds the stream had a block queued or deflating.
+// pgzip: the wall time of the pool's work, where lsk_compress_seconds is
+// its CPU time; zlib: the one compressor thread's busy seconds.
+double lsk_wall_seconds(void* handle) {
+  auto* s = static_cast<Sink*>(handle);
+  std::lock_guard<std::mutex> lock(s->mu);
+  return s->pgzip ? s->wall_s : s->compress_s;
+}
+
+// After lsk_finish: seconds the caller was blocked on the gzip stream.
+// zlib: a full ring in lsk_write / lsk_write_entries, the drain in
+// lsk_finish. pgzip: the oldest block in flight not yet deflated, with
+// more than 2 * lanes + 2 in flight or in lsk_finish. Near 0 where the
+// producer is the brake, near the stream's seconds less the producer's own
+// where gzip is.
 double lsk_wait_seconds(void* handle) {
   return static_cast<Sink*>(handle)->wait_s;
+}
+
+// After lsk_finish: seconds the caller itself digested and wrote
+// compressed blocks (pgzip: write_fd under drain, in lsk_write,
+// lsk_write_entries and lsk_finish). 0 for zlib, whose compressor thread
+// does both.
+double lsk_blob_write_seconds(void* handle) {
+  return static_cast<Sink*>(handle)->blob_write_s;
 }
 
 // Any time, on the caller's thread. read_wait_s: seconds the caller was

@@ -166,14 +166,18 @@ def _cell_reports_its_metrics():
 def _new_metrics_list_their_cells():
     cells_of = {m["name"]: m["workloads"] for m in BENCHMARK["per_layer"]}
     # PR 32 appended its cell to the three it reports.
+    # PR 47 appended the same image's pgzip cell to what this one
+    # reports.
+    pgzip = "huge-layer-pgzip-edit"
     four = [CELL, "monorepo-cold", "monorepo-edit", "small-files-edit",
-            "multi-stage-small-edit"]
+            "multi-stage-small-edit", pgzip]
     assert cells_of["commit_mb_per_s"] == four
     assert cells_of["compress_s_per_build"] == four
     assert cells_of["feed_host_s_per_build"] == four
     # PR 38 appended its cell: 32 sinks' rings at once.
     assert cells_of["process_rss_peak_mb"] == [
-        CELL, "monorepo-cold", "small-files-edit", "farm-concurrent-churn"]
+        CELL, "monorepo-cold", "small-files-edit", "farm-concurrent-churn",
+        pgzip]
     # Put at the end of the list at their PR, together and in order;
     # later PRs append after them.
     names = [m["name"] for m in BENCHMARK["per_layer"]]
@@ -181,7 +185,7 @@ def _new_metrics_list_their_cells():
     assert names[first:first + 4] == list(NEW_READERS)
     assert cells_of["chunk_probe_hit_pct"] == [
         CELL, "monorepo-edit", "monorepo-cold", "small-files-edit",
-        "multi-stage-small-edit"]
+        "multi-stage-small-edit", pgzip]
     # PR 33: what the builder waits for the sink's compressor thread,
     # read where ``compress_s_per_build`` is, appended last.
     assert cells_of["compress_wait_s_per_build"] == four

@@ -255,15 +255,26 @@ def test_either_sink_reports_its_compress_seconds(tmp_path, sink_cls,
         metrics.reset_build_registry(token)
     busy = registry.counter_by_label(metrics.COMMIT_STAGE_BUSY, "stage")
     assert 0 < busy["compress"] < 5
-    # What the writer spent blocked on the native sink's stream (the
-    # drain at least, under zlib; pgzip reports 0): beside ``compress``,
-    # never a part of it. The Python sink has no such series.
+    # What the writer spent blocked on the native sink's stream (under
+    # zlib the drain at least; under pgzip the pool's oldest block, over
+    # the cap and in ``finish``: 0 only where one lane deflates in
+    # line): beside ``compress``, never a part of it. ``compress_wall``
+    # is the stream's wall time (one thread: ``compress`` itself; a
+    # pool: no more than its summed busy seconds), ``blob_write`` what
+    # the writer digested and wrote itself, which only the pool leaves
+    # to it. The Python sink has none of the three.
     if sink_cls is NativeLayerSink:
         assert 0 <= busy["compress_wait"] < 5
+        assert 0 < busy["compress_wall"] <= busy["compress"] + 1e-9
         if backend_id.startswith("zlib"):
             assert busy["compress_wait"] > 0
+            assert busy["compress_wall"] == busy["compress"]
+            assert "blob_write" not in busy
+        else:
+            assert 0 < busy["blob_write"] < 5
     else:
-        assert "compress_wait" not in busy
+        assert not {"compress_wait", "compress_wall", "blob_write"} \
+            & set(busy)
 
 
 def test_native_archive_is_valid_tar(tmp_path):

@@ -328,8 +328,8 @@ def test_prefetch_metrics_list_their_cells():
     by_name = {m["name"]: m for m in per_layer}
     assert [m["name"] for m in per_layer[53:55]] == [
         "sink_prefetch_ready_pct", "read_wait_s_per_build"]
-    # PR 41 appended four, PR 42 one, PR 45 one
-    assert len(per_layer) == 61
+    # PR 41 appended four, PR 42 one, PR 45 one, PR 47 four
+    assert len(per_layer) == 65
     commit = by_name["tar_write_s_per_build"]
     for name, unit, better in (("sink_prefetch_ready_pct", "%", "higher"),
                                ("read_wait_s_per_build", "s", "lower")):
@@ -355,7 +355,10 @@ def test_service_metrics_list_their_cells():
     with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json"),
               encoding="utf-8") as f:
         by_name = {m["name"]: m for m in json.load(f)["per_layer"]}
-    every = by_name["idle_unspanned_pct"]["workloads"]
+    # PR 47's cell joined the lists of its pair but the five that are
+    # to be retired, ``idle_unspanned_pct`` among them.
+    pgzip = "huge-layer-pgzip-edit"
+    every = by_name["idle_unspanned_pct"]["workloads"] + [pgzip]
     for name in ("unspanned_s_per_build", "service_s_per_build",
                  "request_overhead_s_per_build"):
         assert by_name[name]["workloads"] == every, name
@@ -365,9 +368,9 @@ def test_service_metrics_list_their_cells():
     assert by_name["session_begin_s_per_build"]["workloads"] == [
         "farm-churn", "farm-unchanged", "monorepo-edit", "small-files-edit",
         "huge-layer-edit", "multi-stage-small-edit", "farm-concurrent-churn",
-        "run-steps-edit"]
+        "run-steps-edit", pgzip]
     assert by_name["wait_for_push_s_per_build"]["workloads"] == [
-        "monorepo-cold", "monorepo-edit", "huge-layer-edit"]
+        "monorepo-cold", "monorepo-edit", "huge-layer-edit", pgzip]
     assert by_name["save_manifest_s_per_build"]["workloads"] == [
         "farm-churn", "farm-unchanged", "farm-concurrent-churn"]
     for name in ("unspanned_s_per_build", "service_s_per_build",
@@ -392,10 +395,11 @@ def test_every_new_metric_has_its_reader_and_its_cells():
              "multi-stage-small-edit", "farm-concurrent-churn",
              "run-steps-edit"]
     assert cells_of["idle_unspanned_pct"] == every
-    assert cells_of["sync_os_sync_s_per_build"] == every
+    assert cells_of["sync_os_sync_s_per_build"] \
+        == every + ["huge-layer-pgzip-edit"]
     assert cells_of["chunk_index_s_per_build"] == [
         "monorepo-cold", "monorepo-edit", "small-files-edit",
-        "huge-layer-edit", "multi-stage-small-edit"]
+        "huge-layer-edit", "multi-stage-small-edit", "huge-layer-pgzip-edit"]
     for name in cells_of:
         assert os.path.exists(os.path.join(READERS, name + ".py")), name
 
@@ -403,7 +407,7 @@ def test_every_new_metric_has_its_reader_and_its_cells():
 @pytest.mark.parametrize("cell", [
     "monorepo-cold", "farm-churn", "monorepo-edit", "farm-unchanged",
     "small-files-edit", "huge-layer-edit", "multi-stage-small-edit",
-    "farm-concurrent-churn", "run-steps-edit"])
+    "farm-concurrent-churn", "run-steps-edit", "huge-layer-pgzip-edit"])
 def test_every_cell_finds_its_files_and_a_reader_for_each_metric(cell):
     """What ``run.py`` looks up by name for a cell: configuration, mix,
     reference, and a reader for every metric either kind of run
@@ -476,4 +480,4 @@ def test_resolve_reuse_metric_lists_the_cells_that_report_the_request():
                  "better": "higher", "source": "program_counter",
                  "layer": request["layer"], "moves": "build_p50_s",
                  "workloads": request["workloads"]}
-    assert len(m["workloads"]) == 9
+    assert len(m["workloads"]) == 10  # PR 47 appended its cell

@@ -498,6 +498,7 @@ def _walk(node):
 @pytest.mark.parametrize("name,phase", [
     ("memfs_sync.mtime_wait", "hash"), ("layer_scan", "hash"),
     ("tar_write", "hash"), ("sink_finish", "hash"),
+    ("sink_finish.stream_join", "hash"), ("sink_finish.device_drain", "hash"),
     ("chunk_index", "chunk"), ("apply_layer.inflate", "other"),
     ("copy_checksum", "other"), ("session_begin", "other"),
     # PR 35: a build's set-up and tear-down are phases of their own, so
