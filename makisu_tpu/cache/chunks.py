@@ -517,32 +517,39 @@ class ChunkStore:
     # chunker cuts, 64 KiB; ~32 average ones), at most INGEST_WRITERS of
     # them on the commit pool at once, so the bytes staged outside the
     # stream never pass (INGEST_WRITERS + 1) batches and one chunk,
-    # whatever the layer's size.
+    # whatever the layer's size (beside them, where a recipe publisher
+    # observes the pass, the three packs its hand-off may hold).
     INGEST_WRITERS = 8
     INGEST_BATCH_BYTES = 4 * 65536
 
     def _ingest_batch(self, batch: list[tuple[str, bytes, bool]]
                       ) -> list[str]:
         """One writer of index_layer's window, on the commit pool:
-        probe the entries nobody has looked for, verify and store the
-        new ones. Returns the digests it stored, in batch order."""
+        probe the entries nobody has looked for and store the new ones
+        (index_layer held each to its digest as it sliced it). Returns
+        the digests it stored, in batch order."""
         new = [(h, data) for h, data, probe in batch
                if not (probe and self.cas.exists(h))]
-        for hex_digest, data in new:
-            if hashlib.sha256(data).hexdigest() != hex_digest:
-                raise ValueError(
-                    f"chunk content does not match {hex_digest}")
         self.cas.write_many(new)
         return [h for h, _ in new]
 
     def index_layer(self, layer_blob_path: str,
                     chunks: list[tuple[int, int, str]],
-                    stats: dict | None = None) -> list[str]:
+                    stats: dict | None = None,
+                    observer=None) -> list[str]:
         """Slice a layer's uncompressed stream into its chunks and store
         any that are new locally (never fetching: the bytes are already
         in hand). Returns the hex digests newly added, each once, in
         offset order; ``stats`` (if given) receives ``ingest_window``,
         the peak number of writers in flight.
+
+        Every slice is held to its digest here, once, as it is made:
+        what a writer stores and what ``observer`` takes are the same
+        verified bytes. ``observer(hex_digest, data)`` is told of every
+        chunk of ``chunks``, in order: ``data`` is the slice just made,
+        or None where the pass made none (found stored, or a repeat
+        within the layer). The recipe publisher packs a cold layer's
+        bytes from it, so nobody reads them back from the store.
 
         Decompression is streamed — the chunk list is offset-sorted,
         so one forward pass over the gzip stream suffices — a block at
@@ -602,18 +609,23 @@ class ChunkStore:
                             f"chunk list not offset-sorted at "
                             f"{offset} < {pos}")
                     pos = offset + length
-                    if known:
-                        # Stored already: its bytes stay in the block
-                        # they were inflated into, unsliced.
-                        tally["hit"] += 1
-                        continue
-                    if hex_digest in handed:
-                        tally["raced"] += 1
+                    if known or hex_digest in handed:
+                        # Stored already (its bytes stay in the block
+                        # they were inflated into, unsliced) or handed
+                        # to a writer earlier in this pass.
+                        tally["hit" if known else "raced"] += 1
+                        if observer is not None:
+                            observer(hex_digest, None)
                         continue
                     handed.add(hex_digest)
                     tally["probe" if known is None else "miss"] += 1
-                    batch.append((hex_digest, stream.take(offset, length),
-                                  known is None))
+                    data = stream.take(offset, length)
+                    if hashlib.sha256(data).hexdigest() != hex_digest:
+                        raise ValueError(
+                            f"chunk content does not match {hex_digest}")
+                    if observer is not None:
+                        observer(hex_digest, data)
+                    batch.append((hex_digest, data, known is None))
                     batch_bytes += length
                     if batch_bytes >= self.INGEST_BATCH_BYTES:
                         flush()
@@ -1271,18 +1283,27 @@ def attach_chunk_dedup(manager, chunk_root: str) -> ChunkStore:
                 path = manager.store.layers.path(layer_hex)
                 triples = [(c.offset, c.length, c.hex_digest)
                            for c in commit.chunks]
-                with metrics.span("chunk_index",
-                                  chunks=len(triples)) as sp:
-                    stats: dict = {}
-                    added = chunk_store.index_layer(path, triples, stats)
-                    metrics.counter_add("makisu_chunks_indexed_total",
-                                        len(added))
-                    sp.set(added=len(added), bytes_added=_record_index(
-                        layer_hex, cache_id, triples, added),
-                        ingest_window=stats["ingest_window"])
+                publication = _begin_recipe_publish(
+                    pair, triples, commit, cache_id)
+                try:
+                    with metrics.span("chunk_index",
+                                      chunks=len(triples)) as sp:
+                        stats: dict = {}
+                        added = chunk_store.index_layer(
+                            path, triples, stats,
+                            observer=publication and publication.feed)
+                        metrics.counter_add("makisu_chunks_indexed_total",
+                                            len(added))
+                        sp.set(added=len(added),
+                               bytes_added=_record_index(
+                                   layer_hex, cache_id, triples, added),
+                               ingest_window=stats["ingest_window"])
+                except BaseException as e:
+                    _end_recipe_publish(publication, failure=e)
+                    raise
                 log.info("indexed %d new chunks for %s", len(added),
                          cache_id)
-                _spawn_recipe_publish(pair, triples, commit, cache_id)
+                _end_recipe_publish(publication)
             except FileNotFoundError:
                 return
             finally:
@@ -1495,45 +1516,45 @@ def attach_chunk_dedup(manager, chunk_root: str) -> ChunkStore:
                          hex_digest)
         return inner_open_tar(pair)
 
-    def _publish_serve_recipe(pair, triples, commit) -> None:
+    def _begin_recipe_publish(pair, triples, commit, cache_id):
         """Distribution-plane publish hook: when this process serves
         (worker / `makisu-tpu serve` / MAKISU_TPU_SERVE=1), every
         indexed layer also gets a signed recipe + pack member tables
         in ``<storage>/serve/`` — the metadata delta pulls and
-        pack-granular peer exchange consume. Never fails the build;
-        an unpublished layer just stays blob-route-only."""
+        pack-granular peer exchange consume. The publication is opened
+        before ``index_layer`` and fed from its pass: a cold layer's
+        new chunks enter their packs as the pass slices them, and a
+        filled pack is hashed, framed and written on the
+        ``recipepub-*`` thread while the pass goes on. None where
+        publishing is off. Never fails the build; an unpublished layer
+        just stays blob-route-only."""
         from makisu_tpu.serve import server as serve_server
         if not serve_server.publish_enabled():
-            return
+            return None
         try:
-            serve_store = serve_server.register_store(
-                manager.store.root)
-            serve_store.publish(pair, triples,
-                                commit.gzip_backend_id, chunk_store)
+            return serve_server.register_store(manager.store.root).begin(
+                pair, triples, commit.gzip_backend_id, chunk_store,
+                thread_name=f"recipepub-{cache_id}")
         except Exception as e:  # noqa: BLE001 - publish is advisory
             log.warning("serve recipe publish failed for %s: %s",
                         pair.gzip_descriptor.digest.hex(), e)
+            return None
 
-    def _spawn_recipe_publish(pair, triples, commit, cache_id) -> None:
-        """Recipe publish phase 2 re-reads and re-hashes every novel
-        chunk's bytes out of the CAS — gigabytes on a large cold layer
-        — so it rides a background thread exactly like the registry
-        chunk push, joined by ``wait_for_push`` (build exit still
-        implies published; a client asking earlier just takes the blob
-        route)."""
-        from makisu_tpu.serve import server as serve_server
-        if not serve_server.publish_enabled():
+    def _end_recipe_publish(publication, failure=None) -> None:
+        """After the pass: hand the finish (last partial pack, tables,
+        rows, seal, recipe file) to the publication's thread, or
+        abandon it where the pass failed. The thread is joined by
+        ``wait_for_push`` (build exit still implies published; a client
+        asking earlier just takes the blob route)."""
+        if publication is None:
             return
-        import contextvars
-        import threading
-        t = threading.Thread(
-            target=contextvars.copy_context().run,
-            args=(lambda: _publish_serve_recipe(pair, triples,
-                                                commit),),
-            daemon=True, name=f"recipepub-{cache_id}")
-        t.start()
-        with manager._lock:
-            manager._pushes.append(t)
+        if failure is None:
+            publication.finish()
+        else:
+            publication.abandon(failure)
+        if publication.thread is not None:
+            with manager._lock:
+                manager._pushes.append(publication.thread)
 
     manager.push_cache = push_cache
     manager.pull_cache = pull_cache

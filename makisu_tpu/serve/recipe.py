@@ -45,10 +45,12 @@ bytes, never install bytes.
 
 from __future__ import annotations
 
+import contextvars
 import hashlib
 import hmac as hmac_mod
 import json
 import os
+import queue
 import threading
 
 from makisu_tpu.utils import fileio
@@ -332,24 +334,22 @@ class RecipeStore:
             return None, None
         return frames, b"".join(zparts)
 
-    def publish(self, pair, triples: list[tuple[int, int, str]],
-                gz_backend: str | None, chunk_store) -> dict | None:
-        """Publish one built layer: assign every chunk a pack
-        coordinate (reusing existing mappings; grouping novel chunks
-        into new packs read back from ``chunk_store``), persist the
-        pack tables + the sealed recipe. Returns the recipe document,
-        or None when a chunk's bytes are not in the CAS (the layer
-        simply isn't serveable; the blob route still is)."""
+    def begin(self, pair, triples: list[tuple[int, int, str]],
+              gz_backend: str | None, chunk_store,
+              thread_name: str | None = None) -> "Publication | None":
+        """Open the publish of one built layer: validate the chunk
+        tiling and plan which fingerprints are novel to this store
+        (phase 1, under the lock; cheap, in memory). The caller then
+        feeds the ``Publication`` every chunk of ``triples`` in order
+        and finishes it. None where the chunk list does not tile the
+        stream (the layer simply isn't serveable; the blob route still
+        is). With ``thread_name`` the filled packs and the finish run
+        on a thread of that name behind a bounded hand-off."""
         layer_hex = pair.gzip_descriptor.digest.hex()
-        from makisu_tpu.cache.chunks import pack_target_bytes
-        target = pack_target_bytes()
-        # Phase 1 (lock): validate the chunk tiling and plan which
-        # fingerprints are novel. Cheap, in-memory.
         with self._mu:
             self._load_locked()
             pos = 0
-            seen: set[str] = set()
-            novel: list[tuple[str, int]] = []
+            novel: dict[str, int] = {}
             for offset, length, fp in triples:
                 if offset != pos:
                     log.warning("recipe for %s refused: chunk list has "
@@ -357,78 +357,55 @@ class RecipeStore:
                                 offset, pos)
                     return None
                 pos = offset + length
-                if fp in self._chunk_index or fp in seen:
-                    continue
-                seen.add(fp)
-                novel.append((fp, int(length)))
-        # Phase 2 (NO lock): read the novel chunks' bytes back out of
-        # the CAS and group them into packs. This is the expensive
-        # pass (gigabytes on a cold large layer) — pack serving must
-        # not stall behind it. Pack tables persist before anything
-        # references them.
-        new_packs: list[tuple[str, list[tuple[str, int]],
-                              list[list[int]] | None]] = []
-        buf = bytearray()
-        members: list[tuple[str, int]] = []
+                if fp not in self._chunk_index:
+                    novel.setdefault(fp, int(length))
+        return Publication(self, pair, triples, gz_backend, chunk_store,
+                           novel, thread_name)
 
-        def flush() -> None:
-            nonlocal buf, members
-            if not members:
-                return
-            raw = bytes(buf)
-            pack_hex = hashlib.sha256(raw).hexdigest()
-            frames, zblob = self._encode_frames(raw, members)
-            if zblob is not None:
-                os.makedirs(self._zpacks_dir, exist_ok=True)
-                # Frame bytes land BEFORE the table that indexes them:
-                # a reader may see a zpack with no table (unused), but
-                # never a table pointing at a missing/torn file.
-                fileio.write_bytes_atomic(
-                    os.path.join(self._zpacks_dir, f"{pack_hex}.zst"),
-                    zblob)
-            new_packs.append((pack_hex, list(members), frames))
-            buf = bytearray()
-            members = []
+    def publish(self, pair, triples: list[tuple[int, int, str]],
+                gz_backend: str | None, chunk_store) -> dict | None:
+        """Publish one built layer whose chunks are all stored: assign
+        every chunk a pack coordinate (reusing existing mappings;
+        grouping novel chunks into new packs read back from
+        ``chunk_store``), persist the pack tables + the sealed recipe.
+        Returns the recipe document, or None when a chunk's bytes are
+        not in the CAS. The route of a caller that has no pass over the
+        layer's stream; a build feeds its publication from
+        ``index_layer``'s pass instead."""
+        publication = self.begin(pair, triples, gz_backend, chunk_store)
+        if publication is None:
+            return None
+        for _, _, fp in triples:
+            publication.feed(fp)
+        return publication.finish()
 
-        for fp, length in novel:
-            try:
-                data = chunk_store.get(fp)
-            except (OSError, ValueError):
-                log.info("recipe for %s not published: chunk %s "
-                         "not in the local CAS", layer_hex, fp)
-                return None
-            if len(data) != length:
-                log.warning("recipe for %s refused: chunk %s CAS "
-                            "size %d != recorded %d", layer_hex,
-                            fp, len(data), length)
-                return None
-            buf += data
-            members.append((fp, length))
-            if len(buf) >= target:
-                flush()
-        flush()
-        if new_packs:
-            os.makedirs(self._packs_dir, exist_ok=True)
-            for pack_hex, pack_members, frames in new_packs:
-                rows_out = [[fp, length] for fp, length in pack_members]
-                # Legacy bare-list shape when no frames (old readers
-                # parse it); dict shape carries the frame index.
-                table = ({"members": rows_out, "frames": frames}
-                         if frames else rows_out)
-                fileio.write_json_atomic(
-                    os.path.join(self._packs_dir, f"{pack_hex}.json"),
-                    table)
-        # Phase 3 (lock): index the new packs and resolve every row.
-        # A racing publish may have indexed some of our "novel"
-        # chunks into its own pack meanwhile — setdefault keeps the
-        # first mapping, so rows stay consistent with what the index
-        # serves (our duplicate pack is still servable; just unused
-        # by this recipe).
+    def _write_tables(self, packs) -> None:
+        """Pack tables persist before anything references them."""
+        if not packs:
+            return
+        os.makedirs(self._packs_dir, exist_ok=True)
+        for pack_hex, pack_members, frames in packs:
+            rows_out = [[fp, length] for fp, length in pack_members]
+            # Legacy bare-list shape when no frames (old readers
+            # parse it); dict shape carries the frame index.
+            table = ({"members": rows_out, "frames": frames}
+                     if frames else rows_out)
+            fileio.write_json_atomic(
+                os.path.join(self._packs_dir, f"{pack_hex}.json"),
+                table)
+
+    def _resolve(self, packs, triples):
+        """Phase 3 (lock): index the new packs and resolve every row.
+        A racing publish may have indexed some of our "novel" chunks
+        into its own pack meanwhile — setdefault keeps the first
+        mapping, so rows stay consistent with what the index serves
+        (our duplicate pack is still servable; just unused by this
+        recipe). Returns ``(rows, pack sizes, frame indexes)``."""
         rows: list[list] = []
         pack_sizes: dict[str, int] = {}
         zpacks: dict[str, list] = {}
         with self._mu:
-            for pack_hex, pack_members, frames in new_packs:
+            for pack_hex, pack_members, frames in packs:
                 self._index_pack_locked(pack_hex, pack_members, frames)
             for _, length, fp in triples:
                 coords = self._chunk_index.get(fp)
@@ -441,35 +418,7 @@ class RecipeStore:
                 frames = self._pack_frames.get(coords[0])
                 if frames:
                     zpacks[coords[0]] = frames
-        doc = seal({
-            "schema": RECIPE_SCHEMA,
-            "layer": {
-                "tar": pair.tar_digest.hex(),
-                "gzip": layer_hex,
-                "size": pair.gzip_descriptor.size,
-                "gz": gz_backend or "",
-            },
-            "chunks": rows,
-            # True sizes of every referenced pack: a layer may touch
-            # only a sliver of a pack shared with other layers, and
-            # the client's runs-vs-whole decision must be made against
-            # the real pack size (the registry path feeds the planner
-            # exact sizes from the member tables).
-            "packs": pack_sizes,
-            # Frame indexes of every referenced pack that has a
-            # seekable twin: the client's capability signal AND its
-            # span→frame map — absent entries (old packs, libzstd-less
-            # publishers) keep those packs on the raw wire.
-            "zpacks": zpacks,
-        })
-        os.makedirs(self._recipes_dir, exist_ok=True)
-        fileio.write_json_atomic(
-            os.path.join(self._recipes_dir, f"{layer_hex}.json"),
-            doc)
-        metrics.counter_add(metrics.SERVE_RECIPES_PUBLISHED)
-        log.info("published serve recipe for %s (%d chunks, %d new "
-                 "pack(s))", layer_hex, len(rows), len(new_packs))
-        return doc
+        return rows, pack_sizes, zpacks
 
     # -- serving reads ----------------------------------------------------
 
@@ -638,3 +587,234 @@ class RecipeStore:
                         remaining -= len(piece)
                         yield piece
             off += length
+
+
+_FINISH = object()  # the hand-off's last job
+
+
+class Publication:
+    """One layer's publish, open between ``RecipeStore.begin`` and
+    ``finish``: it is fed every chunk of the layer in stream order and
+    keeps what is novel to the recipe store, in that order, so the
+    packs are the ones a read-back of the stored chunks would make.
+
+    ``feed(fp, data)`` takes the bytes a pass over the layer's stream
+    has just sliced (``ChunkStore.index_layer``, which has held them to
+    ``fp``) as they are; ``feed(fp)`` says the pass sliced none, and a
+    novel chunk is then read from the chunk store at that place in the
+    order (stored already, yet in no pack this store knows; the store
+    held it to its name when it took it).
+
+    A filled pack (``pack_target_bytes()``) is sealed: hashed, encoded
+    as zstd frames, its ``.zst`` written. With a ``thread_name`` that
+    happens on the publication's own thread, started at the first
+    hand-over, in the context ``begin`` was called in: the feeder keeps
+    one pack being filled and blocks while ``IN_FLIGHT`` more are
+    handed over and not yet sealed, so a layer of any size holds at
+    most ``IN_FLIGHT + 1`` packs of its new bytes outside the stream.
+    ``finish`` (the last partial pack, the tables, the rows, the seal,
+    the recipe file) runs there too, after the packs; ``thread`` is
+    what a caller joins. Without a name everything runs in the calling
+    thread and ``finish`` returns the document.
+
+    Publishing is advisory: a chunk that is missing or of the wrong
+    size, a failed write, ``abandon`` (the pass failed) leave no table
+    and no recipe; a ``.zst`` written by then has no table, which a
+    reader never looks for."""
+
+    IN_FLIGHT = 2
+
+    def __init__(self, store: "RecipeStore", pair, triples,
+                 gz_backend: str | None, chunk_store,
+                 novel: dict[str, int],
+                 thread_name: str | None) -> None:
+        from makisu_tpu.cache.chunks import pack_target_bytes
+        self._store = store
+        self._pair = pair
+        self._triples = triples
+        self._gz_backend = gz_backend
+        self._chunk_store = chunk_store
+        self._layer_hex = pair.gzip_descriptor.digest.hex()
+        self._target = pack_target_bytes()
+        self._want = novel  # fingerprint -> length, in no pack yet
+        self._novel = len(novel)
+        self._fed = 0  # novel chunks whose bytes the pass handed over
+        self._source_bytes = {"pass": 0, "store": 0}
+        self._parts: list[bytes] = []  # the pack being filled
+        self._members: list[tuple[str, int]] = []
+        self._size = 0
+        # Sealed packs in order: (pack_hex, members, frame rows).
+        self._packs: list[tuple[str, list[tuple[str, int]],
+                                list[list[int]] | None]] = []
+        self._refused = False
+        self.doc: dict | None = None
+        self.thread: threading.Thread | None = None
+        self._thread_name = thread_name
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()
+        self._room = threading.BoundedSemaphore(self.IN_FLIGHT)
+        self._ctx = contextvars.copy_context() if thread_name else None
+
+    def _refuse(self, emit, message: str, *args) -> None:
+        self._refused = True
+        emit("recipe for %s " + message, self._layer_hex, *args)
+
+    def _drop(self) -> None:
+        """Let go of the pack being filled (the feeder's own state)."""
+        self._parts, self._members, self._size = [], [], 0
+
+    def _take(self) -> tuple[bytes, list[tuple[str, int]]]:
+        """The pack being filled, as one buffer with its members."""
+        pack = b"".join(self._parts), self._members
+        self._drop()
+        return pack
+
+    def feed(self, fp: str, data: bytes | None = None) -> None:
+        """The layer's next chunk. With an own thread it never raises,
+        and blocks only on the hand-off."""
+        length = self._want.pop(fp, None)
+        if length is None:
+            return  # in a pack already, or a repeat within the layer
+        if self._refused:
+            return self._drop()
+        source = "pass"
+        if data is None:
+            source = "store"
+            try:
+                data = self._chunk_store.get(fp)
+            except (OSError, ValueError):
+                self._refuse(log.info, "not published: chunk %s not in "
+                             "the local CAS", fp)
+                return
+        if len(data) != length:
+            self._refuse(log.warning, "refused: chunk %s size %d != "
+                         "recorded %d", fp, len(data), length)
+            return
+        if source == "pass":
+            self._fed += 1
+        self._source_bytes[source] += length
+        self._parts.append(data)
+        self._members.append((fp, length))
+        self._size += length
+        if self._size >= self._target:
+            self._flush()
+
+    def _flush(self) -> None:
+        """Seal the pack being filled, here or on the own thread."""
+        if self._thread_name is None:
+            return self._seal_pack(*self._take())
+        self._room.acquire()  # IN_FLIGHT handed over: wait for one
+        self._hand(self._take())
+
+    def _seal_pack(self, raw: bytes,
+                   members: list[tuple[str, int]]) -> None:
+        pack_hex = hashlib.sha256(raw).hexdigest()
+        frames, zblob = self._store._encode_frames(raw, members)
+        if zblob is not None:
+            os.makedirs(self._store._zpacks_dir, exist_ok=True)
+            # Frame bytes land BEFORE the table that indexes them: a
+            # reader may see a zpack with no table (unused), but never
+            # a table pointing at a missing/torn file.
+            fileio.write_bytes_atomic(
+                os.path.join(self._store._zpacks_dir,
+                             f"{pack_hex}.zst"), zblob)
+        self._packs.append((pack_hex, members, frames))
+
+    def _hand(self, job) -> None:
+        self._jobs.put(job)
+        if self.thread is None:
+            self.thread = threading.Thread(
+                target=self._ctx.run, args=(self._run,), daemon=True,
+                name=self._thread_name)
+            self.thread.start()
+
+    def _run(self) -> None:
+        """The own thread: packs as they are handed over, then the
+        finish. A failure refuses the publication and keeps taking
+        jobs, so the feeder is never left waiting for room."""
+        try:
+            while (job := self._jobs.get()) is not _FINISH:
+                try:
+                    if not self._refused:
+                        self._seal_pack(*job)
+                except Exception as e:  # noqa: BLE001 - advisory
+                    self._refuse(log.warning, "not published: %s", e)
+                finally:
+                    del job
+                    self._room.release()
+            self._finish()
+        except Exception as e:  # noqa: BLE001 - publish is advisory
+            log.warning("serve recipe publish failed for %s: %s",
+                        self._layer_hex, e)
+
+    def abandon(self, why) -> None:
+        """The pass over the layer failed: nothing more is fed, and
+        neither a table nor a recipe is written."""
+        self._refuse(log.info, "not published: %s", why)
+        self._drop()
+        if self.thread is not None:
+            self._jobs.put(_FINISH)
+
+    def finish(self) -> dict | None:
+        """Every chunk has been fed. Returns the recipe document where
+        the finish ran in this thread; with an own thread the finish is
+        its last job and the document is ``doc`` once it ends."""
+        if self._thread_name is None:
+            return self._finish()
+        self._hand(_FINISH)
+        return None
+
+    def _finish(self) -> dict | None:
+        with metrics.span("recipe_publish", novel=self._novel) as sp:
+            self.doc = self._publish()
+            sp.set(fed=self._fed, packs=len(self._packs))
+        return self.doc
+
+    def _publish(self) -> dict | None:
+        if self._refused:
+            return None
+        if self._want:
+            self._refuse(log.warning, "refused: %d novel chunk(s) were "
+                         "never fed", len(self._want))
+            return None
+        if self._members:
+            self._seal_pack(*self._take())
+        for source, n in self._source_bytes.items():
+            if n:
+                metrics.counter_add(metrics.SERVE_PACK_SOURCE_BYTES, n,
+                                    source=source)
+        store = self._store
+        store._write_tables(self._packs)
+        resolved = store._resolve(self._packs, self._triples)
+        if resolved is None:
+            return None
+        rows, pack_sizes, zpacks = resolved
+        doc = seal({
+            "schema": RECIPE_SCHEMA,
+            "layer": {
+                "tar": self._pair.tar_digest.hex(),
+                "gzip": self._layer_hex,
+                "size": self._pair.gzip_descriptor.size,
+                "gz": self._gz_backend or "",
+            },
+            "chunks": rows,
+            # True sizes of every referenced pack: a layer may touch
+            # only a sliver of a pack shared with other layers, and
+            # the client's runs-vs-whole decision must be made against
+            # the real pack size (the registry path feeds the planner
+            # exact sizes from the member tables).
+            "packs": pack_sizes,
+            # Frame indexes of every referenced pack that has a
+            # seekable twin: the client's capability signal AND its
+            # span→frame map — absent entries (old packs, libzstd-less
+            # publishers) keep those packs on the raw wire.
+            "zpacks": zpacks,
+        })
+        os.makedirs(store._recipes_dir, exist_ok=True)
+        fileio.write_json_atomic(
+            os.path.join(store._recipes_dir, f"{self._layer_hex}.json"),
+            doc)
+        metrics.counter_add(metrics.SERVE_RECIPES_PUBLISHED)
+        log.info("published serve recipe for %s (%d chunks, %d new "
+                 "pack(s))", self._layer_hex, len(rows),
+                 len(self._packs))
+        return doc

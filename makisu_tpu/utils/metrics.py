@@ -122,6 +122,12 @@ SERVE_PEER_PACK_BYTES = "makisu_serve_peer_pack_bytes_total"
 # as served).
 SERVE_PACK_FRAMES = "makisu_serve_pack_frames_total"
 SERVE_WIRE_BYTES = "makisu_serve_wire_bytes_total"
+# Bytes that entered a new pack at publish (serve/recipe.py), by where
+# the publication took them: source=pass (handed over by index_layer's
+# pass over the layer's stream, sliced and verified there) | store (read
+# back from the chunk CAS: stored already, yet in no pack). One add a
+# layer a source, at the publication's finish.
+SERVE_PACK_SOURCE_BYTES = "makisu_serve_pack_source_bytes_total"
 
 # Deploy-identity info gauge (cli.main): constant 1, identity in the
 # labels — the node_exporter "build_info" idiom.

@@ -75,13 +75,39 @@ def cas_entry_path(root, name: str) -> str:
     return cas.CASDir(str(root))._path(name)
 
 
+def committed_layer(storage: str, blob_path: str, chunks,
+                    backend_id: str = ""):
+    """The gzip blob at ``blob_path``, whose stream ``chunks`` (offset,
+    length, sha256 triples) tile, entered into ``storage``'s layer
+    store as a commit leaves it: ``(digest pair, LayerCommit)`` for a
+    cache manager's ``push_cache``."""
+    import hashlib
+    from makisu_tpu.chunker.hasher import ChunkFingerprint, LayerCommit
+    from makisu_tpu.docker.image import (
+        MEDIA_TYPE_LAYER, Descriptor, Digest, DigestPair)
+    from makisu_tpu.storage import ImageStore
+    with open(blob_path, "rb") as f:
+        blob = f.read()
+    gz_hex = hashlib.sha256(blob).hexdigest()
+    ImageStore(storage).layers.link_file(gz_hex, blob_path)
+    pair = DigestPair(
+        # Any digest that follows the content will do for the tar's.
+        tar_digest=Digest.from_hex(hashlib.sha256(
+            b"".join(h.encode() for _, _, h in chunks)).hexdigest()),
+        gzip_descriptor=Descriptor(MEDIA_TYPE_LAYER, len(blob),
+                                   Digest.from_hex(gz_hex)))
+    return pair, LayerCommit(
+        pair, [ChunkFingerprint(*c) for c in chunks], backend_id)
+
+
 class _FsCalls:
     """Stand-in for the ``os`` module inside ``storage/cas.py``: counts
     every file-system call the store issues (``os.path`` probes
-    included), notes any made by a thread that holds the store's lock,
-    and can make the k-th call of a name fail. The store's background
-    LRU seed (its own thread, once a store) is not on any caller's path
-    and is left out."""
+    included; the builtin ``open`` an entry is read through counts as
+    ``open_read``), notes any made by a thread that holds the store's
+    lock, and can make the k-th call of a name fail. The store's
+    background LRU seed (its own thread, once a store) is not on any
+    caller's path and is left out."""
 
     _FS = ("open", "write", "close", "rename", "mkdir", "makedirs",
            "unlink", "link", "listdir", "stat")
@@ -159,6 +185,9 @@ def fs_calls(monkeypatch):
     def install(store):
         recorder = _FsCalls(store)
         monkeypatch.setattr(cas_mod, "os", recorder)
+        monkeypatch.setattr(cas_mod, "open",
+                            recorder._wrap("open_read", open),
+                            raising=False)
         return recorder
     return install
 

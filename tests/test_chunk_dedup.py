@@ -6,7 +6,7 @@ import os
 
 import pytest
 
-from conftest import cas_entry_path
+from conftest import cas_entry_path, committed_layer
 
 from makisu_tpu.builder import BuildPlan
 from makisu_tpu.cache import CacheManager, MemoryStore
@@ -1336,3 +1336,234 @@ def test_note_fingerprint_from_many_threads_claims_each_digest_once(
     assert sum(a is None for a in answers) == len(digests) % 16
     assert all(a is None or a == (i % 3 == 0)
                for i, a in enumerate(answers))
+
+
+# -- the recipe publisher fed from index_layer's pass (PR 48) ----------------
+
+@pytest.fixture
+def publishing(monkeypatch):
+    """Publishing on, as in a worker; packs of 1 MB (the least a pack
+    may be) so that a layer of a few MB fills several; the process's
+    serve stores dropped before and after."""
+    from makisu_tpu.serve import server as serve_server
+    monkeypatch.setenv("MAKISU_TPU_SERVE", "1")
+    monkeypatch.setenv("MAKISU_TPU_PACK_TARGET_MB", "1")
+    serve_server.reset_stores()
+    yield serve_server
+    serve_server.reset_stores()
+
+
+def _fed_manager(storage: str):
+    """A cache manager with chunk dedup attached over ``storage``, its
+    chunk store at ``<storage>/chunks`` as a build's is."""
+    mgr = CacheManager(MemoryStore(), ImageStore(storage))
+    attach_chunk_dedup(mgr, os.path.join(storage, "chunks"))
+    return mgr
+
+
+def _recipe_store(storage: str):
+    from makisu_tpu.serve import server as serve_server
+    return serve_server.register_store(storage)
+
+
+def _serve_tree(store_tree, storage: str) -> dict:
+    return {rel: data for rel, (_, data) in
+            store_tree(os.path.join(storage, "serve")).items()}
+
+
+def _publish_threads() -> list:
+    import threading
+    return [t for t in threading.enumerate()
+            if t.name.startswith("recipepub-")]
+
+
+_PUBLISH_STATES = {
+    # name -> (pieces of the layer, given the 48 drawn;
+    #          which of its distinct chunks the store holds beforehand;
+    #          which of those the streamed probe found (memo True);
+    #          which an earlier layer's pack holds already)
+    "all_new": (lambda p: p, lambda i: False, lambda i: False,
+                lambda i: False),
+    "stored_unpacked": (lambda p: p, lambda i: True, lambda i: True,
+                        lambda i: False),
+    "mixed": (lambda p: p, lambda i: i % 3 != 0, lambda i: i % 2 == 0,
+              lambda i: False),
+    "repeated": (lambda p: p[:30] + p[5:12] + p[30:] + p[:3],
+                 lambda i: i % 5 == 0, lambda i: True, lambda i: False),
+    "in_another_pack": (lambda p: p, lambda i: i % 2 == 0,
+                        lambda i: i % 4 == 0, lambda i: i % 3 == 0),
+    "over_three_packs": (lambda p: p + _pieces(90, seed=22),
+                         lambda i: i % 7 == 0, lambda i: True,
+                         lambda i: False),
+}
+
+
+@pytest.mark.parametrize("backend", ["zlib", "pgzip"])
+@pytest.mark.parametrize("state", sorted(_PUBLISH_STATES))
+def test_publish_fed_from_the_pass_equals_the_read_back(
+        tmp_path, store_tree, small_blocks, publishing, state, backend):
+    """For one layer and one state of the stores, the route a build
+    takes (push_cache: the publication opened before index_layer, fed
+    from its pass, finished on the recipepub thread) and the read-back
+    route (index_layer, then RecipeStore.publish reading every novel
+    chunk from the store) leave the same serve/ tree byte for byte:
+    zpacks, pack tables, recipe; and the same chunk store."""
+    import hashlib
+    from makisu_tpu import tario
+    from makisu_tpu.serve import recipe as recipe_mod
+    shape, held, probed, packed = _PUBLISH_STATES[state]
+    pieces = shape(_pieces(48, seed=21))
+    chunks = _chunk_list(pieces)
+    backend_id = tario.make_backend_id(backend, "default")
+    blob = _blob(tmp_path, backend, b"".join(pieces))
+    by_digest = {hashlib.sha256(p).hexdigest(): p for p in pieces}
+    distinct = list(by_digest)
+    earlier = [h for i, h in enumerate(distinct) if packed(i)]
+    trees = {}
+    for route in ("fed", "read_back"):
+        storage = str(tmp_path / route)
+        mgr = _fed_manager(storage)
+        store = mgr.chunk_store
+        rs = _recipe_store(storage)
+        if earlier:
+            # An earlier layer, published whole, holds these in its pack.
+            for h in earlier:
+                store.put(h, by_digest[h])
+            other = _chunk_list([by_digest[h] for h in earlier])
+            other_blob = _blob(tmp_path, backend, b"".join(
+                by_digest[h] for h in earlier), f"other-{route}.gz")
+            other_pair, _ = committed_layer(storage, other_blob, other)
+            assert rs.publish(other_pair, other, backend_id, store)
+        for i, h in enumerate(distinct):
+            if held(i) and not packed(i):
+                store.put(h, by_digest[h])
+        with store._memo_lock:
+            store._exists_memo.update({
+                h: True for i, h in enumerate(distinct)
+                if (held(i) or packed(i)) and probed(i)})
+        pair, commit = committed_layer(storage, blob, chunks, backend_id)
+        if route == "fed":
+            mgr.push_cache("cache-id", pair, commit)
+            mgr.wait_for_push()
+        else:
+            store.index_layer(blob, chunks)
+            assert rs.publish(pair, chunks, backend_id, store)
+        doc = rs.recipe(pair.gzip_descriptor.digest.hex())
+        assert recipe_mod.verify(doc, key=b"")
+        assert [row[0] for row in doc["chunks"]] == [
+            h for _, _, h in chunks]
+        trees[route] = (_serve_tree(store_tree, storage),
+                        store_tree(store.cas.root))
+    assert trees["fed"] == trees["read_back"]
+    packs = [rel for rel in trees["fed"][0] if rel.startswith("zpacks/")]
+    assert len(packs) > (3 if state == "over_three_packs" else 1)
+    assert not _publish_threads()
+
+
+@pytest.mark.parametrize("state", ["cold", "stored_unpacked"])
+def test_publish_reads_no_chunk_file_the_pass_sliced(
+        tmp_path, fs_calls, publishing, state):
+    """A cold layer's publish opens no file of the chunk store for
+    reading: its bytes reach the packs from the pass. A chunk the
+    probe found stored, in no pack yet, is opened once."""
+    from makisu_tpu.utils import metrics
+    pieces = _pieces(60, seed=23)
+    blob, chunks = _layer(tmp_path, pieces)
+    storage = str(tmp_path / "storage")
+    mgr = _fed_manager(storage)
+    store = mgr.chunk_store
+    stored = chunks[::4] if state == "stored_unpacked" else []
+    for (_, _, h), piece in zip(chunks[::4], pieces[::4]):
+        if stored:
+            store.put(h, piece)
+    with store._memo_lock:
+        store._exists_memo.update({h: True for _, _, h in stored})
+    pair, commit = committed_layer(storage, blob, chunks)
+    rec = fs_calls(store.cas)
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
+    try:
+        mgr.push_cache("cache-id", pair, commit)
+        mgr.wait_for_push()
+    finally:
+        metrics.reset_build_registry(token)
+    assert rec.calls["open_read"] == len(stored)
+    assert rec.calls["rename"] == len(chunks) - len(stored)
+    from_store = float(sum(n for _, n, _ in stored))
+    want = {"pass": float(sum(n for _, n, _ in chunks)) - from_store}
+    if stored:
+        want["store"] = from_store
+    assert registry.counter_by_label(
+        metrics.SERVE_PACK_SOURCE_BYTES, "source") == want
+    (span,) = [s for s in registry.report()["spans"]
+               if s["name"] == "recipe_publish"]
+    assert span["attrs"]["novel"] == str(len(chunks))
+    assert span["attrs"]["fed"] == str(len(chunks) - len(stored))
+    doc = _recipe_store(storage).recipe(
+        pair.gzip_descriptor.digest.hex())
+    assert len(doc["chunks"]) == len(chunks)
+
+
+def test_publishing_off_index_layer_has_no_observer(
+        tmp_path, monkeypatch):
+    """A one-shot build (MAKISU_TPU_SERVE=0): no publication, no
+    thread, no serve/ directory."""
+    monkeypatch.setenv("MAKISU_TPU_SERVE", "0")
+    blob, chunks = _layer(tmp_path, _pieces(12, seed=24))
+    storage = str(tmp_path / "storage")
+    mgr = _fed_manager(storage)
+    seen = []
+    real = mgr.chunk_store.index_layer
+    monkeypatch.setattr(
+        mgr.chunk_store, "index_layer",
+        lambda *a, **k: seen.append(k.get("observer")) or real(*a, **k))
+    pair, commit = committed_layer(storage, blob, chunks)
+    mgr.push_cache("cache-id", pair, commit)
+    assert seen == [None]
+    assert [t.name for t in mgr._pushes] == ["cachepush-cache-id"]
+    mgr.wait_for_push()
+    assert not os.path.exists(os.path.join(storage, "serve"))
+
+
+@pytest.mark.parametrize("failing", [
+    "truncated", "crc", "writer_oserror", "lying_digest_early",
+    "lying_digest_late"])
+def test_publish_is_abandoned_where_the_pass_fails(
+        tmp_path, fs_calls, store_tree, publishing, failing):
+    """index_layer raising mid-stream (a blob that is not whole, a
+    writer's OSError, a slice that does not hash to its fingerprint:
+    refused where it is made, before a pack takes it) fails the push,
+    leaves no recipe and no pack table, and the recipepub thread ends.
+    Packs filled before the failure may have left a .zst, which no
+    table names."""
+    import errno
+    import zlib
+    pieces = _pieces(100, seed=25)           # ~3.4 MB: three packs
+    blob, chunks = _layer(tmp_path, pieces)
+    storage = str(tmp_path / "storage")
+    mgr = _fed_manager(storage)
+    rec = fs_calls(mgr.chunk_store.cas)
+    if failing in ("truncated", "crc"):
+        _spoil(blob, failing)
+    elif failing == "writer_oserror":
+        rec.fail_at["rename"] = (70, OSError(errno.ENOSPC, "no space"))
+    else:
+        at = 2 if failing == "lying_digest_early" else 90
+        chunks[at] = (chunks[at][0], chunks[at][1], "ab" * 32)
+    pair, commit = committed_layer(storage, blob, chunks)
+    with pytest.raises((ValueError, EOFError, zlib.error, OSError)):
+        mgr.push_cache("cache-id", pair, commit)
+    # A pack was handed over before the late failures: the thread is
+    # among the pushes the build joins, and it ends.
+    assert ("recipepub-cache-id" in [t.name for t in mgr._pushes]) == (
+        failing != "lying_digest_early")
+    mgr.wait_for_push()
+    assert not _publish_threads()
+    serve = _serve_tree(store_tree, storage)
+    assert not [rel for rel in serve if not rel.startswith("zpacks/")]
+    if failing == "lying_digest_early":
+        assert not serve                     # nothing entered a pack
+    rs = _recipe_store(storage)
+    assert rs.recipe(pair.gzip_descriptor.digest.hex()) is None
+    assert rs.stats()["packs"] == 0
+    assert not mgr.chunk_store.cas.exists("ab" * 32)

@@ -328,8 +328,8 @@ def test_prefetch_metrics_list_their_cells():
     by_name = {m["name"]: m for m in per_layer}
     assert [m["name"] for m in per_layer[53:55]] == [
         "sink_prefetch_ready_pct", "read_wait_s_per_build"]
-    # PR 41 appended four, PR 42 one, PR 45 one, PR 47 four
-    assert len(per_layer) == 65
+    # PR 41 appended four, PR 42 one, PR 45 one, PR 47 four, PR 48 one
+    assert len(per_layer) == 66
     commit = by_name["tar_write_s_per_build"]
     for name, unit, better in (("sink_prefetch_ready_pct", "%", "higher"),
                                ("read_wait_s_per_build", "s", "lower")):
@@ -481,3 +481,57 @@ def test_resolve_reuse_metric_lists_the_cells_that_report_the_request():
                  "layer": request["layer"], "moves": "build_p50_s",
                  "workloads": request["workloads"]}
     assert len(m["workloads"]) == 10  # PR 47 appended its cell
+
+
+# -- PR 48: where a new pack's bytes came from --------------------------------
+
+PACK_SOURCE = "makisu_serve_pack_source_bytes_total"
+
+
+def _packed(tmp_path, grown: dict | None):
+    """``_served``'s run over a window in which new packs took
+    ``grown[source]`` bytes from each source (None: a program without
+    the series)."""
+    r = _served(tmp_path)
+    if grown is not None:
+        before = {"pass": 5_000.0, "store": 700.0}
+        r.counters_open.update(
+            _series(PACK_SOURCE, before[source], source=source)
+            for source in grown)
+        r.counters_close.update(
+            _series(PACK_SOURCE, before[source] + n, source=source)
+            for source, n in grown.items())
+    return r
+
+
+@pytest.mark.parametrize("grown, want", [
+    ({"pass": 64_000_000.0}, 100.0),                  # a cold build
+    ({"pass": 30_000.0, "store": 10_000.0}, 75.0),
+    ({"store": 8_000.0}, 0.0),                        # all read back
+    ({"pass": 0.0, "store": 0.0}, None),              # no new pack
+    (None, None),                                     # the parent's side
+])
+def test_recipe_bytes_from_pass_reader(tmp_path, grown, want):
+    read = _reader("recipe_bytes_from_pass_pct")
+    got = read(_packed(tmp_path, grown))
+    assert got is None if want is None else got == pytest.approx(want)
+    untraced = _packed(tmp_path, grown)
+    untraced.counters_open = untraced.counters_close = None
+    assert read(untraced) is None
+
+
+def test_recipe_bytes_from_pass_is_appended_under_the_chunk_store():
+    import json
+
+    from makisu_tpu.utils import metrics
+    assert metrics.SERVE_PACK_SOURCE_BYTES == PACK_SOURCE
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json"),
+              encoding="utf-8") as f:
+        per_layer = json.load(f)["per_layer"]
+    index = {x["name"]: x for x in per_layer}["chunk_index_s_per_build"]
+    assert per_layer[65] == {
+        "name": "recipe_bytes_from_pass_pct", "unit": "%",
+        "better": "higher", "source": "program_counter",
+        "layer": index["layer"], "moves": "build_p50_s",
+        "workloads": ["monorepo-cold", "monorepo-edit", "farm-churn",
+                      "farm-concurrent-churn"]}

@@ -856,3 +856,162 @@ def test_worker_serves_recipes_and_packs_for_own_roots_only(tmp_path):
             server.shutdown()
             server.server_close()
             thread.join(timeout=5)
+
+
+# -- the publication fed from a pass (PR 48) ---------------------------------
+
+def _fed_layer(tmp_path, storage, pieces, name):
+    """A gzip blob of ``pieces`` committed into ``storage``'s layer
+    store: (blob path, chunk list, digest pair, LayerCommit)."""
+    import gzip
+    import hashlib
+    from conftest import committed_layer
+    chunks, pos = [], 0
+    for piece in pieces:
+        chunks.append((pos, len(piece), hashlib.sha256(piece).hexdigest()))
+        pos += len(piece)
+    path = tmp_path / name
+    path.write_bytes(gzip.compress(b"".join(pieces), mtime=0))
+    return str(path), chunks, *committed_layer(storage, str(path), chunks)
+
+
+@pytest.mark.parametrize("in_flight", [1, 2])
+def test_publication_hand_off_holds_the_pass_at_its_bound(
+        tmp_path, monkeypatch, in_flight):
+    """With the publisher's thread held inside its first pack, the
+    pass fills IN_FLIGHT more, blocks handing over the last of them,
+    and slices nothing further: never more than IN_FLIGHT + 1 packs of
+    new bytes outside the stream."""
+    import random
+    import threading
+    from makisu_tpu.cache.chunks import ChunkStore
+    monkeypatch.setenv("MAKISU_TPU_PACK_TARGET_MB", "1")
+    monkeypatch.setattr(recipe_mod.Publication, "IN_FLIGHT", in_flight)
+    rng = random.Random(31)
+    per_pack = 16                       # 16 x 64,000 >= 1,000,000
+    pieces = [rng.randbytes(64_000) for _ in range(per_pack * 6)]
+    storage = str(tmp_path / "storage")
+    blob, chunks, pair, _ = _fed_layer(tmp_path, storage, pieces, "l.gz")
+    store = ChunkStore(os.path.join(storage, "chunks"))
+    rs = serve_server_mod.register_store(storage)
+    held, entered = threading.Event(), threading.Event()
+    sealed = []
+    real_seal = recipe_mod.Publication._seal_pack
+
+    def holding_seal(self, raw, members):
+        entered.set()
+        assert held.wait(timeout=60)
+        sealed.append(len(raw))
+        real_seal(self, raw, members)
+
+    monkeypatch.setattr(recipe_mod.Publication, "_seal_pack", holding_seal)
+    publication = rs.begin(pair, chunks, "", store,
+                           thread_name="recipepub-held")
+    seen = []
+
+    def observer(fp, data):
+        seen.append(fp)
+        publication.feed(fp, data)
+
+    failed = []
+
+    def the_pass():
+        try:
+            store.index_layer(blob, chunks, observer=observer)
+            publication.finish()
+        except BaseException as e:  # noqa: BLE001 - reported below
+            failed.append(e)
+
+    feeder = threading.Thread(target=the_pass, daemon=True)
+    feeder.start()
+    assert entered.wait(timeout=60)
+    want = per_pack * (in_flight + 1)   # one sealing, the rest waiting
+    deadline = time.monotonic() + 60
+    while len(seen) < want and time.monotonic() < deadline:
+        time.sleep(0.01)
+    time.sleep(0.3)                     # room to overrun, were there any
+    assert len(seen) == want and feeder.is_alive()
+    # In hand: the pack that cannot be handed over, and nothing else.
+    assert publication._size == per_pack * 64_000
+    assert publication._jobs.qsize() == in_flight - 1
+    held.set()
+    feeder.join(timeout=60)
+    publication.thread.join(timeout=60)
+    assert not feeder.is_alive() and not publication.thread.is_alive()
+    assert not failed
+    assert sealed == [per_pack * 64_000] * 6
+    assert recipe_mod.verify(publication.doc, key=b"")
+    assert len(publication.doc["packs"]) == 6
+
+
+@pytest.mark.parametrize("builders", [2, 12])
+@pytest.mark.parametrize("shared", ["all", "half"])
+def test_builds_publishing_shared_new_chunks_at_once(
+        tmp_path, monkeypatch, shared, builders):
+    """Two builds (and twelve, more than the cores, under a switch
+    interval that interleaves them) over one storage index and publish
+    layers that share new chunks at the same moment: every publication
+    finds them novel and packs them, the index keeps the first, and
+    every row of every recipe resolves to bytes that hash to its
+    fingerprint."""
+    import hashlib
+    import random
+    import sys
+    import threading
+    monkeypatch.setenv("MAKISU_TPU_PACK_TARGET_MB", "1")
+    rng = random.Random(32)
+    common = [rng.randbytes(rng.randrange(2_000, 66_000))
+              for _ in range(60)]
+    storage = str(tmp_path / "storage")
+    layers = []
+    for k in range(builders):
+        own = [rng.randbytes(rng.randrange(2_000, 66_000))
+               for _ in range(0 if shared == "all" else 30)]
+        pieces = own[:15] + common + own[15:]
+        if shared == "all":
+            pieces = pieces[7 * k:] + pieces[:7 * k]   # another order
+        layers.append(_fed_layer(tmp_path, storage, pieces, f"l{k}.gz"))
+    # One worker, one storage: the builds share its chunk store and its
+    # recipe store, each with a pass and a publication of its own.
+    from makisu_tpu.cache.chunks import ChunkStore, register_serving_store
+    store = ChunkStore(os.path.join(storage, "chunks"))
+    register_serving_store(store)
+    rs = serve_server_mod.register_store(storage)
+    start = threading.Barrier(builders)
+    failed = []
+
+    def build(k):
+        try:
+            blob, chunks, pair, _ = layers[k]
+            start.wait(timeout=60)
+            publication = rs.begin(pair, chunks, "", store,
+                                   thread_name=f"recipepub-{k}")
+            store.index_layer(blob, chunks, observer=publication.feed)
+            publication.finish()
+            publication.thread.join(timeout=60)
+            assert not publication.thread.is_alive()
+        except BaseException as e:  # noqa: BLE001 - reported below
+            failed.append(e)
+
+    builds = [threading.Thread(target=build, args=(k,))
+              for k in range(builders)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in builds:
+            t.start()
+        for t in builds:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not failed and not any(t.is_alive() for t in builds)
+    for _, chunks, pair, _ in layers:
+        doc = rs.recipe(pair.gzip_descriptor.digest.hex())
+        assert doc is not None and recipe_mod.verify(doc, key=b"")
+        assert [(row[0], row[1]) for row in doc["chunks"]] == [
+            (h, n) for _, n, h in chunks]
+        for fp, length, pack_hex, pack_off in doc["chunks"]:
+            data = b"".join(rs.iter_pack_range(
+                pack_hex, pack_off, pack_off + length))
+            assert hashlib.sha256(data).hexdigest() == fp
+            assert doc["packs"][pack_hex] == rs.pack_size(pack_hex)
