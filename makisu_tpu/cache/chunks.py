@@ -413,6 +413,10 @@ class ChunkStore:
             self._exists_memo.clear()
             self._probe_queue = []
             self._memo_gen += 1  # in-flight probe batches discard
+        # The same holds for the store's own index of its segments: a
+        # hit must not outlive a delete or a reclaim by another handle
+        # or process. One listing a commit, not a stat a chunk.
+        self.cas.refresh()
 
     def push_remote(self, hex_digest: str) -> None:
         if self.registry is not None:
@@ -509,29 +513,41 @@ class ChunkStore:
     def put(self, hex_digest: str, data: bytes) -> None:
         if hashlib.sha256(data).hexdigest() != hex_digest:
             raise ValueError(f"chunk content does not match {hex_digest}")
-        self.cas.put(hex_digest, data)
+        self._count_files(self.cas.put(hex_digest, data))
         metrics.counter_add(metrics.CHUNK_INGEST, result="written")
 
+    @staticmethod
+    def _count_files(created: dict[str, int]) -> None:
+        """Files the bulk ingest created, by kind: ``segment`` and
+        ``index`` (a new pair where no segment of this process was
+        free), ``loose`` (an entry no record can name). Files, not
+        entries: an append to a segment that is there creates none."""
+        for kind, n in created.items():
+            metrics.counter_add(metrics.CHUNK_STORE_FILES_CREATED, n,
+                                kind=kind)
+
     # index_layer's ingest window. Chunks leave the gunzip pass in
-    # batches of up to INGEST_BATCH_BYTES (four of the largest chunk the
-    # chunker cuts, 64 KiB; ~32 average ones), at most INGEST_WRITERS of
-    # them on the commit pool at once, so the bytes staged outside the
-    # stream never pass (INGEST_WRITERS + 1) batches and one chunk,
-    # whatever the layer's size (beside them, where a recipe publisher
-    # observes the pass, the three packs its hand-off may hold).
+    # batches of up to INGEST_BATCH_BYTES (sixteen of the largest chunk
+    # the chunker cuts, 64 KiB; ~128 average ones: a batch is one append
+    # to a segment, two writes whatever it holds), at most
+    # INGEST_WRITERS of them on the commit pool at once, so the bytes
+    # staged outside the stream never pass (INGEST_WRITERS + 1) batches
+    # and one chunk, whatever the layer's size (beside them, where a
+    # recipe publisher observes the pass, the three packs its hand-off
+    # may hold).
     INGEST_WRITERS = 8
-    INGEST_BATCH_BYTES = 4 * 65536
+    INGEST_BATCH_BYTES = 16 * 65536
 
     def _ingest_batch(self, batch: list[tuple[str, bytes, bool]]
-                      ) -> list[str]:
+                      ) -> tuple[list[str], dict[str, int]]:
         """One writer of index_layer's window, on the commit pool:
         probe the entries nobody has looked for and store the new ones
         (index_layer held each to its digest as it sliced it). Returns
-        the digests it stored, in batch order."""
+        the digests it stored, in batch order, and the files the store
+        created for them."""
         new = [(h, data) for h, data, probe in batch
                if not (probe and self.cas.exists(h))]
-        self.cas.write_many(new)
-        return [h for h, _ in new]
+        return [h for h, _ in new], self.cas.write_many(new)
 
     def index_layer(self, layer_blob_path: str,
                     chunks: list[tuple[int, int, str]],
@@ -576,10 +592,13 @@ class ChunkStore:
         batch_bytes = 0
         peak = 0
         failure: list[BaseException] = []
+        created = collections.Counter()
 
         def reap() -> None:
             try:
-                added.extend(window.popleft().result())
+                stored, files = window.popleft().result()
+                added.extend(stored)
+                created.update(files)
             except Exception as e:  # noqa: BLE001 - re-raised below
                 failure.append(e)
 
@@ -642,6 +661,7 @@ class ChunkStore:
         finally:
             while window:
                 reap()
+        self._count_files(created)
         if failure:
             raise failure[0]
         if stats is not None:
@@ -765,6 +785,9 @@ class ChunkStore:
         lengths: dict[str, int] = {}
         for _, length, hex_digest in chunks:
             lengths.setdefault(hex_digest, length)
+        # What is found here is read later without another look: the
+        # store's index must have heard of deletes by other handles.
+        self.cas.refresh()
         missing = sorted({h for _, _, h in chunks
                           if not self.cas.exists(h)})
         n_missing = len(missing)

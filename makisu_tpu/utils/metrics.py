@@ -75,10 +75,15 @@ DEVICE_PADDING_WASTE = "makisu_device_padding_waste_bytes_total"
 DEVICE_TRANSFER_BYTES = "makisu_device_transfer_bytes_total"
 
 # Chunks through the chunk store's ingest (cache/chunks.py: index_layer
-# and put), by result: written (staged and renamed into the CAS),
+# and put), by result: written (appended to a segment of the CAS),
 # present (a probe found it stored), raced (a digest index_layer had
 # already handed to a writer: repeated within the layer).
 CHUNK_INGEST = "makisu_chunk_ingest_total"
+# Files that ingest created in the chunk store (storage/cas.py), by
+# kind: segment, index (a new pair; an append to one that is there
+# creates none), loose (an entry no index record can name). Added once
+# an index_layer pass and once a put.
+CHUNK_STORE_FILES_CREATED = "makisu_chunk_store_files_created_total"
 
 # Fleet telemetry (makisu_tpu/fleet/): one name set shared by the
 # scheduler, the peer-exchange module, the worker's /chunks endpoint,

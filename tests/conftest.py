@@ -70,9 +70,31 @@ def cas_entry_path(root, name: str) -> str:
     """Where the CAS directory ``root`` keeps ``name``, for a test that
     ages, corrupts or removes an entry behind the store's back. The
     one place in ``tests/`` (outside ``test_storage.py``, which tests
-    the layout itself) that knows: it asks the owner."""
+    the layout itself) that knows: it asks the owner. The entry is a
+    file of its own when this returns, whichever form it had."""
     from makisu_tpu.storage import cas
-    return cas.CASDir(str(root))._path(name)
+    handle = cas.CASDir(str(root))
+    segment = handle._lookup(name) is not None
+    # A segment entry has no file of its own to age, corrupt or remove:
+    # it is made a loose one first (what ``CASStore.path`` does), and
+    # the process's live stores hear of it.
+    path = handle._loosen(name)
+    if segment:
+        for live in cas.live_stores():
+            live.refresh()
+    return path
+
+
+def cas_index_bytes(root) -> int:
+    """Bytes of the CAS directory ``root`` that are the layout's own
+    and no entry's: the segment indexes. What a census of entries does
+    not count and ``du`` does."""
+    from makisu_tpu.storage import cas
+    seg_dir = cas.CASDir(str(root))._seg_dir
+    if not os.path.isdir(seg_dir):
+        return 0
+    return sum(os.path.getsize(os.path.join(seg_dir, fn))
+               for fn in os.listdir(seg_dir) if fn.endswith(".idx"))
 
 
 def committed_layer(storage: str, blob_path: str, chunks,
@@ -103,14 +125,15 @@ def committed_layer(storage: str, blob_path: str, chunks,
 class _FsCalls:
     """Stand-in for the ``os`` module inside ``storage/cas.py``: counts
     every file-system call the store issues (``os.path`` probes
-    included; the builtin ``open`` an entry is read through counts as
-    ``open_read``), notes any made by a thread that holds the store's
+    included; the builtin ``open`` a loose entry is read through counts
+    as ``open_read``, the ``pread`` of a segment entry or of an index as
+    ``pread``), notes any made by a thread that holds the store's
     lock, and can make the k-th call of a name fail. The store's
     background LRU seed (its own thread, once a store) is not on any
     caller's path and is left out."""
 
-    _FS = ("open", "write", "close", "rename", "mkdir", "makedirs",
-           "unlink", "link", "listdir", "stat")
+    _FS = ("open", "write", "pread", "close", "rename", "mkdir",
+           "makedirs", "unlink", "link", "listdir", "stat")
     _FS_PATH = ("isfile", "isdir", "exists", "lexists", "getsize",
                 "getmtime")
 

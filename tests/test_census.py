@@ -13,7 +13,7 @@ import shutil
 
 import pytest
 
-from conftest import cas_entry_path
+from conftest import cas_entry_path, cas_index_bytes
 
 from makisu_tpu.cache import census as census_mod
 from makisu_tpu.cache.census import IOBudget, StorageCensus
@@ -95,6 +95,8 @@ def test_census_totals_match_disk(tmp_path):
                     or "manifests" in dirpath:
                 continue
             want += os.path.getsize(os.path.join(dirpath, fn))
+    # Entries are counted, the layout's own index of them is not.
+    want -= cas_index_bytes(os.path.join(storage, "chunks"))
     assert out["total_bytes"] == want
     # The cache file is the cheap-consumer path.
     totals = census_mod.cached_totals(storage)
@@ -515,3 +517,47 @@ def test_cli_doctor_storage_repair(tmp_path, capsys):
     assert not os.path.exists(orphan)
     # Repaired store is clean again.
     assert cli.main(["doctor", "--storage", storage]) == 0
+
+
+# -- chunks in segments (PR 49) ----------------------------------------------
+
+
+def test_census_and_scrub_rows_over_segment_entries(tmp_path):
+    """The chunk plane's rows are the layout's live entries, whichever
+    form they have: a census counts a segment entry once with its
+    record's size and stamp, and a scrub finding names the segment and
+    the span the rotten bytes lie at (``where``), which is where a
+    person has to look."""
+    from makisu_tpu.storage import cas
+    storage, _, fps = _populate(tmp_path)
+    chunks_root = os.path.join(storage, "chunks")
+    bare = cas.CASDir(chunks_root)
+    assert sorted((n, s) for n, s, _ in bare.walk()) == sorted(
+        zip(fps, (1000, 3000)))
+    assert not [fn for _, _, files in os.walk(chunks_root)
+                for fn in files if fn in fps]    # no file a chunk
+    census = StorageCensus(storage)
+    out = census.census()
+    assert out["planes"]["chunks"]["objects"] == 2
+    assert out["planes"]["chunks"]["bytes"] == 4000
+    assert out["planes"]["chunks"]["age"]["1h"] == 2
+    assert census.scrub(chunk_samples=10)["findings"] == []
+    # Rot in place: one byte of the second chunk, in its segment.
+    place = bare.where(fps[1])
+    seg_path, span = place.rsplit("@", 1)
+    offset, length = (int(x) for x in span.split("+"))
+    assert length == 3000 and os.path.isfile(seg_path)
+    with open(seg_path, "rb+") as f:
+        f.seek(offset + 17)
+        f.write(b"!")
+    findings = [f for f in StorageCensus(storage).scrub(
+        chunk_samples=10)["findings"]
+        if f["kind"] == "corruption" and f["plane"] == "chunks"]
+    assert len(findings) == 1
+    assert findings[0]["expected"] == fps[1]
+    assert findings[0]["path"] == place
+    # A deleted entry leaves the rows; the audit sees it demoted.
+    bare.delete(fps[0])
+    assert [n for n, _, _ in cas.CASDir(chunks_root).walk()] == [fps[1]]
+    assert StorageCensus(storage).census()["planes"]["chunks"][
+        "objects"] == 1

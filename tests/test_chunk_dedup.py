@@ -970,37 +970,73 @@ def _pieces(n: int, seed: int = 0) -> list[bytes]:
     return [rng.randbytes(rng.randrange(2_000, 66_000)) for _ in range(n)]
 
 
+def _batches(chunks) -> int:
+    """Batches ``index_layer`` cuts a layer of new chunks into."""
+    n = held = 0
+    for _, length, _ in chunks:
+        held += length
+        if held >= ChunkStore.INGEST_BATCH_BYTES:
+            n, held = n + 1, 0
+    return n + bool(held)
+
+
 @pytest.mark.parametrize("writers", [1, ChunkStore.INGEST_WRITERS])
-def test_index_layer_five_calls_a_new_chunk_none_under_lock(
+def test_index_layer_six_calls_a_batch_none_under_lock(
         tmp_path, fs_calls, monkeypatch, writers):
-    """A layer of N new chunks into a fresh store: a stat, create,
-    write, close and rename each; one mkdir a shard (two writers that
-    meet a new shard at once may each issue it); every call made with
-    the store's lock free."""
+    """A layer of N new chunks into a fresh store, per batch of the
+    window and not per chunk: the segment and its index opened, a write
+    each, a close each; no stat (a fresh store answers every probe from
+    memory), no rename, no shard; a segment pair created where no free
+    one was (one with one writer); every call made with the store's
+    lock free."""
+    from makisu_tpu.utils import metrics
     monkeypatch.setattr(ChunkStore, "INGEST_WRITERS", writers)
+    monkeypatch.setattr(ChunkStore, "INGEST_BATCH_BYTES", 4 * 65536)
     path, chunks = _layer(tmp_path, _pieces(90))
+    batches = _batches(chunks)
+    assert batches >= 8
     store = ChunkStore(str(tmp_path / "chunks"))
-    shards = {h[:2] for _, _, h in chunks}
     rec = fs_calls(store.cas)
+    registry = metrics.MetricsRegistry()
+    token = metrics.set_build_registry(registry)
     stats = {}
-    added = store.index_layer(path, chunks, stats)
+    try:
+        added = store.index_layer(path, chunks, stats)
+    finally:
+        metrics.reset_build_registry(token)
     assert added == [h for _, _, h in chunks]
-    assert 0 <= rec.calls.pop("mkdir") - len(shards) < writers
-    assert dict(rec.calls) == {
-        name: len(chunks)
-        for name in ("isfile", "open", "write", "close", "rename")}
+    created = registry.counter_by_label(
+        metrics.CHUNK_STORE_FILES_CREATED, "kind")
+    assert created["segment"] == created["index"] and created["loose"] == 0
+    assert 1 <= created["segment"] <= writers
+    # A writer that finds no _seg/ makes it and creates again.
+    assert 1 <= rec.calls["makedirs"] <= writers
+    assert rec.calls.pop("open") == 2 * batches + rec.calls.pop("makedirs")
+    assert dict(rec.calls) == {"write": 2 * batches, "close": 2 * batches}
     assert rec.under_lock == []
     assert 1 <= stats["ingest_window"] <= writers
-    # The same layer again: one stat a chunk, nothing written.
+    assert len(_cas_tree_files(store.cas.root)) == 2 * created["segment"]
+    # The same layer again: a lookup a chunk, no call, nothing created.
     rec = fs_calls(store.cas)
-    assert store.index_layer(path, chunks) == []
-    assert dict(rec.calls) == {"isfile": len(chunks)}
-    assert rec.under_lock == []
+    token = metrics.set_build_registry(registry)
+    try:
+        assert store.index_layer(path, chunks) == []
+    finally:
+        metrics.reset_build_registry(token)
+    assert dict(rec.calls) == {} and rec.under_lock == []
+    assert registry.counter_by_label(
+        metrics.CHUNK_STORE_FILES_CREATED, "kind") == created
+
+
+def _cas_tree_files(root: str) -> list[str]:
+    return [os.path.join(parent, fn)
+            for parent, _, files in os.walk(root) for fn in files]
 
 
 def test_index_layer_trusts_a_streamed_miss(tmp_path, fs_calls):
     """Digests the commit's streamed probe already looked for cost no
-    second stat: four calls a new chunk, none for a stored one."""
+    second look: one append for the new chunks (six calls, into the
+    segment the process has), none for a stored one."""
     import time
     path, chunks = _layer(tmp_path, _pieces(12, seed=3))
     store = ChunkStore(str(tmp_path / "chunks"))
@@ -1016,7 +1052,7 @@ def test_index_layer_trusts_a_streamed_miss(tmp_path, fs_calls):
     rec = fs_calls(store.cas)
     assert store.index_layer(path, chunks) == [h for _, _, h in chunks[4:]]
     assert rec.calls["isfile"] == 0
-    assert rec.total() - rec.calls["mkdir"] == 4 * 8
+    assert dict(rec.calls) == {"open": 2, "write": 2, "close": 2}
 
 
 def test_index_layer_writes_a_repeated_digest_once(tmp_path, fs_calls):
@@ -1032,36 +1068,43 @@ def test_index_layer_writes_a_repeated_digest_once(tmp_path, fs_calls):
     finally:
         metrics.reset_build_registry(token)
     assert added == [chunks[0][2], chunks[1][2], chunks[3][2]]
-    assert rec.calls["rename"] == rec.calls["isfile"] == 3
+    assert rec.calls["rename"] == rec.calls["isfile"] == 0
+    assert rec.calls["write"] == 2               # one batch: three entries
     assert registry.counter_by_label(metrics.CHUNK_INGEST, "result") == {
         "written": 3.0, "raced": 3.0}
     assert store.get(chunks[0][2]) == a
 
 
-@pytest.mark.parametrize("failing", ["open", "write", "rename"])
+@pytest.mark.parametrize("failing", ["open", "payload", "records"])
 def test_index_layer_write_failure_raises_and_leaves_no_partial_chunk(
-        tmp_path, fs_calls, store_tree, failing):
+        tmp_path, fs_calls, store_tree, monkeypatch, failing):
+    """The open of a segment, the write of a batch's payloads or the
+    write of its records fails: the pass raises, what is stored is what
+    whole batches stored, and every name reads the bytes it hashes."""
     import errno
     import hashlib
-    import os
+    from makisu_tpu.storage import cas as cas_mod
+    monkeypatch.setattr(ChunkStore, "INGEST_BATCH_BYTES", 2 * 65536)
     path, chunks = _layer(tmp_path, _pieces(60, seed=2))
     store = ChunkStore(str(tmp_path / "chunks"))
     rec = fs_calls(store.cas)
-    rec.fail_at[failing] = (17, OSError(errno.ENOSPC, "no space"))
+    name, k = {"open": ("open", 9), "payload": ("write", 7),
+               "records": ("write", 8)}[failing]
+    rec.fail_at[name] = (k, OSError(errno.ENOSPC, "no space"))
     with pytest.raises(OSError) as err:
         store.index_layer(path, chunks)
     assert err.value.errno == errno.ENOSPC
-    stored = store_tree(store.cas.root)
-    assert stored.pop("_tmp/") == (0, b"")        # staging drained
-    stored = {rel: data for rel, (_, data) in stored.items()
-              if not rel.endswith("/")}           # an empty shard is no chunk
+    assert store_tree(store.cas.root)["_tmp/"] == (0, b"")
+    bare = cas_mod.CASDir(store.cas.root)
+    stored = bare.keys()
     assert 0 < len(stored) < len(chunks)
-    for rel, data in stored.items():
-        assert hashlib.sha256(data).hexdigest() == os.path.basename(rel)
+    for h in stored:
+        assert hashlib.sha256(bare.read(h)).hexdigest() == h
     # What the failed call left is put right by the next one.
     rec.fail_at.clear()
     store.index_layer(path, chunks)
-    assert len(store_tree(store.cas.root)) == len(chunks) + 1
+    assert sorted(cas_mod.CASDir(store.cas.root).keys()) == sorted(
+        {h for _, _, h in chunks})
     assert store.coverage(chunks) == 1.0
 
 
@@ -1090,23 +1133,33 @@ def test_index_layer_holds_the_entry_cap_and_pins(tmp_path):
     assert not keys & {h for _, _, h in first[1][1:]}
 
 
+def _held_by(root: str) -> dict[str, bytes]:
+    """What a CAS directory holds, asked of the owner of its layout:
+    name -> bytes for every entry a bare handle walks."""
+    from makisu_tpu.storage import cas as cas_mod
+    bare = cas_mod.CASDir(root)
+    return {name: bare.read(name) for name, _, _ in bare.walk()}
+
+
 def test_index_layer_store_equals_the_one_file_a_chunk_layout(
         tmp_path, store_tree):
-    """The listing, bytes and modes the parent's put-by-put path left
-    for the same layer, built here with hashlib: ``<aa>/<hex>`` files
-    of mode 0600 holding the chunk's bytes, an empty ``_tmp/``."""
+    """The names and bytes the parent's put-by-put path left for the
+    same layer, built here with hashlib and read through the layout's
+    owner; on disk, segments and an empty ``_tmp/``, no file a chunk,
+    and the bytes of the chunks plus 56 a record."""
     import hashlib
     pieces = _pieces(70, seed=7)
     pieces += pieces[:5]                           # repeats change nothing
     path, chunks = _layer(tmp_path, pieces)
     store = ChunkStore(str(tmp_path / "chunks"))
     store.index_layer(path, chunks)
-    golden = {"_tmp/": (0, b"")}
-    for piece in pieces:
-        h = hashlib.sha256(piece).hexdigest()
-        golden[os.path.relpath(cas_entry_path(store.cas.root, h),
-                               store.cas.root)] = (0o600, piece)
-    assert store_tree(store.cas.root) == golden
+    golden = {hashlib.sha256(piece).hexdigest(): piece for piece in pieces}
+    assert _held_by(store.cas.root) == golden
+    tree = store_tree(store.cas.root)
+    assert tree.pop("_tmp/") == (0, b"")
+    assert 2 <= len(tree) <= 2 * ChunkStore.INGEST_WRITERS
+    assert sum(len(data) for _, data in tree.values()) == sum(
+        len(piece) + 56 for piece in golden.values())
 
 
 # -- index_layer's one block pass (PR 31) ------------------------------------
@@ -1208,18 +1261,17 @@ def test_index_layer_one_pass_equals_the_parents_loop(
         metrics.CHUNK_INGEST, "result") == want_ingest
     assert registry.counter_by_label(
         "makisu_chunks_indexed_total", "result") == {}
-    assert rec.calls["isfile"] == want_stats
-    assert rec.calls["rename"] == len(want_added)
+    # The parent's loop paid a stat where nobody had looked; a store
+    # whose entries are all in segments answers those from memory.
+    assert want_stats >= 0 and rec.calls["isfile"] == 0
+    assert rec.calls["rename"] == 0
+    assert bool(rec.calls["write"]) == bool(want_added)
     if memo_kind == "all_true":
         assert stats["ingest_window"] == 0 and rec.total() == 0
     else:
         assert stats["ingest_window"] >= 1
-    golden = {os.path.relpath(cas_entry_path(store.cas.root, h),
-                              store.cas.root): (0o600, by_digest[h])
-              for h in stored | set(want_added)}
-    tree = store_tree(store.cas.root)
-    tree.pop("_tmp/", None)                    # empty, once a chunk is put
-    assert tree == golden
+    assert _held_by(store.cas.root) == {
+        h: by_digest[h] for h in stored | set(want_added)}
 
 
 def _spoil(path: str, how: str) -> None:
@@ -1453,7 +1505,7 @@ def test_publish_fed_from_the_pass_equals_the_read_back(
         assert [row[0] for row in doc["chunks"]] == [
             h for _, _, h in chunks]
         trees[route] = (_serve_tree(store_tree, storage),
-                        store_tree(store.cas.root))
+                        _held_by(store.cas.root))
     assert trees["fed"] == trees["read_back"]
     packs = [rel for rel in trees["fed"][0] if rel.startswith("zpacks/")]
     assert len(packs) > (3 if state == "over_three_packs" else 1)
@@ -1487,8 +1539,10 @@ def test_publish_reads_no_chunk_file_the_pass_sliced(
         mgr.wait_for_push()
     finally:
         metrics.reset_build_registry(token)
-    assert rec.calls["open_read"] == len(stored)
-    assert rec.calls["rename"] == len(chunks) - len(stored)
+    # A stored chunk is a segment entry: its read is one pread.
+    assert rec.calls["open_read"] == 0
+    assert rec.calls["pread"] == len(stored)
+    assert rec.calls["rename"] == 0 and rec.calls["write"] >= 2
     from_store = float(sum(n for _, n, _ in stored))
     want = {"pass": float(sum(n for _, n, _ in chunks)) - from_store}
     if stored:
@@ -1546,7 +1600,7 @@ def test_publish_is_abandoned_where_the_pass_fails(
     if failing in ("truncated", "crc"):
         _spoil(blob, failing)
     elif failing == "writer_oserror":
-        rec.fail_at["rename"] = (70, OSError(errno.ENOSPC, "no space"))
+        rec.fail_at["write"] = (6, OSError(errno.ENOSPC, "no space"))
     else:
         at = 2 if failing == "lying_digest_early" else 90
         chunks[at] = (chunks[at][0], chunks[at][1], "ab" * 32)

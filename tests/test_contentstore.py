@@ -311,3 +311,53 @@ def test_unbudgeted_store_never_evicts(tmp_path):
     assert cstore.maybe_evict() is None
     for fp in fps:
         assert os.path.isfile(_chunk_path(storage, fp))
+
+
+# -- chunks in segments (PR 49) ----------------------------------------------
+
+
+def test_demote_and_refetch_over_chunks_in_segments(tmp_path):
+    """The budget evictor and tier refetch work through the layout's
+    owner, so a store whose chunks are segment entries demotes (read,
+    then delete: tombstones, and the emptied segments go) and promotes
+    them back (``put``: segment entries again) byte for byte."""
+    payloads = [bytes([65 + i]) * (900 + 300 * i) for i in range(12)]
+    storage, store, doc, fps, _ = _publish(tmp_path, payloads)
+    chunks_root = os.path.join(storage, "chunks")
+    bare = cas_mod.CASDir(chunks_root)
+    assert all("@" in bare.where(fp) for fp in fps)
+
+    def on_disk():
+        return sorted(fn for _, _, files in os.walk(chunks_root)
+                      for fn in files)
+    assert len(on_disk()) == 2                   # one segment, its index
+    cstore = contentstore.store_for(storage)
+    total = sum(len(p) for p in payloads)
+    assert cstore.hot_bytes() == total
+    result = cstore.evict(budget_bytes=1)
+    assert result["evicted"] == len(fps)
+    assert result["reasons"].get("demote", 0) == len(fps)
+    assert result["freed_bytes"] == total
+    assert on_disk() == []                       # every entry dead: gone
+    assert cas_mod.CASDir(chunks_root).keys() == []
+    assert cstore.hot_bytes() == 0
+    # The build's handle hears of it at its next refresh, as it would
+    # of another process's evictor.
+    store.reset_fingerprint_memo()
+    assert not any(store.cas.exists(fp) for fp in fps)
+    # Promotion: straight through refetch_chunks, then through a read.
+    lengths = dict(zip(fps, map(len, payloads)))
+    restored = cstore.refetch_chunks(fps[:5], lengths)
+    assert restored == set(fps[:5])
+    fresh = cas_mod.CASDir(chunks_root)
+    assert all(fresh.read(fp) == data
+               for fp, data in list(zip(fps, payloads))[:5])
+    assert all("@" in fresh.where(fp) for fp in fps[:5])
+    triples, off = [], 0
+    for fp, data in zip(fps, payloads):
+        triples.append((off, len(data), fp))
+        off += len(data)
+    assert store.ensure_available(triples)
+    assert [store.get(fp) for fp in fps] == payloads
+    assert cstore.hot_bytes() == total
+    assert len(on_disk()) % 2 == 0 and len(on_disk()) >= 2

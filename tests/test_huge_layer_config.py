@@ -276,8 +276,8 @@ def _held_to_reference(context, b):
 
 
 def _stored_chunks(b):
-    return {name for _, _, names in os.walk(os.path.join(b.storage, "chunks"))
-            for name in names}
+    from makisu_tpu.storage.cas import CASDir
+    return set(CASDir(os.path.join(b.storage, "chunks")).keys())
 
 
 @pytest.fixture(scope="module")

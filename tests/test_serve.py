@@ -374,20 +374,19 @@ def test_corrupt_pack_range_rejected(tmp_path):
     # publish time from the then-healthy CAS, it would otherwise still
     # serve the original bytes — correct, but not this test's
     # scenario: a serving store corrupted across the board).
+    from conftest import cas_entry_path
+    from makisu_tpu.storage.cas import CASDir
     chunk_dir = os.path.join(plane.storage, "chunks")
     flipped = 0
-    for dirpath, _, names in os.walk(chunk_dir):
-        for fname in names:
-            path = os.path.join(dirpath, fname)
-            if not recipe_mod.is_hex_digest(fname) or \
-                    os.path.getsize(path) < 4096:
-                continue
-            with open(path, "r+b") as f:
-                f.seek(100)
-                byte = f.read(1)
-                f.seek(100)
-                f.write(bytes([byte[0] ^ 0xFF]))
-            flipped += 1
+    for fname, size, _ in list(CASDir(chunk_dir).walk()):
+        if not recipe_mod.is_hex_digest(fname) or size < 4096:
+            continue
+        with open(cas_entry_path(chunk_dir, fname), "r+b") as f:
+            f.seek(100)
+            byte = f.read(1)
+            f.seek(100)
+            f.write(bytes([byte[0] ^ 0xFF]))
+        flipped += 1
     assert flipped, "expected chunk files to corrupt"
     zpack_dir = os.path.join(plane.storage, "serve", "zpacks")
     for fname in os.listdir(zpack_dir):

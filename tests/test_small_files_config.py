@@ -279,8 +279,9 @@ def test_build_held_to_the_reference(built, which, count):
 def test_edit_moved_the_layer_and_kept_nearly_every_chunk(built):
     cold, edited = _digests(built["cold"]), _digests(built["edited"])
     assert cold != edited
-    chunks = os.path.join(built["cold"].storage, "chunks")
-    stored = sum(len(names) for _, _, names in os.walk(chunks))
+    from makisu_tpu.storage.cas import CASDir
+    stored = len(CASDir(os.path.join(built["cold"].storage,
+                                     "chunks")).keys())
     assert stored <= built["cold_check"].checked["chunks"] + 8
 
 
@@ -392,3 +393,93 @@ def test_new_reader_reads_a_run_and_nothing_from_an_older_program(
     untraced = _record(tmp_path, False)
     untraced.counters_open = untraced.counters_close = None
     assert read(untraced) is None
+
+
+# -- a storage directory the parent commit wrote (PR 49) --------------------
+
+
+def _as_the_parent_wrote_it(chunks_root):
+    """Rewrite a chunk store into the layout before segments: one file
+    an entry at ``<aa>/<name>``, mode 0600, an empty ``_tmp/``, nothing
+    else. The owner of the layout is asked for every place."""
+    import shutil
+
+    from makisu_tpu.storage.cas import CASDir
+    bare = CASDir(chunks_root)
+    held = {name: bare.read(name) for name in bare.keys()}
+    shutil.rmtree(bare._seg_dir)
+    for name, data in held.items():
+        path = cas_entry_path(chunks_root, name)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "wb") as f:
+            f.write(data)
+        os.chmod(path, 0o600)
+    return held
+
+
+@pytest.mark.parametrize("count", sorted(check.LIMITS))
+def test_storage_the_parent_wrote_builds_dedups_and_checks(
+        parents_storage, count):
+    assert parents_storage["edited"].exit_code == 0
+    checker = parents_storage["check"]
+    assert checker.found[count] == 0, checker.notes
+    assert checker.checked["chunks"] > 200
+
+
+@pytest.fixture(scope="module")
+def parents_storage(tmp_path_factory):
+    """A cold build, its chunk store rewritten as the parent commit's
+    ``write_many`` left one, then an edit built on top of it."""
+    from makisu_tpu.storage.cas import CASDir
+    work = str(tmp_path_factory.mktemp("parentstore"))
+    context = _scaled_context()
+    reference = cells._load_module(
+        os.path.join(PERFBENCH, "reference", CONFIG["reference"] + ".py"))
+    ctx = os.path.join(work, "ctx")
+    gen.make_tree(context, ctx, 3)
+    storage = os.path.join(work, "storage")
+    cold, _ = _build(work, ctx, "cold", "tpu", storage)
+    assert cold.exit_code == 0
+    chunks_root = os.path.join(storage, "chunks")
+    out = {"held": _as_the_parent_wrote_it(chunks_root)}
+    out["tree"] = sorted(
+        os.path.relpath(os.path.join(parent, fn), chunks_root)
+        for parent, _, files in os.walk(chunks_root) for fn in files)
+    gen.apply_edit(EDIT["edit"], context, ctx,
+                   np.random.default_rng([3, 0, 7]), "000001")
+    out["edited"], out["report"] = _build(work, ctx, "edited", "tpu",
+                                          storage)
+    out["check"] = _held_to_reference(reference, context, out["edited"])
+    out["chunks_root"] = chunks_root
+    out["after"] = CASDir(chunks_root)
+    return out
+
+
+def test_storage_the_parent_wrote_keeps_its_files_and_gains_a_segment(
+        parents_storage):
+    """Dedup found the parent's loose files (a handful of new chunks,
+    not a layer's worth), left every one of them where it was, and
+    stored the new ones in a segment beside them."""
+    held, after = parents_storage["held"], parents_storage["after"]
+    new = set(after.keys()) - set(held)
+    assert 1 <= len(new) <= 8
+    assert all(after.read(name) == data for name, data in held.items())
+    root = parents_storage["chunks_root"]
+    tree = sorted(os.path.relpath(os.path.join(parent, fn), root)
+                  for parent, _, files in os.walk(root) for fn in files)
+    added = [rel for rel in tree if rel not in parents_storage["tree"]]
+    assert len(parents_storage["tree"]) == len(held)
+    # One segment and its index; a second pair the rare time another
+    # writer of the build (a session shard's put) held the first.
+    assert len(added) in (2, 4)
+    assert all(rel.endswith((".seg", ".idx")) for rel in added)
+    assert all("@" in after.where(name) for name in new)
+    ingest = {s["labels"]["result"]: s["value"] for s in
+              parents_storage["report"]["counters"][metrics.CHUNK_INGEST]}
+    assert ingest["written"] == len(new)
+    assert ingest["present"] >= len(held) - 8
+    created = {s["labels"]["kind"]: s["value"] for s in
+               parents_storage["report"]["counters"][
+                   metrics.CHUNK_STORE_FILES_CREATED]}
+    assert created == {"segment": len(added) / 2, "index": len(added) / 2,
+                       "loose": 0.0}
