@@ -112,9 +112,11 @@ class FeedClock:
     """Where one stream's device feed spends the host's time, and what
     it moves: monotonic seconds per stage (``gear_dispatch``,
     ``gear_readback``, ``host_cut``, ``sha_dispatch``, ``sha_readback``,
-    ``service_wait``) and bytes per crossing, kept in plain numbers and
-    flushed once (``ChunkSession.finish``, or per batch by the shared
-    hash service) into ``makisu_commit_stage_busy_seconds{stage}`` and
+    ``service_wait``, and beside it ``service_submit``, the part of it
+    blocked in ``HashService.submit``) and bytes per crossing, kept in
+    plain numbers and flushed once (``ChunkSession.finish``, or per
+    batch by the shared hash service) into
+    ``makisu_commit_stage_busy_seconds{stage}`` and
     ``makisu_device_transfer_bytes_total{direction,stage}``. A stage
     entered inside another (a lane dispatch during the host cut) is
     charged to itself alone. Each stage is also a bare annotation on the
@@ -750,7 +752,10 @@ class ChunkSession:
                 # lands: the digest streams out while this build is
                 # still writing its tar, not when finish() collects it.
                 fut.add_done_callback(self._notify_resolved)
-            self._clock.add("service_wait", time.monotonic() - t0)
+            blocked = time.monotonic() - t0
+            self._clock.add("service_wait", blocked)
+            # Beside it, not nested in it: the backpressure alone.
+            self._clock.seconds[metrics.SERVICE_SUBMIT_STAGE] += blocked
             self._service_pending.append((offset, len(data), fut))
             return
         for b in self._batchers:

@@ -39,6 +39,9 @@ _FILL_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0,
 # concurrency (or a longer linger) would pay.
 _OCCUPANCY_BUCKETS = (0.05, 0.1, 0.25, 0.5, 0.75, 0.9, 1.0)
 
+# Sessions in a batch: 1 is a build riding alone.
+_OWNER_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0)
+
 class HashService:
     """Cross-build chunk-hash batcher. Thread-safe; one per process."""
 
@@ -148,6 +151,9 @@ class HashService:
         if len(owners) > 1:
             self.cross_build_batches += 1
             metrics.counter_add("makisu_hash_cross_build_batches_total")
+        if owners:
+            metrics.observe(metrics.HASH_BATCH_OWNERS, len(owners),
+                            buckets=_OWNER_BUCKETS)
         # NOTE: the dispatcher thread runs outside any build's context,
         # so these land in the process-global registry only — correct:
         # a batch can mix several builds' chunks.

@@ -74,6 +74,17 @@ DEVICE_PADDING_WASTE = "makisu_device_padding_waste_bytes_total"
 # lane buffers alone (h2d/sha here); it stays for /healthz.
 DEVICE_TRANSFER_BYTES = "makisu_device_transfer_bytes_total"
 
+# Sessions (builds) whose chunks one batch of the shared hash service
+# carried (chunker/service.py), observed where the cross-build counter
+# is: sum over count is the mean number of builds a device program
+# served.
+HASH_BATCH_OWNERS = "makisu_hash_batch_owners"
+# The stage of COMMIT_STAGE_BUSY that holds the seconds a build was
+# blocked in HashService.submit on a full queue (chunker/cdc.py:_emit):
+# counted beside ``service_wait``, which holds them too and the wait
+# for the futures.
+SERVICE_SUBMIT_STAGE = "service_submit"
+
 # Chunks through the chunk store's ingest (cache/chunks.py: index_layer
 # and put), by result: written (appended to a segment of the CAS),
 # present (a probe found it stored), raced (a digest index_layer had

@@ -123,9 +123,10 @@ def _entry_and_cell_in_benchmark():
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
         == (CELL, "farm-concurrent", "churn", 1)
     assert len(cell["why"]) <= 200
-    # PR 41 added a configuration and a cell after this one, PR 47 too.
-    assert len(BENCHMARK["configs"]) == 8
-    assert len(BENCHMARK["workloads"]) == 10
+    # PR 41 added a configuration and a cell after this one, PRs 47
+    # and 50 too.
+    assert len(BENCHMARK["configs"]) == 9
+    assert len(BENCHMARK["workloads"]) == 11
     assert all(w["chips"] == 1 for w in BENCHMARK["workloads"])
     # The mix is `farm-churn`'s, as it stands.
     assert (CHURN["count"], CHURN["prime_cold"], CHURN["prime_rebuilds"],
@@ -154,13 +155,15 @@ def _cell_reports_what_farm_churn_reports_and_the_six():
 def _new_metrics_list_the_three_farm_cells():
     names = [m["name"] for m in BENCHMARK["per_layer"]]
     # PR 40 added two after, PR 41 four, PR 42 one, PR 45 one, PR 47
-    # four, PR 48 one, PR 49 one.
+    # four, PR 48 one, PR 49 one, PR 50 four.
     assert names[47:53] == list(NEW_READERS)
-    assert len(names) == 67
+    assert len(names) == 71
     by_name = {m["name"]: m for m in BENCHMARK["per_layer"]}
     for name in NEW_READERS:
+        # PR 50's cell joined the lists of this one.
         assert by_name[name]["workloads"] == [
-            "farm-churn", "farm-unchanged", CELL], name
+            "farm-churn", "farm-unchanged", CELL,
+            "monorepo-farm-churn"], name
     admission = by_name["queue_wait_p50_s"]["layer"]
     assert [(by_name[n]["layer"], by_name[n]["moves"], by_name[n]["better"],
              by_name[n]["source"], by_name[n]["unit"])
@@ -178,7 +181,8 @@ def _new_metrics_list_the_three_farm_cells():
     # joined, and it joined every list `farm-churn` is on.
     for m in BENCHMARK["per_layer"][:47] + BENCHMARK["end_to_end"]:
         listed = [w for w in m.get("workloads", ())
-                  if w not in ("run-steps-edit", "huge-layer-pgzip-edit")]
+                  if w not in ("run-steps-edit", "huge-layer-pgzip-edit",
+                               "monorepo-farm-churn")]
         if CELL in listed:
             assert listed[-1] == CELL, m["name"]
         if "workloads" in m:

@@ -169,15 +169,17 @@ def _new_metrics_list_their_cells():
     # PR 47 appended the same image's pgzip cell to what this one
     # reports.
     pgzip = "huge-layer-pgzip-edit"
+    # PR 50 appended its cell: eight such commits at once.
+    farm = "monorepo-farm-churn"
     four = [CELL, "monorepo-cold", "monorepo-edit", "small-files-edit",
-            "multi-stage-small-edit", pgzip]
+            "multi-stage-small-edit", pgzip, farm]
     assert cells_of["commit_mb_per_s"] == four
     assert cells_of["compress_s_per_build"] == four
     assert cells_of["feed_host_s_per_build"] == four
     # PR 38 appended its cell: 32 sinks' rings at once.
     assert cells_of["process_rss_peak_mb"] == [
         CELL, "monorepo-cold", "small-files-edit", "farm-concurrent-churn",
-        pgzip]
+        pgzip, farm]
     # Put at the end of the list at their PR, together and in order;
     # later PRs append after them.
     names = [m["name"] for m in BENCHMARK["per_layer"]]
@@ -185,7 +187,7 @@ def _new_metrics_list_their_cells():
     assert names[first:first + 4] == list(NEW_READERS)
     assert cells_of["chunk_probe_hit_pct"] == [
         CELL, "monorepo-edit", "monorepo-cold", "small-files-edit",
-        "multi-stage-small-edit", pgzip]
+        "multi-stage-small-edit", pgzip, farm]
     # PR 33: what the builder waits for the sink's compressor thread,
     # read where ``compress_s_per_build`` is, appended last.
     assert cells_of["compress_wait_s_per_build"] == four

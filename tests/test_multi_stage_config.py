@@ -268,24 +268,28 @@ def _new_metrics_list_their_cells():
         assert by_name[name]["workloads"] == [CELL] + later * (
             name != NEW_READERS[0])
         assert by_name[name]["layer"].startswith("on-disk stage tree")
+    # PR 50's cell commits a layer, waits out an mtime and ends a
+    # session eight at a time.
+    farm = ["monorepo-farm-churn"]
     assert by_name["layer_commits_per_build"]["workloads"] \
-        == [CELL, "monorepo-cold", "monorepo-edit"] + later
+        == [CELL, "monorepo-cold", "monorepo-edit"] + later + farm
     assert by_name["mtime_wait_slept_per_build"]["workloads"] \
-        == [CELL, "monorepo-edit"] + later
+        == [CELL, "monorepo-edit"] + later + farm
     assert by_name["session_finish_s_per_build"]["workloads"] == [
         CELL, "farm-churn", "farm-unchanged", "monorepo-edit",
-        "small-files-edit", "farm-concurrent-churn"] + later
+        "small-files-edit", "farm-concurrent-churn"] + later + farm
     for name in NEW_READERS:
         assert by_name[name]["moves"] == "build_p50_s"
         assert by_name[name]["better"] == "lower"
     # Appended, never inserted: the cell was the last of every list it
-    # joined, and only PR 38's, PR 41's and PR 47's cells have been
-    # appended after it.
+    # joined, and only PR 38's, PR 41's, PR 47's and PR 50's cells have
+    # been appended after it.
     for m in BENCHMARK["per_layer"][:first] \
             + BENCHMARK["per_layer"][first + 6:] + BENCHMARK["end_to_end"]:
         listed = [w for w in m.get("workloads", ())
                   if w not in ("farm-concurrent-churn", "run-steps-edit",
-                               "huge-layer-pgzip-edit")]
+                               "huge-layer-pgzip-edit",
+                               "monorepo-farm-churn")]
         if CELL in listed:
             assert listed[-1] == CELL, m["name"]
 

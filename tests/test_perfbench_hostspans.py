@@ -329,8 +329,8 @@ def test_prefetch_metrics_list_their_cells():
     assert [m["name"] for m in per_layer[53:55]] == [
         "sink_prefetch_ready_pct", "read_wait_s_per_build"]
     # PR 41 appended four, PR 42 one, PR 45 one, PR 47 four, PR 48
-    # one, PR 49 one
-    assert len(per_layer) == 67
+    # one, PR 49 one, PR 50 four
+    assert len(per_layer) == 71
     commit = by_name["tar_write_s_per_build"]
     for name, unit, better in (("sink_prefetch_ready_pct", "%", "higher"),
                                ("read_wait_s_per_build", "s", "lower")):
@@ -340,7 +340,8 @@ def test_prefetch_metrics_list_their_cells():
         # farm-unchanged.
         assert m["workloads"] == [
             "small-files-edit", "monorepo-edit", "monorepo-cold",
-            "farm-churn", "farm-concurrent-churn", "multi-stage-small-edit"]
+            "farm-churn", "farm-concurrent-churn", "multi-stage-small-edit",
+            "monorepo-farm-churn"]
         assert set(m["workloads"]) < set(commit["workloads"])
         assert (m["layer"], m["moves"], m["source"]) == (
             commit["layer"], "build_p50_s", "program_counter")
@@ -359,21 +360,23 @@ def test_service_metrics_list_their_cells():
     # PR 47's cell joined the lists of its pair but the five that are
     # to be retired, ``idle_unspanned_pct`` among them.
     pgzip = "huge-layer-pgzip-edit"
-    every = by_name["idle_unspanned_pct"]["workloads"] + [pgzip]
+    # PR 50's cell likewise.
+    farm = "monorepo-farm-churn"
+    every = by_name["idle_unspanned_pct"]["workloads"] + [pgzip, farm]
     for name in ("unspanned_s_per_build", "service_s_per_build",
                  "request_overhead_s_per_build"):
         assert by_name[name]["workloads"] == every, name
     assert by_name["build_setup_s_per_build"]["workloads"] == [
         "farm-churn", "farm-unchanged", "monorepo-edit",
-        "multi-stage-small-edit", "farm-concurrent-churn"]
+        "multi-stage-small-edit", "farm-concurrent-churn", farm]
     assert by_name["session_begin_s_per_build"]["workloads"] == [
         "farm-churn", "farm-unchanged", "monorepo-edit", "small-files-edit",
         "huge-layer-edit", "multi-stage-small-edit", "farm-concurrent-churn",
-        "run-steps-edit", pgzip]
+        "run-steps-edit", pgzip, farm]
     assert by_name["wait_for_push_s_per_build"]["workloads"] == [
-        "monorepo-cold", "monorepo-edit", "huge-layer-edit", pgzip]
+        "monorepo-cold", "monorepo-edit", "huge-layer-edit", pgzip, farm]
     assert by_name["save_manifest_s_per_build"]["workloads"] == [
-        "farm-churn", "farm-unchanged", "farm-concurrent-churn"]
+        "farm-churn", "farm-unchanged", "farm-concurrent-churn", farm]
     for name in ("unspanned_s_per_build", "service_s_per_build",
                  "request_overhead_s_per_build", "build_setup_s_per_build",
                  "session_begin_s_per_build", "wait_for_push_s_per_build",
@@ -397,10 +400,11 @@ def test_every_new_metric_has_its_reader_and_its_cells():
              "run-steps-edit"]
     assert cells_of["idle_unspanned_pct"] == every
     assert cells_of["sync_os_sync_s_per_build"] \
-        == every + ["huge-layer-pgzip-edit"]
+        == every + ["huge-layer-pgzip-edit", "monorepo-farm-churn"]
     assert cells_of["chunk_index_s_per_build"] == [
         "monorepo-cold", "monorepo-edit", "small-files-edit",
-        "huge-layer-edit", "multi-stage-small-edit", "huge-layer-pgzip-edit"]
+        "huge-layer-edit", "multi-stage-small-edit", "huge-layer-pgzip-edit",
+        "monorepo-farm-churn"]
     for name in cells_of:
         assert os.path.exists(os.path.join(READERS, name + ".py")), name
 
@@ -408,7 +412,8 @@ def test_every_new_metric_has_its_reader_and_its_cells():
 @pytest.mark.parametrize("cell", [
     "monorepo-cold", "farm-churn", "monorepo-edit", "farm-unchanged",
     "small-files-edit", "huge-layer-edit", "multi-stage-small-edit",
-    "farm-concurrent-churn", "run-steps-edit", "huge-layer-pgzip-edit"])
+    "farm-concurrent-churn", "run-steps-edit", "huge-layer-pgzip-edit",
+    "monorepo-farm-churn"])
 def test_every_cell_finds_its_files_and_a_reader_for_each_metric(cell):
     """What ``run.py`` looks up by name for a cell: configuration, mix,
     reference, and a reader for every metric either kind of run
@@ -481,7 +486,7 @@ def test_resolve_reuse_metric_lists_the_cells_that_report_the_request():
                  "better": "higher", "source": "program_counter",
                  "layer": request["layer"], "moves": "build_p50_s",
                  "workloads": request["workloads"]}
-    assert len(m["workloads"]) == 10  # PR 47 appended its cell
+    assert len(m["workloads"]) == 11  # PRs 47 and 50 appended theirs
 
 
 # -- PR 48: where a new pack's bytes came from --------------------------------
@@ -535,4 +540,4 @@ def test_recipe_bytes_from_pass_is_appended_under_the_chunk_store():
         "better": "higher", "source": "program_counter",
         "layer": index["layer"], "moves": "build_p50_s",
         "workloads": ["monorepo-cold", "monorepo-edit", "farm-churn",
-                      "farm-concurrent-churn"]}
+                      "farm-concurrent-churn", "monorepo-farm-churn"]}
