@@ -6,9 +6,9 @@ applyLayer:133, push/pullCacheLayer:151-181).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
-import tarfile
 
 from makisu_tpu import tario
 from makisu_tpu.context import BuildContext
@@ -131,26 +131,28 @@ class BuildNode:
         # local chunks (no blob transfer, no gzip inflate at all).
         with metrics.span("apply_layer", digest=hex_digest[:12],
                           untar=modify_fs), \
-                metrics.span("apply_layer.inflate"):
+                metrics.span("apply_layer.inflate") as inflate, \
+                contextlib.ExitStack() as stack:
             open_tar = getattr(cache_mgr, "open_layer_tar", None)
             if open_tar is not None:
-                with open_tar(pair) as gz:
-                    with tarfile.open(fileobj=gz, mode="r|") as tf:
-                        memfs.update_from_tar(
-                            tf, untar=modify_fs, record=record,
-                            chain_key=hex_digest)
+                stream = stack.enter_context(open_tar(pair))
             else:
-                with self.ctx.image_store.layers.open(hex_digest) as f:
-                    with tario.gzip_reader(f) as gz:
-                        with tarfile.open(fileobj=gz, mode="r|") as tf:
-                            memfs.update_from_tar(
-                                tf, untar=modify_fs, record=record,
-                                chain_key=hex_digest)
+                stream = stack.enter_context(tario.gzip_reader(
+                    stack.enter_context(
+                        self.ctx.image_store.layers.open(hex_digest))))
+            with tario.layer_tar(stream) as tf:
+                memfs.update_from_tar(tf, untar=modify_fs, record=record,
+                                      chain_key=hex_digest)
+            # decompress calls the blob took: tens a layer, a block
+            # each; 0 where the stream was not gzip (the chunk route).
+            reads = getattr(stream, "reads", 0)
+            inflate.set(reads=reads)
         if record is not None:
             session.replay_store(memo_key, record)
         # After the span: a failed application must not count.
         metrics.counter_add(metrics.CACHED_LAYERS_APPLIED_TOTAL)
         metrics.counter_add(metrics.LAYER_REPLAY_TOTAL, result="inflate")
+        metrics.counter_add(metrics.LAYER_INFLATE_READS_TOTAL, reads)
 
     def pull_cache_layer(self, cache_mgr) -> bool:
         """Try to prefetch this node's layer. A miss or failure returns

@@ -19,7 +19,6 @@ from __future__ import annotations
 import collections
 import hashlib
 import os
-import zlib
 
 import json
 
@@ -104,67 +103,9 @@ def open_served_chunk(hex_digest: str, roots=None):
     return None
 
 
-class _Inflated:
-    """A gzip blob's inflated stream, read forward a block at a time
-    (index_layer's one pass). zlib inflates each block with the GIL
-    free and verifies a member's trailer (CRC32, ISIZE) as it reads the
-    member's last byte; ``take`` copies out only the spans it is asked
-    for, and ``finish`` inflates whatever is left, so every byte is
-    inflated once whoever wanted it. What follows a member's trailer is
-    read as ``GzipFile`` reads it: zero padding is passed over, anything
-    else is a further member of the same stream (or fails as one)."""
-
-    READ = 1 << 20   # compressed bytes a read
-    BLOCK = 4 << 20  # inflated bytes a block, at most
-
-    def __init__(self, raw) -> None:
-        self._raw = raw
-        self._z = zlib.decompressobj(31)
-        self._block = b""
-        self._start = 0  # stream offset of _block[0]
-
-    def _next(self) -> bool:
-        """Step to the next block; False at the stream's end."""
-        self._start += len(self._block)
-        self._block = b""
-        while not self._block:
-            if self._z.eof:
-                pending = self._z.unused_data.lstrip(b"\0")
-                while not pending:
-                    pending = self._raw.read(self.READ)
-                    if not pending:
-                        return False
-                    pending = pending.lstrip(b"\0")
-                self._z = zlib.decompressobj(31)
-            else:
-                pending = (self._z.unconsumed_tail
-                           or self._raw.read(self.READ))
-                if not pending:
-                    raise EOFError("layer blob ended before its gzip "
-                                   "stream's end-of-stream marker")
-            self._block = self._z.decompress(pending, self.BLOCK)
-        return True
-
-    def take(self, offset: int, length: int) -> bytes:
-        """The stream's bytes [offset, offset + length). Offsets never
-        go back; blocks wholly before ``offset`` are dropped unsliced."""
-        end = offset + length
-        parts: list[bytes] = []
-        while offset < end:
-            while self._start + len(self._block) <= offset:
-                if not self._next():
-                    raise ValueError(f"layer stream ended at "
-                                     f"{self._start}, chunk needs {end}")
-            lo = offset - self._start
-            parts.append(self._block[lo:lo + end - offset])
-            offset += len(parts[-1])
-        return parts[0] if len(parts) == 1 else b"".join(parts)
-
-    def finish(self) -> int:
-        """Inflate to the blob's end; returns the stream's length."""
-        while self._next():
-            pass
-        return self._start
+# index_layer's one pass reads the blob through the tree's one block
+# inflater (its tests patch READ and BLOCK under this name).
+_Inflated = tario.BlockInflater
 
 
 def plan_pack_runs(rows, missing, gap=None, whole_fraction=None,

@@ -478,9 +478,8 @@ class MemFS:
 
     def update_from_tar_path(self, source: str, untar: bool) -> Layer:
         with open(source, "rb") as f:
-            with tario.gzip_reader(f) as gz:
-                with tarfile.open(fileobj=gz, mode="r|") as tf:
-                    return self.update_from_tar(tf, untar)
+            with tario.gzip_reader(f) as gz, tario.layer_tar(gz) as tf:
+                return self.update_from_tar(tf, untar)
 
     def update_from_tar(self, tf: tarfile.TarFile, untar: bool,
                         record: list | None = None,

@@ -140,15 +140,14 @@ class FromStep(BuildStep):
                 log.info("applying FROM layer %s", descriptor.digest.hex())
                 with ctx.image_store.layers.open(
                         descriptor.digest.hex()) as f:
-                    with tario.gzip_reader(f) as gz:
-                        import tarfile
-                        with tarfile.open(fileobj=gz, mode="r|") as tf:
-                            # chain_key keeps the applied-chain
-                            # identity intact, so cached layers ABOVE
-                            # this base stay replay-memoizable.
-                            ctx.memfs.update_from_tar(
-                                tf, untar=modify_fs,
-                                chain_key=descriptor.digest.hex())
+                    with tario.gzip_reader(f) as gz, \
+                            tario.layer_tar(gz) as tf:
+                        # chain_key keeps the applied-chain identity
+                        # intact, so cached layers ABOVE this base
+                        # stay replay-memoizable.
+                        ctx.memfs.update_from_tar(
+                            tf, untar=modify_fs,
+                            chain_key=descriptor.digest.hex())
         except BaseException:
             self._abandon_pull()
             raise

@@ -12,10 +12,14 @@ same compression level always produce identical gzip bytes.
 
 from __future__ import annotations
 
+import contextlib
 import gzip
 import hashlib
+import io
 import os
+import sys
 import tarfile
+import zlib
 from typing import BinaryIO
 
 
@@ -420,13 +424,133 @@ def gzip_writer(fileobj: BinaryIO, level: int | None = None,
     return gz
 
 
+class BlockInflater:
+    """A gzip blob's inflated stream, read forward a block at a time:
+    the one reader of a gzip layer blob (a cached layer's parse,
+    index_layer's pass). zlib inflates each block with the GIL free and
+    verifies a member's trailer (CRC32, ISIZE) as it reads the member's
+    last byte; a read slices the block it falls in and never inflates
+    less than a block, a forward seek drops whole blocks unsliced, and
+    ``finish`` inflates whatever is left, so every byte is inflated
+    once whoever wanted it. What follows a member's trailer is read as
+    ``GzipFile`` reads it: zero padding is passed over, anything else
+    is a further member of the same stream (or fails as one).
+
+    File-like as far as ``tarfile`` mode ``"r:"`` asks: ``read``,
+    ``tell``, ``seek`` forward, and back by at most ``TAIL`` bytes
+    before the current block (``TarFile.next`` steps back one byte to
+    see that a member's body was all there)."""
+
+    READ = 1 << 20   # compressed bytes a read
+    BLOCK = 4 << 20  # inflated bytes a block, at most
+    TAIL = 512       # bytes kept of the block before
+
+    def __init__(self, raw) -> None:
+        self._raw = raw
+        self._z = zlib.decompressobj(31)
+        self._block = b""
+        self._start = 0  # stream offset of _block[0]
+        self._tail = b""
+        self._pos = 0
+        self.reads = 0   # decompress calls made
+
+    def _next(self) -> bool:
+        """Step to the next block; False at the stream's end."""
+        if self._block:
+            self._tail = self._block[-self.TAIL:]
+        self._start += len(self._block)
+        self._block = b""
+        while not self._block:
+            if self._z.eof:
+                pending = self._z.unused_data.lstrip(b"\0")
+                while not pending:
+                    pending = self._raw.read(self.READ)
+                    if not pending:
+                        return False
+                    pending = pending.lstrip(b"\0")
+                self._z = zlib.decompressobj(31)
+            else:
+                pending = (self._z.unconsumed_tail
+                           or self._raw.read(self.READ))
+                if not pending:
+                    raise EOFError("layer blob ended before its gzip "
+                                   "stream's end-of-stream marker")
+            self.reads += 1
+            try:
+                self._block = self._z.decompress(pending, self.BLOCK)
+            except zlib.error as e:
+                # The class GzipFile gave a bad header or trailer.
+                raise gzip.BadGzipFile(str(e)) from e
+        return True
+
+    def read(self, n: int = -1) -> bytes:
+        """Up to ``n`` bytes from the position on (all that is left
+        without ``n``); fewer only at the stream's end."""
+        if n is None or n < 0:
+            n = sys.maxsize
+        parts: list[bytes] = []
+        while n > 0:
+            lo = self._pos - self._start
+            if lo < 0:
+                piece = self._tail[lo:][:n]
+            else:
+                if lo >= len(self._block):
+                    if not self._next():
+                        break
+                    continue
+                piece = self._block[lo:lo + n]
+            parts.append(piece)
+            self._pos += len(piece)
+            n -= len(piece)
+        return parts[0] if len(parts) == 1 else b"".join(parts)
+
+    def tell(self) -> int:
+        return self._pos
+
+    def seek(self, offset: int, whence: int = io.SEEK_SET) -> int:
+        """Forward for free: the blocks passed over are dropped by the
+        next read. Back as far as the kept tail reaches."""
+        if whence != io.SEEK_SET:
+            raise io.UnsupportedOperation("seek from the start only")
+        if offset < self._start - len(self._tail):
+            raise io.UnsupportedOperation(
+                f"seek back to {offset}, block starts at {self._start}")
+        self._pos = offset
+        return offset
+
+    def take(self, offset: int, length: int) -> bytes:
+        """The stream's bytes [offset, offset + length). Offsets never
+        go back; blocks wholly before ``offset`` are dropped unsliced."""
+        self.seek(offset)
+        data = self.read(length)
+        if len(data) < length:
+            raise ValueError(f"layer stream ended at {self._pos}, "
+                             f"chunk needs {offset + length}")
+        return data
+
+    def finish(self) -> int:
+        """Inflate to the blob's end; returns the stream's length."""
+        while self._next():
+            pass
+        return self._start
+
+    def close(self) -> None:
+        self._block = self._tail = b""
+
+    def __enter__(self) -> "BlockInflater":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
 def gzip_reader(fileobj: BinaryIO):
-    """Layer-blob reader: gzip by default, transparently zstd when the
-    blob's frame magic says so (zstd-published base images reach every
-    apply/extract/diff site through this one function). Unseekable
-    inputs keep the legacy gzip-only path — every layer-blob call site
-    hands in a real file, and a wrong guess on an exotic stream must
-    not break it."""
+    """Layer-blob reader: gzip by default (``BlockInflater``),
+    transparently zstd when the blob's frame magic says so
+    (zstd-published base images reach every apply/extract/diff site
+    through this one function). Unseekable inputs keep the legacy
+    gzip-only path — every layer-blob call site hands in a real file,
+    and a wrong guess on an exotic stream must not break it."""
     try:
         pos = fileobj.tell()
         head = fileobj.read(4)
@@ -436,7 +560,23 @@ def gzip_reader(fileobj: BinaryIO):
     from makisu_tpu.utils import zstdio
     if zstdio.is_zstd(head):
         return zstdio.ZstdReader(fileobj)
-    return gzip.GzipFile(fileobj=fileobj, mode="rb")
+    return BlockInflater(fileobj)
+
+
+@contextlib.contextmanager
+def layer_tar(stream):
+    """The parse of a layer's tar stream, as every apply site opens it.
+    Over a ``BlockInflater`` the members are read from its blocks
+    (mode ``"r:"``: a body nobody unpacks is sought past, one that is
+    unpacked is sliced from the block), and once the last member is
+    parsed the blob is inflated to its end, so every member's trailer
+    is verified before the apply counts. Any other stream (zstd, the
+    chunk route) is read forward in mode ``"r|"``."""
+    blocks = isinstance(stream, BlockInflater)
+    with tarfile.open(fileobj=stream, mode="r:" if blocks else "r|") as tf:
+        yield tf
+    if blocks:
+        stream.finish()
 
 
 def is_similar_header(h: tarfile.TarInfo, nh: tarfile.TarInfo,

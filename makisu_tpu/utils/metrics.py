@@ -191,6 +191,11 @@ SESSION_DIRTY_PATHS = "makisu_session_dirty_paths_total"
 # having read the tree, builder/stage.py). The first two sum to
 # makisu_cached_layers_applied_total.
 LAYER_REPLAY_TOTAL = "makisu_layer_replay_total"
+# decompress calls the inflates of result="inflate" above took
+# (tario.BlockInflater.reads: one a block of 4 MiB inflated or 1 MiB
+# of gzip read; builder/node.py, one add an apply): ÷ that result is
+# the calls a layer.
+LAYER_INFLATE_READS_TOTAL = "makisu_layer_inflate_reads_total"
 # Entries of each committed layer as its tar holds them, kind=file|dir|
 # symlink|other|whiteout (snapshot/memfs.py, added once a layer).
 LAYER_ENTRIES_TOTAL = "makisu_layer_entries_total"
