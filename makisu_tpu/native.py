@@ -38,6 +38,7 @@ _NATIVE_DIR = os.environ.get("MAKISU_TPU_NATIVE_DIR") or os.path.join(
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libpgzip.so")
 _LSK_PATH = os.path.join(_NATIVE_DIR, "liblayersink.so")
 _GEAR_PATH = os.path.join(_NATIVE_DIR, "libgear.so")
+_TSK_PATH = os.path.join(_NATIVE_DIR, "libthreadstate.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -63,6 +64,7 @@ _LIB_SOURCES = {
                         "sha256_common.h"),
     "libgear.so": ("gear.cpp", "gear_simd.cpp", "sha_ni.cpp",
                    "gear_isa.h", "sha256_common.h"),
+    "libthreadstate.so": ("threadstate.cpp",),
 }
 
 
@@ -435,6 +437,74 @@ def gear_scan_positions(buf, table, mask: int):
     return np.concatenate([
         out[s * slot_cap:s * slot_cap + int(counts[s])]
         for s in range(nslots)])
+
+
+_DOUBLE_P = ctypes.POINTER(ctypes.c_double)
+_TSK_SYMBOLS = {
+    "tsk_table": (_DOUBLE_P, []),
+    "tsk_vitals": (_DOUBLE_P, []),
+    "tsk_slots": (ctypes.c_int, []),
+    "tsk_probe": (None, []),
+    "tsk_calibrate": (ctypes.c_int, [ctypes.c_int, ctypes.c_int,
+                                     ctypes.c_int]),
+    "tsk_watch": (ctypes.c_int, [ctypes.c_int]),
+    "tsk_unwatch": (None, [ctypes.c_int, ctypes.c_int]),
+    "tsk_test_refuse": (None, [ctypes.c_int]),
+}
+
+# A row of the reader's table (native/threadstate.cpp: enum Col) and
+# its vitals row (enum Vital), in order.
+TSK_COLS = ("tid", "run", "runqueue", "system", "running",
+            "interpreter_lock", "wait", "fs", "socket", "other")
+TSK_VITALS = ("source", "schedstat", "lock_ref", "sightings", "beats",
+              "reads", "busy_seconds")
+
+
+class ThreadStateReader:
+    """libthreadstate.so: the kernel's word on where each watched
+    thread is (native/threadstate.cpp). ``table`` and ``vitals`` are
+    the library's own memory, read without a call; the reads of
+    ``/proc/self/task`` happen on the library's thread, never on the
+    caller's."""
+
+    def __init__(self, lib: ctypes.CDLL) -> None:
+        self.lib = lib
+        # Watching and unwatching take a mutex for microseconds: called
+        # with the interpreter lock kept, so the sampler's beat hands
+        # the lock to nobody (a hand-over costs it a turn of the queue
+        # it is there to measure).
+        held = ctypes.PyDLL(lib._name)
+        self.watch, self.unwatch = held.tsk_watch, held.tsk_unwatch
+        for fn in (self.watch, self.unwatch):
+            fn.restype, fn.argtypes = _TSK_SYMBOLS[fn.__name__]
+        self.table = ctypes.cast(lib.tsk_table(), ctypes.POINTER(
+            ctypes.c_double * (lib.tsk_slots() * len(TSK_COLS)))).contents
+        self.vitals = ctypes.cast(lib.tsk_vitals(), ctypes.POINTER(
+            ctypes.c_double * len(TSK_VITALS))).contents
+        lib.tsk_probe()
+
+    def row(self, slot: int) -> list[float]:
+        base = slot * len(TSK_COLS)
+        return self.table[base:base + len(TSK_COLS)]
+
+    def vital(self, name: str) -> float:
+        return self.vitals[TSK_VITALS.index(name)]
+
+
+_tsk: ThreadStateReader | None = None
+_tsk_failed = False
+
+
+def thread_state_reader() -> ThreadStateReader | None:
+    """The process's reader, or ``None`` where the library cannot be
+    built or loaded."""
+    global _tsk, _tsk_failed
+    with _lock:
+        if _tsk is None and not _tsk_failed:
+            lib = _open(_TSK_PATH, "tsk_abi_version", 1, _TSK_SYMBOLS)
+            _tsk = ThreadStateReader(lib) if lib is not None else None
+            _tsk_failed = _tsk is None
+        return _tsk
 
 
 class LayerSinkHandle:

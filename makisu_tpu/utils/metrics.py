@@ -316,6 +316,29 @@ SPAN_SELF_CPU_SECONDS = "makisu_span_self_cpu_seconds_total"
 # ``service_seconds``. One add a request.
 WORKER_BUILD_THREAD_CPU_SECONDS = \
     "makisu_worker_build_thread_cpu_seconds_total"
+# The CPU seconds (``time.thread_time()``) of each span directly under
+# a structural span, on the thread that opened it: the clock reads the
+# structural span's self time is made of, let out span by span. One add
+# a close, label span=<name>; a span deeper down reads no clock and
+# adds nothing.
+SPAN_THREAD_CPU_SECONDS = "makisu_span_thread_cpu_seconds_total"
+# Where the threads that own an open span were, by the kernel's word
+# (native/threadstate.cpp through utils/resources.py; global registry
+# only; label span=<the thread's innermost open span>). Sampled every
+# 10 ms, state=running (on a CPU or waiting for one) | interpreter_lock
+# (a futex on the interpreter lock's words) | wait (any other futex, a
+# poll, a child, a sleep) | fs (blocked in a path, directory,
+# descriptor or data call) | socket | other; and exact, from the
+# scheduler's own clocks, kind=run (on a CPU) | runqueue (runnable with
+# no CPU) | system (the part of run in the kernel: from `stat` alone,
+# where a sandboxed kernel books a file-system call it handles on the
+# caller's thread). THREAD_STATE_SOURCE says what the reader could read: 2
+# `syscall` with the lock's address found, 1 the state letters of
+# `stat` (or `syscall` with no address: interpreter_lock stays 0), 0 no
+# reader.
+THREAD_STATE_SECONDS = "makisu_thread_state_seconds_total"
+THREAD_SCHED_SECONDS = "makisu_thread_sched_seconds_total"
+THREAD_STATE_SOURCE = "makisu_thread_state_source"
 # What a request asked for more than once, by how it was answered
 # (cli.py:parse_args and main, worker/server.py:run_build,
 # utils/pathutils.py:real_path; one add a question): kind=parse (the
@@ -495,10 +518,11 @@ class Span:
         self.thread_cpu_self_seconds: float | None = None
         # Filled by the resource sampler (utils/resources.py) while the
         # span is open: peak process RSS observed, and the CPU seconds
-        # charged to this span while it was an open LEAF. None = never
-        # sampled (sampler off, or span shorter than the interval).
+        # its thread ran (the scheduler's clock) while this span was
+        # the innermost one open on it. None = never sampled (sampler
+        # off, span shorter than the interval, no thread-state reader).
         self.peak_rss: int | None = None
-        self.cpu_seconds = 0.0
+        self.cpu_seconds: float | None = None
         self.late_attrs: dict[str, str] = {}
 
     def set(self, **attrs: Any) -> None:
@@ -532,11 +556,13 @@ class Span:
             out["attrs"] = dict(self.attrs)
         if self.error:
             out["error"] = self.error
+        resources = {}
         if self.peak_rss is not None:
-            out["resources"] = {
-                "peak_rss_bytes": int(self.peak_rss),
-                "cpu_seconds": round(self.cpu_seconds, 6),
-            }
+            resources["peak_rss_bytes"] = int(self.peak_rss)
+        if self.cpu_seconds is not None:
+            resources["cpu_seconds"] = round(self.cpu_seconds, 6)
+        if resources:
+            out["resources"] = resources
         if self.children:
             out["children"] = [c.to_dict() for c in self.children]
         return out
@@ -812,23 +838,25 @@ def open_span_snapshot() -> list[dict[str, Any]]:
     return out
 
 
-def attribute_resource_sample(rss_bytes: int, cpu_delta: float) -> None:
-    """Charge one resource sample to the open spans: every open span
-    tracks the peak RSS observed while it was open; the CPU burned
-    since the previous sample is split evenly across the open LEAF
-    spans (concurrent builds share the process's CPU — an even split
-    is the honest default). Called by ``utils/resources.py``."""
-    spans = snapshot_concurrent(_open_spans.values())
-    if not spans:
-        return
-    parent_ids = {s.parent_id for s in spans}
-    leaves = [s for s in spans if s.span_id not in parent_ids]
-    share = cpu_delta / len(leaves) if leaves else 0.0
-    for s in spans:
+def attribute_resource_sample(rss_bytes: int) -> None:
+    """Every open span tracks the peak RSS observed while it was open.
+    Called by ``utils/resources.py``, which charges CPU seconds thread
+    by thread (:func:`open_spans_by_thread`)."""
+    for s in snapshot_concurrent(_open_spans.values()):
         if s.peak_rss is None or rss_bytes > s.peak_rss:
             s.peak_rss = rss_bytes
-    for s in leaves:
-        s.cpu_seconds += share
+
+
+def open_spans_by_thread() -> dict[int, list[Span]]:
+    """The open spans of each thread that owns one (``Span.thread``, a
+    ``threading`` ident), in the order they opened: the last is the
+    thread's innermost. Empty while nothing is open. Lock-free, as
+    every reader of ``_open_spans``."""
+    out: dict[int, list[Span]] = {}
+    if _open_spans:
+        for s in snapshot_concurrent(_open_spans.values()):
+            out.setdefault(s.thread, []).append(s)
+    return out
 
 
 def counter_add(name: str, value: float = 1.0, **labels: Any) -> None:
@@ -888,7 +916,9 @@ def span(name: str, *, structural: bool = False,
     and onto the profiler's host timeline once a backend is up.
     ``structural`` is the opener's word that the span names a place
     and no operation: its self time goes to ``SPAN_SELF_SECONDS`` and,
-    on the thread's CPU clock, to ``SPAN_SELF_CPU_SECONDS``."""
+    on the thread's CPU clock, to ``SPAN_SELF_CPU_SECONDS``; a span
+    directly under one adds its own CPU seconds to
+    ``SPAN_THREAD_CPU_SECONDS``."""
     reg = active_registry()
     parent = _current_span.get()
     if parent is None or parent.registry is not reg:
@@ -929,6 +959,8 @@ def span(name: str, *, structural: bool = False,
                         cpu - s.child_cpu_seconds, 0.0)
                     counter_add(SPAN_SELF_CPU_SECONDS,
                                 s.thread_cpu_self_seconds, span=name)
+                else:
+                    counter_add(SPAN_THREAD_CPU_SECONDS, cpu, span=name)
             if structural:
                 counter_add(SPAN_SELF_SECONDS, s.self_seconds, span=name)
             events.emit("span_end", name=name, span_id=s.span_id,

@@ -14,9 +14,16 @@ right before it died. One daemon sampler thread per process:
   worker's ``/metrics`` scrape sees;
 - attributes each sample to the currently-open spans
   (``metrics.attribute_resource_sample``): every open span tracks its
-  peak RSS, and the CPU burned between samples is charged to the open
-  *leaf* spans (split evenly across concurrent leaves), so
-  ``makisu-tpu report`` can print peak-RSS/CPU per build phase;
+  peak RSS, so ``makisu-tpu report`` can print it per build phase;
+- on a faster beat of its own (:data:`STATE_BEAT`, working only while
+  a span is open) charges what the kernel says of each thread that
+  owns an open span (:class:`ThreadStates`: running, queueing for the
+  interpreter lock, in a wait the program wrote, in a file-system
+  call; on a CPU, runnable without one) to the thread's innermost
+  open span: ``makisu_thread_state_seconds_total{span, state}``,
+  ``makisu_thread_sched_seconds_total{span, kind}`` and the span's
+  ``resources.cpu_seconds``. The reading is done by a native thread
+  (native/threadstate.cpp); nothing runs on a building thread;
 - keeps a bounded recent trajectory (:func:`trajectory`) that the
   flight recorder folds into diagnostic bundles — the "was RSS
   climbing toward the OOM?" record.
@@ -35,6 +42,7 @@ import threading
 import time
 from typing import Any
 
+from makisu_tpu import native
 from makisu_tpu.utils import metrics
 
 try:
@@ -44,6 +52,13 @@ except ImportError:  # pragma: no cover - non-POSIX
 
 DEFAULT_INTERVAL = 0.5          # seconds between samples
 TRAJECTORY_KEEP = 240           # recent samples kept for bundles (~2min)
+STATE_BEAT = 0.05               # seconds between thread-state beats
+CALIBRATE_MS = 60               # the two helpers spin this long, once
+
+# The columns of the native reader's table after a row's tid, by the
+# counter each grows: exact from the scheduler's clocks, then sampled.
+SCHED_KINDS = native.TSK_COLS[1:4]
+STATES = native.TSK_COLS[4:]
 
 _PAGE_SIZE = 4096
 try:
@@ -117,6 +132,149 @@ def read_sample() -> dict[str, Any]:
     return sample
 
 
+class ThreadStates:
+    """Where the threads that own an open span are, charged to each
+    thread's innermost open span.
+
+    The kernel is asked by the native reader's own thread
+    (``native.ThreadStateReader``), which adds to a row of shared
+    memory per watched thread; :meth:`beat` reads those rows and adds
+    each one's growth to the global registry's counters. It watches a
+    thread from the first beat that finds a span open on it to the
+    first that finds none. Without the library there is no reader and
+    nothing is charged: ``makisu_thread_state_source`` reads 0."""
+
+    def __init__(self) -> None:
+        self._reader = None
+        self._resolved = False
+        # threading ident -> [slot, native id, the row as last read,
+        # the name of the innermost span then]
+        self._watched: dict[int, list] = {}
+
+    def _resolve(self):
+        """The reader, made on first need: the library loaded, the
+        source probed, the interpreter lock's address found (two
+        helper threads spin in pure Python while the library looks at
+        what they block on: the caller is inside a foreign call and
+        holds no lock), the outcome logged once."""
+        if self._resolved:
+            return self._reader
+        self._resolved = True
+        from makisu_tpu.utils import logging as log
+        reader = native.thread_state_reader()
+        if reader is not None and not reader.vital("sightings"):
+            done = threading.Event()
+
+            def spin() -> None:
+                while not done.is_set():
+                    pass
+            # The helpers belong to the process, not to a build: they
+            # open no span and write no log.
+            # check: allow(ctx-propagation)
+            helpers = [threading.Thread(target=spin, daemon=True,
+                                        name=f"tsk-calibrate-{i}")
+                       for i in range(2)]
+            for t in helpers:
+                t.start()
+            try:
+                reader.lib.tsk_calibrate(helpers[0].native_id,
+                                         helpers[1].native_id, CALIBRATE_MS)
+            finally:
+                done.set()
+                for t in helpers:
+                    t.join()
+        self._reader = reader
+        source = self.publish_source()
+        ref = int(reader.vital("lock_ref")) if reader is not None else 0
+        log.info("thread states: source=%s lock_ref=%s beat=10ms "
+                 "sightings=%d", source, hex(ref) if ref else "unset",
+                 int(reader.vital("sightings")) if reader is not None else 0)
+        if reader is not None:
+            g = metrics.global_registry()
+            # A kind the source cannot give has no series: absent, not 0.
+            given = {"run": True, "runqueue": reader.vital("schedstat"),
+                     "system": reader.vital("source") == 1}
+            for kind in SCHED_KINDS:
+                if given[kind]:
+                    g.counter_add(metrics.THREAD_SCHED_SECONDS, 0.0,
+                                  span="build", kind=kind)
+        return reader
+
+    def publish_source(self) -> str:
+        """Set ``makisu_thread_state_source`` from what the reader last
+        opened; returns the source's name."""
+        reader = self._reader
+        code = int(reader.vital("source")) if reader is not None else 0
+        name = ("none", "stat", "syscall")[code]
+        if code == 2 and not reader.vital("lock_ref"):
+            code = 1
+        if self._resolved:
+            metrics.global_registry().gauge_set(
+                metrics.THREAD_STATE_SOURCE, code)
+        return name
+
+    def beat(self) -> None:
+        by_thread = metrics.open_spans_by_thread()
+        if not by_thread and not self._watched:
+            return
+        reader = self._resolve()
+        if reader is None:
+            return
+        g = metrics.global_registry()
+        for ident, watch in list(self._watched.items()):
+            slot, tid, last, name = watch
+            row = reader.row(slot)
+            spans = by_thread.get(ident)
+            gone = row[0] != tid  # the reader freed the slot: no thread
+            if gone or not spans:
+                # A thread that ended took its last span's tail with it
+                # (the reader's samples stop where the thread does); one
+                # that lives on without a span is idle, and nobody's.
+                # (Spans under a thread that is gone: its ident has a
+                # new owner, watched below.)
+                reader.unwatch(slot, tid)
+                del self._watched[ident]
+                if not gone or name is None:
+                    continue
+            else:
+                name = watch[3] = spans[-1].name
+                for s in spans:
+                    if s.cpu_seconds is None:
+                        s.cpu_seconds = 0.0
+                spans[-1].cpu_seconds += max(row[1] - last[1], 0.0)
+            watch[2] = row
+            for column, now, was in zip(native.TSK_COLS[1:], row[1:],
+                                        last[1:]):
+                if now <= was:
+                    continue
+                if column in SCHED_KINDS:
+                    g.counter_add(metrics.THREAD_SCHED_SECONDS, now - was,
+                                  span=name, kind=column)
+                else:
+                    g.counter_add(metrics.THREAD_STATE_SECONDS, now - was,
+                                  span=name, state=column)
+        unwatched = by_thread.keys() - self._watched.keys()
+        if unwatched:
+            native_ids = {t.ident: t.native_id
+                          for t in threading.enumerate()}
+            for ident in unwatched:
+                tid = native_ids.get(ident)
+                slot = reader.watch(tid) if tid else -1
+                if slot >= 0:
+                    self._watched[ident] = [slot, tid, reader.row(slot), None]
+
+    def release(self) -> None:
+        """Unwatch everything (the native reader parks)."""
+        for slot, tid, _, _ in self._watched.values():
+            self._reader.unwatch(slot, tid)
+        self._watched.clear()
+
+    def vitals(self) -> dict[str, float] | None:
+        reader = self._reader
+        return (dict(zip(native.TSK_VITALS, reader.vitals))
+                if reader is not None else None)
+
+
 class ResourceSampler:
     """Background sampler; one per process (see :func:`ensure_started`).
 
@@ -128,8 +286,8 @@ class ResourceSampler:
         self.interval = max(float(interval), 0.05)
         self._trajectory: "collections.deque[dict]" = \
             collections.deque(maxlen=TRAJECTORY_KEEP)
-        self._last_cpu: float | None = None
         self._peak_rss = 0
+        self.thread_states = ThreadStates()
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
 
@@ -155,11 +313,8 @@ class ResourceSampler:
                         sample["io_read_bytes"])
             g.gauge_set(metrics.PROCESS_IO_WRITE_BYTES,
                         sample["io_write_bytes"])
-        cpu_delta = 0.0
-        if self._last_cpu is not None:
-            cpu_delta = max(sample["cpu_seconds"] - self._last_cpu, 0.0)
-        self._last_cpu = sample["cpu_seconds"]
-        metrics.attribute_resource_sample(sample["rss_bytes"], cpu_delta)
+        metrics.attribute_resource_sample(sample["rss_bytes"])
+        self.thread_states.publish_source()
         return sample
 
     def trajectory(self) -> list[dict]:
@@ -168,14 +323,27 @@ class ResourceSampler:
         return metrics.snapshot_concurrent(self._trajectory)
 
     def _run(self) -> None:
-        while not self._stop.wait(self.interval):
+        # The process sample rides every tenth beat at the default
+        # interval; a longer interval, as many beats as fit it.
+        every = max(round(self.interval / STATE_BEAT), 1)
+        beats = 0
+        while not self._stop.wait(STATE_BEAT):
+            beats += 1
             try:
-                self.sample_once()
+                self.thread_states.beat()
+                if beats % every == 0:
+                    self.sample_once()
             except Exception:  # noqa: BLE001 - sampling never fails a build
                 pass
 
     def start(self) -> None:
         if self._thread is None or not self._thread.is_alive():
+            # Every state's series exists from here on, so a reader of
+            # the counter tells 0 from absent.
+            g = metrics.global_registry()
+            for state in STATES:
+                g.counter_add(metrics.THREAD_STATE_SECONDS, 0.0,
+                              span="build", state=state)
             self._stop.clear()
             self._thread = threading.Thread(
                 target=self._run, name="resource-sampler", daemon=True)
@@ -186,6 +354,7 @@ class ResourceSampler:
         if self._thread is not None:
             self._thread.join(timeout=2.0)
             self._thread = None
+        self.thread_states.release()
 
 
 # -- process singleton ------------------------------------------------------
@@ -222,6 +391,14 @@ def trajectory() -> list[dict]:
     module-global read (atomic under the GIL) is harmless here."""
     sampler = _sampler
     return sampler.trajectory() if sampler is not None else []
+
+
+def thread_state_vitals() -> dict[str, float] | None:
+    """The native reader's own numbers (source, the lock's address and
+    its sightings, beats, reads, seconds busy), or ``None`` where the
+    process has no reader (yet)."""
+    sampler = _sampler
+    return sampler.thread_states.vitals() if sampler is not None else None
 
 
 def stop() -> None:

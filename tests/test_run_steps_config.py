@@ -242,10 +242,13 @@ def _cell_reports_its_metrics():
     mine = {m["name"] for m in cell.per_layer()}
     # PR 42's one: what the unpack under the root asks the disk; PR
     # 45's one: what a request asks about itself more than once; PR
-    # 51's one: the decompress calls a cached layer's inflate takes.
+    # 51's one: the decompress calls a cached layer's inflate takes;
+    # PR 52's two that every cell reports: where the kernel says the
+    # build's threads were.
     assert mine == set(NEW_READERS) | set(JOINED) | {
         "untar_probe_free_pct", "request_resolve_reuse_pct",
-        "apply_inflate_reads_per_layer"}
+        "apply_inflate_reads_per_layer", "fs_blocked_s_per_build",
+        "thread_state_coverage_pct"}
     for name in mine:
         assert callable(cell.reader(name))
 
