@@ -5,11 +5,22 @@ the builds run: ``chiprun -- python3 benchmarks/fs_calls.py``."""
 import collections
 import os
 import shutil
+import sys
 import tempfile
 import time
 
+sys.path.insert(
+    0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 FILES, DIRS, BATCH = 2000, 100, 250  # a batch stays under any fd limit
+WIDE = 300  # entries of the one directory read whole (PERF.md §5, PR 53)
 DATA = b"x" * 14000
+
+
+def _scandir_lstat(path):
+    with os.scandir(path) as it:
+        return [(entry.name, entry.stat(follow_symlinks=False))
+                for entry in it]
 
 
 def _mkdir_again(path):
@@ -52,6 +63,29 @@ def main():
         paths)
     lap("utime_path", lambda p: os.utime(p, (2000, 2000)), paths)
     lap("lstat_hit", os.lstat, paths)
+    # The same lstat asked of the open directory, which walks one name
+    # where the path walks five components.
+    for d in dirs:
+        dir_fd = os.open(d, os.O_RDONLY | os.O_DIRECTORY)
+        lap("fstatat_hit", lambda name: os.stat(
+            name, dir_fd=dir_fd, follow_symlinks=False), os.listdir(d))
+        os.close(dir_fd)
+    # A directory of WIDE files read whole: scandir and an lstat a
+    # child from Python, and the native reader's one call.
+    wide = f"{root}/wide"
+    os.mkdir(wide)
+    for k in range(WIDE):
+        with open(f"{wide}/f{k:03d}", "wb") as f:
+            f.write(DATA)
+    lap(f"scandir_lstat_{WIDE}", _scandir_lstat, [wide] * 20)
+    from makisu_tpu import native
+    reader = native.dir_reader()
+    if reader is not None:
+        lap(f"native_dir_lstat_{WIDE}", lambda p: reader.read(p, True),
+            [wide] * 20)
+        lap(f"native_dir_types_{WIDE}", lambda p: reader.read(p, False),
+            [wide] * 20)
+    shutil.rmtree(wide)
     lap("isdir_file", os.path.isdir, paths)
     lap("mkdir_eexist", _mkdir_again, parents)
     lap("open_wb_trunc_close", lambda p: open(p, "wb").close(), paths)

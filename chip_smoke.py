@@ -220,12 +220,13 @@ print("NATIVE " + json.dumps({
     "libpgzip.so": bool(native.pgzip_available()),
     "liblayersink.so": bool(native.layersink_available()),
     "libgear.so": bool(native.gear_scan_available()),
+    "libdirscan.so": native.dir_reader() is not None,
     "isa": native.isa_route()}))
 """
 
 
 def stage_native(work: str, env: dict) -> dict:
-    """Build the three libraries from the committed sources. The copy
+    """Build the libraries from the committed sources. The copy
     may carry another machine's git-ignored .so/.o files, and the
     loader's own make is mtime-driven and swallows a failure, after
     which the whole commit path would switch to Python without a word

@@ -27,6 +27,7 @@ from __future__ import annotations
 import ctypes
 import itertools
 import os
+import struct
 import subprocess
 import threading
 
@@ -39,6 +40,7 @@ _LIB_PATH = os.path.join(_NATIVE_DIR, "libpgzip.so")
 _LSK_PATH = os.path.join(_NATIVE_DIR, "liblayersink.so")
 _GEAR_PATH = os.path.join(_NATIVE_DIR, "libgear.so")
 _TSK_PATH = os.path.join(_NATIVE_DIR, "libthreadstate.so")
+_DSC_PATH = os.path.join(_NATIVE_DIR, "libdirscan.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -65,6 +67,7 @@ _LIB_SOURCES = {
     "libgear.so": ("gear.cpp", "gear_simd.cpp", "sha_ni.cpp",
                    "gear_isa.h", "sha256_common.h"),
     "libthreadstate.so": ("threadstate.cpp",),
+    "libdirscan.so": ("dirscan.cpp",),
 }
 
 
@@ -505,6 +508,96 @@ def thread_state_reader() -> ThreadStateReader | None:
             _tsk = ThreadStateReader(lib) if lib is not None else None
             _tsk_failed = _tsk is None
         return _tsk
+
+
+_DSC_SYMBOLS = {
+    "dsc_read": (ctypes.c_int, [
+        ctypes.c_char_p, ctypes.c_int, ctypes.c_void_p, ctypes.c_size_t,
+        ctypes.c_void_p, ctypes.c_size_t, _U64_P]),
+    "dsc_test_before_lstat": (None, [ctypes.c_void_p]),
+}
+
+# A child's record where its lstat was asked for (native/dirscan.cpp):
+# the 19 fields of ``os.stat_result`` in its own order.
+_DSC_RECORD = struct.Struct("=qQQ4q3q3d3q2qQ")
+_DSC_SMALL, _DSC_RANGE = -1, -2
+# What the first call of a directory brings: room for 512 children and
+# 32 bytes of name each. A larger directory is read again, once, into
+# buffers of the size the first call reported.
+_DSC_FIRST_CHILDREN, _DSC_FIRST_NAMES = 512, 16384
+_DT_DIR = 4
+
+
+class DirReader:
+    """libdirscan.so: a directory's names and types, and where asked
+    every child's ``lstat``, by one foreign call with the interpreter
+    lock free (native/dirscan.cpp). Stateless: any thread may call."""
+
+    def __init__(self, lib: ctypes.CDLL) -> None:
+        self.lib = lib
+        self._read = lib.dsc_read
+
+    def read(self, path: str, want_stat: bool) -> list[tuple] | None:
+        """``path``'s children in the directory's own order, unsorted:
+        ``(name, os.stat_result)`` with ``want_stat``, the result equal
+        to ``os.lstat``'s field for field and a child gone before its
+        ``lstat`` left out; else ``(name, is a directory itself)`` from
+        the type bits. Names are decoded as ``os.scandir`` decodes them. Raises the ``OSError`` the
+        directory's ``open`` or read gave; ``None`` where a child's time
+        does not fit the record (the caller asks ``os`` itself)."""
+        raw = os.fsencode(path)
+        record = _DSC_RECORD.size if want_stat else 1
+        children, names_cap = _DSC_FIRST_CHILDREN, _DSC_FIRST_NAMES
+        out = (ctypes.c_uint64 * 2)()
+        while True:
+            records = bytearray(children * record)
+            names = bytearray(names_cap)
+            rc = self._read(
+                raw, int(want_stat),
+                (ctypes.c_char * len(records)).from_buffer(records),
+                len(records),
+                (ctypes.c_char * names_cap).from_buffer(names), names_cap,
+                out)
+            if rc != _DSC_SMALL:
+                break
+            # Grown past what the call saw, for a directory that is
+            # growing meanwhile.
+            children, names_cap = out[0] + 64, out[1] + 4096
+            from makisu_tpu.utils import metrics
+            metrics.counter_add(metrics.DIR_READ_REPEATS_TOTAL)
+        if rc == _DSC_RANGE:
+            return None
+        if rc:
+            raise OSError(rc, os.strerror(rc), path)
+        count = out[0]
+        if not count:
+            return []
+        listed = os.fsdecode(bytes(names[:out[1] - 1])).split("\0")
+        if not want_stat:
+            return [(name, d_type == _DT_DIR)
+                    for name, d_type in zip(listed, records)]
+        rows = _DSC_RECORD.iter_unpack(
+            memoryview(records)[:count * record])
+        return [(name, os.stat_result(row))
+                for name, row in zip(listed, rows)]
+
+
+_dsc: DirReader | None = None
+_dsc_failed = False
+
+
+def dir_reader() -> DirReader | None:
+    """The process's directory reader, or ``None`` where the library
+    was not built or is not this tree's."""
+    global _dsc, _dsc_failed
+    if _dsc is not None or _dsc_failed:
+        return _dsc
+    with _lock:
+        if _dsc is None and not _dsc_failed:
+            lib = _open(_DSC_PATH, "dsc_abi_version", 1, _DSC_SYMBOLS)
+            _dsc = DirReader(lib) if lib is not None else None
+            _dsc_failed = _dsc is None
+        return _dsc
 
 
 class LayerSinkHandle:

@@ -18,7 +18,7 @@ import stat as statmod
 import tarfile
 import time
 
-from makisu_tpu import tario
+from makisu_tpu import native, tario
 from makisu_tpu.utils import metrics, mountinfo, pathutils, sysutils
 
 WHITEOUT_PREFIX = ".wh."
@@ -38,19 +38,52 @@ def should_skip(path: str, st: os.stat_result | None,
     return mountinfo.is_mountpoint(path)
 
 
+def _read_dir(path: str, want_stat: bool) -> list[tuple]:
+    """``path``'s children in the directory's own order, from disk:
+    ``(name, lstat)`` with ``want_stat`` (a child gone before its
+    ``lstat`` is left out, as if the listing had run a moment later),
+    else ``(name, is a directory itself and no link to one)`` from the
+    type bits alone.
+
+    Where ``libdirscan.so`` was built the directory is one foreign call
+    with the interpreter lock free from the ``open`` to the last
+    ``lstat``: one hand-back of the lock a directory. The ``scandir``
+    body hands it back at every ``readdir`` and every ``lstat``, two an
+    entry, each a turn in the queue where several builds share the
+    interpreter; it is the route where no library was built and the
+    reference the tests hold the other to."""
+    reader = native.dir_reader()
+    out = reader.read(path, want_stat) if reader is not None else None
+    route = "native" if out is not None else "python"
+    if out is None:
+        out = []
+        with os.scandir(path) as it:
+            for entry in it:
+                if not want_stat:
+                    out.append((entry.name,
+                                entry.is_dir(follow_symlinks=False)))
+                    continue
+                try:
+                    out.append((entry.name,
+                                entry.stat(follow_symlinks=False)))
+                except FileNotFoundError:
+                    continue
+    metrics.counter_add(metrics.DIR_READS_TOTAL, route=route,
+                        stat="1" if want_stat else "0")
+    return out
+
+
 def _list_dir(path: str) -> list[tuple[str, os.stat_result]]:
-    """``path``'s children as ``(name, lstat)``, sorted by name: one
-    ``scandir`` and one ``lstat`` a child. A child gone between the two
-    is left out, as if the listing had run a moment later."""
-    out = []
-    with os.scandir(path) as it:
-        for entry in it:
-            try:
-                out.append((entry.name, entry.stat(follow_symlinks=False)))
-            except FileNotFoundError:
-                continue
+    """``path``'s children as ``(name, lstat)``, sorted by name."""
+    out = _read_dir(path, True)
     out.sort(key=lambda child: child[0])
     return out
+
+
+def child_dirs(path: str) -> list[str]:
+    """The names of ``path``'s children that are directories (type bits
+    only, no ``lstat`` a file), in the directory's own order."""
+    return [name for name, is_dir in _read_dir(path, False) if is_dir]
 
 
 class TreeListing:

@@ -227,6 +227,16 @@ MTIME_WAIT_TOTAL = "makisu_mtime_wait_total"
 # result=listed (scandir and an lstat a child) | replayed (the build's
 # memo of an earlier pass, no file-system call).
 TREE_LISTING_DIRS_TOTAL = "makisu_tree_listing_dirs_total"
+# Directories read from disk (never a replay), one add a directory
+# (snapshot/walk.py: a context listing, any other walk, the session
+# watcher's descent): route=native (libdirscan's one foreign call, the
+# interpreter lock handed back once a directory) | python (``scandir``,
+# the lock handed back at every ``readdir`` and ``lstat``); stat=1 (an
+# ``lstat`` a child) | 0 (names and type bits).
+DIR_READS_TOTAL = "makisu_dir_reads_total"
+# Native directory reads made a second time because the directory held
+# more than the first call's buffers (512 children, 16 KiB of names).
+DIR_READ_REPEATS_TOTAL = "makisu_dir_read_repeats_total"
 SESSION_INVALIDATIONS = "makisu_session_invalidations_total"
 SESSION_RESIDENT_BYTES = "makisu_session_resident_bytes"
 

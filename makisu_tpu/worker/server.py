@@ -877,6 +877,11 @@ class WorkerServer(socketserver.ThreadingMixIn, socketserver.UnixStreamServer):
         if _warm_probe_wanted():
             from makisu_tpu.ops import backend as _backend
             _backend.warm_probe(source="worker")
+        # The directory reader every build lists its context through:
+        # built (a source checkout) and loaded here, not under the
+        # first build's ``copy_checksum``.
+        from makisu_tpu import native as _native
+        _native.dir_reader()
         # Resident build sessions: each server owns ITS OWN manager
         # (bound per build via the session contextvar) so multiple
         # in-process workers — the fleet loadgen topology — model real

@@ -254,9 +254,10 @@ class InotifyWatcher:
             self.close()
 
     def _dirs(self) -> list[str]:
-        """Directory list via a stat-free scandir descent (dirent type
-        bits only): registering watches over a 100k-file tree must not
-        pay a full per-file lstat walk."""
+        """Directory list via a stat-free descent (dirent type bits
+        only, a directory read whole by one call where the native
+        reader is built: ``walk.child_dirs``): registering watches over
+        a 100k-file tree must not pay a full per-file lstat walk."""
         from makisu_tpu.utils import pathutils
         dirs = [self.root]
         stack = [self.root]
@@ -264,17 +265,15 @@ class InotifyWatcher:
         try:
             while stack:
                 cur = stack.pop()
-                with os.scandir(cur) as it:
-                    for entry in it:
-                        if not entry.is_dir(follow_symlinks=False):
-                            continue
-                        if pathutils.is_descendant_of_any(
-                                entry.path, self.blacklist):
-                            continue
-                        dirs.append(entry.path)
-                        if len(dirs) > limit:
-                            return dirs  # caller sees > cap and bails
-                        stack.append(entry.path)
+                for name in walk_mod.child_dirs(cur):
+                    path = os.path.join(cur, name)
+                    if pathutils.is_descendant_of_any(
+                            path, self.blacklist):
+                        continue
+                    dirs.append(path)
+                    if len(dirs) > limit:
+                        return dirs  # caller sees > cap and bails
+                    stack.append(path)
         except OSError:
             return []
         return dirs

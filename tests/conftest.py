@@ -215,6 +215,20 @@ def fs_calls(monkeypatch):
     return install
 
 
+@pytest.fixture(params=["native", "python"])
+def dir_route(request, monkeypatch):
+    """The test runs once a route a directory is read from disk by
+    (``snapshot/walk.py:_read_dir``): with the ``libdirscan.so`` this
+    tree builds, and with none (``dir_reader()`` answers ``None``, as
+    where ``MAKISU_TPU_NATIVE_DIR`` holds no library)."""
+    from makisu_tpu import native
+    if request.param == "python":
+        monkeypatch.setattr(native, "dir_reader", lambda: None)
+    elif native.dir_reader() is None:
+        pytest.skip("libdirscan.so cannot be built or loaded here")
+    return request.param
+
+
 def _store_tree(root: str) -> dict[str, tuple[int, bytes]]:
     """What a store directory holds: relative path -> (mode, bytes) per
     file, ``"<dir>/" -> (0, b"")`` per empty directory."""

@@ -115,9 +115,10 @@ def _entry_and_cell_in_benchmark():
 
 def _four_metrics_appended_with_their_cells():
     per_layer = BENCHMARK["per_layer"]
-    # PR 48 added one after, PR 49 one, PR 50 four, PR 51 one, PR 52 four.
+    # PR 48 added one after, PR 49 one, PR 50 four, PR 51 one, PR 52 four,
+    # PR 53 one.
     assert [m["name"] for m in per_layer[61:65]] == list(NEW_READERS)
-    assert len(per_layer) == 76
+    assert len(per_layer) == 77
     by_name = {m["name"]: m for m in per_layer}
     commit = by_name["tar_write_s_per_build"]["layer"]
     want = {
@@ -133,7 +134,7 @@ def _four_metrics_appended_with_their_cells():
             "name": name, "unit": unit, "better": better, "source": source,
             "layer": commit, "moves": "build_p50_s", "workloads": listed}
     readers = os.listdir(os.path.join(PERFBENCH, "readers"))
-    assert len([r for r in readers if r.endswith(".py")]) == 83
+    assert len([r for r in readers if r.endswith(".py")]) == 84
 
 
 def _cell_joins_the_lists_of_its_pair():

@@ -151,9 +151,9 @@ def _entry_and_cell_in_benchmark():
 
 def _four_metrics_appended_with_their_cell():
     per_layer = BENCHMARK["per_layer"]
-    # PR 51 added one after, PR 52 four.
+    # PR 51 added one after, PR 52 four, PR 53 one.
     assert [m["name"] for m in per_layer[67:71]] == list(NEW_READERS)
-    assert len(per_layer) == 76
+    assert len(per_layer) == 77
     by_name = {m["name"]: m for m in per_layer}
     commit = by_name["tar_write_s_per_build"]["layer"]
     batching = by_name["hash_batch_occupancy_pct"]["layer"]
@@ -171,7 +171,7 @@ def _four_metrics_appended_with_their_cell():
             "name": name, "unit": unit, "better": better, "source": source,
             "layer": layer, "moves": moves, "workloads": [CELL]}
     readers = os.listdir(os.path.join(PERFBENCH, "readers"))
-    assert len([r for r in readers if r.endswith(".py")]) == 83
+    assert len([r for r in readers if r.endswith(".py")]) == 84
     assert metrics.HASH_BATCH_OWNERS == "makisu_hash_batch_owners"
     assert metrics.SERVICE_SUBMIT_STAGE == "service_submit"
 

@@ -329,8 +329,8 @@ def test_prefetch_metrics_list_their_cells():
     assert [m["name"] for m in per_layer[53:55]] == [
         "sink_prefetch_ready_pct", "read_wait_s_per_build"]
     # PR 41 appended four, PR 42 one, PR 45 one, PR 47 four, PR 48
-    # one, PR 49 one, PR 50 four, PR 51 one, PR 52 four
-    assert len(per_layer) == 76
+    # one, PR 49 one, PR 50 four, PR 51 one, PR 52 four, PR 53 one
+    assert len(per_layer) == 77
     commit = by_name["tar_write_s_per_build"]
     for name, unit, better in (("sink_prefetch_ready_pct", "%", "higher"),
                                ("read_wait_s_per_build", "s", "lower")):

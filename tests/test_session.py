@@ -432,3 +432,82 @@ def test_worker_metrics_count_unread_cached_layers(tmp_path, worker):
     assert unread_after_build(3) == base + 4
     (ctx / "top.txt").write_text("top, edited")
     assert unread_after_build(4) == base + 4
+
+
+# -- the watcher's descent, by both routes a directory is read by -----------
+
+
+def _watched_tree(tmp_path):
+    root = tmp_path / "tree"
+    for sub in ("a/deep/deeper", "b", "skip/inner", "c"):
+        (root / sub).mkdir(parents=True)
+    (root / "a" / "f.txt").write_text("f")
+    os.symlink("a", root / "link-to-a")
+    os.mkfifo(root / "b" / "fifo")
+    return root
+
+
+@pytest.mark.parametrize("case", ["whole", "blacklist", "watch-cap"])
+def test_watcher_descends_the_same_directories_by_both_routes(
+        tmp_path, monkeypatch, case):
+    """Directories themselves, never a link to one or a file; what the
+    blacklist names is neither listed nor entered; one past the cap is
+    where the descent stops (the caller sees more than the cap and
+    falls back)."""
+    from makisu_tpu import native
+    root = _watched_tree(tmp_path)
+    blacklist = [str(root / "skip")] if case == "blacklist" else []
+    if case == "watch-cap":
+        monkeypatch.setenv("MAKISU_TPU_SESSION_MAX_WATCHES", "3")
+    watcher = object.__new__(session_mod.InotifyWatcher)
+    watcher.root, watcher.blacklist = str(root), blacklist
+    if native.dir_reader() is None:
+        pytest.skip("libdirscan.so cannot be built or loaded here")
+    by_native = watcher._dirs()
+    with monkeypatch.context() as m:
+        m.setattr(native, "dir_reader", lambda: None)
+        by_python = watcher._dirs()
+    assert by_native == by_python
+    everything = {str(root)} | {str(root / p) for p in (
+        "a", "a/deep", "a/deep/deeper", "b", "skip", "skip/inner", "c")}
+    if case == "whole":
+        assert set(by_native) == everything and by_native[0] == str(root)
+    elif case == "blacklist":
+        assert set(by_native) == everything - {
+            str(root / "skip"), str(root / "skip" / "inner")}
+    else:
+        assert len(by_native) == 4 and set(by_native) <= everything
+
+
+def test_watcher_is_armed_before_the_listings_first_stat(
+        tmp_path, monkeypatch, dir_route):
+    """The order the guarantee "a layer holds the tree's files as they
+    are on disk when the build starts" rests on: the session's watches
+    are placed (its own descent asks type bits, never the listing)
+    before the build's listing takes its first ``lstat``."""
+    ctx = _make_ctx(tmp_path)
+    order = []
+    real_add, real_start = (session_mod.InotifyWatcher._add_watches,
+                            walk_mod.TreeListing._start)
+
+    def add_watches(self):
+        ok = real_add(self)
+        order.append(("armed", time.time_ns()))
+        return ok
+
+    def start(self):
+        if self.started_ns is None:
+            real_start(self)
+            order.append(("first-stat", self.started_ns))
+
+    monkeypatch.setattr(session_mod.InotifyWatcher, "_add_watches",
+                        add_watches)
+    monkeypatch.setattr(walk_mod.TreeListing, "_start", start)
+    _build(tmp_path, ctx, "order/t:1")
+    kinds = [kind for kind, _ in order]
+    assert kinds[0] == "armed" and "first-stat" in kinds
+    assert order[0][1] <= order[kinds.index("first-stat")][1]
+    s = session_mod.manager().peek(str(ctx))
+    if s.watcher is not None and s.watcher.healthy:
+        assert sorted(s.watcher._wd_paths.values()) \
+            == [str(ctx), str(ctx / "src")]
