@@ -17,6 +17,13 @@ How a window counts builds (``traffic["count"]``):
   kind, and a build is counted if it *completed* inside the window, with
   its whole latency from submission.
 
+A lane's ``--root`` (``traffic["root"]``): ``build`` (or no key) names
+a directory of its own every build, made before it and removed after
+it, so no build meets the session the one before it left (``root`` is
+in a session's identity); ``lane`` names one directory for the lane's
+whole run, as a user's builds name one root every time. Nothing else
+about a build differs.
+
 ``os.sync()`` is called by the harness before the window opens and,
 where the mix asks for it, between builds: never inside a timed
 interval (the program's own ``MemFS._sync`` flushes the whole host, so
@@ -131,6 +138,11 @@ class _Lane:
         self.storage_seen = 0
         self.measure_storage = bool(self.traffic.get("measure_storage"))
         self.fresh = bool(self.traffic.get("fresh_storage"))
+        root = self.traffic.get("root", "build")
+        if root not in ("build", "lane"):
+            raise ValueError(f"traffic.root is {root!r}: a lane's builds "
+                             "name one --root a \"build\" or one a \"lane\"")
+        self.one_root = root == "lane"
         # An edit may name the leading share of the lanes it applies to.
         edit = self.traffic.get("edit") or {}
         self.edits = bool(edit) and index < max(
@@ -163,7 +175,8 @@ class _Lane:
             self.context_bytes[slot] += gen.apply_edit(
                 self.traffic["edit"], self.config["context"],
                 self.contexts[slot], self.rng, f"{self.built:06d}")
-        root = os.path.join(self.dir, f"root{self.built}")
+        root = os.path.join(
+            self.dir, "root" if self.one_root else f"root{self.built}")
         os.makedirs(root, exist_ok=True)
         b = Build(lane=self.index, index=self.built, kind=kind,
                   tag=f"perfbench/lane{self.index}:b{self.built}",
@@ -193,7 +206,8 @@ class _Lane:
         self.built += 1
         if kind == "rebuild":
             self.rebuilds_done += 1
-        shutil.rmtree(root, ignore_errors=True)
+        if not self.one_root:
+            shutil.rmtree(root, ignore_errors=True)
         if self.measure_storage:
             size = gen.tree_bytes(b.storage)
             b.storage_growth = size - (0 if self.fresh else self.storage_seen)

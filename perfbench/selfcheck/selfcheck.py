@@ -19,7 +19,13 @@ It proves nothing about the device. It checks that
    (``stored_chunk_byte``: ``stored_chunks_differing``);
 7. where the storages the lane removed stand again with one file in
    them (``remade_storage``) ``correct`` stays true and none of them is
-   among the builds checked.
+   among the builds checked;
+8. a mix with ``"root": "lane"`` meets the session the build before
+   left: end to end and traced, ``correct`` true in both resident cells
+   and in their twins; every counted build of a resident cell is a
+   session hit, the edit's one path is its dirty set and layer a comes
+   from the memo; in the twins, a root a build, all three stay 0 and
+   every build invalidates the session it found.
 """
 
 import json
@@ -218,6 +224,44 @@ def main() -> int:
                   proc.stdout[-300:] if not result
                   else f"remade {remade}, checked "
                        f"{result['check']['sampled']}")
+    # 8. one --root a lane: the session the build before left is met
+    for workload, resident in (("monorepo-edit-resident", True),
+                               ("monorepo-edit", False),
+                               ("farm-unchanged-resident", True),
+                               ("farm-unchanged", False)):
+        rc, result, _, proc = run_inner("--workload", workload, "--seed",
+                                        "44", "--seconds", "5", "--trace", "1")
+        got = {k: v["value"] for k, v in result["metrics"].items()} \
+            if result else {}
+        hits = got.get("session_hits_per_build")
+        dirty = got.get("session_dirty_paths_per_build")
+        memo = got.get("layer_memo_replays_per_build")
+        dropped = got.get("session_invalidations_per_build")
+        # A farm's window cuts builds in flight at both edges, so a
+        # counter's growth per counted build is within a build of 1.
+        one_lane = workload.startswith("monorepo")
+        slack = 0.0 if one_lane else 0.05
+
+        def every_build(x):
+            return x is not None and abs(x - 1.0) <= slack
+        if resident and one_lane:
+            met = every_build(hits) and dropped == 0.0 \
+                and dirty is not None and dirty >= 1.0 and memo == 1.0
+        elif resident:
+            # Only the lane that edits has a dirty set.
+            met = every_build(hits) and dropped == 0.0 \
+                and dirty is not None and dirty > 0
+        else:
+            met = hits == 0.0 and dirty == 0.0 and memo == 0.0 \
+                and every_build(dropped)
+        good &= check(
+            f"tiny {workload} traced: correct, "
+            + ("every build meets its session" if resident
+               else "no build meets a session"),
+            rc == 0 and bool(result) and result["correct"]
+            and result["failed"] == 0 and met,
+            proc.stdout[-300:] if not result else
+            f"hits {hits} dirty {dirty} memo {memo} invalidations {dropped}")
     print("self-check " + ("passed" if good else "FAILED"))
     return 0 if good else 1
 
